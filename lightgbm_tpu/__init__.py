@@ -24,26 +24,14 @@ __version__ = "0.1.0"
 # worker startup is milliseconds, not a backend import.
 _INGEST_WORKER = os.environ.get("LIGHTGBM_TPU_INGEST_WORKER") == "1"
 
-# Persistent XLA compilation cache: the unrolled tree-grower programs take
-# minutes to compile; caching makes every process after the first start hot.
-# TPU-only — CPU AOT artifacts are host-feature-specific and a cache shared
-# across heterogeneous hosts can SIGILL.
+# Persistent XLA compilation cache (compile_cache.py holds the placement
+# rule).  Off when the process is pinned to the CPU: CPU AOT artifacts are
+# host-feature-specific, and the test suite opts in for itself
+# (tests/conftest.py).
 if not _INGEST_WORKER:
-    try:  # pragma: no cover - environment dependent
-        import jax
-
-        if (jax.config.jax_compilation_cache_dir is None
-                and "cpu" not in os.environ.get("JAX_PLATFORMS",
-                                                "").lower()):
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.environ.get(
-                    "LIGHTGBM_TPU_CACHE",
-                    os.path.expanduser("~/.cache/lightgbm_tpu_xla")))
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    if "cpu" not in os.environ.get("JAX_PLATFORMS", "").lower():
+        from . import compile_cache
+        compile_cache.configure()
 
     from . import telemetry
     from .config import OverallConfig, load_config

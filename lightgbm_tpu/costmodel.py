@@ -365,9 +365,16 @@ class Instrumented:
         if compiled is not None:
             try:
                 return compiled(*args)
-            except Exception:
+            except Exception as e:
                 from . import telemetry
+                from .utils import log
                 telemetry.count("costmodel/aot_call_fallback")
+                # counted AND said: the retry through plain jit below
+                # compiles the program a second time
+                log.warning("costmodel: compiled call of %s failed (%s: %s)"
+                            "; retrying through jit"
+                            % (self.name, type(e).__name__,
+                               " ".join(str(e).split())[:300]))
                 # poison the executable for this signature (keep the
                 # record: the static analysis is still right)
                 self._cache[sig] = (rec, None)

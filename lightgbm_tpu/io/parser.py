@@ -120,6 +120,11 @@ class _DelimitedParser(Parser):
         return ParsedData(matrix, labels)
 
 
+# chunks parsed per tier in THIS process (a harness reads it to say which
+# tier a load actually ran on; exec'd ingest workers log theirs)
+TIER_CALLS = {"native": 0, "pandas": 0, "exact": 0}
+
+
 def _parse_delimited_fast(lines: List[str], delimiter: str) -> np.ndarray:
     """Tokenize uniform delimited lines to float64; na/nan → 0.
 
@@ -130,10 +135,13 @@ def _parse_delimited_fast(lines: List[str], delimiter: str) -> np.ndarray:
     if native is not None:
         out = native.parse_delimited(lines, delimiter)
         if out is not None:
+            TIER_CALLS["native"] += 1
             return out
     out = _parse_delimited_pandas(lines, delimiter)
     if out is not None:
+        TIER_CALLS["pandas"] += 1
         return out
+    TIER_CALLS["exact"] += 1
     first_cols = len(lines[0].rstrip("\r\n").split(delimiter))
     out = np.empty((len(lines), first_cols), dtype=np.float64)
     for i, line in enumerate(lines):

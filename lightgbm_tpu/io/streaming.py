@@ -178,16 +178,13 @@ class DeviceRowWriter:
             self._buf = None
             return
         self._stage = None
-        try:
-            self._buf = jax.jit(
-                lambda: jnp.zeros((num_features, self.num_rows),
-                                  dtype.name),
-                out_shardings=self.sharding)()
-        except TypeError:  # older jax without out_shardings
-            self._buf = jax.device_put(
-                jnp.zeros((num_features, self.num_rows), dtype.name),
-                self.sharding)
-        self._update = _update_program(donate=True)
+        self._buf = jax.jit(
+            lambda: jnp.zeros((num_features, self.num_rows), dtype.name),
+            out_shardings=self.sharding)()
+        from jax.sharding import NamedSharding, PartitionSpec
+        self._chunk_sharding = NamedSharding(self.sharding.mesh,
+                                             PartitionSpec())
+        self._update = _update_program(self.sharding)
 
     def append(self, chunk: np.ndarray, start: int) -> None:
         """Dispatch one ``[F, c]`` chunk landing at column ``start``."""
@@ -199,7 +196,8 @@ class DeviceRowWriter:
             self.h2d_bytes += chunk.nbytes
             telemetry.count("ingest/h2d_bytes", chunk.nbytes)
             return
-        dev = self._jax.device_put(np.ascontiguousarray(chunk))
+        dev = self._jax.device_put(np.ascontiguousarray(chunk),
+                                   self._chunk_sharding)
         self._buf = self._update(self._buf, dev, np.int32(start))
         self._pending.append((dev, time.perf_counter()))
         self.h2d_bytes += chunk.nbytes
@@ -242,28 +240,54 @@ class DeviceRowWriter:
         return self._buf
 
 
-# one instrumented update program per donation mode, shared process-wide
-# (jit re-traces per chunk shape: full chunks and the ragged tail are the
-# only two shapes of a load)
+# one instrumented update program per buffer placement, shared
+# process-wide (jit re-traces per chunk shape: full chunks and the ragged
+# tail are the only two shapes of a load)
 _UPDATE_PROGRAMS: dict = {}
 
 
-def _update_program(donate: bool):
-    prog = _UPDATE_PROGRAMS.get(donate)
+def _update_program(sharding):
+    """Donated in-place landing of a replicated ``[F, c]`` chunk at
+    column ``start`` of the ``[F, N]`` buffer placed by ``sharding``.
+
+    One device (or a replicated buffer): a ``dynamic_update_slice``.  A
+    ROW-SHARDED buffer: under ``shard_map`` every device lands only the
+    part of the chunk that falls into its own row block — the same
+    ``dynamic_update_slice``, into its block padded by one chunk width on
+    either side so that a chunk lying partly or wholly outside the block
+    spills into the padding.  Leaving the sharded case to the SPMD
+    partitioner (all-gather the buffer, update, re-slice) took 27 s per
+    200k-row chunk on four v5e chips (PR 24)."""
+    prog = _UPDATE_PROGRAMS.get(sharding)
     if prog is None:
         import jax
         import jax.numpy as jnp
+        from jax.sharding import PartitionSpec
 
         def _update(buf, chunk, start):
             return jax.lax.dynamic_update_slice(
                 buf, chunk, (jnp.int32(0), start))
 
-        jitted = jax.jit(_update,
-                         donate_argnums=(0,) if donate else ())
+        spec = sharding.spec
+        row_axis = spec[1] if len(spec) > 1 else None
+        fn = _update
+        if row_axis is not None and sharding.mesh.shape[row_axis] > 1:
+            def _update_block(block, chunk, start):
+                per, c = block.shape[1], chunk.shape[1]
+                lo = jax.lax.axis_index(row_axis) * per
+                at = jnp.clip(start - lo + c, 0, per + c)
+                padded = jnp.pad(block, ((0, 0), (c, c)))
+                return _update(padded, chunk, at)[:, c:c + per]
+
+            fn = jax.shard_map(
+                _update_block, mesh=sharding.mesh,
+                in_specs=(spec, PartitionSpec(), PartitionSpec()),
+                out_specs=spec)
+        jitted = jax.jit(fn, donate_argnums=(0,), out_shardings=sharding)
         from .. import costmodel
         prog = costmodel.instrument("ingest/update", jitted,
                                     phase="ingest")
-        _UPDATE_PROGRAMS[donate] = prog
+        _UPDATE_PROGRAMS[sharding] = prog
     return prog
 
 

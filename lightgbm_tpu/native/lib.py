@@ -21,18 +21,19 @@ def _lib_path() -> str:
     return os.path.join(os.path.dirname(__file__), "liblgbm_native.so")
 
 
-def _build():
+def _build() -> str:
     """Compile the helper at first use (PipelineReader has no Python
     analog fast enough for Higgs-scale CSVs; a one-time ~3 s g++ build
-    makes the native path the default).  Failures are silent — callers
-    fall back to the vectorized/pure-Python parsers."""
+    makes the native path the default).  Returns "" on success, else the
+    reason — the caller logs it, and text parsing then takes the slower
+    pandas / exact tiers."""
     import shutil
     import subprocess
     if shutil.which("g++") is None:
-        return
-    src = os.path.join(os.path.dirname(__file__), "src", "lgbm_native.cpp")
+        return "no g++ on PATH"
+    src = _src_path()
     if not os.path.exists(src):
-        return
+        return "source %s missing" % src
     # compile to a temp path and rename into place: another process may
     # race first use, and a killed build must not leave a corrupt .so
     # that permanently disables the native path
@@ -43,11 +44,15 @@ def _build():
              src, "-o", tmp],
             check=True, capture_output=True, timeout=120)
         os.replace(tmp, _lib_path())
-    except Exception:
+        return ""
+    except Exception as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        stderr = getattr(e, "stderr", b"") or b""
+        return ("g++ build failed: %s %s"
+                % (type(e).__name__, stderr.decode(errors="replace")[-300:]))
 
 
 def _src_path() -> str:
@@ -58,6 +63,7 @@ def _load():
     global _LIB, _TRIED
     if not _TRIED:
         _TRIED = True
+        from ..utils import log
         path = _lib_path()
         stale = False
         try:
@@ -69,8 +75,9 @@ def _load():
                      > os.path.getmtime(path))
         except OSError:
             pass
+        why = ""
         if not os.path.exists(path) or stale:
-            _build()
+            why = _build()
         if os.path.exists(path):
             try:
                 lib = ctypes.CDLL(path)
@@ -81,9 +88,22 @@ def _load():
                     ctypes.POINTER(ctypes.c_double),
                 ]
                 _LIB = lib
-            except Exception:   # bad/incomplete .so: missing symbols too
+            except Exception as e:  # bad/incomplete .so: missing symbols too
                 _LIB = None
+                why = why or "cannot load %s: %r" % (path, e)
+        # say which text-parser tier this process runs on: the slower
+        # tiers are correct but a Higgs-scale load on them is a finding
+        if _LIB is not None:
+            log.info("text parser tier: native (%s)" % path)
+        else:
+            log.warning("text parser tier: pandas/exact — native helper "
+                        "unavailable (%s)" % (why or "no library built"))
     return _LIB
+
+
+def loaded_path() -> Optional[str]:
+    """Path of the loaded helper library, or None on the slower tiers."""
+    return _lib_path() if _load() is not None else None
 
 
 def available() -> bool:

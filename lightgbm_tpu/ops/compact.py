@@ -42,15 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX-version compat: the TPU host runs a newer JAX where these carry
-# their current names; older releases (this CPU test container) spell
-# them pltpu.ANY / pltpu.TPUCompilerParams.  ANY-vs-HBM only matters to
-# real Mosaic lowering (see the out_specs comment below) — interpret
-# mode treats them alike.
-_HBM_SPACE = getattr(pltpu, "HBM", pltpu.ANY)
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 BLOCK = 2048  # partition lane block; the kernel's VMEM working set at
               # this block (pane slices, the [2176, 2048] one-hot
               # selection matrix, the RMW window buffers and blend
@@ -426,10 +417,10 @@ def _partition_segment_scoped(seg, mask3, delta, cnt, plcnt, *, block,
             ],
             # HBM, not ANY: Mosaic may place ANY in VMEM, where dynamic
             # DMA lane offsets (128-aligned here) are disallowed
-            out_specs=pl.BlockSpec(memory_space=_HBM_SPACE),
+            out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
             out_shape=jax.ShapeDtypeStruct((R, W + block + 256), jnp.int8),
             scratch_shapes=scratch,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(mask3[None, :], scal, seg)

@@ -38,25 +38,15 @@ def init_distributed(config=None) -> None:
     coordinator = hatches.raw("LGBM_TPU_COORDINATOR")
     if not coordinator:
         return
-    try:
-        # private probe — there is no public "is the distributed client
-        # up?" API; tolerate its removal in future JAX versions
-        from jax._src import distributed as _distributed
-        if _distributed.global_state.client is not None:
-            return
-    except Exception:
-        pass
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=hatches.int_value("LGBM_TPU_NUM_PROCS", 1),
-            process_id=hatches.int_value("LGBM_TPU_PROC_ID", 0))
-    except RuntimeError as e:
-        # the public double-initialization signal ("distributed.initialize
-        # should only be called once." in jax 0.9; older builds said
-        # "already initialized"); anything else is a real bootstrap failure
-        if not any(s in str(e).lower() for s in ("already", "once")):
-            raise
+    # private probe — there is no public "is the distributed client up?"
+    # API (a launcher may have initialized it before the CLI runs)
+    from jax._src import distributed as _distributed
+    if _distributed.global_state.client is not None:
+        return
+    jax.distributed.initialize(
+        coordinator_address=coordinator,
+        num_processes=hatches.int_value("LGBM_TPU_NUM_PROCS", 1),
+        process_id=hatches.int_value("LGBM_TPU_PROC_ID", 0))
     clock_handshake()
 
 

@@ -1110,11 +1110,12 @@ _NULL_SPAN = _NullSpan()
 
 
 def _tracing() -> bool:
-    try:
-        import jax.core
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    # private in jax 0.9 (jax.core no longer re-exports it).  No except:
+    # answering "not tracing" by default made every instrumented program
+    # called under a trace compile standalone and fall back (first chip
+    # run of PR 24: costmodel/aot_call_fallback = 20)
+    from jax._src.core import trace_state_clean
+    return not trace_state_clean()
 
 
 class Span:

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests._pltpu_probe import requires_pltpu_interpret
 
 from lightgbm_tpu.ops.histogram import (histogram_leafbatch,
                                         histogram_leafbatch_segsum)
@@ -31,7 +30,6 @@ def hist_inputs():
     return bins, grad, hess, cid, ok, F, N, B, C
 
 
-@requires_pltpu_interpret
 def test_bf16_variant_matches_rounded_oracle(hist_inputs):
     """Single-pass bf16: equal to the exact oracle fed bf16-rounded
     grad/hess (to f32 accumulation-order noise), counts exact."""
@@ -49,7 +47,6 @@ def test_bf16_variant_matches_rounded_oracle(hist_inputs):
                                rtol=1e-5, atol=1e-4)
 
 
-@requires_pltpu_interpret
 def test_f32x2_variant_near_exact(hist_inputs):
     """Two-pass hi/lo split recovers ~16 operand mantissa bits: per-cell
     error must sit far below the single-pass bf16 rounding floor."""
@@ -77,7 +74,6 @@ def test_f32x2_variant_near_exact(hist_inputs):
     assert err_x2.sum() < 0.05 * err_bf.sum() + 1e-6
 
 
-@requires_pltpu_interpret
 def test_wide_level_grouping(hist_inputs):
     """>64 columns split into groups; results must tile back exactly."""
     from jax.experimental.pallas import tpu as pltpu
@@ -99,7 +95,6 @@ def test_wide_level_grouping(hist_inputs):
                                rtol=1e-5, atol=1e-4)
 
 
-@requires_pltpu_interpret
 def test_uint8_bins_above_127_not_dropped():
     """max_bin=255 bins ride as uint8 bit-patterns; the kernel must mask
     the int8 sign-extension back off (same guarantee as the int8 path)."""
@@ -133,7 +128,6 @@ def test_einsum_dispatch_unaffected_off_tpu(hist_inputs):
                                rtol=1e-5, atol=1e-3)
 
 
-@requires_pltpu_interpret
 def test_wide_dataset_feature_grid():
     """Datasets wider than one VMEM accumulator block (feature_block() =
     96 at B=256/lanes=128) ride the kernel's feature-block grid axis —
@@ -167,7 +161,6 @@ def test_wide_dataset_feature_grid():
                                rtol=1e-5, atol=1e-4)
 
 
-@requires_pltpu_interpret
 def test_f32x1_bit_identical_to_f32x2(hist_inputs):
     """The single-pass 5-stat packing accumulates the same per-lane f32
     partial sums as the two-pass variant — outputs must be bit-equal

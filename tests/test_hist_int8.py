@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests._pltpu_probe import requires_pltpu_interpret
 
 from lightgbm_tpu.ops.histogram import histogram_leafbatch
 from lightgbm_tpu.ops.hist_pallas import (hist_pallas_leafbatch,
@@ -32,7 +31,6 @@ def hist_inputs():
     return bins, grad, hess, cid, ok, F, N, B, C
 
 
-@requires_pltpu_interpret
 def test_xla_quant_matches_pallas_interpret(hist_inputs):
     from jax.experimental.pallas import tpu as pltpu
     bins, grad, hess, cid, ok, F, N, B, C = hist_inputs
@@ -65,7 +63,6 @@ def test_dispatch_through_leafbatch(hist_inputs):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@requires_pltpu_interpret
 def test_uint8_bins_above_127_not_dropped():
     """Production max_bin=255 stores bins as uint8 with values up to 254;
     the Pallas kernel must mask the int8 sign-extension back off (a plain

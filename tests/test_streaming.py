@@ -614,3 +614,32 @@ def test_ingest_telemetry_counters(tmp_path):
     finally:
         telemetry.disable()
         telemetry.reset()
+
+
+@pytest.mark.parametrize("devices,n,chunk", [
+    (1, 1000, 300),       # one device: plain dynamic_update_slice
+    (4, 1000, 300),       # chunk wider than a 250-row block, ragged tail
+    (4, 4096, 512),       # chunk narrower than a block, block-aligned
+    (2, 1001, 400),       # rows do not divide: replicated placement
+])
+def test_device_row_writer_lands_chunks_exactly(monkeypatch, devices, n,
+                                                chunk):
+    """The DEVICE path of DeviceRowWriter (the one a TPU takes; the CPU
+    backend stages on host instead): per-chunk donated updates into a
+    one-device, row-sharded or replicated buffer reproduce the matrix
+    exactly, with the placement asked for."""
+    from lightgbm_tpu.parallel.mesh import dataset_row_sharding
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.RandomState(n + chunk)
+    full = rng.randint(0, 255, (5, n)).astype(np.uint8)
+    sharding = dataset_row_sharding(n, shard_rows=devices > 1,
+                                    num_machines=devices)
+    writer = streaming.DeviceRowWriter(5, n, np.uint8, sharding=sharding)
+    for start in range(0, n, chunk):
+        writer.append(full[:, start:start + chunk], start)
+    out = writer.finish()
+    np.testing.assert_array_equal(np.asarray(out), full)
+    assert out.sharding.is_equivalent_to(sharding, 2)
+    if devices > 1 and n % devices == 0:
+        assert {s.data.shape for s in out.addressable_shards} \
+            == {(5, n // devices)}
