@@ -1462,9 +1462,11 @@ def main() -> int:
     # span per chunk — the JSON gains a phase-breakdown block for free.
     # memory=True adds the span-boundary HBM gauges (a host-side stats
     # read per chunk) so BENCH_*.json rounds carry the memory trajectory;
-    # the armed telemetry also resolves health="auto" ON, so the chunk
-    # programs accumulate the in-program health vector (a handful of [C,N]
-    # reductions per iteration — noise next to the histogram passes).
+    # health="auto" follows the record sink, not the bare enabled flag,
+    # so the chunked configurations ask for the monitor by name
+    # below: the chunk programs accumulate the in-program health vector (a
+    # handful of [C,N] reductions per iteration — noise next to the
+    # histogram passes).
     # DEPTHWISE runs fence the spans (ISSUE 4): unfenced spans on the
     # async TPU time the chunk DISPATCH, not its execution, and the
     # roofline attained rates would be meaningless.  Total timed wall is
@@ -1500,6 +1502,7 @@ def main() -> int:
             "num_iterations": str(2 * iters),
             "mixed_bin": args.mixed_bin,
             "pipeline": args.pipeline,
+            "health": "true",
         }
         if grow_policy == "leafwise":
             # leaf-wise times train_one_iter per iteration: the health

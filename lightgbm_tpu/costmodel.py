@@ -200,9 +200,8 @@ def _tracing() -> bool:
 
 
 def _jit_disabled() -> bool:
-    # under jax.disable_jit() the POINT is eager per-op execution
-    # (profile_phases --mode=telemetry); serving a compiled program would
-    # defeat it
+    # under jax.disable_jit() the POINT is eager per-op execution;
+    # serving a compiled program would defeat it
     try:
         import jax
         return bool(jax.config.jax_disable_jit)

@@ -151,15 +151,32 @@ def tree_health_counts(num_leaves: int, split_gain, leaf_count) -> dict:
             "degenerate_trees": int(n <= 1)}
 
 
+_told_auto_off = False
+
+
 def resolve_enabled(health_setting: str) -> bool:
     """The ``health=`` resolution rule, single-homed: "auto" (default)
-    follows the telemetry registry — armed telemetry (metrics_out= or
-    library enable()) turns the monitor on; "true"/"false" force it."""
+    follows the record SINK — a run with ``metrics_out=`` (or a library
+    ``enable(jsonl_path)``) has somewhere to put the health blocks and
+    turns the monitor on; "true"/"false" force it.  It does not follow
+    the bare enabled flag: the monitor puts the
+    health vector INTO the fused chunk program, so a run that arms
+    telemetry only to read spans and counters — the benchmark's traced
+    run — would train with another program than the timed run."""
     if health_setting == "true":
         return True
     if health_setting == "false":
         return False
-    return telemetry.enabled()
+    on = telemetry.sink_active()
+    global _told_auto_off
+    if telemetry.enabled() and not on and not _told_auto_off:
+        # a library user who armed telemetry expecting the monitor (and
+        # on_anomaly=halt with it) must hear, once, that it is not armed
+        _told_auto_off = True
+        log.info("telemetry is on without a metrics_out= sink: health=auto "
+                 "leaves the training-health monitor off (health=true "
+                 "turns it on)")
+    return on
 
 
 class HealthMonitor:

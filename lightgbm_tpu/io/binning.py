@@ -19,7 +19,7 @@ from typing import List, NamedTuple, Optional
 
 import numpy as np
 
-from .. import hatches
+from .. import hatches, telemetry
 
 
 @dataclass
@@ -398,12 +398,21 @@ def plan_feature_packing(num_bins, num_bins_max: int,
         perm=tuple(int(i) for i in order))
 
 
-def find_bins_for_matrix(sample: np.ndarray, max_bin: int) -> List[BinMapper]:
+def find_bins_for_matrix(sample: np.ndarray, max_bin: int,
+                         skip=()) -> List[Optional[BinMapper]]:
     """Compute a BinMapper per column of a dense sample matrix
-    (ConstructBinMappers single-machine path, dataset.cpp:322-350)."""
-    mappers = []
-    for j in range(sample.shape[1]):
-        mapper = BinMapper()
-        mapper.find_bin(sample[:, j], max_bin)
-        mappers.append(mapper)
-    return mappers
+    (ConstructBinMappers single-machine path, dataset.cpp:322-350); a
+    column in ``skip`` gets None.  The one place cut points are found for
+    every loader, so the ``find_bins`` span and ``bin/sample_rows`` live
+    here and nowhere else; the caller holds ``dataset_bin`` open."""
+    with telemetry.span("find_bins"):
+        telemetry.count("bin/sample_rows", int(sample.shape[0]))
+        mappers = []
+        for j in range(sample.shape[1]):
+            if j in skip:
+                mappers.append(None)
+                continue
+            mapper = BinMapper()
+            mapper.find_bin(sample[:, j], max_bin)
+            mappers.append(mapper)
+        return mappers

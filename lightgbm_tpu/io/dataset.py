@@ -20,9 +20,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..utils import log
 from . import parser as parser_mod
-from .binning import BinMapper
+from .binning import BinMapper, find_bins_for_matrix
 from .metadata import Metadata
 
 SAMPLE_CNT = 50000  # dataset.cpp:219 — max rows sampled for bin finding
@@ -339,17 +340,14 @@ class Dataset:
                            ignore_set) -> None:
         """Bin mappers for every raw feature column plus trivial/ignored
         feature removal (dataset.cpp:275-350)."""
-        if bin_finder is not None:
-            raw_mappers = bin_finder(sample, max_bin)
-        else:
-            raw_mappers = []
-            for j in range(self.num_total_features):
-                if j in ignore_set:
-                    raw_mappers.append(None)
-                    continue
-                m = BinMapper()
-                m.find_bin(sample[:, j], max_bin)
-                raw_mappers.append(m)
+        with telemetry.span("dataset_bin"):
+            if bin_finder is not None:
+                # distributed bin finding (parallel/learners)
+                raw_mappers = bin_finder(sample, max_bin)
+            else:
+                raw_mappers = find_bins_for_matrix(
+                    sample[:, :self.num_total_features], max_bin,
+                    skip=ignore_set)
         for j, mapper in enumerate(raw_mappers):
             if mapper is None or j in ignore_set:
                 if j not in ignore_set:
@@ -575,19 +573,19 @@ class Dataset:
             self.used_feature_map = dict(reference.used_feature_map)
             self.bin_mappers = reference.bin_mappers
         else:
-            rng = np.random.RandomState(seed)
-            if total_rows > sample_cnt:
-                sample = features[np.sort(rng.choice(total_rows, sample_cnt,
-                                                     replace=False))]
-            else:
-                sample = features
-            for j in range(features.shape[1]):
-                m = BinMapper()
-                m.find_bin(sample[:, j], max_bin)
-                if m.is_trivial:
-                    continue
-                self.used_feature_map[j] = len(self.bin_mappers)
-                self.bin_mappers.append(m)
+            # drawing the row sample is dataset_bin's own time
+            with telemetry.span("dataset_bin"):
+                rng = np.random.RandomState(seed)
+                if total_rows > sample_cnt:
+                    sample = features[np.sort(rng.choice(
+                        total_rows, sample_cnt, replace=False))]
+                else:
+                    sample = features
+                for j, m in enumerate(find_bins_for_matrix(sample, max_bin)):
+                    if m.is_trivial:
+                        continue
+                    self.used_feature_map[j] = len(self.bin_mappers)
+                    self.bin_mappers.append(m)
         self.real_feature_idx = np.array(sorted(self.used_feature_map),
                                          dtype=np.int32)
         self.num_bins = np.array([m.num_bin for m in self.bin_mappers],
@@ -608,13 +606,19 @@ class Dataset:
     # ------------------------------------------------------------- internals
 
     def _binarize(self, features: np.ndarray) -> None:
-        """Quantize the dense value matrix into the [F, N] bin matrix."""
+        """Quantize the dense value matrix into the [F, N] bin matrix:
+        one ``searchsorted`` per used column, on one thread (the
+        ``binarize`` span; ``bin/values`` counts the values quantized)."""
         num_features = len(self.bin_mappers)
         dtype = _bin_dtype(int(self.num_bins.max()) if num_features else 256)
-        bins = np.empty((num_features, features.shape[0]), dtype=dtype)
-        for j_raw, j_inner in self.used_feature_map.items():
-            mapper = self.bin_mappers[j_inner]
-            bins[j_inner] = mapper.value_to_bin(features[:, j_raw]).astype(dtype)
+        with telemetry.span("dataset_bin"), telemetry.span("binarize"):
+            bins = np.empty((num_features, features.shape[0]), dtype=dtype)
+            for j_raw, j_inner in self.used_feature_map.items():
+                mapper = self.bin_mappers[j_inner]
+                bins[j_inner] = mapper.value_to_bin(
+                    features[:, j_raw]).astype(dtype)
+            telemetry.count("bin/values",
+                            int(features.shape[0]) * num_features)
         self.bins = bins
 
     def _attach_init_score_values(self, features: np.ndarray,

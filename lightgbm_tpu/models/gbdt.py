@@ -120,7 +120,25 @@ class GBDT:
              training_metrics=(), learner=None) -> None:
         """GBDT::Init (gbdt.cpp:41-89).  ``learner`` optionally overrides the
         tree-growing callable (serial default; parallel learners plug in via
-        lightgbm_tpu.parallel)."""
+        lightgbm_tpu.parallel).  The ``booster_init`` span is set-up's
+        share here; its child ``h2d`` is the bin table's placement."""
+        with telemetry.span("booster_init"):
+            self._init(boosting_config, train_data, objective,
+                       training_metrics, learner)
+
+    def _place_bins(self, place, host_bins, **kwargs):
+        """The bin table's host → device placement under the ``h2d`` span,
+        which waits for the table (a span that only timed the enqueue
+        would read nothing) — when telemetry is off nothing waits."""
+        with telemetry.span("h2d"):
+            placed = place(host_bins, **kwargs)
+            if telemetry.enabled():
+                telemetry.count("init/h2d_bytes", int(host_bins.nbytes))
+                jax.block_until_ready(placed)
+        return placed
+
+    def _init(self, boosting_config, train_data, objective,
+              training_metrics, learner) -> None:
         self.gbdt_config = boosting_config
         self.tree_config = boosting_config.tree_config
         self.train_data = train_data
@@ -267,8 +285,9 @@ class GBDT:
                           "data-parallel training (no row-aligned state "
                           "globalization)")
             self.num_data = max_n * jax.process_count()
-            self.bins_device = self._mp_make_global(
-                self._bins_host(train_data), row_axis=1)
+            self.bins_device = self._place_bins(
+                self._mp_make_global, self._bins_host(train_data),
+                row_axis=1)
             # replicated small arrays stay host-side (every process passes
             # identical values into the jitted programs)
             self.num_bins_device = np.asarray(train_data.num_bins)
@@ -313,7 +332,8 @@ class GBDT:
                     "this streamed dataset's device bin matrix was "
                     "consumed by a previous mixed-bin GBDT.init — reload "
                     "the dataset to train another booster on it")
-                self.bins_device = _arr0(self._bins_host(train_data))
+                self.bins_device = self._place_bins(
+                    _arr0, self._bins_host(train_data))
             self.num_bins_device = _arr0(train_data.num_bins)
             self._row_valid = None
             init_score = train_data.metadata.init_score
@@ -435,8 +455,8 @@ class GBDT:
                 metric.init("training", train_data.metadata, N)
 
         # training-health monitor (ISSUE 2): "auto" follows the telemetry
-        # registry, so metrics_out= runs get health blocks with no extra
-        # flag; health=true forces it on for library users without a sink
+        # SINK, so metrics_out= runs get health blocks with no extra flag;
+        # health=true forces it on for library users without a sink
         from .. import health as _health
         if _health.resolve_enabled(getattr(boosting_config, "health",
                                            "auto")):
@@ -1258,8 +1278,7 @@ class GBDT:
                         sp.fence(new_cls)
 
             # now block on the (already in-flight) host copy for the model
-            with telemetry.span("model_readback"):
-                host = jax.device_get(small)
+            host = _read_back(small)
             num_leaves = int(host.num_leaves)
             if mon is not None:
                 # tree-derived health counts ride the readback for free
@@ -1285,9 +1304,11 @@ class GBDT:
                     mon.apply_policy(block, self.iter + 1)
                 return True
 
-            tree = self._to_host_tree(host)
-            tree.shrinkage(self.gbdt_config.learning_rate)
-            self.models.append(tree)
+            with telemetry.span("tree_build"):
+                tree = self._to_host_tree(host)
+                tree.shrinkage(self.gbdt_config.learning_rate)
+                self.models.append(tree)
+        telemetry.count("train/iterations")
 
         # dispatch the health program over this iteration's arrays (async:
         # the host copy overlaps the eval phase; fetched at assemble)
@@ -1421,8 +1442,7 @@ class GBDT:
         C = self.num_class
         it = entry["iter_no"]
         for cls, rec in enumerate(entry["cls"]):
-            with telemetry.span("model_readback"):
-                host = jax.device_get(rec["small"])
+            host = _read_back(rec["small"])
             num_leaves = int(host.num_leaves)
             if mon is not None:
                 mon.add_tree(num_leaves, host.split_gain, host.leaf_count)
@@ -1454,9 +1474,11 @@ class GBDT:
                             extra={"stopped": "degenerate_tree"})
                     mon.apply_policy(block, it + 1)
                 return True
-            tree = self._to_host_tree(host)
-            tree.shrinkage(self.gbdt_config.learning_rate)
-            self.models.append(tree)
+            with telemetry.span("tree_build"):
+                tree = self._to_host_tree(host)
+                tree.shrinkage(self.gbdt_config.learning_rate)
+                self.models.append(tree)
+        telemetry.count("train/iterations")
 
         last = entry["cls"][-1]
         hvec = (mon.grad_health_async(entry["grad"], entry["hess"],
@@ -2155,11 +2177,12 @@ class GBDT:
         score_before = rec["score_before"]
         valid_before = rec["valid_before"]
         C = self.num_class
-        with telemetry.span("model_readback"):
-            host = jax.device_get(stacked)
-            mvals_host = np.asarray(mvals) if eval_each else None
-            # stacked [k, H] in-program health vectors, one per iteration
-            hvals_host = np.asarray(hvals) if mon is not None else None
+        # stacked trees, metric values when evaluated, and the stacked
+        # [k, H] in-program health vectors, one per iteration
+        host, mvals_host, hvals_host = _read_back(
+            (stacked, mvals if eval_each else None,
+             hvals if mon is not None else None))
+        telemetry.count("train/chunks")
 
         # per-iteration telemetry records: the fused program's phases are
         # indivisible from the host, so its wall time is amortized evenly
@@ -2216,9 +2239,11 @@ class GBDT:
                     else:
                         self.iter += i
                     return True
-                tree = self._to_host_tree(sub)
-                tree.shrinkage(self.gbdt_config.learning_rate)
-                self.models.append(tree)
+                with telemetry.span("tree_build"):
+                    tree = self._to_host_tree(sub)
+                    tree.shrinkage(self.gbdt_config.learning_rate)
+                    self.models.append(tree)
+            telemetry.count("train/iterations")
             if eval_each:
                 train_vals, valid_vals = self._split_metric_values(
                     mvals_host[i])
@@ -2723,6 +2748,25 @@ class GBDT:
         return "\n".join(out) + "\n"
 
 
+def _read_back(tree):
+    """The model readback, ``jax.device_get(tree)``, in its three parts.
+    With telemetry on the host first waits for the device under a span of
+    its own (``device_wait``): spans are not fenced by default and the
+    readback is where the host first blocks, so without it
+    ``model_readback`` would time the program it waits for.  Then the
+    copy (``model_readback``), then ``train/readback_bytes``, counted
+    where the bytes are consumed."""
+    if telemetry.enabled():
+        with telemetry.span("device_wait"):
+            jax.block_until_ready(tree)
+    with telemetry.span("model_readback"):
+        host = jax.device_get(tree)
+    if telemetry.enabled():
+        telemetry.count("train/readback_bytes", sum(
+            int(getattr(a, "nbytes", 0)) for a in jax.tree.leaves(host)))
+    return host
+
+
 # Compiled k-iteration chunk programs, shared process-wide.  Keyed ONLY on
 # hashable statics — per-dataset arrays (labels, weights, bins) enter as
 # runtime inputs via obj_params, so the traced HLO is data-independent and a
@@ -2769,14 +2813,18 @@ def make_chunk_body(*, grad_fn, obj_params, num_class: int, lrf, grow_fn,
             rmask, fmask = xs
         else:
             rmask, fmask, goss_it = xs
-        grad, hess = grad_fn(obj_params,
-                             score if num_class > 1 else score[0])
-        if num_class == 1:
-            grad, hess = grad[None], hess[None]
-        if goss_fn is not None:
-            g_grow, h_grow, goss_mask = goss_fn(goss_it, grad, hess)
-        else:
-            g_grow, h_grow, goss_mask = grad, hess, None
+        # every piece of device work below sits under one scope of
+        # telemetry.DEVICE_PHASES, unconditionally: a device trace splits
+        # the iteration by these names whether or not telemetry is armed
+        with telemetry.phase_scope("gradient"):
+            grad, hess = grad_fn(obj_params,
+                                 score if num_class > 1 else score[0])
+            if num_class == 1:
+                grad, hess = grad[None], hess[None]
+            if goss_fn is not None:
+                g_grow, h_grow, goss_mask = goss_fn(goss_it, grad, hess)
+            else:
+                g_grow, h_grow, goss_mask = grad, hess, None
         outs = []
         vscores = list(vscores)
         ones = (base_mask if base_mask is not None
@@ -2788,29 +2836,34 @@ def make_chunk_body(*, grad_fn, obj_params, num_class: int, lrf, grow_fn,
                 rm = (rmask[cls] & ones) if has_bag else ones
             fm = fmask[cls] if has_ff else jnp.ones((F,), jnp.bool_)
             ta = grow_fn(bins, g_grow[cls], h_grow[cls], rm, fm, num_bins)
-            shrunk = jnp.where(ta.num_leaves > 1, ta.leaf_value * lrf, 0.0)
-            score = score.at[cls].add(_leaf_lookup(shrunk, ta.leaf_ids))
-            # valid scores by tree replay (gbdt.cpp:220-222)
-            for v in range(n_valid):
-                vscores[v] = vscores[v].at[cls].set(add_tree_score(
-                    valid_bins[v], vscores[v][cls], ta.split_feature,
-                    ta.threshold_bin, ta.left_child, ta.right_child,
-                    shrunk, ta.num_leaves, max_nodes=max_nodes))
+            with telemetry.phase_scope("score_update"):
+                shrunk = jnp.where(ta.num_leaves > 1, ta.leaf_value * lrf,
+                                   0.0)
+                score = score.at[cls].add(_leaf_lookup(shrunk, ta.leaf_ids))
+                # valid scores by tree replay (gbdt.cpp:220-222)
+                for v in range(n_valid):
+                    vscores[v] = vscores[v].at[cls].set(add_tree_score(
+                        valid_bins[v], vscores[v][cls], ta.split_feature,
+                        ta.threshold_bin, ta.left_child, ta.right_child,
+                        shrunk, ta.num_leaves, max_nodes=max_nodes))
             outs.append(ta._replace(leaf_ids=jnp.zeros((0,), jnp.int32)))
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        with telemetry.phase_scope("tree_pack"):
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
         # in-program metric evaluation (Metric::Eval on CPU threads in the
         # reference; here the scores never leave the device)
-        mv = []
-        for f, p in zip(train_metric_fns, train_mparams):
-            mv.append(f(p, score if num_class > 1 else score[0]))
-        for v in range(n_valid):
-            sv = vscores[v] if num_class > 1 else vscores[v][0]
-            for f, p in zip(valid_metric_fns[v], valid_mparams[v]):
-                mv.append(f(p, sv))
-        mvals = jnp.concatenate(mv) if mv else jnp.zeros((0,), jnp.float32)
-        hvec = (health_fn(grad, hess, score) if health_fn is not None
-                else jnp.zeros((0,), jnp.float32))
+        with telemetry.phase_scope("eval"):
+            mv = []
+            for f, p in zip(train_metric_fns, train_mparams):
+                mv.append(f(p, score if num_class > 1 else score[0]))
+            for v in range(n_valid):
+                sv = vscores[v] if num_class > 1 else vscores[v][0]
+                for f, p in zip(valid_metric_fns[v], valid_mparams[v]):
+                    mv.append(f(p, sv))
+            mvals = (jnp.concatenate(mv) if mv
+                     else jnp.zeros((0,), jnp.float32))
+            hvec = (health_fn(grad, hess, score) if health_fn is not None
+                    else jnp.zeros((0,), jnp.float32))
         return (score, tuple(vscores)), (stacked, mvals, hvec)
 
     return body

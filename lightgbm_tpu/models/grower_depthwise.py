@@ -12,9 +12,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-# patchable histogram seam: tests and scripts/profile_phases.py
-# monkeypatch THIS attribute (the unified grower resolves it through
-# this module at trace time)
+# patchable histogram seam: tests monkeypatch THIS attribute (the
+# unified grower resolves it through this module at trace time)
 from ..ops.histogram import histogram_leafbatch  # noqa: F401
 
 from .grower_unified import (  # noqa: F401

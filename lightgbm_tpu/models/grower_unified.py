@@ -65,6 +65,7 @@ import jax.numpy as jnp
 
 from ..ops.histogram import build_histogram, histogram_leafbatch
 from ..ops.split import SplitResult, find_best_split
+from ..telemetry import phase_scope
 
 GROW_POLICIES = ("leafwise", "depthwise", "leafcompact")
 
@@ -166,10 +167,9 @@ def _is_int8(compute_dtype) -> bool:
 
 def _patchable(module_name: str, attr: str, default):
     """Resolve a histogram entry through its historical compat module at
-    trace time: tests and scripts/profile_phases.py monkeypatch
-    ``grower.build_histogram`` / ``grower_depthwise.histogram_leafbatch``
-    (the established stub seams), and the collapse must not silently
-    disconnect them."""
+    trace time: tests monkeypatch ``grower.build_histogram`` /
+    ``grower_depthwise.histogram_leafbatch`` (the established stub
+    seams), and the collapse must not silently disconnect them."""
     import importlib
     try:
         mod = importlib.import_module("%s.%s" % (__package__, module_name))
@@ -415,10 +415,18 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
 
     def best_of(hist, sum_g, sum_h, cnt, depth, root=False):
         f = (s.root_split_finder or finder) if root else finder
-        res = f(hist, sum_g, sum_h, cnt, num_bins, feature_mask,
-                float(min_data_in_leaf),
-                float(min_sum_hessian_in_leaf))
-        return _depth_gated(res, depth, max_depth)
+        with phase_scope("split_find"):
+            res = f(hist, sum_g, sum_h, cnt, num_bins, feature_mask,
+                    float(min_data_in_leaf),
+                    float(min_sum_hessian_in_leaf))
+            return _depth_gated(res, depth, max_depth)
+
+    # Device phases: every piece of device work of this policy
+    # sits under one scope of telemetry.DEVICE_PHASES, unconditionally.
+    # Inside the split loop the candidate tables (cand_*, leaf_*) are
+    # split_find's, the [L, F, B, 3] cache and the sibling subtraction
+    # histogram's, the leaf-id update row_route's, the node records
+    # tree_pack's.
 
     # ---- root init (BeforeTrain, serial_tree_learner.cpp:155-236);
     # skipped entirely when resuming from a carried state (segmentation)
@@ -430,109 +438,122 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
                                axis_name=s.hist_axis, packing=packing,
                                **_fg),
             lambda: hist_of(row_mask), s, compute_dtype)
-        root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
-                                    row_mask)
+        with phase_scope("histogram"):
+            root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
+                                        row_mask)
         root_g, root_h, root_c = root_stats[0], root_stats[1], root_stats[2]
         root_best = best_of(root_hist, root_g, root_h, root_c,
                             jnp.asarray(1, jnp.int32), root=True)
+        with phase_scope("tree_pack"):
+            neg_inf = jnp.full((L,), -jnp.inf, dtype=f32)
+            zeros_i = jnp.zeros((L,), dtype=jnp.int32)
+            zeros_f = jnp.zeros((L,), dtype=f32)
 
-        neg_inf = jnp.full((L,), -jnp.inf, dtype=f32)
-        zeros_i = jnp.zeros((L,), dtype=jnp.int32)
-        zeros_f = jnp.zeros((L,), dtype=f32)
-
-        tree = TreeArrays(
-            num_leaves=jnp.asarray(1, jnp.int32),
-            split_feature=jnp.zeros((L - 1,), jnp.int32),
-            threshold_bin=jnp.zeros((L - 1,), jnp.int32),
-            split_gain=jnp.zeros((L - 1,), f32),
-            left_child=jnp.zeros((L - 1,), jnp.int32),
-            right_child=jnp.zeros((L - 1,), jnp.int32),
-            leaf_parent=jnp.full((L,), -1, jnp.int32),
-            leaf_value=zeros_f,
-            leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
-            leaf_ids=jnp.zeros((N,), jnp.int32),
-        )
-        return _GrowState(
-            tree=tree,
-            hist_cache=jnp.zeros((L,) + root_hist.shape,
-                                 f32).at[0].set(root_hist),
-            cand_gain=neg_inf.at[0].set(root_best.gain),
-            cand_feature=zeros_i.at[0].set(root_best.feature),
-            cand_threshold=zeros_i.at[0].set(root_best.threshold),
-            cand_left_out=zeros_f.at[0].set(root_best.left_output),
-            cand_right_out=zeros_f.at[0].set(root_best.right_output),
-            cand_left_cnt=zeros_i.at[0].set(root_best.left_count),
-            cand_right_cnt=zeros_i.at[0].set(root_best.right_count),
-            cand_left_g=zeros_f.at[0].set(root_best.left_sum_grad),
-            cand_left_h=zeros_f.at[0].set(root_best.left_sum_hess),
-            cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
-            cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
-            leaf_sum_g=zeros_f.at[0].set(root_g),
-            leaf_sum_h=zeros_f.at[0].set(root_h),
-            leaf_cnt=zeros_i.at[0].set(root_c.astype(jnp.int32)),
-            leaf_depth=zeros_i.at[0].set(1),
-            done=jnp.asarray(False),
-        )
+            tree = TreeArrays(
+                num_leaves=jnp.asarray(1, jnp.int32),
+                split_feature=jnp.zeros((L - 1,), jnp.int32),
+                threshold_bin=jnp.zeros((L - 1,), jnp.int32),
+                split_gain=jnp.zeros((L - 1,), f32),
+                left_child=jnp.zeros((L - 1,), jnp.int32),
+                right_child=jnp.zeros((L - 1,), jnp.int32),
+                leaf_parent=jnp.full((L,), -1, jnp.int32),
+                leaf_value=zeros_f,
+                leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
+                leaf_ids=jnp.zeros((N,), jnp.int32),
+            )
+            return _GrowState(
+                tree=tree,
+                hist_cache=jnp.zeros((L,) + root_hist.shape,
+                                     f32).at[0].set(root_hist),
+                cand_gain=neg_inf.at[0].set(root_best.gain),
+                cand_feature=zeros_i.at[0].set(root_best.feature),
+                cand_threshold=zeros_i.at[0].set(root_best.threshold),
+                cand_left_out=zeros_f.at[0].set(root_best.left_output),
+                cand_right_out=zeros_f.at[0].set(root_best.right_output),
+                cand_left_cnt=zeros_i.at[0].set(root_best.left_count),
+                cand_right_cnt=zeros_i.at[0].set(root_best.right_count),
+                cand_left_g=zeros_f.at[0].set(root_best.left_sum_grad),
+                cand_left_h=zeros_f.at[0].set(root_best.left_sum_hess),
+                cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
+                cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
+                leaf_sum_g=zeros_f.at[0].set(root_g),
+                leaf_sum_h=zeros_f.at[0].set(root_h),
+                leaf_cnt=zeros_i.at[0].set(root_c.astype(jnp.int32)),
+                leaf_depth=zeros_i.at[0].set(1),
+                done=jnp.asarray(False),
+            )
 
     state = init_state if init_state is not None else _root_state()
 
     def body(_, state: _GrowState) -> _GrowState:
         # pick the best leaf to split (FindBestSplitsForLeaves argmax,
         # serial_tree_learner.cpp:140-147)
-        best_leaf = jnp.argmax(state.cand_gain).astype(jnp.int32)
-        best_gain = state.cand_gain[best_leaf]
-        should_split = jnp.logical_and(~state.done, best_gain > 0.0)
+        with phase_scope("split_find"):
+            best_leaf = jnp.argmax(state.cand_gain).astype(jnp.int32)
+            best_gain = state.cand_gain[best_leaf]
+            should_split = jnp.logical_and(~state.done, best_gain > 0.0)
 
         def do_split(state: _GrowState) -> _GrowState:
             tree = state.tree
             bl = best_leaf
             nl = tree.num_leaves
-            node = nl - 1
+            with phase_scope("tree_pack"):
+                node = nl - 1
             new_leaf = nl
 
-            feat = state.cand_feature[bl]
-            thr = state.cand_threshold[bl]
+            with phase_scope("split_find"):
+                feat = state.cand_feature[bl]
+                thr = state.cand_threshold[bl]
+                lcnt = state.cand_left_cnt[bl]
+                rcnt = state.cand_right_cnt[bl]
+                left_is_smaller = lcnt <= rcnt
+                small_leaf = jnp.where(left_is_smaller, bl, new_leaf)
 
             # --- record the node (Tree::Split, tree.cpp:50-83)
-            p = tree.leaf_parent[bl]
-            pp = jnp.maximum(p, 0)
-            lc_at_p = jnp.where((p >= 0) & (tree.left_child[pp] == ~bl),
-                                node, tree.left_child[pp])
-            rc_at_p = jnp.where((p >= 0) & (tree.right_child[pp] == ~bl),
-                                node, tree.right_child[pp])
-            left_child = tree.left_child.at[pp].set(lc_at_p).at[node].set(~bl)
-            right_child = (tree.right_child.at[pp].set(rc_at_p)
-                           .at[node].set(~new_leaf))
+            with phase_scope("tree_pack"):
+                p = tree.leaf_parent[bl]
+                pp = jnp.maximum(p, 0)
+                lc_at_p = jnp.where(
+                    (p >= 0) & (tree.left_child[pp] == ~bl),
+                    node, tree.left_child[pp])
+                rc_at_p = jnp.where(
+                    (p >= 0) & (tree.right_child[pp] == ~bl),
+                    node, tree.right_child[pp])
+                left_child = (tree.left_child.at[pp].set(lc_at_p)
+                              .at[node].set(~bl))
+                right_child = (tree.right_child.at[pp].set(rc_at_p)
+                               .at[node].set(~new_leaf))
 
             # --- partition rows (DataPartition::Split as masked where,
             # data_partition.hpp:93-139), split feature translated through
             # the storage-layout map (partition-index-translate seam)
-            pfeat = partition_feature(partition_packing, feat)
-            fbin = jax.lax.dynamic_index_in_dim(
-                partition_bins, pfeat, axis=0, keepdims=False).astype(jnp.int32)
-            go_right = fbin > thr
-            leaf_ids = jnp.where((tree.leaf_ids == bl) & go_right,
-                                 new_leaf, tree.leaf_ids)
+            with phase_scope("row_route"):
+                pfeat = partition_feature(partition_packing, feat)
+                fbin = jax.lax.dynamic_index_in_dim(
+                    partition_bins, pfeat, axis=0,
+                    keepdims=False).astype(jnp.int32)
+                go_right = fbin > thr
+                leaf_ids = jnp.where((tree.leaf_ids == bl) & go_right,
+                                     new_leaf, tree.leaf_ids)
+                small_mask = row_mask & (leaf_ids == small_leaf)
 
             # --- child histograms: build the smaller, subtract for the larger
-            # (serial_tree_learner.cpp:262-283)
-            lcnt = state.cand_left_cnt[bl]
-            rcnt = state.cand_right_cnt[bl]
-            left_is_smaller = lcnt <= rcnt
-            small_leaf = jnp.where(left_is_smaller, bl, new_leaf)
-            small_mask = row_mask & (leaf_ids == small_leaf)
+            # (serial_tree_learner.cpp:262-283).
             # salt = the new leaf index: varies per split pass so the
             # stochastic-rounding bits decorrelate across passes
             small_hist = hist_of(small_mask, salt=new_leaf)
-            parent_hist = state.hist_cache[bl]
-            large_hist = parent_hist - small_hist
-            lhist = jnp.where(left_is_smaller, small_hist, large_hist)
-            rhist = jnp.where(left_is_smaller, large_hist, small_hist)
+            with phase_scope("histogram"):
+                parent_hist = state.hist_cache[bl]
+                large_hist = parent_hist - small_hist
+                lhist = jnp.where(left_is_smaller, small_hist, large_hist)
+                rhist = jnp.where(left_is_smaller, large_hist, small_hist)
 
             # --- child stats
-            lg, lh = state.cand_left_g[bl], state.cand_left_h[bl]
-            rg, rh = state.cand_right_g[bl], state.cand_right_h[bl]
-            depth = state.leaf_depth[bl] + 1
+            with phase_scope("split_find"):
+                lg, lh = state.cand_left_g[bl], state.cand_left_h[bl]
+                rg, rh = state.cand_right_g[bl], state.cand_right_h[bl]
+                lcf, rcf = lcnt.astype(f32), rcnt.astype(f32)
+                depth = state.leaf_depth[bl] + 1
 
             # --- new candidate splits for both children.  Issued BEFORE
             # the [L, F, B, 3] cache scatter below: under an ownership
@@ -542,56 +563,66 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
             # cache writeback's HBM traffic and the node bookkeeping that
             # dispatches the next split (ISSUE 9 overlap seam; pure
             # scheduling — the traced values are bit-identical)
-            lbest = best_of(lhist, lg, lh, lcnt.astype(f32), depth)
-            rbest = best_of(rhist, rg, rh, rcnt.astype(f32), depth)
-            hist_cache = state.hist_cache.at[bl].set(lhist).at[new_leaf].set(rhist)
+            lbest = best_of(lhist, lg, lh, lcf, depth)
+            rbest = best_of(rhist, rg, rh, rcf, depth)
+            with phase_scope("histogram"):
+                hist_cache = (state.hist_cache.at[bl].set(lhist)
+                              .at[new_leaf].set(rhist))
 
-            tree = tree._replace(
-                num_leaves=nl + 1,
-                split_feature=tree.split_feature.at[node].set(feat),
-                threshold_bin=tree.threshold_bin.at[node].set(thr),
-                split_gain=tree.split_gain.at[node].set(best_gain),
-                left_child=left_child,
-                right_child=right_child,
-                leaf_parent=tree.leaf_parent.at[bl].set(node)
-                                            .at[new_leaf].set(node),
-                leaf_value=tree.leaf_value.at[bl].set(state.cand_left_out[bl])
-                                          .at[new_leaf].set(state.cand_right_out[bl]),
-                leaf_count=tree.leaf_count.at[bl].set(lcnt)
-                                          .at[new_leaf].set(rcnt),
-                leaf_ids=leaf_ids,
-            )
-            return state._replace(
-                tree=tree,
-                hist_cache=hist_cache,
-                cand_gain=state.cand_gain.at[bl].set(lbest.gain)
-                                         .at[new_leaf].set(rbest.gain),
-                cand_feature=state.cand_feature.at[bl].set(lbest.feature)
-                                               .at[new_leaf].set(rbest.feature),
-                cand_threshold=state.cand_threshold.at[bl].set(lbest.threshold)
-                                                   .at[new_leaf].set(rbest.threshold),
-                cand_left_out=state.cand_left_out.at[bl].set(lbest.left_output)
-                                                 .at[new_leaf].set(rbest.left_output),
-                cand_right_out=state.cand_right_out.at[bl].set(lbest.right_output)
-                                                   .at[new_leaf].set(rbest.right_output),
-                cand_left_cnt=state.cand_left_cnt.at[bl].set(lbest.left_count)
-                                                 .at[new_leaf].set(rbest.left_count),
-                cand_right_cnt=state.cand_right_cnt.at[bl].set(lbest.right_count)
-                                                   .at[new_leaf].set(rbest.right_count),
-                cand_left_g=state.cand_left_g.at[bl].set(lbest.left_sum_grad)
-                                             .at[new_leaf].set(rbest.left_sum_grad),
-                cand_left_h=state.cand_left_h.at[bl].set(lbest.left_sum_hess)
-                                             .at[new_leaf].set(rbest.left_sum_hess),
-                cand_right_g=state.cand_right_g.at[bl].set(lbest.right_sum_grad)
-                                               .at[new_leaf].set(rbest.right_sum_grad),
-                cand_right_h=state.cand_right_h.at[bl].set(lbest.right_sum_hess)
-                                               .at[new_leaf].set(rbest.right_sum_hess),
-                leaf_sum_g=state.leaf_sum_g.at[bl].set(lg).at[new_leaf].set(rg),
-                leaf_sum_h=state.leaf_sum_h.at[bl].set(lh).at[new_leaf].set(rh),
-                leaf_cnt=state.leaf_cnt.at[bl].set(lcnt).at[new_leaf].set(rcnt),
-                leaf_depth=state.leaf_depth.at[bl].set(depth)
-                                           .at[new_leaf].set(depth),
-            )
+            with phase_scope("tree_pack"):
+                tree = tree._replace(
+                    num_leaves=nl + 1,
+                    split_feature=tree.split_feature.at[node].set(feat),
+                    threshold_bin=tree.threshold_bin.at[node].set(thr),
+                    split_gain=tree.split_gain.at[node].set(best_gain),
+                    left_child=left_child,
+                    right_child=right_child,
+                    leaf_parent=tree.leaf_parent.at[bl].set(node)
+                                                .at[new_leaf].set(node),
+                    leaf_value=tree.leaf_value
+                                   .at[bl].set(state.cand_left_out[bl])
+                                   .at[new_leaf]
+                                   .set(state.cand_right_out[bl]),
+                    leaf_count=tree.leaf_count.at[bl].set(lcnt)
+                                              .at[new_leaf].set(rcnt),
+                    leaf_ids=leaf_ids,
+                )
+            def put(arr, left, right):
+                return arr.at[bl].set(left).at[new_leaf].set(right)
+
+            with phase_scope("split_find"):
+                return state._replace(
+                    tree=tree,
+                    hist_cache=hist_cache,
+                    cand_gain=put(state.cand_gain, lbest.gain, rbest.gain),
+                    cand_feature=put(state.cand_feature, lbest.feature,
+                                     rbest.feature),
+                    cand_threshold=put(state.cand_threshold,
+                                       lbest.threshold, rbest.threshold),
+                    cand_left_out=put(state.cand_left_out,
+                                      lbest.left_output, rbest.left_output),
+                    cand_right_out=put(state.cand_right_out,
+                                       lbest.right_output,
+                                       rbest.right_output),
+                    cand_left_cnt=put(state.cand_left_cnt, lbest.left_count,
+                                      rbest.left_count),
+                    cand_right_cnt=put(state.cand_right_cnt,
+                                       lbest.right_count, rbest.right_count),
+                    cand_left_g=put(state.cand_left_g, lbest.left_sum_grad,
+                                    rbest.left_sum_grad),
+                    cand_left_h=put(state.cand_left_h, lbest.left_sum_hess,
+                                    rbest.left_sum_hess),
+                    cand_right_g=put(state.cand_right_g,
+                                     lbest.right_sum_grad,
+                                     rbest.right_sum_grad),
+                    cand_right_h=put(state.cand_right_h,
+                                     lbest.right_sum_hess,
+                                     rbest.right_sum_hess),
+                    leaf_sum_g=put(state.leaf_sum_g, lg, rg),
+                    leaf_sum_h=put(state.leaf_sum_h, lh, rh),
+                    leaf_cnt=put(state.leaf_cnt, lcnt, rcnt),
+                    leaf_depth=put(state.leaf_depth, depth, depth),
+                )
 
         def no_split(state: _GrowState) -> _GrowState:
             return state._replace(done=jnp.asarray(True))
@@ -690,39 +721,48 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
     # named_scope per level (ISSUE 2): profile_dir= Perfetto traces show
     # the unrolled level structure ("level0/histogram", ...) instead of a
     # flat op soup — unconditional, so it can't perturb program identity
+    # Every piece of device work below also sits under one scope of
+    # telemetry.DEVICE_PHASES, unconditionally, so a device trace
+    # splits a level into histogram / split_find / row_route / tree_pack
+    # with telemetry on or off.
     with jax.named_scope("level0"):
         hists = batch_hist(jnp.zeros((N,), i32), row_mask, 1)  # [1,F,B,3]
-    root_stats = _root_stats_of(hists[0], s, compute_dtype, grad, hess,
-                                row_mask)
-    if s.own_slice is not None:
-        # ownership schedule: keep only this shard's contiguous feature
-        # block from here on (root stats above came from the full
-        # replicated histogram, so they stay bit-identical to the psum
-        # schedule)
-        hists = s.own_slice(hists)
+        with phase_scope("histogram"):
+            root_stats = _root_stats_of(hists[0], s, compute_dtype, grad,
+                                        hess, row_mask)
+            if s.own_slice is not None:
+                # ownership schedule: keep only this shard's contiguous
+                # feature block from here on (root stats above came from
+                # the full replicated histogram, so they stay
+                # bit-identical to the psum schedule)
+                hists = s.own_slice(hists)
 
-    # per-slot level state (slot s at level d holds one candidate leaf)
-    alive = jnp.ones((1,), bool)
-    leaf_of = jnp.zeros((1,), i32)          # output leaf index per slot
-    parent_node = jnp.full((1,), -1, i32)   # node owning this slot's leaf
-    slot_g = root_stats[0][None]
-    slot_h = root_stats[1][None]
-    slot_c = root_stats[2][None]
+    with phase_scope("split_find"):
+        # per-slot level state (slot s at level d holds one candidate leaf)
+        alive = jnp.ones((1,), bool)
+        leaf_of = jnp.zeros((1,), i32)          # output leaf index per slot
+        parent_node = jnp.full((1,), -1, i32)   # node owning the slot's leaf
+        slot_g = root_stats[0][None]
+        slot_h = root_stats[1][None]
+        slot_c = root_stats[2][None]
 
-    slot_id = jnp.zeros((N,), i32)          # row → level-local slot
-    out_leaf = jnp.zeros((N,), i32)         # row → output leaf index
+    with phase_scope("row_route"):
+        slot_id = jnp.zeros((N,), i32)          # row → level-local slot
+        out_leaf = jnp.zeros((N,), i32)         # row → output leaf index
 
-    # output tree arrays (static size L)
-    leaf_value = jnp.zeros((L,), f32)
-    leaf_count = jnp.zeros((L,), i32).at[0].set(root_stats[2].astype(i32))
-    leaf_parent = jnp.full((L,), -1, i32)
-    split_feature = jnp.zeros((max(L - 1, 1),), i32)
-    threshold_bin = jnp.zeros((max(L - 1, 1),), i32)
-    split_gain = jnp.zeros((max(L - 1, 1),), f32)
-    left_child = jnp.zeros((max(L - 1, 1),), i32)
-    right_child = jnp.zeros((max(L - 1, 1),), i32)
+    with phase_scope("tree_pack"):
+        # output tree arrays (static size L)
+        leaf_value = jnp.zeros((L,), f32)
+        leaf_count = jnp.zeros((L,), i32).at[0].set(
+            root_stats[2].astype(i32))
+        leaf_parent = jnp.full((L,), -1, i32)
+        split_feature = jnp.zeros((max(L - 1, 1),), i32)
+        threshold_bin = jnp.zeros((max(L - 1, 1),), i32)
+        split_gain = jnp.zeros((max(L - 1, 1),), f32)
+        left_child = jnp.zeros((max(L - 1, 1),), i32)
+        right_child = jnp.zeros((max(L - 1, 1),), i32)
 
-    n_nodes = jnp.asarray(0, i32)           # == num_leaves_cur - 1
+        n_nodes = jnp.asarray(0, i32)           # == num_leaves_cur - 1
 
     for d in range(D):
         P = 1 << d
@@ -730,57 +770,64 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # ---- best split per slot (vmapped FindBestThreshold scan).  The
         # span wraps the CALL (not the vmapped body — a batching trace is
         # never "execution"), so eager runs (jax.disable_jit telemetry
-        # profiling) attribute real split-search time
-        with telemetry.span("split_find") as _sp:
+        # profiling) attribute real split-search time.  The scope is its
+        # own: under vmap the finder's inner scope reads
+        # "vmap(split_find)", which no phase pattern matches
+        with phase_scope("split_find"), telemetry.span("split_find") as _sp:
             res = _sp.fence(vsplit(hists, slot_g, slot_h, slot_c, num_bins,
                                    feature_mask, mind, minh))
-        can = alive & (res.gain > 0.0) & jnp.isfinite(res.gain)
+            can = alive & (res.gain > 0.0) & jnp.isfinite(res.gain)
 
-        # ---- budget: split the top-gain slots first (within-level
-        # best-first, matching the leaf-wise selection rule at level scope)
-        budget = (L - 1) - n_nodes
-        gains_m = jnp.where(can, res.gain, -jnp.inf)
-        order = jnp.argsort(-gains_m)                 # best slot first
-        rank = jnp.argsort(order).astype(i32)         # slot → rank
-        chosen = can & (rank < budget)
-        n_chosen = jnp.sum(chosen.astype(i32))
+            # ---- budget: split the top-gain slots first (within-level
+            # best-first, matching the leaf-wise selection rule at level
+            # scope)
+            budget = (L - 1) - n_nodes
+            gains_m = jnp.where(can, res.gain, -jnp.inf)
+            order = jnp.argsort(-gains_m)             # best slot first
+            rank = jnp.argsort(order).astype(i32)     # slot → rank
+            chosen = can & (rank < budget)
+            n_chosen = jnp.sum(chosen.astype(i32))
 
-        # ---- index assignment, in slot order (deterministic)
-        csum = jnp.cumsum(chosen.astype(i32))
-        node_of = n_nodes + csum - 1                  # node per chosen slot
-        right_leaf = (n_nodes + 1) + csum - 1         # new leaf per chosen
-        bl = leaf_of
+            # ---- index assignment, in slot order (deterministic)
+            csum = jnp.cumsum(chosen.astype(i32))
+            node_of = n_nodes + csum - 1              # node per chosen slot
+            right_leaf = (n_nodes + 1) + csum - 1     # new leaf per chosen
+            bl = leaf_of
 
-        nidx = jnp.where(chosen, node_of, BIG)
-        blx = jnp.where(chosen, bl, BIG)
-        rlx = jnp.where(chosen, right_leaf, BIG)
+        with phase_scope("tree_pack"):
+            nidx = jnp.where(chosen, node_of, BIG)
+            blx = jnp.where(chosen, bl, BIG)
+            rlx = jnp.where(chosen, right_leaf, BIG)
 
-        # ---- node records (Tree::Split, tree.cpp:50-83)
-        split_feature = split_feature.at[nidx].set(res.feature, mode="drop")
-        threshold_bin = threshold_bin.at[nidx].set(res.threshold, mode="drop")
-        split_gain = split_gain.at[nidx].set(res.gain, mode="drop")
-        left_child = left_child.at[nidx].set(~bl, mode="drop")
-        right_child = right_child.at[nidx].set(~right_leaf, mode="drop")
+            # ---- node records (Tree::Split, tree.cpp:50-83)
+            split_feature = split_feature.at[nidx].set(res.feature,
+                                                       mode="drop")
+            threshold_bin = threshold_bin.at[nidx].set(res.threshold,
+                                                       mode="drop")
+            split_gain = split_gain.at[nidx].set(res.gain, mode="drop")
+            left_child = left_child.at[nidx].set(~bl, mode="drop")
+            right_child = right_child.at[nidx].set(~right_leaf, mode="drop")
 
-        # parent child-pointer fixup: slot parity says which side this
-        # slot's leaf sits on in its parent node (even = left)
-        pfix = jnp.where(chosen & (parent_node >= 0), parent_node, BIG)
-        if d > 0:
-            is_left = (jnp.arange(P, dtype=i32) % 2) == 0
-            left_child = left_child.at[
-                jnp.where(is_left, pfix, BIG)].set(node_of, mode="drop")
-            right_child = right_child.at[
-                jnp.where(is_left, BIG, pfix)].set(node_of, mode="drop")
+            # parent child-pointer fixup: slot parity says which side this
+            # slot's leaf sits on in its parent node (even = left)
+            pfix = jnp.where(chosen & (parent_node >= 0), parent_node, BIG)
+            if d > 0:
+                is_left = (jnp.arange(P, dtype=i32) % 2) == 0
+                left_child = left_child.at[
+                    jnp.where(is_left, pfix, BIG)].set(node_of, mode="drop")
+                right_child = right_child.at[
+                    jnp.where(is_left, BIG, pfix)].set(node_of, mode="drop")
 
-        # ---- leaf records
-        leaf_value = leaf_value.at[blx].set(res.left_output, mode="drop")
-        leaf_value = leaf_value.at[rlx].set(res.right_output, mode="drop")
-        leaf_count = leaf_count.at[blx].set(res.left_count, mode="drop")
-        leaf_count = leaf_count.at[rlx].set(res.right_count, mode="drop")
-        leaf_parent = leaf_parent.at[blx].set(node_of, mode="drop")
-        leaf_parent = leaf_parent.at[rlx].set(node_of, mode="drop")
+            # ---- leaf records
+            leaf_value = leaf_value.at[blx].set(res.left_output, mode="drop")
+            leaf_value = leaf_value.at[rlx].set(res.right_output,
+                                                mode="drop")
+            leaf_count = leaf_count.at[blx].set(res.left_count, mode="drop")
+            leaf_count = leaf_count.at[rlx].set(res.right_count, mode="drop")
+            leaf_parent = leaf_parent.at[blx].set(node_of, mode="drop")
+            leaf_parent = leaf_parent.at[rlx].set(node_of, mode="drop")
 
-        n_nodes = n_nodes + n_chosen
+            n_nodes = n_nodes + n_chosen
 
         # ---- partition rows (DataPartition::Split as fused masked passes)
         # All per-slot attributes a row needs (split feature, threshold,
@@ -789,8 +836,11 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # slot-select one-hot is the expensive object (O(P·N) comparisons),
         # so it is generated once and contracted against a packed [P, K]
         # table.
-        small_is_right = res.right_count < res.left_count        # ties → left
-        with telemetry.span("partition") as _sp:
+        # The device name is "row_route": "partition" is the compacted
+        # grower's stream-partition kernel.  The host span keeps its
+        # canonical JSONL key.
+        with phase_scope("row_route"), telemetry.span("partition") as _sp:
+            small_is_right = res.right_count < res.left_count   # ties → left
             # mixed-bin packing stores the matrix rows in packed order;
             # the per-slot partition feature must address that layout
             # (the recorded split_feature above stays canonical)
@@ -853,17 +903,17 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         def interleave(a, b):
             return jnp.stack([a, b], axis=1).reshape(2 * P, *a.shape[1:])
 
-        alive = interleave(chosen, chosen)
-        leaf_of = interleave(bl, right_leaf)
-        parent_node = interleave(node_of, node_of)
-        slot_g = interleave(res.left_sum_grad, res.right_sum_grad)
-        slot_h = interleave(res.left_sum_hess, res.right_sum_hess)
-        slot_c = interleave(res.left_count.astype(f32),
-                            res.right_count.astype(f32))
+        with phase_scope("split_find"):
+            alive = interleave(chosen, chosen)
+            leaf_of = interleave(bl, right_leaf)
+            parent_node = interleave(node_of, node_of)
+            slot_g = interleave(res.left_sum_grad, res.right_sum_grad)
+            slot_h = interleave(res.left_sum_hess, res.right_sum_hess)
+            slot_c = interleave(res.left_count.astype(f32),
+                                res.right_count.astype(f32))
 
         # ---- level histogram: build ONLY the smaller child of every chosen
         # parent in one batched pass, derive the sibling by subtraction
-        par_of_row = slot_id // 2
         # Smaller-child choice from the SplitResult counts (integer-valued
         # f32 histogram sums; replicated under the data-parallel learner,
         # whose counts come from psum'd histograms).  Above 2^24 rows per
@@ -871,7 +921,9 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # only means the pass histograms the slightly larger child (the
         # sibling is still exact via subtraction), a perf non-event, so no
         # recount is needed at any scale.
-        sel = in_chosen & (go_right == small_right_row) & row_mask
+        with phase_scope("row_route"):
+            par_of_row = slot_id // 2
+            sel = in_chosen & (go_right == small_right_row) & row_mask
         # The masked full-N pass is the fastest smaller-child schedule
         # measured on v5e (1M and 11M rows): gathering the selected rows
         # into a compact N/2 buffer first (the masked-dense analog of the
@@ -881,26 +933,29 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         with jax.named_scope("level%d" % (d + 1)):
             hist_small = batch_hist(par_of_row, sel, P, level=True,
                                     salt=d + 1)
-        hist_large = hists - hist_small
-        hsmall_slot = interleave(jnp.where(small_is_right[:, None, None, None],
-                                           hist_large, hist_small),
-                                 jnp.where(small_is_right[:, None, None, None],
-                                           hist_small, hist_large))
-        hists = hsmall_slot
+            # the sibling subtraction and the slot interleave read and
+            # write whole [P, F, B, 3] histograms: the histogram phase's
+            with phase_scope("histogram"):
+                hist_large = hists - hist_small
+                flip = small_is_right[:, None, None, None]
+                hists = interleave(
+                    jnp.where(flip, hist_large, hist_small),
+                    jnp.where(flip, hist_small, hist_large))
 
-    num_leaves_final = n_nodes + 1
-    return TreeArrays(
-        num_leaves=num_leaves_final,
-        split_feature=split_feature[:max(L - 1, 1)],
-        threshold_bin=threshold_bin,
-        split_gain=split_gain,
-        left_child=left_child,
-        right_child=right_child,
-        leaf_parent=leaf_parent,
-        leaf_value=leaf_value,
-        leaf_count=leaf_count,
-        leaf_ids=out_leaf,
-    )
+    with phase_scope("tree_pack"):
+        num_leaves_final = n_nodes + 1
+        return TreeArrays(
+            num_leaves=num_leaves_final,
+            split_feature=split_feature[:max(L - 1, 1)],
+            threshold_bin=threshold_bin,
+            split_gain=split_gain,
+            left_child=left_child,
+            right_child=right_child,
+            leaf_parent=leaf_parent,
+            leaf_value=leaf_value,
+            leaf_count=leaf_count,
+            leaf_ids=out_leaf,
+        )
 
 
 # ==================================================== leafcompact policy
@@ -996,13 +1051,14 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
 
     def best_of(hist, sum_g, sum_h, cnt, depth, root=False):
         f = (s.root_split_finder or finder) if root else finder
-        if root:
-            return _depth_gated(
-                f(hist, sum_g, sum_h, cnt, num_bins, feature_mask,
-                  float(min_data_in_leaf),
-                  float(min_sum_hessian_in_leaf)), depth, max_depth)
-        return _depth_gated(_finder(hist, sum_g, sum_h, cnt), depth,
-                            max_depth)
+        with phase_scope("split_find"):
+            if root:
+                return _depth_gated(
+                    f(hist, sum_g, sum_h, cnt, num_bins, feature_mask,
+                      float(min_data_in_leaf),
+                      float(min_sum_hessian_in_leaf)), depth, max_depth)
+            return _depth_gated(_finder(hist, sum_g, sum_h, cnt), depth,
+                                max_depth)
 
     def best_of_pair(lhist, rhist, lg, lh, lc, rg, rh, rc, depth):
         """Both children's candidate searches in ONE batched finder call
@@ -1010,12 +1066,15 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
         is tiny, so per-call XLA overhead — paid 2x per split otherwise —
         is the cost that matters.  Elementwise math is identical to two
         single calls (both children share the same depth)."""
-        both = _depth_gated(
-            jax.vmap(_finder)(jnp.stack([lhist, rhist]),
-                              jnp.stack([lg, rg]), jnp.stack([lh, rh]),
-                              jnp.stack([lc, rc])), depth, max_depth)
-        lbest = jax.tree.map(lambda x: x[0], both)
-        rbest = jax.tree.map(lambda x: x[1], both)
+        # the scope is this call's own: under vmap the finder's inner
+        # scope reads "vmap(split_find)", which no phase pattern matches
+        with phase_scope("split_find"):
+            both = _depth_gated(
+                jax.vmap(_finder)(jnp.stack([lhist, rhist]),
+                                  jnp.stack([lg, rg]), jnp.stack([lh, rh]),
+                                  jnp.stack([lc, rc])), depth, max_depth)
+            lbest = jax.tree.map(lambda x: x[0], both)
+            rbest = jax.tree.map(lambda x: x[1], both)
         return lbest, rbest
 
     # ---- root (BeforeTrain): full-data pass over the ORIGINAL arrays —
@@ -1027,55 +1086,66 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                            compute_dtype=compute_dtype,
                            axis_name=s.hist_axis, packing=packing, **_fg),
         lambda: hist_of(bins, grad, hess, row_mask), s, compute_dtype)
-    root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
-                                row_mask)
+    # Device phases, as in the masked policy: the candidate and
+    # segment tables are split_find's, the cache and the sibling
+    # subtraction histogram's, the original-order leaf ids row_route's,
+    # the pane (packing it, slicing a range out and writing it back)
+    # partition's, the node records tree_pack's.
+    with phase_scope("histogram"):
+        root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
+                                    row_mask)
     root_g, root_h, root_c = root_stats[0], root_stats[1], root_stats[2]
     root_best = best_of(root_hist, root_g, root_h, root_c,
                         jnp.asarray(1, jnp.int32), root=True)
+    with phase_scope("partition"):
+        root_pane = pack_planes(bins, grad, hess, row_mask, P)
 
     neg_inf = jnp.full((L,), -jnp.inf, dtype=f32)
     zeros_i = jnp.zeros((L,), dtype=jnp.int32)
     zeros_f = jnp.zeros((L,), dtype=f32)
 
-    tree = TreeArrays(
-        num_leaves=jnp.asarray(1, jnp.int32),
-        split_feature=jnp.zeros((L - 1,), jnp.int32),
-        threshold_bin=jnp.zeros((L - 1,), jnp.int32),
-        split_gain=jnp.zeros((L - 1,), f32),
-        left_child=jnp.zeros((L - 1,), jnp.int32),
-        right_child=jnp.zeros((L - 1,), jnp.int32),
-        leaf_parent=jnp.full((L,), -1, jnp.int32),
-        leaf_value=zeros_f,
-        leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
-        leaf_ids=jnp.zeros((N,), jnp.int32),
-    )
-    state = _CompactState(
-        tree=tree,
-        pane=pack_planes(bins, grad, hess, row_mask, P),
-        seg_start=zeros_i,
-        seg_cnt=zeros_i.at[0].set(N),
-        seg_bucket=zeros_i.at[0].set(bucket_of(N)),
-        # owned-block shape under an ownership schedule, full F otherwise
-        hist_cache=jnp.zeros((L,) + root_hist.shape, f32).at[0].set(
-            root_hist),
-        cand_gain=neg_inf.at[0].set(root_best.gain),
-        cand_feature=zeros_i.at[0].set(root_best.feature),
-        cand_threshold=zeros_i.at[0].set(root_best.threshold),
-        cand_left_out=zeros_f.at[0].set(root_best.left_output),
-        cand_right_out=zeros_f.at[0].set(root_best.right_output),
-        cand_left_cnt=zeros_i.at[0].set(root_best.left_count),
-        cand_right_cnt=zeros_i.at[0].set(root_best.right_count),
-        cand_left_g=zeros_f.at[0].set(root_best.left_sum_grad),
-        cand_left_h=zeros_f.at[0].set(root_best.left_sum_hess),
-        cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
-        cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
-        leaf_depth=zeros_i.at[0].set(1),
-        done=jnp.asarray(False),
-    )
+    with phase_scope("tree_pack"):
+        tree = TreeArrays(
+            num_leaves=jnp.asarray(1, jnp.int32),
+            split_feature=jnp.zeros((L - 1,), jnp.int32),
+            threshold_bin=jnp.zeros((L - 1,), jnp.int32),
+            split_gain=jnp.zeros((L - 1,), f32),
+            left_child=jnp.zeros((L - 1,), jnp.int32),
+            right_child=jnp.zeros((L - 1,), jnp.int32),
+            leaf_parent=jnp.full((L,), -1, jnp.int32),
+            leaf_value=zeros_f,
+            leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
+            leaf_ids=jnp.zeros((N,), jnp.int32),
+        )
+        state = _CompactState(
+            tree=tree,
+            pane=root_pane,
+            seg_start=zeros_i,
+            seg_cnt=zeros_i.at[0].set(N),
+            seg_bucket=zeros_i.at[0].set(bucket_of(N)),
+            # owned-block shape under an ownership schedule, full F
+            # otherwise
+            hist_cache=jnp.zeros((L,) + root_hist.shape, f32).at[0].set(
+                root_hist),
+            cand_gain=neg_inf.at[0].set(root_best.gain),
+            cand_feature=zeros_i.at[0].set(root_best.feature),
+            cand_threshold=zeros_i.at[0].set(root_best.threshold),
+            cand_left_out=zeros_f.at[0].set(root_best.left_output),
+            cand_right_out=zeros_f.at[0].set(root_best.right_output),
+            cand_left_cnt=zeros_i.at[0].set(root_best.left_count),
+            cand_right_cnt=zeros_i.at[0].set(root_best.right_count),
+            cand_left_g=zeros_f.at[0].set(root_best.left_sum_grad),
+            cand_left_h=zeros_f.at[0].set(root_best.left_sum_hess),
+            cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
+            cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
+            leaf_depth=zeros_i.at[0].set(1),
+            done=jnp.asarray(False),
+        )
 
     def make_partition_branch(k: int):
         W = table[k]
 
+        @phase_scope("partition")
         def branch(op):
             pane, start, cnt, feat, thr = op
             cs = jnp.minimum(start, P - W)        # clamp: slice stays
@@ -1104,6 +1174,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
     def make_hist_branch(k: int):
         W = table[k]
 
+        @phase_scope("histogram")
         def branch(op):
             pane2, sstart, scnt, salt = op
             cs2 = jnp.minimum(sstart, P - W)
@@ -1121,151 +1192,159 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
     hist_branches = [make_hist_branch(k) for k in range(K)]
 
     def body(_, state: _CompactState) -> _CompactState:
-        best_leaf = jnp.argmax(state.cand_gain).astype(jnp.int32)
-        best_gain = state.cand_gain[best_leaf]
-        should_split = jnp.logical_and(~state.done, best_gain > 0.0)
+        with phase_scope("split_find"):
+            best_leaf = jnp.argmax(state.cand_gain).astype(jnp.int32)
+            best_gain = state.cand_gain[best_leaf]
+            should_split = jnp.logical_and(~state.done, best_gain > 0.0)
 
         def do_split(state: _CompactState) -> _CompactState:
             tree = state.tree
             bl = best_leaf
             nl = tree.num_leaves
-            node = nl - 1
+            with phase_scope("tree_pack"):
+                node = nl - 1
             new_leaf = nl
 
-            feat = state.cand_feature[bl]
-            thr = state.cand_threshold[bl]
+            with phase_scope("split_find"):
+                feat = state.cand_feature[bl]
+                thr = state.cand_threshold[bl]
+                start = state.seg_start[bl]
+                cnt = state.seg_cnt[bl]
 
             # --- record the node (Tree::Split, tree.cpp:50-83)
-            p = tree.leaf_parent[bl]
-            pp = jnp.maximum(p, 0)
-            lc_at_p = jnp.where((p >= 0) & (tree.left_child[pp] == ~bl),
-                                node, tree.left_child[pp])
-            rc_at_p = jnp.where((p >= 0) & (tree.right_child[pp] == ~bl),
-                                node, tree.right_child[pp])
-            left_child = (tree.left_child.at[pp].set(lc_at_p)
-                          .at[node].set(~bl))
-            right_child = (tree.right_child.at[pp].set(rc_at_p)
-                           .at[node].set(~new_leaf))
+            with phase_scope("tree_pack"):
+                p = tree.leaf_parent[bl]
+                pp = jnp.maximum(p, 0)
+                lc_at_p = jnp.where(
+                    (p >= 0) & (tree.left_child[pp] == ~bl),
+                    node, tree.left_child[pp])
+                rc_at_p = jnp.where(
+                    (p >= 0) & (tree.right_child[pp] == ~bl),
+                    node, tree.right_child[pp])
+                left_child = (tree.left_child.at[pp].set(lc_at_p)
+                              .at[node].set(~bl))
+                right_child = (tree.right_child.at[pp].set(rc_at_p)
+                               .at[node].set(~new_leaf))
 
             # --- original-order leaf ids (score updates need them; the
             # pane's permutation never leaves this function)
-            ofeat = feat if c2p_arr is None else c2p_arr[feat]
-            obin = jax.lax.dynamic_index_in_dim(
-                bins, ofeat, axis=0, keepdims=False).astype(jnp.int32)
-            leaf_ids = jnp.where((tree.leaf_ids == bl) & (obin > thr),
-                                 new_leaf, tree.leaf_ids)
+            with phase_scope("row_route"):
+                ofeat = feat if c2p_arr is None else c2p_arr[feat]
+                obin = jax.lax.dynamic_index_in_dim(
+                    bins, ofeat, axis=0, keepdims=False).astype(jnp.int32)
+                leaf_ids = jnp.where((tree.leaf_ids == bl) & (obin > thr),
+                                     new_leaf, tree.leaf_ids)
 
             # --- partition the parent's lane range at ITS tier (local,
             # collective-free: shards may take different branches)
-            start = state.seg_start[bl]
-            cnt = state.seg_cnt[bl]
-            pane2, plcnt = jax.lax.switch(
-                state.seg_bucket[bl], partition_branches,
-                (state.pane, start, cnt, feat, thr))
-            prcnt = cnt - plcnt
+            with phase_scope("partition"):
+                pane2, plcnt = jax.lax.switch(
+                    state.seg_bucket[bl], partition_branches,
+                    (state.pane, start, cnt, feat, thr))
+                prcnt = cnt - plcnt
 
             # --- smaller-child histogram at the CHILD's own tier.  The
             # directly-built side is the VALID-smaller one, exactly like
             # the masked grower (same direct/subtracted f32 rounding);
             # its physical span picks the slice tier — pmax-synced across
             # shards so the collectives inside the branch line up
-            lcnt = state.cand_left_cnt[bl]
-            rcnt = state.cand_right_cnt[bl]
-            left_small = lcnt <= rcnt
-            scnt = jnp.where(left_small, plcnt, prcnt)
-            sstart = jnp.where(left_small, start, start + plcnt)
-            hk_span = scnt
-            if s.hist_axis is not None:
-                # tier-selector sync: a scalar pmax per split — tiny on
-                # the wire but a full collective latency, so it belongs
-                # in the interconnect inventory
-                _tl.record_collective(
-                    "leafcompact/tier_pmax", "pmax", s.hist_axis,
-                    _tl._tree_nbytes(hk_span), loop=L - 1, phase="grow")
-                hk_span = jax.lax.pmax(hk_span, s.hist_axis)
-            small_hist = jax.lax.switch(
-                bucket_of(hk_span), hist_branches,
-                (pane2, sstart, scnt, new_leaf))
+            with phase_scope("histogram"):
+                lcnt = state.cand_left_cnt[bl]
+                rcnt = state.cand_right_cnt[bl]
+                left_small = lcnt <= rcnt
+                scnt = jnp.where(left_small, plcnt, prcnt)
+                sstart = jnp.where(left_small, start, start + plcnt)
+                hk_span = scnt
+                if s.hist_axis is not None:
+                    # tier-selector sync: a scalar pmax per split — tiny
+                    # on the wire but a full collective latency, so it
+                    # belongs in the interconnect inventory
+                    _tl.record_collective(
+                        "leafcompact/tier_pmax", "pmax", s.hist_axis,
+                        _tl._tree_nbytes(hk_span), loop=L - 1, phase="grow")
+                    hk_span = jax.lax.pmax(hk_span, s.hist_axis)
+                small_hist = jax.lax.switch(
+                    bucket_of(hk_span), hist_branches,
+                    (pane2, sstart, scnt, new_leaf))
 
-            parent_hist = state.hist_cache[bl]
-            large_hist = parent_hist - small_hist
-            lhist = jnp.where(left_small, small_hist, large_hist)
-            rhist = jnp.where(left_small, large_hist, small_hist)
+                parent_hist = state.hist_cache[bl]
+                large_hist = parent_hist - small_hist
+                lhist = jnp.where(left_small, small_hist, large_hist)
+                rhist = jnp.where(left_small, large_hist, small_hist)
 
-            lg, lh = state.cand_left_g[bl], state.cand_left_h[bl]
-            rg, rh = state.cand_right_g[bl], state.cand_right_h[bl]
-            depth = state.leaf_depth[bl] + 1
+            with phase_scope("split_find"):
+                lg, lh = state.cand_left_g[bl], state.cand_left_h[bl]
+                rg, rh = state.cand_right_g[bl], state.cand_right_h[bl]
+                lcf, rcf = lcnt.astype(f32), rcnt.astype(f32)
+                depth = state.leaf_depth[bl] + 1
 
             # finder before the cache scatter: the packed-SplitInfo
             # allgather overlaps the HBM writeback (ISSUE 9 overlap seam;
             # pure program order, bit-identical values)
-            lbest, rbest = best_of_pair(lhist, rhist, lg, lh,
-                                        lcnt.astype(f32), rg, rh,
-                                        rcnt.astype(f32), depth)
-            hist_cache = (state.hist_cache.at[bl].set(lhist)
-                          .at[new_leaf].set(rhist))
+            lbest, rbest = best_of_pair(lhist, rhist, lg, lh, lcf, rg, rh,
+                                        rcf, depth)
+            with phase_scope("histogram"):
+                hist_cache = (state.hist_cache.at[bl].set(lhist)
+                              .at[new_leaf].set(rhist))
 
-            tree = tree._replace(
-                num_leaves=nl + 1,
-                split_feature=tree.split_feature.at[node].set(feat),
-                threshold_bin=tree.threshold_bin.at[node].set(thr),
-                split_gain=tree.split_gain.at[node].set(best_gain),
-                left_child=left_child,
-                right_child=right_child,
-                leaf_parent=tree.leaf_parent.at[bl].set(node)
-                                            .at[new_leaf].set(node),
-                leaf_value=tree.leaf_value
-                               .at[bl].set(state.cand_left_out[bl])
-                               .at[new_leaf].set(state.cand_right_out[bl]),
-                leaf_count=tree.leaf_count.at[bl].set(lcnt)
-                                          .at[new_leaf].set(rcnt),
-                leaf_ids=leaf_ids,
-            )
-            return state._replace(
-                tree=tree,
-                pane=pane2,
-                seg_start=state.seg_start.at[new_leaf].set(start + plcnt),
-                seg_cnt=state.seg_cnt.at[bl].set(plcnt)
-                                     .at[new_leaf].set(prcnt),
-                seg_bucket=state.seg_bucket.at[bl].set(bucket_of(plcnt))
-                                           .at[new_leaf].set(
-                                               bucket_of(prcnt)),
-                hist_cache=hist_cache,
-                cand_gain=state.cand_gain.at[bl].set(lbest.gain)
-                                         .at[new_leaf].set(rbest.gain),
-                cand_feature=state.cand_feature.at[bl].set(lbest.feature)
-                                               .at[new_leaf]
-                                               .set(rbest.feature),
-                cand_threshold=state.cand_threshold
-                                    .at[bl].set(lbest.threshold)
-                                    .at[new_leaf].set(rbest.threshold),
-                cand_left_out=state.cand_left_out
-                                   .at[bl].set(lbest.left_output)
-                                   .at[new_leaf].set(rbest.left_output),
-                cand_right_out=state.cand_right_out
-                                    .at[bl].set(lbest.right_output)
-                                    .at[new_leaf].set(rbest.right_output),
-                cand_left_cnt=state.cand_left_cnt
-                                   .at[bl].set(lbest.left_count)
-                                   .at[new_leaf].set(rbest.left_count),
-                cand_right_cnt=state.cand_right_cnt
-                                    .at[bl].set(lbest.right_count)
-                                    .at[new_leaf].set(rbest.right_count),
-                cand_left_g=state.cand_left_g
-                                 .at[bl].set(lbest.left_sum_grad)
-                                 .at[new_leaf].set(rbest.left_sum_grad),
-                cand_left_h=state.cand_left_h
-                                 .at[bl].set(lbest.left_sum_hess)
-                                 .at[new_leaf].set(rbest.left_sum_hess),
-                cand_right_g=state.cand_right_g
-                                  .at[bl].set(lbest.right_sum_grad)
-                                  .at[new_leaf].set(rbest.right_sum_grad),
-                cand_right_h=state.cand_right_h
-                                  .at[bl].set(lbest.right_sum_hess)
-                                  .at[new_leaf].set(rbest.right_sum_hess),
-                leaf_depth=state.leaf_depth.at[bl].set(depth)
-                                           .at[new_leaf].set(depth),
-            )
+            with phase_scope("tree_pack"):
+                tree = tree._replace(
+                    num_leaves=nl + 1,
+                    split_feature=tree.split_feature.at[node].set(feat),
+                    threshold_bin=tree.threshold_bin.at[node].set(thr),
+                    split_gain=tree.split_gain.at[node].set(best_gain),
+                    left_child=left_child,
+                    right_child=right_child,
+                    leaf_parent=tree.leaf_parent.at[bl].set(node)
+                                                .at[new_leaf].set(node),
+                    leaf_value=tree.leaf_value
+                                   .at[bl].set(state.cand_left_out[bl])
+                                   .at[new_leaf]
+                                   .set(state.cand_right_out[bl]),
+                    leaf_count=tree.leaf_count.at[bl].set(lcnt)
+                                              .at[new_leaf].set(rcnt),
+                    leaf_ids=leaf_ids,
+                )
+
+            def put(arr, left, right):
+                return arr.at[bl].set(left).at[new_leaf].set(right)
+
+            with phase_scope("split_find"):
+                return state._replace(
+                    tree=tree,
+                    pane=pane2,
+                    seg_start=state.seg_start.at[new_leaf].set(
+                        start + plcnt),
+                    seg_cnt=put(state.seg_cnt, plcnt, prcnt),
+                    seg_bucket=put(state.seg_bucket, bucket_of(plcnt),
+                                   bucket_of(prcnt)),
+                    hist_cache=hist_cache,
+                    cand_gain=put(state.cand_gain, lbest.gain, rbest.gain),
+                    cand_feature=put(state.cand_feature, lbest.feature,
+                                     rbest.feature),
+                    cand_threshold=put(state.cand_threshold,
+                                       lbest.threshold, rbest.threshold),
+                    cand_left_out=put(state.cand_left_out,
+                                      lbest.left_output, rbest.left_output),
+                    cand_right_out=put(state.cand_right_out,
+                                       lbest.right_output,
+                                       rbest.right_output),
+                    cand_left_cnt=put(state.cand_left_cnt, lbest.left_count,
+                                      rbest.left_count),
+                    cand_right_cnt=put(state.cand_right_cnt,
+                                       lbest.right_count, rbest.right_count),
+                    cand_left_g=put(state.cand_left_g, lbest.left_sum_grad,
+                                    rbest.left_sum_grad),
+                    cand_left_h=put(state.cand_left_h, lbest.left_sum_hess,
+                                    rbest.left_sum_hess),
+                    cand_right_g=put(state.cand_right_g,
+                                     lbest.right_sum_grad,
+                                     rbest.right_sum_grad),
+                    cand_right_h=put(state.cand_right_h,
+                                     lbest.right_sum_hess,
+                                     rbest.right_sum_hess),
+                    leaf_depth=put(state.leaf_depth, depth, depth),
+                )
 
         def no_split(state: _CompactState) -> _CompactState:
             return state._replace(done=jnp.asarray(True))

@@ -363,8 +363,10 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
     int-domain bit-exactness chain and the DP ownership schedule are
     untouched."""
     from .. import telemetry
-    # named_scope unconditionally (the span is a no-op with telemetry
-    # off): profile_dir= traces label the kernel "histogram" either way
+    # the device name comes from the unconditional named_scope; the span
+    # is a host timer only and puts nothing into the program (a scope of
+    # its own would make a traced run's kernel read "histogram/histogram"
+    # and miss the timed run's compile-cache entry)
     with jax.named_scope("histogram"), telemetry.span("histogram") as sp:
         return sp.fence(_grouped(
             _hist_pallas_one, bins, grad, hess, col_id, col_ok,

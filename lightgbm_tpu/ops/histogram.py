@@ -216,10 +216,10 @@ def histogram_matmul(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
 def _histogram_matmul_impl(bins, grad, hess, mask, num_bins_max, chunk,
                            compute_dtype) -> jax.Array:
-    # named_scope is UNCONDITIONAL (unlike the telemetry span wrapping the
-    # caller): a profile_dir= Perfetto trace labels these ops "histogram"
-    # whether or not telemetry is armed, and the scope is always present
-    # so telemetry on/off cannot change the traced program's identity
+    # the device name: an UNCONDITIONAL named_scope (telemetry.DEVICE_PHASES).
+    # The telemetry span around the caller is a host timer and never
+    # enters a scope, so telemetry on/off cannot change the traced
+    # program's text or its compile-cache key
     with jax.named_scope("histogram"):
         return _histogram_matmul_scoped(bins, grad, hess, mask,
                                         num_bins_max, chunk, compute_dtype)

@@ -1,8 +1,9 @@
 """Process-wide telemetry: phase timers, kernel-route counters, JSONL sink.
 
 The repo's previous observability was three ad-hoc hacks: ``time.time()``
-prints in cli.py, hist-stubbed A/B differencing in scripts/profile_phases.py
-(PROFILE.md), and hand-assembled counter tables in BENCH rounds.  This module
+prints in cli.py, hist-stubbed A/B differencing (PROFILE.md; the script
+went in PR 27, replaced by the device-trace reduction under benchmarks/),
+and hand-assembled counter tables in BENCH rounds.  This module
 replaces them with one registry, designed around two JAX realities:
 
 1. **Route decisions are trace-time events.**  Kernel routing (Pallas int8 /
@@ -12,15 +13,22 @@ replaces them with one registry, designed around two JAX realities:
    chosen route forever.  Counters therefore increment once per traced
    decision — exactly the record of "which route did this program actually
    bake in" that the mixed-backend hardening episodes (commit e7ff0d9)
-   lacked.  Recompiles are counted via a ``jax.monitoring`` backend-compile
-   listener (cache hits fire nothing, so the count is true recompiles).
+   lacked.  Compiles are counted via one ``jax.monitoring`` listener:
+   ``jit/backend_compile`` (a persistent-cache hit fires none, so the
+   count is true compiles), ``jit/persistent_cache_hit`` / ``_miss``, and
+   under ``trace_times`` the seconds of each stage of building a program:
+   ``jaxpr_trace``, ``lower``, ``backend_compile``, ``cache_load``.
 
-2. **Spans are host-side wall timers.**  ``span("histogram")`` times the
-   enclosed *host* call with ``time.perf_counter``.  A span entered while
-   JAX is tracing is recorded under ``trace_times`` (it measured tracing,
-   not execution); a span entered with concrete arrays (the boosting loop's
-   host phases, or any op under ``jax.disable_jit()``) is recorded under
-   ``phase_times``.  The optional **fence mode** (``set_fence(True)`` /
+2. **Spans are host-side wall timers, and nothing else.**
+   ``span("histogram")`` times the enclosed *host* call with
+   ``time.perf_counter`` and puts nothing into a traced program.  A span
+   entered while JAX is tracing is recorded under ``trace_times`` (it
+   measured tracing, not execution); a span entered with concrete arrays
+   (the boosting loop's host phases, set-up's ``dataset_bin`` /
+   ``booster_init``, or any op under ``jax.disable_jit()``) is recorded
+   under ``phase_times``.  Spans nest: a layer's self time is its span
+   less the spans entered inside it (``dataset_bin`` less ``find_bins``
+   and ``binarize``).  The optional **fence mode** (``set_fence(True)`` /
    ``enable(fence=True)``) calls ``jax.block_until_ready`` on a value the
    caller hands to ``Span.fence(x)`` before stopping the timer, so async
    dispatch does not attribute device time to the wrong phase.  Fencing
@@ -28,10 +36,20 @@ replaces them with one registry, designed around two JAX realities:
    computation — so it cannot trip the environment's ~60 s per-dispatch
    execution watchdog (BASELINE.md).
 
-Zero overhead when disabled: every public entry checks one module flag and
-returns a no-op singleton; nothing is ever inserted into traced programs,
-so enabling/disabling telemetry perturbs neither numerics nor jit caching
-(tests/test_telemetry.py locks this in).
+One program, traced or not: every public entry checks one module flag and
+returns a no-op singleton when disabled, and a span is a HOST object only —
+a timer plus a ``jax.profiler.TraceAnnotation`` on the profiler's clock.  A
+span never enters ``jax.named_scope``: the ops sites open their spans
+inside an unconditional scope of the same name, so a span that entered one
+would make a program traced with telemetry on carry ``histogram/histogram``
+where the timed run's carries ``histogram`` — another HLO text and, through
+the locations serialized into the Pallas kernels, another persistent-cache
+key (PERF.md: a second 35 s compile for every traced run).  Names that must
+reach the device trace are unconditional ``jax.named_scope``s at the site,
+drawn from the closed set ``DEVICE_PHASES`` below.  So enabling/disabling
+telemetry perturbs neither numerics, nor the lowered text (debug info
+included), nor the compile-cache key (tests/test_trace_scopes.py and
+tests/test_telemetry.py lock this in).
 
 JSONL sink: ``enable(jsonl_path)`` (the ``metrics_out=...`` config/CLI
 option) arms a per-iteration record stream; the boosting loop emits one
@@ -64,12 +82,17 @@ ISSUE 2 additions — the device-side observability triad:
    never dispatches device work.
 
 4. **Profiler alignment**: every span body runs under
-   ``jax.named_scope(name)`` + ``jax.profiler.TraceAnnotation(name)``, so
-   a Perfetto trace captured via ``profile_dir=`` carries the SAME phase
-   names as the JSONL records — device rows (HLO op metadata) and host
-   timeline rows line up with ``phase_times`` keys.  Health events (NaN
-   counts, saturation, divergence — lightgbm_tpu/health.py) ride the
-   iteration records as a ``health`` block via ``emit_iteration``.
+   ``jax.profiler.TraceAnnotation(name)``, so a Perfetto trace captured
+   via ``profile_dir=`` (or the benchmark's ``--trace 1``) carries the
+   span on the HOST timeline, on the profiler's own clock, beside the
+   device rows.  The DEVICE rows get their phase names from the
+   program's unconditional ``jax.named_scope``s (``DEVICE_PHASES``),
+   which the compiler keeps in each operation's metadata whether or not
+   telemetry is armed: the host span ``histogram`` and the device scope
+   ``histogram`` share a name because the site gives both, not because
+   the span writes into the program.  Health events (NaN counts,
+   saturation, divergence — lightgbm_tpu/health.py) ride the iteration
+   records as a ``health`` block via ``emit_iteration``.
 
 ISSUE 4 — roofline attribution and compile observability
 (lightgbm_tpu/costmodel.py rides this registry's lifecycle):
@@ -188,6 +211,29 @@ ISSUE 16 — flight recorder + per-request latency attribution
     ``disable()`` disarms the recorder (dumping first when configured);
     ``emit_iteration`` files one ``train_iter`` ring event per
     iteration sharing the timeline-shard record keys.
+
+ISSUE 27 — every millisecond of an iteration and every second of set-up
+under a name the program gives it (PERF.md section 3 has the table of
+which benchmark metric reads which):
+
+13. **Device phases** (``DEVICE_PHASES`` / ``phase_scope``): the closed
+    set of unconditional scopes described above.  **Host turn**:
+    ``device_wait`` (the host blocked on the dispatched program; entered
+    only with telemetry on, where the readback would block anyway),
+    ``model_readback`` (the copy alone) and ``tree_build`` (host tree
+    construction), with the counters ``train/iterations``,
+    ``train/chunks`` and ``train/readback_bytes`` counted where the
+    trees are consumed.  **Set-up**: ``dataset_bin`` (opened once per
+    loader phase; its own time is the row sample) ⊇ ``find_bins`` (cut
+    points, in ``find_bins_for_matrix`` alone) + ``binarize`` (one
+    ``searchsorted`` per column), in the loaders' shared internals,
+    with ``bin/values`` and ``bin/sample_rows``; ``booster_init`` ⊇ ``h2d`` (the bin table's
+    placement, waited for) with ``init/h2d_bytes``; and the compile
+    listener's ``trace_times`` keys ``jaxpr_trace`` (nested traces
+    counted once), ``lower``, ``backend_compile``, ``cache_load`` with
+    ``jit/persistent_cache_hit`` / ``_miss``.  Every one of these
+    names has a reader: a per-layer metric of ``BENCHMARK.json``
+    (``benchmarks/metrics/``) and a row of README's "Telemetry" table.
 """
 from __future__ import annotations
 
@@ -220,6 +266,8 @@ COUNTER_FAMILIES = (
     "allhosts/*",                 # cross-host sums (aggregate_telemetry)
     "bagging/device",
     "bagging/host",
+    "bin/sample_rows",            # rows sampled for the cut points
+    "bin/values",                 # rows x used columns quantized
     "ckpt/async_write_us",
     "ckpt/dropped",
     "ckpt/pruned",
@@ -262,9 +310,11 @@ COUNTER_FAMILIES = (
     "ingest/parse_us",
     "ingest/rows",
     "ingest/worker_wait_us",
+    "init/h2d_bytes",             # bin table placed by GBDT.init
     "jit/backend_compile",
     "jit/midrun_recompile",
     "jit/persistent_cache_hit",
+    "jit/persistent_cache_miss",
     "learner/fp_*",               # feature-parallel ownership routes
     "monitor/drift_scores",
     "monitor/slo_breaches",
@@ -296,15 +346,24 @@ COUNTER_FAMILIES = (
     "serve/warmups",
     "trace/dropped",
     "trace/dumps",
+    "train/chunks",               # fused chunks consumed
+    "train/iterations",           # iterations whose trees were consumed
+    "train/readback_bytes",       # device -> host bytes of the model readback
 )
 
 SPAN_FAMILIES = (
     "bagging",
+    "binarize",
+    "booster_init",
+    "dataset_bin",
+    "device_wait",
     "elastic",
     "eval",
+    "find_bins",
     "goss",
     "gradient",
     "grow",
+    "h2d",
     "histogram",
     "ingest",
     "ingest_bin",
@@ -320,8 +379,42 @@ SPAN_FAMILIES = (
     "split_find",
     "trace_dump",
     "train_chunk",
+    "tree_build",
     "valid_update",
 )
+
+# The closed set of DEVICE phase names: every unconditional
+# ``jax.named_scope`` that the fused iteration (models/gbdt.make_chunk_body),
+# the per-iteration path and the three grow policies put around device
+# work is one of these, so a device trace splits an iteration into these
+# rows plus a remainder that is a number (the benchmark's
+# ``unscoped_ms_per_iter``), not a guess.  ``level<d>``, ``leafwise_split``
+# and ``leafcompact_split`` are OUTER grouping scopes and the objectives'
+# ``gradient_<objective>`` nest inside ``gradient``.  XLA gives a fusion
+# the metadata of its root operation, so a boundary between two phases is
+# exact only where the compiler did not fuse across it.
+DEVICE_PHASES = (
+    "gradient",       # objective gradients, GOSS selection
+    "histogram",      # histogram passes, sibling subtraction, interleave
+    "split_find",     # threshold scan, candidate bookkeeping
+    "row_route",      # row -> slot / leaf update of the masked growers
+    "partition",      # the compacted grower's stream partition
+    "score_update",   # leaf lookup + add, valid-score replay
+    "tree_pack",      # leaf values, TreeArrays assembly, chunk stacking
+    "eval",           # in-program metrics and the health vector
+)
+
+
+def phase_scope(name: str):
+    """The unconditional ``jax.named_scope`` of one device phase: the one
+    way a phase name reaches the device trace.  Not gated on the enabled
+    flag (a scope costs nothing at run time and must not differ between
+    the timed and the traced program); a name outside ``DEVICE_PHASES``
+    is a programming error."""
+    if name not in DEVICE_PHASES:
+        raise ValueError("%r is not one of DEVICE_PHASES" % (name,))
+    import jax
+    return jax.named_scope(name)
 
 WIRE_SITE_FAMILIES = (
     "dp/grad_score_allgather",
@@ -555,6 +648,7 @@ def reset() -> None:
     _collectives.clear()
     _ring.clear()
     del _span_stack[:]
+    del _open_traces[:]
 
 
 def set_fence(on: bool) -> None:
@@ -1123,36 +1217,27 @@ class Span:
     ``jax.block_until_ready`` at exit when fence mode is on (execution-time
     spans only; trace-time spans never block).
 
-    Profiler alignment (ISSUE 2): the span body runs under
-    ``jax.named_scope(name)`` (ops traced inside carry the phase name in
-    HLO metadata → Perfetto device rows) and
-    ``jax.profiler.TraceAnnotation(name)`` (a host-timeline trace event),
-    so ``profile_dir=`` traces line up with the JSONL phase keys.  With
-    memory gauges armed, the span also samples the allocator at its
-    boundaries (per-phase byte delta + watermark)."""
-    __slots__ = ("name", "_t0", "_fence_val", "_is_trace", "_scope",
-                 "_ann", "_mem0")
+    Profiler alignment: the span body runs under
+    ``jax.profiler.TraceAnnotation(name)`` (a host-timeline trace event
+    on the profiler's clock), so ``profile_dir=`` traces line up with the
+    JSONL phase keys.  The span never enters ``jax.named_scope``: what a
+    traced program carries must not depend on whether telemetry is armed
+    (module docstring); device rows are named by the sites' unconditional
+    scopes (``DEVICE_PHASES``).  With memory gauges armed, the span also
+    samples the allocator at its boundaries (per-phase byte delta +
+    watermark)."""
+    __slots__ = ("name", "_t0", "_fence_val", "_is_trace", "_ann", "_mem0")
 
     def __init__(self, name: str):
         self.name = name
         self._fence_val = None
         self._is_trace = False
         self._t0 = 0.0
-        self._scope = None
         self._ann = None
         self._mem0 = None
 
     def __enter__(self):
         self._is_trace = _tracing()
-        # two independent try blocks: if the annotation fails AFTER the
-        # named scope entered, the scope must still be tracked (and later
-        # exited) or the global name stack would grow one entry per span
-        try:
-            import jax
-            self._scope = jax.named_scope(self.name)
-            self._scope.__enter__()
-        except Exception:
-            self._scope = None
         try:
             import jax
             self._ann = jax.profiler.TraceAnnotation(self.name)
@@ -1187,12 +1272,6 @@ class Span:
             except Exception:
                 pass
             self._ann = None
-        if self._scope is not None:
-            try:
-                self._scope.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
-            self._scope = None
         if self._mem0 is not None:
             b1 = _mem_sample()
             _mem_phase_delta[self.name] = (
@@ -1302,11 +1381,45 @@ def merge_host_counters(totals: Dict[str, int]) -> None:
         _counters["allhosts/" + k] = int(v)
 
 
+# jax.monitoring event -> ``trace_times`` key: the stages of building one
+# program.  ``backend_compile`` is kept for true compiles only — jax fires
+# its duration event around the persistent-cache lookup as well, so a load
+# from the cache would otherwise count as a compile; a load's seconds go
+# under ``cache_load`` (the four keys are disjoint and sum to what the
+# process spent building programs)
+_BUILD_SECONDS = {
+    "jaxpr_to_mlir_module_duration": "lower",
+    "cache_retrieval_time_sec": "cache_load",
+}
+# True between a persistent-cache hit and the backend-compile duration
+# event that closes the same lookup
+_cache_load_pending = False
+# jaxpr traces nest (an inner jit traced inside an outer one fires first
+# and lies inside the outer's interval): (start, seconds) of the traces
+# not yet found inside another, so each second is counted once
+_open_traces: List[tuple] = []
+
+
+def _on_jaxpr_trace(dur: float) -> None:
+    end = time.perf_counter()
+    start = end - dur
+    inner = 0.0
+    while _open_traces and _open_traces[-1][0] >= start:
+        inner += _open_traces.pop()[1]
+    _open_traces.append((start, dur))
+    del _open_traces[:-256]
+    _trace_times["jaxpr_trace"] = (
+        _trace_times.get("jaxpr_trace", 0.0) + dur - inner)
+
+
 def _install_compile_listener() -> None:
-    """Count true recompiles via jax.monitoring: the backend-compile
-    duration event fires once per compilation-cache miss and never on a
-    hit, so the counter is exactly the number of XLA compiles this process
-    paid.  Registered once; increments are gated on the enabled flag
+    """The one ``jax.monitoring`` listener: counts true compiles
+    (``jit/backend_compile``: executables the backend built, not those the
+    persistent cache served), cache hits and misses, and keeps under
+    ``trace_times`` the seconds of each stage of building a program
+    (``jaxpr_trace``, ``lower``, ``backend_compile``, ``cache_load``).
+    Only the whole process is seen: the events name no program.
+    Registered once; increments are gated on the enabled flag
     (jax.monitoring has no unregister)."""
     global _compile_listener_installed
     if _compile_listener_installed:
@@ -1315,11 +1428,25 @@ def _install_compile_listener() -> None:
         from jax import monitoring
 
         def _on_duration(name: str, dur: float, **kw) -> None:
-            if _enabled and name.endswith("backend_compile_duration"):
+            global _cache_load_pending
+            if not _enabled:
+                return
+            name = name.rsplit("/", 1)[-1]
+            if name == "backend_compile_duration":
+                if _cache_load_pending:
+                    # the lookup hit: its seconds are under cache_load
+                    _cache_load_pending = False
+                    return
                 _counters["jit/backend_compile"] = (
                     _counters.get("jit/backend_compile", 0) + 1)
                 _trace_times["backend_compile"] = (
                     _trace_times.get("backend_compile", 0.0) + dur)
+                return
+            if name == "jaxpr_trace_duration":
+                _on_jaxpr_trace(dur)
+            elif name in _BUILD_SECONDS:
+                key = _BUILD_SECONDS[name]
+                _trace_times[key] = _trace_times.get(key, 0.0) + dur
 
         monitoring.register_event_duration_secs_listener(_on_duration)
         # the duration listener is registered: mark installed NOW —
@@ -1329,14 +1456,22 @@ def _install_compile_listener() -> None:
         _compile_listener_installed = True
 
         def _on_event(name: str, **kw) -> None:
-            # persistent-compilation-cache hits (ISSUE 4): jax records
+            # the persistent compilation cache (ISSUE 4): jax records
             # '/jax/compilation_cache/cache_hits' once per executable
-            # served from the on-disk cache — together with
+            # served from the on-disk cache and '.../cache_misses' once
+            # per executable compiled and written to it — together with
             # jit/backend_compile this decomposes "programs built" into
-            # paid-compiles vs cache-served
-            if _enabled and "cache_hit" in name:
+            # paid compiles and cache-served
+            global _cache_load_pending
+            if not _enabled:
+                return
+            if name.endswith("/cache_hits"):
+                _cache_load_pending = True
                 _counters["jit/persistent_cache_hit"] = (
                     _counters.get("jit/persistent_cache_hit", 0) + 1)
+            elif name.endswith("/cache_misses"):
+                _counters["jit/persistent_cache_miss"] = (
+                    _counters.get("jit/persistent_cache_miss", 0) + 1)
 
         try:
             monitoring.register_event_listener(_on_event)

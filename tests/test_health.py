@@ -316,6 +316,37 @@ def test_health_config_options():
         OverallConfig().set({"on_anomaly": "explode"}, require_data=False)
 
 
+@pytest.mark.parametrize("arm, want", [
+    ("off", False), ("enable", False), ("enable_path", True)])
+def test_health_auto_follows_the_record_sink(tmp_path, capsys, arm, want):
+    """``health=auto`` is on with a ``metrics_out=`` sink and off under a
+    bare ``telemetry.enable()``: the monitor adds its vector to the fused
+    program, so arming the registry alone (the benchmark's traced run) must
+    not change the program.  Said once when telemetry is on and the monitor
+    stays off; ``true`` / ``false`` force it either way."""
+    if arm == "enable":
+        telemetry.enable()
+    elif arm == "enable_path":
+        telemetry.enable(str(tmp_path / "m.jsonl"))
+    health_mod._told_auto_off = False
+    assert health_mod.resolve_enabled("auto") is want
+    assert health_mod.resolve_enabled("auto") is want
+    said = capsys.readouterr()
+    assert (said.out + said.err).count("health=auto leaves") \
+        == (1 if arm == "enable" else 0)
+    assert health_mod.resolve_enabled("true") is True
+    assert health_mod.resolve_enabled("false") is False
+    # and the booster follows: no monitor, so on_anomaly=halt is not armed
+    x, y = _data()
+    booster = GBDT()
+    from lightgbm_tpu.objectives import create_objective
+    cfg = OverallConfig()
+    cfg.set({k: str(v) for k, v in BASE.items()}, require_data=False)
+    booster.init(cfg.boosting_config, Dataset.from_arrays(x, y, max_bin=32),
+                 create_objective(cfg.objective_type, cfg.objective_config))
+    assert (booster._health_monitor is not None) is want
+
+
 def test_quant_saturation_gauge():
     """int8 saturation gauge: uniform magnitudes all sit at the per-pass
     max → every entry saturates; a spread distribution saturates only the

@@ -114,6 +114,142 @@ def test_disabled_mode_records_nothing(tmp_path):
     lgb.train(dict(BASE, num_iterations=2), ds)
     snap = telemetry.snapshot()
     assert snap["phase_times"] == {} and snap["counters"] == {}
+    # nor does the fused chunk path, whose set-up and host-turn spans and
+    # counters (ISSUE 27) are entered all the same: dataset_bin, find_bins,
+    # binarize, booster_init, h2d, tree_build; bin/*, init/*, train/*, and
+    # the compile listener's trace seconds
+    ds = Dataset.from_arrays(x, y, max_bin=32)
+    lgb.train(dict(BASE, num_iterations=8, grow_policy="depthwise"), ds)
+    snap = telemetry.snapshot()
+    assert snap["phase_times"] == {} and snap["counters"] == {}
+    assert snap["trace_times"] == {} and snap["phase_counts"] == {}
+
+
+# ------------------------------------------- set-up and host-turn spans
+
+def _setup_and_train(n_iters=16):
+    """What the benchmark's traffic does, at a toy size, with telemetry
+    on: bin a table, build a booster, train in fused chunks of 8."""
+    from lightgbm_tpu.objectives import create_objective
+    telemetry.enable(fence=False)
+    telemetry.reset()
+    x, y = _data(n=3000, features=5)
+    ds = Dataset.from_arrays(x, y, max_bin=32)
+    config = lgb.OverallConfig()
+    config.set({k: str(v) for k, v in dict(
+        BASE, grow_policy="depthwise", hist_dtype="int8").items()},
+        require_data=False)
+    booster = lgb.GBDT()
+    booster.init(config.boosting_config, ds,
+                 create_objective(config.objective_type,
+                                  config.objective_config))
+    booster.run_training(n_iters, is_eval=False)
+    snap = telemetry.snapshot()
+    telemetry.disable()
+    return booster, snap
+
+
+def test_setup_spans_nest_and_sum():
+    """A layer's self time is its span less its children (choosing-metrics
+    guide, section 4): dataset_bin holds find_bins and binarize,
+    booster_init holds h2d, and what is left over is not negative."""
+    booster, snap = _setup_and_train()
+    t, n = snap["phase_times"], snap["phase_counts"]
+    for name in ("dataset_bin", "find_bins", "binarize", "booster_init",
+                 "h2d", "train_chunk", "model_readback", "tree_build"):
+        assert t[name] > 0, name
+    assert t["dataset_bin"] >= t["find_bins"] + t["binarize"]
+    # the children fill the parent: nothing else is timed under it
+    assert t["dataset_bin"] - t["find_bins"] - t["binarize"] \
+        < 0.05 * t["dataset_bin"] + 1e-3
+    assert t["booster_init"] >= t["h2d"]
+    assert n["find_bins"] == n["binarize"] == n["booster_init"] \
+        == n["h2d"] == 1
+    assert n["train_chunk"] == n["model_readback"] == 2
+    assert n["tree_build"] == len(booster.models) == 16
+    c = snap["counters"]
+    assert c["bin/values"] == 3000 * 5
+    assert c["bin/sample_rows"] == 3000
+    assert c["init/h2d_bytes"] == 3000 * 5        # uint8 codes
+    assert c["train/readback_bytes"] > 0
+    # the compile listener keeps every stage of building a program
+    for stage in ("jaxpr_trace", "lower"):
+        assert snap["trace_times"][stage] > 0, stage
+
+
+def test_train_counters_repeat_exactly():
+    """``bin/values``, ``train/iterations`` and ``train/chunks`` are
+    counts of work, not of time: two runs of one job read alike."""
+    keys = ("bin/values", "bin/sample_rows", "init/h2d_bytes",
+            "train/iterations", "train/chunks", "train/readback_bytes")
+    first, second = [
+        {k: _setup_and_train()[1]["counters"].get(k) for k in keys}
+        for _ in range(2)]
+    assert first == second
+    assert first["train/iterations"] == 16 and first["train/chunks"] == 2
+
+
+def test_file_loaders_get_the_binning_spans(tmp_path):
+    """The spans live in the shared internals, not in from_arrays."""
+    x, y = _data(n=500, features=4)
+    path = tmp_path / "train.tsv"
+    np.savetxt(path, np.column_stack([y, x]), delimiter="\t", fmt="%.6f")
+    config = lgb.OverallConfig()
+    config.set({"data": str(path), "max_bin": "16"}, require_data=False)
+    telemetry.enable()
+    telemetry.reset()
+    Dataset.load_train(config.io_config)
+    snap = telemetry.snapshot()
+    t = snap["phase_times"]
+    assert t["dataset_bin"] >= t["find_bins"] + t["binarize"] > 0
+    assert snap["counters"]["bin/values"] == 500 * 4
+    assert snap["counters"]["bin/sample_rows"] == 500
+
+
+def test_compile_listener_separates_loads_from_compiles():
+    """jax fires its backend-compile duration event around the
+    persistent-cache lookup too: a lookup that hit is a load, kept under
+    cache_load, and is not counted as a compile."""
+    telemetry.enable()
+    telemetry.reset()
+    from jax import monitoring
+    base = "/jax/core/compile/"
+    monitoring.record_event_duration_secs(base + "jaxpr_trace_duration", 0.5)
+    monitoring.record_event_duration_secs(
+        base + "jaxpr_to_mlir_module_duration", 0.25)
+    monitoring.record_event_duration_secs(
+        base + "backend_compile_duration", 2.0)          # a true compile
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event_duration_secs(
+        "/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+    monitoring.record_event_duration_secs(
+        base + "backend_compile_duration", 0.125)        # the same lookup
+    snap = telemetry.snapshot()
+    assert snap["trace_times"] == {"jaxpr_trace": 0.5, "lower": 0.25,
+                                   "backend_compile": 2.0,
+                                   "cache_load": 0.125}
+    assert snap["counters"] == {"jit/backend_compile": 1,
+                                "jit/persistent_cache_miss": 1,
+                                "jit/persistent_cache_hit": 1}
+
+
+def test_nested_jaxpr_traces_are_counted_once(monkeypatch):
+    """An inner jit traced inside an outer one reports first and lies
+    inside the outer's interval: its seconds are the outer's too."""
+    telemetry.enable()
+    telemetry.reset()
+    now = [100.0]
+    monkeypatch.setattr(telemetry.time, "perf_counter", lambda: now[0])
+    now[0] = 101.0
+    telemetry._on_jaxpr_trace(0.25)      # inner: [100.75, 101.0]
+    now[0] = 101.5
+    telemetry._on_jaxpr_trace(0.25)      # inner: [101.25, 101.5]
+    now[0] = 102.0
+    telemetry._on_jaxpr_trace(1.5)       # outer: [100.5, 102.0]
+    now[0] = 103.0
+    telemetry._on_jaxpr_trace(0.5)       # a later, separate trace
+    assert telemetry.snapshot()["trace_times"]["jaxpr_trace"] == 2.0
 
 
 # --------------------------------------------------------------------- sink
