@@ -13,6 +13,13 @@ peak and cannot use the int8 MXU path.  This kernel owns the schedule:
   compare (never touches HBM) and contracted on the MXU
   (sublane-contracting dot_general) against the column-expanded value
   block [chunk, K];
+- a pass with few leaf columns FOLDS the bin code (``hist_fold``): the
+  low log2(k) bits of the bin move out of the one-hot into k copies of
+  the few live value rows, so the VPU builds ceil(B / k) + k * 3 * cols
+  operand rows per feature instead of B (56 instead of 256 at the root)
+  and the MXU contracts that much less.  Building those rows, not the
+  matmul, is what a pass of up to 128 lanes costs; the int32 sums land in
+  the same cells, so the histograms are bit-identical at every fold;
 - ``dtype="int8"`` is the quantized-gradient variant: stochastically /
   nearest-rounded int8 grad/hess, int8xint8->int32 MXU at 2x the bf16
   rate, exact int32 counts — modern LightGBM's quantized-training idea
@@ -30,13 +37,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128  # default value-operand width: 42 leaf columns x 3 stats + 2
+# default value-operand width, one MXU tile: 42 leaf columns x 3 stats + 2.
+# A pass pays for B one-hot rows whatever part of the 128 lanes is live;
+# hist_fold moves bin bits into the idle part when 16 columns or fewer are.
+LANES = 128
 
 
 def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
-                 compute_dtype, acc_dtype, stats=3):
+                 compute_dtype, acc_dtype, stats=3, fold=1, gw=None):
     # grid = (feature_blocks, row_chunks), rows minor: each feature
     # block's accumulator lives in VMEM across its whole row sweep and is
     # written back to HBM once
@@ -54,9 +65,22 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
     # ``stats`` values interleave per leaf column (3 = grad/hess/count;
     # 5 = the f32 single-pass hi/lo packing g_hi,g_lo,h_hi,h_lo,count).
     wide = jnp.int32 if compute_dtype == jnp.int8 else jnp.float32
-    jrow = jax.lax.broadcasted_iota(jnp.int32, (lanes, chunk), 0)
-    leaf_j = jrow // stats
-    k_j = jrow - stats * leaf_j
+    # bin fold (``fold`` > 1): bin = hi * fold + lo.  ``hi`` keeps a
+    # one-hot of B / fold rows; ``lo`` picks one of ``fold`` groups of
+    # ``gw`` value rows, so cell (hi, lo * gw + jj) of the product is cell
+    # (hi * fold + lo, jj) of the unfolded histogram: the same products
+    # summed into the same cell.  The VPU then builds B / fold + fold * gw
+    # operand rows per feature where the unfolded kernel builds B, and
+    # that build, not the MXU, sets the pace up to 128 lanes.
+    vrows = lanes if fold == 1 else fold * gw
+    jrow = jax.lax.broadcasted_iota(jnp.int32, (vrows, chunk), 0)
+    if fold == 1:
+        jj = jrow
+    else:
+        lo_j = jrow // gw
+        jj = jrow - gw * lo_j
+    leaf_j = jj // stats
+    k_j = jj - stats * leaf_j
     # packed may be int8 (quantized levels) or bf16 (float values); both
     # convert exactly to ``wide`` (int levels <= 127, cid <= 191 — small
     # integers are exact in f32, so the cid equality compare is safe)
@@ -64,28 +88,35 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
     terms = None
     for k in range(stats):
         vk = ((k_j == k).astype(wide)
-              * jnp.broadcast_to(packed[k:k + 1, :], (lanes, chunk)))
+              * jnp.broadcast_to(packed[k:k + 1, :], (vrows, chunk)))
         terms = vk if terms is None else terms + vk
-    cidb = jnp.broadcast_to(packed[stats:stats + 1, :], (lanes, chunk))
+    cidb = jnp.broadcast_to(packed[stats:stats + 1, :], (vrows, chunk))
     lmask = (cidb == leaf_j.astype(wide)).astype(wide)
-    vLt = (terms * lmask).astype(compute_dtype)     # [lanes, chunk]
+    vL = terms * lmask                              # [vrows, chunk]
+    if fold == 1:
+        vLt = vL.astype(compute_dtype)
 
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, chunk), 0)
+    shift = fold.bit_length() - 1
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B // fold, chunk), 0)
     dn = (((1,), (1,)), ((), ()))                           # contract chunk
     for f in range(F):
         # bins ride as int8 bit-patterns; values >= 128 (uint8 source,
         # max_bin up to 256) wrap negative on the cast, so mask back
         # (int8-domain compares don't compile in Mosaic)
         brow = bins_ref[f:f + 1, :].astype(jnp.int32) & 255  # [1, chunk]
-        oh = (iota_b == brow).astype(compute_dtype)         # [B, chunk]
+        if fold > 1:
+            vLt = ((lo_j == (brow & (fold - 1))).astype(wide)
+                   * vL).astype(compute_dtype)
+            brow = brow >> shift
+        oh = (iota_b == brow).astype(compute_dtype)     # [B/fold, chunk]
         out_ref[f] += jax.lax.dot_general(
             oh, vLt, dimension_numbers=dn,
-            preferred_element_type=acc_dtype)               # [B, LANES]
+            preferred_element_type=acc_dtype)           # [B/fold, vrows]
 
 
 def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
                         dtype: str = "int8", lanes: int = LANES,
-                        stats: int = 3):
+                        stats: int = 3, fold: int = 1, gw: int = None):
     """[F, B, lanes] accumulator from [F, N] bins and packed values.
 
     Rows must be pre-padded to a multiple of ``chunk`` (pad cid with -1).
@@ -103,7 +134,11 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     ``bins`` may carry uint8 bit-patterns (the kernel masks the
     sign-extension back off).  ``lanes`` widens the value operand past one
     MXU tile (192 fits 64 leaf columns in 1.5 tiles instead of two full
-    128-lane passes).
+    128-lane passes).  ``fold`` > 1 (a power of two, with ``gw`` >= the
+    live stats * columns; ``hist_fold`` picks both) runs the bin-folded
+    kernel on a [F, ceil(B / fold), fold * gw] accumulator and unfolds it:
+    the result is the same [F, B, lanes] array, bit for bit, for the
+    integer-level modes ("bf16v" does not fold).
 
     Wide datasets ride a FEATURE-BLOCK grid axis: the [Fb, B, lanes]
     accumulator of one block fits VMEM (~12 MB) and each block sweeps the
@@ -136,9 +171,17 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
         pad_f = n_fblocks * fb - F
         if pad_f:
             bins = jnp.pad(bins, ((0, pad_f), (0, 0)))
+    if fold == 1:
+        out_block = (fb, B, lanes)
+    else:
+        assert dtype != "bf16v" and fold & (fold - 1) == 0
+        assert stats <= gw and fold * gw <= lanes
+        Bh = -(-B // fold)
+        out_block = (fb, Bh, fold * gw)
     kernel = functools.partial(
-        _hist_kernel, F=fb, B=B, chunk=chunk, lanes=lanes,
-        compute_dtype=compute_dtype, acc_dtype=acc_dtype, stats=stats)
+        _hist_kernel, F=fb, B=B if fold == 1 else Bh * fold, chunk=chunk,
+        lanes=lanes, compute_dtype=compute_dtype, acc_dtype=acc_dtype,
+        stats=stats, fold=fold, gw=gw)
     out = pl.pallas_call(
         kernel,
         grid=(n_fblocks, N // chunk),
@@ -146,12 +189,24 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
             pl.BlockSpec((fb, chunk), lambda i, j: (i, j)),
             pl.BlockSpec((stats + 1, chunk), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((fb, B, lanes), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_fblocks * fb, B, lanes),
-                                       acc_dtype),
+        out_specs=pl.BlockSpec(out_block, lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_fblocks * out_block[0],) + out_block[1:], acc_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(bins, packed)
+    if fold > 1:
+        # unfold: cell (hi, lo * gw + jj) -> (hi * fold + lo, jj), value
+        # columns zero-padded back to ``lanes``.  The layout constraint
+        # hands the consumers what the unfolded kernel's custom call
+        # would, a row-major array: without it XLA lays the float
+        # histograms out after the narrow accumulator and the split
+        # search's sums round in another order (same ints, trees that
+        # differ in the last place of a gain).  The pad itself fuses away
+        out = out.reshape(-1, Bh * fold, gw)[:, :B]
+        out = with_layout_constraint(
+            jnp.pad(out, ((0, 0), (0, 0), (0, lanes - gw))),
+            Layout(major_to_minor=(0, 1, 2)))
     out = out[:F]
     if dtype in ("int8", "bf16v"):
         return out                       # int32 / f32 accumulator as-is
@@ -169,7 +224,8 @@ from .. import costmodel as _costmodel  # noqa: E402
 hist_pallas_raw = _costmodel.instrument(
     "hist/pallas_raw",
     jax.jit(_hist_pallas_raw_fn,
-            static_argnames=("B", "chunk", "dtype", "lanes", "stats")),
+            static_argnames=("B", "chunk", "dtype", "lanes", "stats", "fold",
+                             "gw")),
     phase="histogram")
 
 
@@ -181,6 +237,42 @@ def feature_block(B: int, lanes: int, budget: int = 12 << 20) -> int:
     the grid and Mosaic double-buffers it)."""
     fb = budget // (B * lanes * 4)
     return max(8, fb - fb % 8)
+
+
+def fold_options(stats: int, num_cols: int, B: int, lanes: int):
+    """Every (fold, gw, operand rows per feature) the folded kernel's
+    layout allows: at least one int8 sublane tile (32 rows) of one-hot,
+    a value block of whole 8-row sublane groups (``gw`` is the live width
+    stats * num_cols rounded up to a multiple of 8 / fold) that stays one
+    MXU tile wide (fold * gw <= lanes)."""
+    for fold in (2, 4, 8):
+        hi_rows = -(-B // fold)
+        step = 8 // fold
+        gw = -(-stats * num_cols // step) * step
+        if hi_rows >= 32 and fold * gw <= lanes:
+            yield fold, gw, hi_rows + fold * gw
+
+
+def hist_fold(stats: int, num_cols: int, B: int, lanes: int, dtype: str):
+    """(fold, gw) of one histogram pass, from its static shapes.
+
+    A pass with few leaf columns leaves most of the value operand's lanes
+    idle, and up to 128 lanes the kernel's pace is the VPU's operand
+    build (measured on a v5e at [28, 10.5M]: 3.6 ms + 0.20 ms per operand
+    row built per feature and chunk, PERF.md section 6).  The fold moves
+    the low log2(fold) bits of the bin code into ``fold`` groups of ``gw``
+    value rows, so the kernel builds ceil(B / fold) + fold * gw rows per
+    feature where fold 1, the unfolded kernel, builds B.  This picks the
+    fold with the fewest rows (the larger on a tie: a smaller
+    accumulator) if it saves an eighth of them or more, else fold 1.
+    Only the modes whose accumulation is exact and order-free fold:
+    "bf16v" (float gradients) keeps its summation shape."""
+    best, best_rows = (1, None), B - B // 8
+    if dtype != "bf16v" and lanes == LANES:
+        for fold, gw, rows in fold_options(stats, num_cols, B, lanes):
+            if rows <= best_rows:
+                best, best_rows = (fold, gw), rows
+    return best
 
 
 def _mix32(x):
@@ -355,6 +447,8 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
     64 columns run as ONE pass (<=42 columns fill one 128-lane MXU tile;
     43-64 use a 192-lane operand = 1.5 tiles, cheaper than two full
     passes over the data); wider levels split into 64-column groups.
+    Passes of 16 columns or fewer fold the bin code into the idle value
+    rows (``hist_fold``); the accumulator handed on is the unfolded one.
 
     ``packing`` (mixed-bin layout): one kernel launch per bin-width class
     — the narrow class's [Fc, 64, lanes] accumulator costs a quarter of
@@ -395,19 +489,25 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
     if pad:
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         packed = jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1)
+    from .. import telemetry
+
+    def launch(rows, width):
+        fold, gw = hist_fold(3, num_cols, width, lanes, dtype)
+        # counted per pass, here: two passes of one shape share one trace
+        # of the jitted kernel, so its own counters see them once
+        telemetry.count("hist/pallas_fold_" + str(fold))
+        return hist_pallas_raw(rows.astype(jnp.int8), packed, B=width,
+                               chunk=chunk, dtype=dtype, lanes=lanes,
+                               fold=fold, gw=gw)         # [F, width, lanes]
+
     if _packing_on(packing):
-        from .. import telemetry
         telemetry.count("hist/mixedbin_pallas_int")
-        parts = [hist_pallas_raw(
-            jax.lax.slice_in_dim(bins, start, start + cnt,
-                                 axis=0).astype(jnp.int8),
-            packed, B=width, chunk=chunk, dtype=dtype, lanes=lanes)
-            for start, cnt, width in packing.ranges]
+        parts = [launch(jax.lax.slice_in_dim(bins, start, start + cnt,
+                                             axis=0), width)
+                 for start, cnt, width in packing.ranges]
         acc = _class_acc_assemble(parts, packing, B)         # [F, B, lanes]
     else:
-        acc = hist_pallas_raw(bins.astype(jnp.int8), packed, B=B,
-                              chunk=chunk, dtype=dtype,
-                              lanes=lanes)                   # [F, B, lanes]
+        acc = launch(bins, B)
     if feat_gather is not None:
         # block-local packing's storage->canonical reorder, IN the int
         # domain and BEFORE the cross-shard reduction: the gather
@@ -426,7 +526,6 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         # reduce the INT accumulators across shards: dequantize-then-psum
         # would round (sum of 8 f32 products != int-sum x scale) and break
         # the bit-identical serial == data-parallel invariant
-        from .. import telemetry
         telemetry.record_collective("hist/int8_pallas_psum", "psum",
                                     axis_name, telemetry._tree_nbytes(acc))
         acc = jax.lax.psum(acc, axis_name)

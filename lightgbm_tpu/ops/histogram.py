@@ -106,11 +106,15 @@ def _einsum_chunk(chunk: int, F: int, B: int, itemsize: int, N: int) -> int:
     return min(chunk, max(256, -(-N // 256) * 256))
 
 
-def dense_pass_cost(N: int, F: int, B: int, num_cols: int):
+def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
+                    int_levels: bool = False):
     """Analytic cost of ONE leaf-batched histogram pass — the dense
     one-hot-matmul MAC count PROFILE.md's roofline derives by hand
     (N x F x B x lanes per group; the MXU tile floor makes <=42 leaf
-    columns cost 128 lanes, 43-64 ride a 192-lane operand) and the HBM
+    columns cost 128 lanes, 43-64 ride a 192-lane operand; a pass of
+    ``int_levels`` that hist_pallas.hist_fold folds contracts
+    ceil(B / fold) one-hot rows against fold * gw value rows instead)
+    and the HBM
     bytes streamed (int8 bins + the packed per-row side-band, re-read
     once per group, + the per-group accumulator write-back).  Wider
     levels are modeled on the PALLAS grouping rule — balanced groups of
@@ -126,9 +130,15 @@ def dense_pass_cost(N: int, F: int, B: int, num_cols: int):
         groups = -(-num_cols // 64)
         width = -(-num_cols // groups)
         lanes = 128.0 if width <= 42 else 192.0
-    macs = float(N) * F * B * lanes * groups
+    cells = float(B) * lanes                 # accumulator cells a feature
+    if int_levels and num_cols <= 42:
+        from .hist_pallas import LANES, hist_fold
+        fold, gw = hist_fold(3, num_cols, B, LANES, "int8")
+        if fold > 1:
+            cells = float(-(-B // fold)) * fold * gw
+    macs = float(N) * F * cells * groups
     bytes_moved = (groups * (float(N) * F + 4.0 * N)
-                   + groups * float(F) * B * lanes * 4.0)
+                   + groups * float(F) * cells * 4.0)
     return macs, bytes_moved
 
 
@@ -143,16 +153,19 @@ def _note_hist_pass(bins, num_cols: int, num_bins_max: int,
         return
     F, N = bins.shape
     dt = getattr(compute_dtype, "__name__", None) or str(compute_dtype)
+    int_levels = dt.startswith("int8")   # float gradients never fold
     if _packing_active(packing):
         for _, cnt, width in packing.ranges:
-            macs, bytes_moved = dense_pass_cost(N, cnt, width, num_cols)
+            macs, bytes_moved = dense_pass_cost(N, cnt, width, num_cols,
+                                                int_levels)
             costmodel.note_traced_pass(
                 "histogram",
                 ("pass", N, cnt, width, num_cols, dt,
                  "binclass%d" % width),
                 macs=macs, bytes_moved=bytes_moved)
         return
-    macs, bytes_moved = dense_pass_cost(N, F, num_bins_max, num_cols)
+    macs, bytes_moved = dense_pass_cost(N, F, num_bins_max, num_cols,
+                                        int_levels)
     costmodel.note_traced_pass(
         "histogram", ("pass", N, F, num_bins_max, num_cols, dt),
         macs=macs, bytes_moved=bytes_moved)

@@ -113,19 +113,29 @@ _GROW_KW = dict(num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=100,
 
 # ------------------------------------------------------------- kernels
 
-@pytest.mark.parametrize("dtype,lanes,stats", [
-    ("int8", 128, 3), ("int8", 192, 3),
-    ("bf16v", 128, 3), ("bf16v", 192, 5),
+@pytest.mark.parametrize("dtype,lanes,stats,num_cols", [
+    ("int8", 128, 3, 32), ("int8", 192, 3, 64),
+    ("bf16v", 128, 3, 1), ("bf16v", 192, 5, 38),
+    # the bin fold's shapes at the cell's levels (hist_fold: fold 8, 8,
+    # 4, 4, 2 for 1, 2, 4, 8, 16 leaf columns), value blocks of 24 to 96
+    # rows against one-hots of 32 to 128
+    ("int8", 128, 3, 1), ("int8", 128, 3, 2), ("int8", 128, 3, 4),
+    ("int8", 128, 3, 8), ("int8", 128, 3, 16), ("bf16", 128, 3, 1),
 ])
-def test_hist_kernel_compiles(one_chip, as_tpu, dtype, lanes, stats):
-    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn
-    packed_dtype = jnp.int8 if dtype == "int8" else jnp.bfloat16
+def test_hist_kernel_compiles(one_chip, as_tpu, dtype, lanes, stats,
+                              num_cols):
+    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn, hist_fold
+    packed_dtype = jnp.bfloat16 if dtype == "bf16v" else jnp.int8
+    fold, gw = hist_fold(stats, num_cols, 256, lanes, dtype)
+    assert (fold > 1) == (dtype != "bf16v" and num_cols <= 16)
     fn = jax.jit(_hist_pallas_raw_fn,
-                 static_argnames=("B", "chunk", "dtype", "lanes", "stats"))
+                 static_argnames=("B", "chunk", "dtype", "lanes", "stats",
+                                  "fold", "gw"))
     compiled = fn.lower(
         _shape(one_chip, (F, N), jnp.int8),
         _shape(one_chip, (stats + 1, N), packed_dtype),
-        B=256, chunk=2048, dtype=dtype, lanes=lanes, stats=stats).compile()
+        B=256, chunk=2048, dtype=dtype, lanes=lanes, stats=stats,
+        fold=fold, gw=gw).compile()
     _check(compiled, custom_call=True)
 
 
