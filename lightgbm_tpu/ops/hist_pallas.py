@@ -140,10 +140,17 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     the result is the same [F, B, lanes] array, bit for bit, for the
     integer-level modes ("bf16v" does not fold).
 
-    Wide datasets ride a FEATURE-BLOCK grid axis: the [Fb, B, lanes]
-    accumulator of one block fits VMEM (~12 MB) and each block sweeps the
-    rows in turn, so F is unbounded (the row side-band is re-read per
-    block — F/Fb x a few MB of HBM, noise next to the matmuls).
+    Wide datasets ride a FEATURE-BLOCK grid axis: each block of Fb
+    features sweeps the rows in turn with its [Fb, B, lanes] accumulator
+    resident in VMEM (the row side-band is re-read per block — F/Fb x a
+    few MB of HBM, noise next to the matmuls).  Fb comes from
+    ``rotating_feature_block``, which counts the two buffers of the
+    rotating window as Mosaic lays them out, so every F lowers inside the
+    16 MiB a kernel may hold: compiled for a described v5e at F = 2,000
+    for every pass of a 255-leaf level-wise tree
+    (tests/test_tpu_compile.py), 24 features a block at 192 lanes and 48
+    at 128 with 255 bins.  ``feature_block`` and fewer features run as
+    ONE block, the kernel as it always was.
     """
     from .. import telemetry
     telemetry.count("hist/pallas_kernel_" + dtype)
@@ -153,24 +160,9 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     acc_dtype = jnp.int32 if dtype == "int8" else jnp.float32
     if dtype == "bf16v":
         assert packed.dtype == jnp.bfloat16, packed.dtype
-    if F <= feature_block(B, lanes):
-        # single block: the output window is constant across the grid, so
-        # Mosaic keeps ONE VMEM copy — the full ~12 MB budget applies
-        # (the round-2 kernel ran exactly this shape)
-        fb, n_fblocks = F, 1
-    else:
-        # multi-block: the output window rotates with grid axis i, which
-        # Mosaic DOUBLE-BUFFERS — budget half the VMEM per block.  Blocks
-        # are balanced: with fb_max=48 (B=256, lanes=128), 100 features
-        # run as 3 x 40 (20 pad) instead of 48+48+48 (44 pad) — padded
-        # features cost full matmul passes
-        fb_max = feature_block(B, lanes, budget=6 << 20)
-        n_fblocks = -(-F // fb_max)
-        fb = -(-F // n_fblocks)
-        fb += (-fb) % 8                       # sublane-tile multiple
-        pad_f = n_fblocks * fb - F
-        if pad_f:
-            bins = jnp.pad(bins, ((0, pad_f), (0, 0)))
+    fb, n_fblocks = feature_grid(F, B, lanes, chunk)
+    if n_fblocks * fb > F:
+        bins = jnp.pad(bins, ((0, n_fblocks * fb - F), (0, 0)))
     if fold == 1:
         out_block = (fb, B, lanes)
     else:
@@ -229,13 +221,64 @@ hist_pallas_raw = _costmodel.instrument(
     phase="histogram")
 
 
-def feature_block(B: int, lanes: int, budget: int = 12 << 20) -> int:
-    """Features per VMEM-resident accumulator block: the largest multiple
-    of 8 (sublane tile) whose [Fb, B, lanes] int32/f32 block fits the
-    given budget (~12 MB of v5e VMEM with operand headroom for the
-    single-buffered case; callers halve it when the block rotates across
-    the grid and Mosaic double-buffers it)."""
-    fb = budget // (B * lanes * 4)
+# what one kernel may hold of a v5e's VMEM (Mosaic's default scoped limit;
+# the compiler refuses a kernel whose windows need more), and the part of
+# it kept free of windows for the kernel's own temporaries: the value
+# block and a one-hot in both widths, [256, chunk] rows at most
+VMEM_SCOPED_BYTES = 16 << 20
+VMEM_TEMPORARIES_BYTES = 2 << 20
+
+
+def feature_block(B: int, lanes: int) -> int:
+    """Most features that run as ONE block, the output window constant
+    across the grid: the largest multiple of 8 (sublane tile) whose
+    [Fb, B, lanes] int32/f32 accumulator is 12 MB or less.  Not a VMEM
+    account (at 192 lanes the 64 features it gives are 16 MiB as laid
+    out); it is the rule the single-block kernels were measured under,
+    kept so that they stay the same programs: 64 features at 192 lanes
+    and 96 at 128 compile for a v5e (255 and 256 bins).  Wider tables
+    take ``rotating_feature_block``."""
+    fb = (12 << 20) // (B * lanes * 4)
+    return max(8, fb - fb % 8)
+
+
+def feature_grid(F: int, B: int, lanes: int, chunk: int):
+    """(features per block, blocks) of one pass over F features."""
+    if F <= feature_block(B, lanes):
+        # single block: the output window is constant across the grid, so
+        # Mosaic keeps ONE VMEM copy (the round-2 kernel ran exactly this
+        # shape)
+        return F, 1
+    # multi-block: the output window rotates with grid axis i, which
+    # Mosaic DOUBLE-BUFFERS.  Blocks are balanced: with 48 a block
+    # (B=256, lanes=128), 100 features run as 3 x 40 (20 pad) instead of
+    # 48+48+48 (44 pad) — padded features cost full matmul passes
+    n_fblocks = -(-F // rotating_feature_block(B, lanes, chunk))
+    fb = -(-F // n_fblocks)
+    return fb + (-fb) % 8, n_fblocks          # sublane-tile multiple
+
+
+def rotating_feature_block(B: int, lanes: int, chunk: int) -> int:
+    """Most features a block when the table is wider than one block: the
+    output window then rotates with the feature axis of the grid and
+    Mosaic keeps TWO buffers of it, like of every operand.  Counted as
+    laid out in VMEM (``T(8, 128)`` tiles of 4-byte cells: bins up to a
+    multiple of 8 sublanes, 192 lanes up to 256), per feature a
+    [B, lanes] accumulator and a [chunk] row of bin codes, twice each,
+    beside the two buffers of the packed side-band (its stats + 1 rows
+    fill one 32-sublane int8 tile, or two 16-sublane bf16 ones) and the
+    kernel's temporaries, under ``VMEM_SCOPED_BYTES``.  At 255 bins and
+    chunk 2048: 48 features at 128 lanes (12.4 MiB of windows), 24 at 192
+    (12.2 MiB; the 32 that B * lanes * 4 bytes a feature allowed were
+    16.12 MiB, which the TPU compiler refused at F = 300, 700 and 2,000).
+    Raising the kernel's own ``vmem_limit_bytes`` instead buys 1% (on a
+    v5e at [2000, 401,408]: 293.1 ms a 192-lane pass at 24 a block,
+    290.2 at 32 under 32 MiB, 353.1 at 64 under 48 MiB; PERF.md, PR 30).
+    The fold's narrower accumulator is not counted: a folded pass takes
+    the unfolded block."""
+    acc = (B + (-B) % 8) * (lanes + (-lanes) % 128) * 4
+    room = VMEM_SCOPED_BYTES - VMEM_TEMPORARIES_BYTES - 2 * 32 * chunk
+    fb = room // (2 * (acc + chunk))
     return max(8, fb - fb % 8)
 
 
@@ -496,6 +539,8 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         # counted per pass, here: two passes of one shape share one trace
         # of the jitted kernel, so its own counters see them once
         telemetry.count("hist/pallas_fold_" + str(fold))
+        telemetry.count("hist/pallas_fblocks",
+                        feature_grid(rows.shape[0], width, lanes, chunk)[1])
         return hist_pallas_raw(rows.astype(jnp.int8), packed, B=width,
                                chunk=chunk, dtype=dtype, lanes=lanes,
                                fold=fold, gw=gw)         # [F, width, lanes]

@@ -34,9 +34,9 @@ LEAFBATCH_VIRTUAL_BUDGET = 8 << 30
 def _pallas_hist_ok(num_bins_max: int) -> bool:
     """THE Pallas-histogram eligibility rule, shared by the int8 and float
     dispatches: TPU backend and 8-bit bin ids (max_bin > 256 datasets
-    carry int16 bins the kernel cannot ride).  Dataset WIDTH is unbounded:
-    the kernel grids over VMEM-sized feature blocks
-    (hist_pallas.feature_block).  LGBM_TPU_HIST_EINSUM=1 forces the XLA
+    carry int16 bins the kernel cannot ride).  Dataset WIDTH is not part
+    of the rule: the kernel grids over VMEM-sized feature blocks
+    (hist_pallas.feature_grid).  LGBM_TPU_HIST_EINSUM=1 forces the XLA
     formulation for ALL dtypes (A/B timing escape hatch).
 
     Every outcome is counted (telemetry): routing decisions are trace-time

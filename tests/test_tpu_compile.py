@@ -9,8 +9,10 @@ that is described, not attached: what it refuses (unaligned slices, VMEM
 overflow, HBM overflow) costs no chip time.  A compile that passes is
 not a chip run — ``chip_smoke.py`` is.
 
-Shapes are the one supported configuration at a real size: F=28, 255
-bins, 255 leaves, N=2**20.
+Shapes are the supported configurations at a real size: F=28, 255 bins,
+255 leaves, N=2**20; and the wide table of the benchmark's second
+configuration, F=2,000 at N=400,000, whose histogram passes run the
+kernel's feature-block grid.
 
 Rules this file keeps (on-chip-measurement guide §2): the topology is
 described inside a module-scoped fixture that skips if it cannot be;
@@ -30,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 F, B, LEAVES, N = 28, 255, 255, 1 << 20
+WIDE_F, WIDE_N = 2000, 400_000  # benchmarks/configs/epsilon-levelwise-int8
 HBM_BYTES = 16 * 1000 ** 3      # one v5e chip
 
 
@@ -98,13 +101,21 @@ def _check(compiled, custom_call: bool):
     return ma
 
 
-def _grow_args(one_chip, n=N):
-    return (_shape(one_chip, (F, n), jnp.uint8),       # bins
+def _grow_args(one_chip, n=N, f=F):
+    return (_shape(one_chip, (f, n), jnp.uint8),       # bins
             _shape(one_chip, (n,), jnp.float32),       # grad
             _shape(one_chip, (n,), jnp.float32),       # hess
             _shape(one_chip, (n,), jnp.bool_),         # row_mask
-            _shape(one_chip, (F,), jnp.bool_),         # feature_mask
-            _shape(one_chip, (F,), jnp.int32))         # num_bins
+            _shape(one_chip, (f,), jnp.bool_),         # feature_mask
+            _shape(one_chip, (f,), jnp.int32))         # num_bins
+
+
+def _cell_size(ma):
+    """The size argument of the wide cell, pinned: what the compiler counts
+    for the program is over the 2 GiB a new cell has to hold with the chip
+    busy (it measured 3.24 GB of temporaries and 0.80 GB of arguments)."""
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes > 2 << 30, (
+        ma.temp_size_in_bytes, ma.argument_size_in_bytes)
 
 
 _GROW_KW = dict(num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=100,
@@ -139,6 +150,72 @@ def test_hist_kernel_compiles(one_chip, as_tpu, dtype, lanes, stats,
     _check(compiled, custom_call=True)
 
 
+def _lower_kernel(one_chip, features, dtype, lanes, stats, num_cols):
+    """The raw kernel, traced anew (a wrapper of its own, so that no
+    cached trace answers) and lowered for the described chip."""
+    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn, hist_fold
+    fold, gw = hist_fold(stats, num_cols, 256, lanes, dtype)
+
+    def fresh(bins, packed):
+        return _hist_pallas_raw_fn(bins, packed, B=256, chunk=2048,
+                                   dtype=dtype, lanes=lanes, stats=stats,
+                                   fold=fold, gw=gw)
+    return jax.jit(fresh).lower(
+        _shape(one_chip, (features, 2048 * 4), jnp.int8),
+        _shape(one_chip, (stats + 1, 2048 * 4),
+               jnp.bfloat16 if dtype == "bf16v" else jnp.int8))
+
+
+@pytest.mark.parametrize("dtype,lanes,stats,num_cols", [
+    # the 64-leaf level: 192 lanes, 24 features a block, the pass the
+    # compiler refused at 32 a block (16.12 MiB of windows)
+    ("int8", 192, 3, 64),
+    # 128 lanes unfolded and folded: 48 features a block
+    ("int8", 128, 3, 32), ("int8", 128, 3, 1),
+    # float gradients, five statistics a column
+    ("bf16v", 192, 5, 38),
+])
+def test_hist_kernel_compiles_on_the_feature_block_grid(
+        one_chip, as_tpu, dtype, lanes, stats, num_cols):
+    from lightgbm_tpu.ops.hist_pallas import feature_grid
+    fb, blocks = feature_grid(WIDE_F, 256, lanes, 2048)
+    assert (fb, blocks) == ((24, 84) if lanes == 192 else (48, 42))
+    compiled = _lower_kernel(one_chip, WIDE_F, dtype, lanes, stats,
+                             num_cols).compile()
+    _check(compiled, custom_call=True)
+
+
+def test_narrow_kernels_lower_as_before_the_feature_block_repair(
+        one_chip, as_tpu, monkeypatch):
+    """The VMEM account of the rotating block is not on the path of a
+    table that fits one block: with the account put back to the rule it
+    replaced (B * lanes * 4 bytes a feature in 6 MiB), every F=28 kernel
+    of this file lowers to the same text, and the 192-lane pass of the
+    wide table, which that rule sized at 32 features a block, does not."""
+    from lightgbm_tpu.ops import hist_pallas
+    shapes = [("int8", 128, 3, 32), ("int8", 192, 3, 64),
+              ("bf16v", 128, 3, 1), ("bf16v", 192, 5, 38),
+              ("int8", 128, 3, 1), ("int8", 128, 3, 2), ("int8", 128, 3, 4),
+              ("int8", 128, 3, 8), ("int8", 128, 3, 16), ("bf16", 128, 3, 1)]
+
+    def before(b, lanes, _chunk):
+        fb = (6 << 20) // (b * lanes * 4)
+        return max(8, fb - fb % 8)
+
+    def texts(features):
+        return [_lower_kernel(one_chip, features, *s).as_text()
+                for s in shapes[:2 if features > F else None]]
+    # both rounds lower from the same lines: the kernel is serialized with
+    # the locations of its call stack, this test's frames among them
+    rounds = []
+    for account in (hist_pallas.rotating_feature_block, before):
+        monkeypatch.setattr(hist_pallas, "rotating_feature_block", account)
+        rounds.append((texts(F), texts(WIDE_F)))
+    (narrow, wide), (narrow_was, wide_was) = rounds
+    assert narrow == narrow_was
+    assert wide[0] == wide_was[0] and wide[1] != wide_was[1]
+
+
 @pytest.mark.parametrize("overlap", [True, False])
 def test_partition_kernel_compiles(one_chip, as_tpu, overlap):
     from lightgbm_tpu.ops import compact
@@ -163,6 +240,17 @@ def test_grow_depthwise_int8_compiles(one_chip, as_tpu):
     compiled = grow_tree_depthwise_jit.lower(
         *_grow_args(one_chip), compute_dtype="int8", **_GROW_KW).compile()
     _check(compiled, custom_call=True)
+
+
+def test_grow_depthwise_int8_compiles_on_the_wide_table(one_chip, as_tpu):
+    """Every pass of a 255-leaf level-wise tree over 2,000 columns: the
+    parent of the feature-block repair was refused here for VMEM (the
+    64-leaf pass, ``s32[2016,255,192]``)."""
+    from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
+    compiled = grow_tree_depthwise_jit.lower(
+        *_grow_args(one_chip, WIDE_N, WIDE_F), compute_dtype="int8",
+        **_GROW_KW).compile()
+    _cell_size(_check(compiled, custom_call=True))
 
 
 def test_grow_leafcompact_f32_compiles(one_chip, as_tpu):
@@ -199,35 +287,31 @@ class _Captured(Exception):
     pass
 
 
-def _tiny_binary_dataset(n):
+def _tiny_binary_dataset(n, f=F):
     from lightgbm_tpu.io.dataset import Dataset
     rng = np.random.RandomState(5)
-    x = rng.randn(n, F).astype(np.float32)
+    x = rng.randn(n, f).astype(np.float32)
     y = (x[:, 0] + 0.3 * rng.randn(n) > 0).astype(np.float32)
     return Dataset.from_arrays(x, y, max_bin=B)
 
 
-def test_fused_chunk_program_compiles(one_chip, as_tpu, monkeypatch):
-    """chip_smoke phase (b): the depth-wise int8 chunk of 8 iterations,
-    built by GBDT.train_chunk itself — the call is intercepted at the
-    program boundary and its real argument tree re-shaped to N=2**20."""
+def _captured_chunk_program(monkeypatch, params, dataset, is_eval):
+    """(program, arguments) of the chunk of 8 iterations as
+    GBDT.train_chunk itself builds and calls it: the call is intercepted
+    at the program boundary."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.models import gbdt as gbdt_mod
-    n_tiny = 1000                      # no other axis has this length
-    config = lgb.OverallConfig()
-    config.set({"objective": "binary", "num_leaves": str(LEAVES),
-                "max_bin": str(B), "grow_policy": "depthwise",
-                "hist_dtype": "int8", "metric": "binary_logloss",
-                "is_training_metric": "true"}, require_data=False)
     from lightgbm_tpu.metrics import create_metric
     from lightgbm_tpu.objectives import create_objective
+    config = lgb.OverallConfig()
+    config.set(params, require_data=False)
     booster = lgb.GBDT()
-    booster.init(config.boosting_config, _tiny_binary_dataset(n_tiny),
+    booster.init(config.boosting_config, dataset,
                  create_objective(config.objective_type,
                                   config.objective_config),
                  [create_metric(t, config.metric_config)
-                  for t in config.metric_types])
-    assert booster.chunkable_for(True)
+                  for t in config.metric_types] if is_eval else [])
+    assert booster.chunkable_for(is_eval)
     seen = {}
     real_get = gbdt_mod._get_chunk_program
 
@@ -241,10 +325,43 @@ def test_fused_chunk_program_compiles(one_chip, as_tpu, monkeypatch):
 
     monkeypatch.setattr(gbdt_mod, "_get_chunk_program", capturing_get)
     with pytest.raises(_Captured):
-        booster.train_chunk(8, is_eval=True)
-    args = _like(one_chip, seen["args"], rows_from=n_tiny, rows_to=N)
-    compiled = seen["prog"].lower(*args).compile()
-    _check(compiled, custom_call=True)
+        booster.train_chunk(8, is_eval=is_eval)
+    return seen["prog"], seen["args"]
+
+
+def test_fused_chunk_program_compiles(one_chip, as_tpu, monkeypatch):
+    """chip_smoke phase (b): the depth-wise int8 chunk of 8 iterations,
+    built by GBDT.train_chunk itself, its real argument tree re-shaped to
+    N=2**20."""
+    n_tiny = 1000                      # no other axis has this length
+    prog, seen = _captured_chunk_program(
+        monkeypatch,
+        {"objective": "binary", "num_leaves": str(LEAVES),
+         "max_bin": str(B), "grow_policy": "depthwise",
+         "hist_dtype": "int8", "metric": "binary_logloss",
+         "is_training_metric": "true"},
+        _tiny_binary_dataset(n_tiny), is_eval=True)
+    args = _like(one_chip, seen, rows_from=n_tiny, rows_to=N)
+    _check(prog.lower(*args).compile(), custom_call=True)
+
+
+def test_fused_chunk_program_compiles_on_the_wide_table(
+        one_chip, as_tpu, monkeypatch):
+    """The program of the cell ``epsilon-levelwise-int8.train``: the
+    configuration's own ``key=value`` pairs, 2,000 columns, its argument
+    tree re-shaped to the 400,000 rows."""
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "epsilon-levelwise-int8.json")) as fh:
+        conf = json.load(fh)
+    assert (conf["rows"], conf["features"]) == (WIDE_N, WIDE_F)
+    n_tiny = 1000
+    prog, seen = _captured_chunk_program(
+        monkeypatch, conf["params"], _tiny_binary_dataset(n_tiny, WIDE_F),
+        is_eval=False)
+    args = _like(one_chip, seen, rows_from=n_tiny, rows_to=WIDE_N)
+    _cell_size(_check(prog.lower(*args).compile(), custom_call=True))
 
 
 def test_data_parallel_chunk_program_compiles_for_four_chips(

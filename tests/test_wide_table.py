@@ -1,0 +1,183 @@
+"""The wide dense table: more columns than one VMEM-resident block of the
+histogram kernel holds, so every pass runs the kernel's feature-block grid
+(``hist_pallas.feature_grid``).
+
+Two things are held here, on the CPU at a small size.  The kernel alone, in
+interpret mode on multi-block shapes, equals exact integer histograms bit
+for bit.  And the program, on the level-wise int8 route through
+``GBDT.run_training`` with that kernel under it, agrees with the
+benchmark's plain reference (``benchmarks/harness/reference.py``: NumPy,
+float64, nothing of the program) under the limits of the benchmark's wide
+cell, ``epsilon-levelwise-int8.train``, while the reference's answer in
+int4 does not.  The cell's own size (400,000 x 2,000) runs on the chip;
+``tests/test_tpu_compile.py`` compiles it.
+"""
+import argparse
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL = "epsilon-levelwise-int8.train"
+ROWS, COLUMNS = 4096, 200
+
+
+def _exact(bins, vals, cid, B, lanes):
+    """[F, B, lanes] int64: every row's three levels added into the cell
+    of its bin and leaf column, in integers."""
+    F = bins.shape[0]
+    live = cid >= 0
+    out = np.zeros((F, B, lanes), np.int64)
+    for f in range(F):
+        key = bins[f, live].astype(np.int64) * lanes + cid[live] * 3
+        for k in range(3):
+            out[f] += np.bincount(key + k, weights=vals[k, live],
+                                  minlength=B * lanes).astype(
+                                      np.int64).reshape(B, lanes)
+    return out
+
+
+@pytest.mark.parametrize("F,lanes,num_cols,grid", [
+    # 128 lanes, 48 features a block: whole blocks, and a ragged last one
+    (200, 128, 32, (40, 5)), (100, 128, 32, (40, 3)),
+    # the same grid under each fold of the bin code
+    (100, 128, 1, (40, 3)), (200, 128, 4, (40, 5)), (100, 128, 16, (40, 3)),
+    # 192 lanes, 24 features a block (the 64-leaf level)
+    (200, 192, 64, (24, 9)), (100, 192, 64, (24, 5)),
+])
+def test_multi_block_kernel_equals_exact_histograms(F, lanes, num_cols,
+                                                    grid):
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu.ops.hist_pallas import (_hist_pallas_raw_fn,
+                                              feature_grid, hist_fold)
+    B, N, chunk = 255, 1024, 512
+    assert feature_grid(F, B, lanes, chunk) == grid
+    assert grid[0] * grid[1] >= F and grid[1] > 1
+    fold, gw = hist_fold(3, num_cols, B, lanes, "int8")
+    assert (fold > 1) == (num_cols <= 16)
+    rng = np.random.RandomState(F + lanes + num_cols)
+    bins = rng.randint(0, B, (F, N)).astype(np.uint8)     # codes >= 128 too
+    vals = np.stack([rng.randint(-127, 128, N), rng.randint(0, 128, N),
+                     np.ones(N, np.int64)])
+    cid = rng.randint(-1, num_cols, N)                    # -1: masked rows
+    vals = vals * (cid >= 0)
+    packed = np.concatenate([vals, cid[None]]).astype(np.int8)
+    with pltpu.force_tpu_interpret_mode():
+        got = _hist_pallas_raw_fn(
+            jnp.asarray(bins.astype(np.int8)), jnp.asarray(packed), B=B,
+            chunk=chunk, dtype="int8", lanes=lanes, fold=fold, gw=gw)
+    assert got.shape == (F, B, lanes) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got, np.int64),
+                                  _exact(bins, vals, cid, B, lanes))
+
+
+def test_feature_blocks_fit_the_scoped_vmem():
+    """The account of the rotating block, in bytes as Mosaic lays the
+    windows out: two buffers of the accumulator, of the bin rows and of
+    the side-band, with room left for the kernel's temporaries."""
+    from lightgbm_tpu.ops import hist_pallas as hp
+    for B in (64, 100, 128, 200, 255, 256):
+        for lanes in (128, 192):
+            for chunk in (512, 2048):
+                fb = hp.rotating_feature_block(B, lanes, chunk)
+                assert fb >= 8 and fb % 8 == 0
+                windows = 2 * (fb * (-(-B // 8) * 8) * (-(-lanes // 128)
+                                                        * 128) * 4
+                               + fb * chunk + 32 * chunk)
+                assert windows <= (hp.VMEM_SCOPED_BYTES
+                                   - hp.VMEM_TEMPORARIES_BYTES) or fb == 8
+    # what the benchmark's cells run: the narrow table is one block on
+    # every pass, the wide one 42 blocks of 48 and, at 192 lanes, 84 of 24
+    assert hp.feature_grid(28, 255, 128, 2048) == (28, 1)
+    assert hp.feature_grid(28, 255, 192, 2048) == (28, 1)
+    assert hp.feature_grid(64, 255, 192, 2048) == (64, 1)
+    assert hp.feature_grid(96, 255, 128, 2048) == (96, 1)
+    assert hp.feature_grid(2000, 255, 128, 2048) == (48, 42)
+    assert hp.feature_grid(2000, 255, 192, 2048) == (24, 84)
+
+
+# ------------------------------------------- the program and the reference
+
+@pytest.fixture(scope="module")
+def wide_run():
+    """One run of the benchmark's own harness on the wide cell cut to
+    4,096 rows and 200 columns: its traffic kind builds the booster as the
+    CLI does, drives ``run_training`` in slices of 8 and hands the trees,
+    the scores and the binned table to the reference.  The histogram
+    routing is steered onto its TPU branch, the Pallas kernel run by the
+    interpreter; the registry is on so that the route can be read back."""
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu import telemetry
+    added = [p for p in (os.path.join(ROOT, "benchmarks"), ROOT)
+             if p not in sys.path]
+    sys.path[:0] = added
+    import run as runner
+    real_load = runner.load_json
+
+    def cut(*parts):
+        loaded = real_load(*parts)
+        if parts[0] == "configs":
+            loaded = dict(loaded, features=COLUMNS)
+        elif parts[0] == "cells":
+            # the first slice compiles inside the window; nothing judged
+            # reads the clock
+            loaded = dict(loaded, params=dict(loaded["params"],
+                                              warmup_slices=0))
+        return loaded
+    args = argparse.Namespace(workload=CELL, seed=3000000019, seconds=0.5,
+                              trace=0, rows=ROWS, control=1)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(runner, "load_json", cut)
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        with pltpu.force_tpu_interpret_mode():
+            line, code = runner.execute(args, require_chip=False)
+        counters = dict(telemetry.snapshot()["counters"])
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+        mp.undo()
+        for p in added:
+            sys.path.remove(p)
+    assert code == 0
+    return line, counters
+
+
+def test_wide_program_took_the_feature_block_grid(wide_run):
+    _line, counters = wide_run
+    # one traced tree: seven passes of 128 lanes in 5 blocks of 40, the
+    # 64-leaf pass of 192 lanes in 9 of 24; no pass left the Pallas route
+    passes = sum(v for k, v in counters.items()
+                 if k.startswith("hist/pallas_fold_"))
+    assert passes and passes % 8 == 0
+    assert counters["hist/pallas_fblocks"] == passes // 8 * (7 * 5 + 9)
+    assert "hist/xla_int_kernel" not in counters
+
+
+def test_wide_program_is_correct_by_the_cells_limits(wide_run):
+    line, _counters = wide_run
+    assert line["failed"] == 0 and line["attempted"] == 8
+    checks = line["checks"]
+    for name in ("score_gap", "bin_code_gap", "split_gain_gap",
+                 "leaf_value_gap", "leaf_sum_gap", "trees_short"):
+        assert checks[name]["limit"] is not None
+        assert checks[name]["value"] <= checks[name]["limit"], (
+            name, checks[name])
+    assert line["correct"] is True
+
+
+def test_wide_control_in_int4_is_not_correct(wide_run):
+    line, _counters = wide_run
+    assert line["control_correct"] is False
+    assert any(c["limit"] is not None and c["value"] > c["limit"]
+               for name, c in line["checks"].items()
+               if name.startswith("control."))
+    assert line["faults_correct"] == {"half_batch": False,
+                                      "state_unchanged": False}
