@@ -12,7 +12,15 @@ peak and cannot use the int8 MXU path.  This kernel owns the schedule:
 - per feature, the bin one-hot [chunk, B] is generated in VMEM by an iota
   compare (never touches HBM) and contracted on the MXU
   (sublane-contracting dot_general) against the column-expanded value
-  block [chunk, K];
+  block [chunk, K].  A matmul pass costs the rows of the operand that
+  STREAMS times the 128-wide tiles of the operand the MXU HOLDS, and only
+  the held one is padded to whole tiles: with the one-hot streamed, 192
+  value lanes (64 leaf columns) pay 256 rows x 2 tiles, a quarter of it
+  on 64 lanes of zeros.  So the unfolded integer passes turn the product
+  round where that is fewer units (``held_onehot``): the live value rows
+  stream against the one-hot's two tiles, 192 x 2 at 64 columns and
+  96 x 2 at 32, into the transposed accumulator, which the wrapper
+  transposes back;
 - a pass with few leaf columns FOLDS the bin code (``hist_fold``): the
   low log2(k) bits of the bin move out of the one-hot into k copies of
   the few live value rows, so the VPU builds ceil(B / k) + k * 3 * cols
@@ -41,13 +49,15 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 # default value-operand width, one MXU tile: 42 leaf columns x 3 stats + 2.
-# A pass pays for B one-hot rows whatever part of the 128 lanes is live;
-# hist_fold moves bin bits into the idle part when 16 columns or fewer are.
+# With the one-hot streamed a pass pays for B one-hot rows whatever part
+# of the 128 lanes is live; hist_fold moves bin bits into the idle part
+# when 16 columns or fewer are, held_onehot streams the live part alone.
 LANES = 128
 
 
 def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
-                 compute_dtype, acc_dtype, stats=3, fold=1, gw=None):
+                 compute_dtype, acc_dtype, stats=3, fold=1, gw=None,
+                 held=0):
     # grid = (feature_blocks, row_chunks), rows minor: each feature
     # block's accumulator lives in VMEM across its whole row sweep and is
     # written back to HBM once
@@ -61,7 +71,8 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
     # boolean vectors.  VPU math runs wide (8-bit vector arithmetic is
     # unsupported) and casts to compute_dtype only for the MXU operands.
     # Everything is LANE-major ([*, chunk]); the value block vL is built
-    # TRANSPOSED [lanes, chunk] so the contraction is an NT-form matmul.
+    # TRANSPOSED [lanes, chunk] so the contraction is an NT-form matmul,
+    # whichever of the two operands comes first.
     # ``stats`` values interleave per leaf column (3 = grad/hess/count;
     # 5 = the f32 single-pass hi/lo packing g_hi,g_lo,h_hi,h_lo,count).
     wide = jnp.int32 if compute_dtype == jnp.int8 else jnp.float32
@@ -72,7 +83,7 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
     # summed into the same cell.  The VPU then builds B / fold + fold * gw
     # operand rows per feature where the unfolded kernel builds B, and
     # that build, not the MXU, sets the pace up to 128 lanes.
-    vrows = lanes if fold == 1 else fold * gw
+    vrows = held or (lanes if fold == 1 else fold * gw)
     jrow = jax.lax.broadcasted_iota(jnp.int32, (vrows, chunk), 0)
     if fold == 1:
         jj = jrow
@@ -109,14 +120,20 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
                    * vL).astype(compute_dtype)
             brow = brow >> shift
         oh = (iota_b == brow).astype(compute_dtype)     # [B/fold, chunk]
+        # the first operand streams, the MXU holds the second.  Held, the
+        # one-hot is B rows = whole 128-wide tiles and the ``held`` live
+        # value rows stream into the transposed accumulator: the same
+        # products into the same cells
+        streamed, kept = (vLt, oh) if held else (oh, vLt)
         out_ref[f] += jax.lax.dot_general(
-            oh, vLt, dimension_numbers=dn,
-            preferred_element_type=acc_dtype)           # [B/fold, vrows]
+            streamed, kept, dimension_numbers=dn,
+            preferred_element_type=acc_dtype)   # [B/fold, vrows] | [vrows, B]
 
 
 def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
                         dtype: str = "int8", lanes: int = LANES,
-                        stats: int = 3, fold: int = 1, gw: int = None):
+                        stats: int = 3, fold: int = 1, gw: int = None,
+                        held: int = 0):
     """[F, B, lanes] accumulator from [F, N] bins and packed values.
 
     Rows must be pre-padded to a multiple of ``chunk`` (pad cid with -1).
@@ -133,12 +150,19 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
                 XLA einsum-lowering regressions (BASELINE.md round 3).
     ``bins`` may carry uint8 bit-patterns (the kernel masks the
     sign-extension back off).  ``lanes`` widens the value operand past one
-    MXU tile (192 fits 64 leaf columns in 1.5 tiles instead of two full
-    128-lane passes).  ``fold`` > 1 (a power of two, with ``gw`` >= the
-    live stats * columns; ``hist_fold`` picks both) runs the bin-folded
-    kernel on a [F, ceil(B / fold), fold * gw] accumulator and unfolds it:
-    the result is the same [F, B, lanes] array, bit for bit, for the
-    integer-level modes ("bf16v" does not fold).
+    MXU tile (192 holds 64 leaf columns in one pass over the data; as the
+    operand the MXU holds they are two tiles, as the one it streams 192
+    rows).  ``fold`` > 1 (a power of two, with ``gw`` >= the live
+    stats * columns; ``hist_fold`` picks both) runs the bin-folded kernel
+    on a [F, ceil(B / fold), fold * gw] accumulator and unfolds it.
+    ``held`` > 0 (a multiple of 32 holding the live stats * columns;
+    ``held_onehot`` picks it, for unfolded passes) turns the contraction
+    round: ``held`` value rows stream against the one-hot, held as whole
+    128-wide tiles, into a [F, held, B up to whole tiles] accumulator that
+    is transposed back and zero-padded.  Either way the result is the same
+    [F, B, lanes] array, bit for bit, for the integer-level modes ("bf16v"
+    neither folds nor turns round); ``fold=1, held=0`` is the kernel as it
+    always was.
 
     Wide datasets ride a FEATURE-BLOCK grid axis: each block of Fb
     features sweeps the rows in turn with its [Fb, B, lanes] accumulator
@@ -148,9 +172,9 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     rotating window as Mosaic lays them out, so every F lowers inside the
     16 MiB a kernel may hold: compiled for a described v5e at F = 2,000
     for every pass of a 255-leaf level-wise tree
-    (tests/test_tpu_compile.py), 24 features a block at 192 lanes and 48
-    at 128 with 255 bins.  ``feature_block`` and fewer features run as
-    ONE block, the kernel as it always was.
+    (tests/test_tpu_compile.py), with 255 bins 48 features a block at 128
+    lanes and at 192 lanes 32 with the one-hot held, 24 with it streamed.
+    ``feature_block`` and fewer features run as ONE block.
     """
     from .. import telemetry
     telemetry.count("hist/pallas_kernel_" + dtype)
@@ -160,20 +184,27 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     acc_dtype = jnp.int32 if dtype == "int8" else jnp.float32
     if dtype == "bf16v":
         assert packed.dtype == jnp.bfloat16, packed.dtype
-    fb, n_fblocks = feature_grid(F, B, lanes, chunk)
+    fb, n_fblocks = feature_grid(F, B, lanes, chunk, held)
     if n_fblocks * fb > F:
         bins = jnp.pad(bins, ((0, n_fblocks * fb - F), (0, 0)))
-    if fold == 1:
+    if held:
+        assert dtype != "bf16v" and fold == 1
+        assert stats <= held <= lanes and held % 32 == 0
+        Bk = B + (-B) % LANES            # the held operand: whole tiles
+        out_block = (fb, held, Bk)
+    elif fold == 1:
+        Bk = B
         out_block = (fb, B, lanes)
     else:
         assert dtype != "bf16v" and fold & (fold - 1) == 0
         assert stats <= gw and fold * gw <= lanes
         Bh = -(-B // fold)
+        Bk = Bh * fold
         out_block = (fb, Bh, fold * gw)
     kernel = functools.partial(
-        _hist_kernel, F=fb, B=B if fold == 1 else Bh * fold, chunk=chunk,
+        _hist_kernel, F=fb, B=Bk, chunk=chunk,
         lanes=lanes, compute_dtype=compute_dtype, acc_dtype=acc_dtype,
-        stats=stats, fold=fold, gw=gw)
+        stats=stats, fold=fold, gw=gw, held=held)
     out = pl.pallas_call(
         kernel,
         grid=(n_fblocks, N // chunk),
@@ -187,17 +218,20 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(bins, packed)
-    if fold > 1:
-        # unfold: cell (hi, lo * gw + jj) -> (hi * fold + lo, jj), value
-        # columns zero-padded back to ``lanes``.  The layout constraint
-        # hands the consumers what the unfolded kernel's custom call
-        # would, a row-major array: without it XLA lays the float
-        # histograms out after the narrow accumulator and the split
-        # search's sums round in another order (same ints, trees that
-        # differ in the last place of a gain).  The pad itself fuses away
-        out = out.reshape(-1, Bh * fold, gw)[:, :B]
+    if held or fold > 1:
+        # the narrow accumulator back to [F, B, lanes]: the transpose of
+        # the held one-hot's [held, B], or the unfold, cell
+        # (hi, lo * gw + jj) -> (hi * fold + lo, jj); value columns
+        # zero-padded back to ``lanes``.  The layout constraint hands the
+        # consumers what the plain kernel's custom call would, a row-major
+        # array: without it XLA lays the float histograms out after the
+        # narrow accumulator and the split search's sums round in another
+        # order (same ints, trees that differ in the last place of a
+        # gain).  The pad itself fuses away
+        out = (jnp.swapaxes(out, 1, 2) if held
+               else out.reshape(-1, Bh * fold, gw))[:, :B]
         out = with_layout_constraint(
-            jnp.pad(out, ((0, 0), (0, 0), (0, lanes - gw))),
+            jnp.pad(out, ((0, 0), (0, 0), (0, lanes - out.shape[2]))),
             Layout(major_to_minor=(0, 1, 2)))
     out = out[:F]
     if dtype in ("int8", "bf16v"):
@@ -217,7 +251,7 @@ hist_pallas_raw = _costmodel.instrument(
     "hist/pallas_raw",
     jax.jit(_hist_pallas_raw_fn,
             static_argnames=("B", "chunk", "dtype", "lanes", "stats", "fold",
-                             "gw")),
+                             "gw", "held")),
     phase="histogram")
 
 
@@ -227,6 +261,12 @@ hist_pallas_raw = _costmodel.instrument(
 # block and a one-hot in both widths, [256, chunk] rows at most
 VMEM_SCOPED_BYTES = 16 << 20
 VMEM_TEMPORARIES_BYTES = 2 << 20
+# most features a block of a pass with the one-hot held, whatever fits:
+# the kernel's feature loop is unrolled, and past the 48 a streamed
+# 128-lane pass takes a longer one buys less than it costs to compile (on
+# a v5e at [2000, 401,408], 96 value rows: 147.0 ms a pass and 15.7 s of
+# compile at 72 a block, 148.2 ms and 9.8 s at 48; PERF.md, PR 31)
+HELD_BLOCK_FEATURES = 48
 
 
 def feature_block(B: int, lanes: int) -> int:
@@ -242,7 +282,39 @@ def feature_block(B: int, lanes: int) -> int:
     return max(8, fb - fb % 8)
 
 
-def feature_grid(F: int, B: int, lanes: int, chunk: int):
+def held_onehot(stats: int, num_cols: int, B: int, lanes: int,
+                dtype: str) -> int:
+    """Value rows a pass STREAMS with the one-hot as the operand the MXU
+    holds (the transposed accumulator), or 0 for the kernel as it always
+    ran, the one-hot streamed and the value block held: every pass that
+    ``hist_fold`` folds, and of the unfolded ones those below.  From the
+    pass's static shapes: a matmul pass costs (rows streamed) x (128-wide
+    tiles of the held operand), and only the held operand is padded to
+    whole tiles.  Streamed one-hot: B rows (up to the 32-row int8 tile)
+    x ceil(lanes / 128) tiles of value rows, two at 192 lanes whatever
+    part of them is live.  Held one-hot: the live stats * num_cols value
+    rows (up to the 32-row tile) x ceil(B / 128) tiles.  This takes the
+    held one-hot where it is fewer units: at 255 bins 64 columns are
+    192 x 2 against 256 x 2 and 32 columns 96 x 2 against 256 x 1, while
+    33-42 columns (128 x 2) and a 64-bin class of the mixed-bin layout
+    (192 x 1 against 64 x 2) keep the streamed one.  Measured on a v5e at
+    [28, 10.5M], the kernel alone (PERF.md section 6, PR 31): 64 columns
+    104.4 -> 78.5 ms, the dot alone 78.0 and the int8 peak's floor for
+    384 units 73.5; 43 columns (160 rows) 104.4 -> 65.9; 32 columns,
+    which the VPU's build of the one-hot bounds, 57.3 -> 53.6; 21 columns
+    (64 rows) 57.3 -> 50.0; 42 columns (128 rows against two tiles) 57.3
+    -> 57.6 had it turned, the 64-bin class 29.8 -> 41.7.  Only the
+    integer-level modes, whose sums are exact and order-free: "bf16v"
+    (float gradients) keeps its summation shape."""
+    if dtype == "bf16v" or hist_fold(stats, num_cols, B, lanes, dtype)[0] > 1:
+        return 0
+    rows = stats * num_cols + (-stats * num_cols) % 32
+    if rows * -(-B // LANES) < (B + (-B) % 32) * -(-lanes // LANES):
+        return rows
+    return 0
+
+
+def feature_grid(F: int, B: int, lanes: int, chunk: int, held: int = 0):
     """(features per block, blocks) of one pass over F features."""
     if F <= feature_block(B, lanes):
         # single block: the output window is constant across the grid, so
@@ -253,32 +325,43 @@ def feature_grid(F: int, B: int, lanes: int, chunk: int):
     # Mosaic DOUBLE-BUFFERS.  Blocks are balanced: with 48 a block
     # (B=256, lanes=128), 100 features run as 3 x 40 (20 pad) instead of
     # 48+48+48 (44 pad) — padded features cost full matmul passes
-    n_fblocks = -(-F // rotating_feature_block(B, lanes, chunk))
+    n_fblocks = -(-F // rotating_feature_block(B, lanes, chunk, held))
     fb = -(-F // n_fblocks)
     return fb + (-fb) % 8, n_fblocks          # sublane-tile multiple
 
 
-def rotating_feature_block(B: int, lanes: int, chunk: int) -> int:
+def rotating_feature_block(B: int, lanes: int, chunk: int,
+                           held: int = 0) -> int:
     """Most features a block when the table is wider than one block: the
     output window then rotates with the feature axis of the grid and
     Mosaic keeps TWO buffers of it, like of every operand.  Counted as
-    laid out in VMEM (``T(8, 128)`` tiles of 4-byte cells: bins up to a
-    multiple of 8 sublanes, 192 lanes up to 256), per feature a
-    [B, lanes] accumulator and a [chunk] row of bin codes, twice each,
-    beside the two buffers of the packed side-band (its stats + 1 rows
-    fill one 32-sublane int8 tile, or two 16-sublane bf16 ones) and the
-    kernel's temporaries, under ``VMEM_SCOPED_BYTES``.  At 255 bins and
-    chunk 2048: 48 features at 128 lanes (12.4 MiB of windows), 24 at 192
-    (12.2 MiB; the 32 that B * lanes * 4 bytes a feature allowed were
-    16.12 MiB, which the TPU compiler refused at F = 300, 700 and 2,000).
-    Raising the kernel's own ``vmem_limit_bytes`` instead buys 1% (on a
-    v5e at [2000, 401,408]: 293.1 ms a 192-lane pass at 24 a block,
-    290.2 at 32 under 32 MiB, 353.1 at 64 under 48 MiB; PERF.md, PR 30).
-    The fold's narrower accumulator is not counted: a folded pass takes
-    the unfolded block."""
-    acc = (B + (-B) % 8) * (lanes + (-lanes) % 128) * 4
+    laid out in VMEM (``T(8, 128)`` tiles of 4-byte cells: the
+    accumulator's rows up to a multiple of 8 sublanes, its columns up to
+    whole 128-lane tiles), per feature an accumulator and a [chunk] row
+    of bin codes, twice each, beside the two buffers of the packed
+    side-band (its stats + 1 rows fill one 32-sublane int8 tile, or two
+    16-sublane bf16 ones) and the kernel's temporaries, under
+    ``VMEM_SCOPED_BYTES``.  The accumulator is [B, lanes], 192 lanes laid
+    out as 256, or with the one-hot held (``held_onehot``) [held, B], the
+    ``held`` value rows that stream and 255 bins laid out as 256.  At 255
+    bins and chunk 2048: 48 features at 128 lanes (12.4 MiB of windows);
+    at 192 lanes 24 as [B, lanes] (12.2 MiB; the 32 that B * lanes * 4
+    bytes a feature allowed were 16.12 MiB, which the TPU compiler
+    refused at F = 300, 700 and 2,000); held, 32 features at 192 value
+    rows (12.2 MiB again: 196,608 bytes a feature where the other layout
+    takes 262,144) and, 72 fitting, ``HELD_BLOCK_FEATURES`` at 96.
+    Raising the kernel's own
+    ``vmem_limit_bytes`` instead buys 1% (on a v5e at [2000, 401,408],
+    one-hot streamed: 293.1 ms a 192-lane pass at 24 a block, 290.2 at 32
+    under 32 MiB, 353.1 at 64 under 48 MiB; PERF.md, PR 30).  The fold's
+    narrower accumulator is not counted: a folded pass takes the unfolded
+    block."""
+    rows, cols = (held, B) if held else (B, lanes)
+    acc = (rows + (-rows) % 8) * (cols + (-cols) % 128) * 4
     room = VMEM_SCOPED_BYTES - VMEM_TEMPORARIES_BYTES - 2 * 32 * chunk
     fb = room // (2 * (acc + chunk))
+    if held:
+        fb = min(fb, HELD_BLOCK_FEATURES)
     return max(8, fb - fb % 8)
 
 
@@ -444,8 +527,8 @@ def _grouped(fn, bins, grad, hess, col_id, col_ok, num_cols, B, *,
     """Split levels wider than ``group_width`` columns into balanced
     groups (the same rule as ops/histogram.histogram_leafbatch: ceil-split
     so the last group is never a nearly-empty full pass).  42 = one
-    128-lane MXU tile (XLA paths); the Pallas kernels take 64 (a 192-lane
-    operand is cheaper than two passes)."""
+    128-lane MXU tile (XLA paths); the Pallas kernels take 64 (one pass
+    of 192 value rows is cheaper than two passes over the data)."""
     if num_cols <= group_width:
         return fn(bins, grad, hess, col_id, col_ok, num_cols, B, **kw)
     n_groups = -(-num_cols // group_width)
@@ -487,11 +570,13 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
 
     ``bins`` is the usual [F, N] matrix (int8 or uint8).  The int32
     accumulator dequantizes to the usual [C, F, B, 3] f32.  Levels up to
-    64 columns run as ONE pass (<=42 columns fill one 128-lane MXU tile;
-    43-64 use a 192-lane operand = 1.5 tiles, cheaper than two full
-    passes over the data); wider levels split into 64-column groups.
-    Passes of 16 columns or fewer fold the bin code into the idle value
-    rows (``hist_fold``); the accumulator handed on is the unfolded one.
+    64 columns run as ONE pass (<=42 columns in a 128-lane accumulator,
+    43-64 in a 192-lane one, cheaper than two passes over the data);
+    wider levels split into 64-column groups.  Passes of 16 columns or
+    fewer fold the bin code into the idle value rows (``hist_fold``); the
+    unfolded ones of 17-32 and 43-64 columns hold the one-hot in the MXU
+    and stream their live value rows (``held_onehot``); the accumulator
+    handed on is [F, B, lanes] in every case.
 
     ``packing`` (mixed-bin layout): one kernel launch per bin-width class
     — the narrow class's [Fc, 64, lanes] accumulator costs a quarter of
@@ -536,14 +621,18 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
 
     def launch(rows, width):
         fold, gw = hist_fold(3, num_cols, width, lanes, dtype)
+        held = held_onehot(3, num_cols, width, lanes, dtype)
         # counted per pass, here: two passes of one shape share one trace
         # of the jitted kernel, so its own counters see them once
         telemetry.count("hist/pallas_fold_" + str(fold))
+        telemetry.count("hist/pallas_held_onehot", int(held > 0))
         telemetry.count("hist/pallas_fblocks",
-                        feature_grid(rows.shape[0], width, lanes, chunk)[1])
+                        feature_grid(rows.shape[0], width, lanes, chunk,
+                                     held)[1])
         return hist_pallas_raw(rows.astype(jnp.int8), packed, B=width,
                                chunk=chunk, dtype=dtype, lanes=lanes,
-                               fold=fold, gw=gw)         # [F, width, lanes]
+                               fold=fold, gw=gw,
+                               held=held)                # [F, width, lanes]
 
     if _packing_on(packing):
         telemetry.count("hist/mixedbin_pallas_int")

@@ -113,7 +113,9 @@ def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
     (N x F x B x lanes per group; the MXU tile floor makes <=42 leaf
     columns cost 128 lanes, 43-64 ride a 192-lane operand; a pass of
     ``int_levels`` that hist_pallas.hist_fold folds contracts
-    ceil(B / fold) one-hot rows against fold * gw value rows instead)
+    ceil(B / fold) one-hot rows against fold * gw value rows instead,
+    and an unfolded one that hist_pallas.held_onehot turns round B
+    one-hot rows against its live value rows alone)
     and the HBM
     bytes streamed (int8 bins + the packed per-row side-band, re-read
     once per group, + the per-group accumulator write-back).  Wider
@@ -131,11 +133,14 @@ def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
         width = -(-num_cols // groups)
         lanes = 128.0 if width <= 42 else 192.0
     cells = float(B) * lanes                 # accumulator cells a feature
-    if int_levels and num_cols <= 42:
-        from .hist_pallas import LANES, hist_fold
-        fold, gw = hist_fold(3, num_cols, B, LANES, "int8")
+    if int_levels and groups == 1:
+        from .hist_pallas import held_onehot, hist_fold
+        fold, gw = hist_fold(3, num_cols, B, int(lanes), "int8")
+        held = held_onehot(3, num_cols, B, int(lanes), "int8")
         if fold > 1:
             cells = float(-(-B // fold)) * fold * gw
+        elif held:
+            cells = float(B) * held
     macs = float(N) * F * cells * groups
     bytes_moved = (groups * (float(N) * F + 4.0 * N)
                    + groups * float(F) * cells * 4.0)

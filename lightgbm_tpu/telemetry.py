@@ -293,6 +293,7 @@ COUNTER_FAMILIES = (
     "hist/pallas_*",              # per-dtype kernel hits
     "hist/pallas_eligible",
     "hist/pallas_fblocks",        # feature blocks of the kernel's grid, summed over int passes
+    "hist/pallas_held_onehot",    # int passes contracted with the one-hot held, the value rows streamed
     "hist/pallas_ineligible",
     "hist/pallas_int8",
     "hist/pallas_kernel_*",       # per-width kernel-class hits

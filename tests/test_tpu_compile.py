@@ -132,54 +132,69 @@ _GROW_KW = dict(num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=100,
     # rows against one-hots of 32 to 128
     ("int8", 128, 3, 1), ("int8", 128, 3, 2), ("int8", 128, 3, 4),
     ("int8", 128, 3, 8), ("int8", 128, 3, 16), ("bf16", 128, 3, 1),
+    # the 64-leaf pass in the bf16 level mode: turned round like "int8"
+    ("bf16", 192, 3, 64),
 ])
 def test_hist_kernel_compiles(one_chip, as_tpu, dtype, lanes, stats,
                               num_cols):
-    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn, hist_fold
+    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn
     packed_dtype = jnp.bfloat16 if dtype == "bf16v" else jnp.int8
-    fold, gw = hist_fold(stats, num_cols, 256, lanes, dtype)
+    fold, gw, held = _pass_rules(dtype, lanes, stats, num_cols)
     assert (fold > 1) == (dtype != "bf16v" and num_cols <= 16)
+    # the integer modes' unfolded passes hold the one-hot and stream the
+    # live value rows, 96 of 128 lanes and all 192; nothing else does
+    assert held == (0 if dtype == "bf16v" or fold > 1 else 3 * num_cols)
     fn = jax.jit(_hist_pallas_raw_fn,
                  static_argnames=("B", "chunk", "dtype", "lanes", "stats",
-                                  "fold", "gw"))
+                                  "fold", "gw", "held"))
     compiled = fn.lower(
         _shape(one_chip, (F, N), jnp.int8),
         _shape(one_chip, (stats + 1, N), packed_dtype),
         B=256, chunk=2048, dtype=dtype, lanes=lanes, stats=stats,
-        fold=fold, gw=gw).compile()
+        fold=fold, gw=gw, held=held).compile()
     _check(compiled, custom_call=True)
+
+
+def _pass_rules(dtype, lanes, stats, num_cols):
+    """(fold, gw, held) as ``_hist_pallas_one`` picks them for a pass."""
+    from lightgbm_tpu.ops.hist_pallas import held_onehot, hist_fold
+    return (*hist_fold(stats, num_cols, 256, lanes, dtype),
+            held_onehot(stats, num_cols, 256, lanes, dtype))
 
 
 def _lower_kernel(one_chip, features, dtype, lanes, stats, num_cols):
     """The raw kernel, traced anew (a wrapper of its own, so that no
     cached trace answers) and lowered for the described chip."""
-    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn, hist_fold
-    fold, gw = hist_fold(stats, num_cols, 256, lanes, dtype)
+    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn
+    fold, gw, held = _pass_rules(dtype, lanes, stats, num_cols)
 
     def fresh(bins, packed):
         return _hist_pallas_raw_fn(bins, packed, B=256, chunk=2048,
                                    dtype=dtype, lanes=lanes, stats=stats,
-                                   fold=fold, gw=gw)
+                                   fold=fold, gw=gw, held=held)
     return jax.jit(fresh).lower(
         _shape(one_chip, (features, 2048 * 4), jnp.int8),
         _shape(one_chip, (stats + 1, 2048 * 4),
                jnp.bfloat16 if dtype == "bf16v" else jnp.int8))
 
 
-@pytest.mark.parametrize("dtype,lanes,stats,num_cols", [
-    # the 64-leaf level: 192 lanes, 24 features a block, the pass the
-    # compiler refused at 32 a block (16.12 MiB of windows)
-    ("int8", 192, 3, 64),
-    # 128 lanes unfolded and folded: 48 features a block
-    ("int8", 128, 3, 32), ("int8", 128, 3, 1),
-    # float gradients, five statistics a column
-    ("bf16v", 192, 5, 38),
+@pytest.mark.parametrize("dtype,lanes,stats,num_cols,grid", [
+    # the 64-leaf level, 192 lanes, the one-hot held: the accumulator is
+    # [192, 256] cells a feature, 32 features a block
+    ("int8", 192, 3, 64, (32, 63)), ("bf16", 192, 3, 64, (32, 63)),
+    # 128 lanes: unfolded, the one-hot held and 96 live rows streamed
+    # ([96, 256] cells a feature: 72 would fit, 48 are taken), and folded
+    ("int8", 128, 3, 32, (48, 42)), ("int8", 128, 3, 1, (48, 42)),
+    # float gradients, five statistics a column, the one-hot streamed:
+    # [256, 192 -> 256] cells a feature, 24 a block (the compiler refused
+    # 32 of those: 16.12 MiB of windows)
+    ("bf16v", 192, 5, 38, (24, 84)),
 ])
 def test_hist_kernel_compiles_on_the_feature_block_grid(
-        one_chip, as_tpu, dtype, lanes, stats, num_cols):
+        one_chip, as_tpu, dtype, lanes, stats, num_cols, grid):
     from lightgbm_tpu.ops.hist_pallas import feature_grid
-    fb, blocks = feature_grid(WIDE_F, 256, lanes, 2048)
-    assert (fb, blocks) == ((24, 84) if lanes == 192 else (48, 42))
+    assert feature_grid(WIDE_F, 256, lanes, 2048,
+                        _pass_rules(dtype, lanes, stats, num_cols)[2]) == grid
     compiled = _lower_kernel(one_chip, WIDE_F, dtype, lanes, stats,
                              num_cols).compile()
     _check(compiled, custom_call=True)
@@ -187,33 +202,48 @@ def test_hist_kernel_compiles_on_the_feature_block_grid(
 
 def test_narrow_kernels_lower_as_before_the_feature_block_repair(
         one_chip, as_tpu, monkeypatch):
-    """The VMEM account of the rotating block is not on the path of a
-    table that fits one block: with the account put back to the rule it
+    """What the rules of the grid and of the orientation leave alone.
+    The VMEM account of the rotating block is not on the path of a table
+    that fits one block: with the account put back to the rule it
     replaced (B * lanes * 4 bytes a feature in 6 MiB), every F=28 kernel
-    of this file lowers to the same text, and the 192-lane pass of the
-    wide table, which that rule sized at 32 features a block, does not."""
+    of this file lowers to the same text.  And the held one-hot
+    (``held_onehot``) is the integer modes' unfolded passes and no other:
+    with the rule switched off every folded kernel and every "bf16v"
+    kernel lowers to the same text, at F=28 and on the wide table, and
+    the 32- and 64-column int8 passes do not."""
     from lightgbm_tpu.ops import hist_pallas
     shapes = [("int8", 128, 3, 32), ("int8", 192, 3, 64),
               ("bf16v", 128, 3, 1), ("bf16v", 192, 5, 38),
               ("int8", 128, 3, 1), ("int8", 128, 3, 2), ("int8", 128, 3, 4),
               ("int8", 128, 3, 8), ("int8", 128, 3, 16), ("bf16", 128, 3, 1)]
+    turned = (0, 1)
 
-    def before(b, lanes, _chunk):
+    def before(b, lanes, _chunk, _held=0):
         fb = (6 << 20) // (b * lanes * 4)
         return max(8, fb - fb % 8)
 
     def texts(features):
         return [_lower_kernel(one_chip, features, *s).as_text()
-                for s in shapes[:2 if features > F else None]]
-    # both rounds lower from the same lines: the kernel is serialized with
+                for s in shapes[:4 if features > F else None]]
+    # all rounds lower from the same lines: the kernel is serialized with
     # the locations of its call stack, this test's frames among them
     rounds = []
-    for account in (hist_pallas.rotating_feature_block, before):
+    for account, rule in (
+            (hist_pallas.rotating_feature_block, hist_pallas.held_onehot),
+            (before, hist_pallas.held_onehot),
+            (hist_pallas.rotating_feature_block, lambda *a: 0)):
         monkeypatch.setattr(hist_pallas, "rotating_feature_block", account)
+        monkeypatch.setattr(hist_pallas, "held_onehot", rule)
         rounds.append((texts(F), texts(WIDE_F)))
-    (narrow, wide), (narrow_was, wide_was) = rounds
+    (narrow, wide), (narrow_was, wide_was), (narrow_off, wide_off) = rounds
     assert narrow == narrow_was
-    assert wide[0] == wide_was[0] and wide[1] != wide_was[1]
+    # the old rule sized a block at 48 features of 128 lanes and 32 of
+    # 192: what the account gives the held [96, 256] and [192, 256]
+    # accumulators, and not the streamed [256, 192 -> 256] of "bf16v"
+    assert wide[:3] == wide_was[:3] and wide[3] != wide_was[3]
+    for got, off in ((narrow, narrow_off), (wide, wide_off)):
+        assert [a == b for a, b in zip(got, off)] == [
+            i not in turned for i in range(len(got))]
 
 
 @pytest.mark.parametrize("overlap", [True, False])

@@ -43,23 +43,29 @@ def _exact(bins, vals, cid, B, lanes):
 
 
 @pytest.mark.parametrize("F,lanes,num_cols,grid", [
-    # 128 lanes, 48 features a block: whole blocks, and a ragged last one
+    # 128 lanes unfolded, the one-hot held and 96 value rows streamed, 48
+    # features a block: whole blocks, and a ragged last one
     (200, 128, 32, (40, 5)), (100, 128, 32, (40, 3)),
+    # 128 lanes, the one-hot streamed (33-42 columns), 48 a block
+    (200, 128, 40, (40, 5)),
     # the same grid under each fold of the bin code
     (100, 128, 1, (40, 3)), (200, 128, 4, (40, 5)), (100, 128, 16, (40, 3)),
-    # 192 lanes, 24 features a block (the 64-leaf level)
-    (200, 192, 64, (24, 9)), (100, 192, 64, (24, 5)),
+    # 192 lanes (the 64-leaf level), the one-hot held: 32 features a block
+    (200, 192, 64, (32, 7)), (100, 192, 64, (32, 4)),
 ])
 def test_multi_block_kernel_equals_exact_histograms(F, lanes, num_cols,
                                                     grid):
     from jax.experimental.pallas import tpu as pltpu
     from lightgbm_tpu.ops.hist_pallas import (_hist_pallas_raw_fn,
-                                              feature_grid, hist_fold)
+                                              feature_grid, held_onehot,
+                                              hist_fold)
     B, N, chunk = 255, 1024, 512
-    assert feature_grid(F, B, lanes, chunk) == grid
-    assert grid[0] * grid[1] >= F and grid[1] > 1
     fold, gw = hist_fold(3, num_cols, B, lanes, "int8")
     assert (fold > 1) == (num_cols <= 16)
+    held = held_onehot(3, num_cols, B, lanes, "int8")
+    assert (held > 0) == (num_cols in (32, 64))
+    assert feature_grid(F, B, lanes, chunk, held) == grid
+    assert grid[0] * grid[1] >= F and grid[1] > 1
     rng = np.random.RandomState(F + lanes + num_cols)
     bins = rng.randint(0, B, (F, N)).astype(np.uint8)     # codes >= 128 too
     vals = np.stack([rng.randint(-127, 128, N), rng.randint(0, 128, N),
@@ -70,7 +76,8 @@ def test_multi_block_kernel_equals_exact_histograms(F, lanes, num_cols,
     with pltpu.force_tpu_interpret_mode():
         got = _hist_pallas_raw_fn(
             jnp.asarray(bins.astype(np.int8)), jnp.asarray(packed), B=B,
-            chunk=chunk, dtype="int8", lanes=lanes, fold=fold, gw=gw)
+            chunk=chunk, dtype="int8", lanes=lanes, fold=fold, gw=gw,
+            held=held)
     assert got.shape == (F, B, lanes) and got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got, np.int64),
                                   _exact(bins, vals, cid, B, lanes))
@@ -84,13 +91,16 @@ def test_feature_blocks_fit_the_scoped_vmem():
     for B in (64, 100, 128, 200, 255, 256):
         for lanes in (128, 192):
             for chunk in (512, 2048):
-                fb = hp.rotating_feature_block(B, lanes, chunk)
-                assert fb >= 8 and fb % 8 == 0
-                windows = 2 * (fb * (-(-B // 8) * 8) * (-(-lanes // 128)
-                                                        * 128) * 4
-                               + fb * chunk + 32 * chunk)
-                assert windows <= (hp.VMEM_SCOPED_BYTES
-                                   - hp.VMEM_TEMPORARIES_BYTES) or fb == 8
+                for held in (0, 64, 96, lanes):
+                    fb = hp.rotating_feature_block(B, lanes, chunk, held)
+                    assert fb >= 8 and fb % 8 == 0
+                    rows, cols = (held, B) if held else (B, lanes)
+                    windows = 2 * (fb * (-(-rows // 8) * 8)
+                                   * (-(-cols // 128) * 128) * 4
+                                   + fb * chunk + 32 * chunk)
+                    assert windows <= (hp.VMEM_SCOPED_BYTES
+                                       - hp.VMEM_TEMPORARIES_BYTES
+                                       ) or fb == 8
     # what the benchmark's cells run: the narrow table is one block on
     # every pass, the wide one 42 blocks of 48 and, at 192 lanes, 84 of 24
     assert hp.feature_grid(28, 255, 128, 2048) == (28, 1)
@@ -99,6 +109,8 @@ def test_feature_blocks_fit_the_scoped_vmem():
     assert hp.feature_grid(96, 255, 128, 2048) == (96, 1)
     assert hp.feature_grid(2000, 255, 128, 2048) == (48, 42)
     assert hp.feature_grid(2000, 255, 192, 2048) == (24, 84)
+    assert hp.feature_grid(2000, 255, 192, 2048, 192) == (32, 63)
+    assert hp.feature_grid(2000, 255, 128, 2048, 96) == (48, 42)
 
 
 # ------------------------------------------- the program and the reference
@@ -153,11 +165,13 @@ def wide_run():
 def test_wide_program_took_the_feature_block_grid(wide_run):
     _line, counters = wide_run
     # one traced tree: seven passes of 128 lanes in 5 blocks of 40, the
-    # 64-leaf pass of 192 lanes in 9 of 24; no pass left the Pallas route
+    # 64-leaf pass of 192 lanes in 7 of 32; the two unfolded passes (32
+    # and 64 leaves) hold the one-hot; no pass left the Pallas route
     passes = sum(v for k, v in counters.items()
                  if k.startswith("hist/pallas_fold_"))
     assert passes and passes % 8 == 0
-    assert counters["hist/pallas_fblocks"] == passes // 8 * (7 * 5 + 9)
+    assert counters["hist/pallas_fblocks"] == passes // 8 * (7 * 5 + 7)
+    assert counters["hist/pallas_held_onehot"] == passes // 8 * 2
     assert "hist/xla_int_kernel" not in counters
 
 
