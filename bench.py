@@ -1512,18 +1512,6 @@ def main() -> int:
             # rounds — health off here (the chunked path keeps it: its
             # vector rides IN the fused program and the readback)
             params["health"] = "false"
-            # keep every leaf-wise dispatch under the environment's ~60 s
-            # execution watchdog: segment the per-tree split loop so each
-            # dispatch stays ~30 s (bit-identical trees,
-            # models/grower.grow_tree_segmented).  Coefficients = measured
-            # per-row-per-split pass cost on v5e per kernel (leaf-wise
-            # passes are single-column, so f32's 5-stat single pass costs
-            # ~one bf16 pass; int8 runs at 2x the bf16 rate).
-            per_row = {"float32": 1.6e-8, "bfloat16": 1.5e-8,
-                       "int8": 9e-9}[hist_dtype]
-            split_s = args.rows * per_row
-            segs = max(1, math.ceil((args.leaves - 1) * split_s / 30.0))
-            params["leafwise_segments"] = str(segs)
         if args.tree_learner != "serial":
             params.update({"tree_learner": args.tree_learner,
                            "num_machines": "4",

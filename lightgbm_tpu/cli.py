@@ -9,7 +9,7 @@ jax.distributed + a device mesh (lightgbm_tpu/parallel/).
 TPU-native training knobs beyond the reference surface (all parsed as
 ordinary ``key=value`` options, see config.py for semantics):
 ``grow_policy``, ``hist_dtype``, ``hist_chunk``, ``dp_schedule``,
-``leafwise_compact``, ``leafwise_segments``, ``quant_rounding``,
+``leafwise_compact``, ``quant_rounding``,
 ``mixed_bin`` (per-bin-width-class histogram passes, ISSUE 6) and
 ``pipeline`` (deferred-readback boosting, ISSUE 6).  ``grow_policy`` and
 ``hist_dtype`` are documented accuracy/order trades; all the others are
@@ -195,7 +195,6 @@ KNOB_INVENTORY = {
     "hist_chunk": "XLA histogram scan row-chunk (0 = per-policy default)",
     "hist_dtype": "float32/bfloat16/int8 histogram operand dtype",
     "dp_schedule": "auto/psum/reduce_scatter DP reduction schedule",
-    "leafwise_segments": "split the leafwise grow loop across N dispatches",
     "leafwise_compact": "auto/true/false contiguous-leaf growth",
     "mixed_bin": "auto/true/false per-bin-width-class histogram passes",
     "feature_shards": "2-D mesh feature-axis factor (0 = auto)",

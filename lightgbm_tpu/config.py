@@ -577,14 +577,6 @@ class TreeConfig:
     # reduce_scatter (the reference's N-machine mode IS that schedule);
     # single-process meshes keep psum (parallel/learners.py _schedule)
     dp_schedule: str = "auto"
-    # leaf-wise dispatch segmentation (TreeConfig extension, grow_policy=
-    # leafwise only): a 255-leaf leaf-wise tree is 254 sequential
-    # histogram passes in ONE XLA dispatch; >1 splits that loop across N
-    # dispatches with the grow state carried device-resident — bit-
-    # identical trees (models/grower.grow_tree_segmented), just shorter
-    # dispatches (runtime watchdogs, interactivity).  Default 1 = the
-    # whole tree in one dispatch.
-    leafwise_segments: int = 1
     # compacted leaf-wise growth (TreeConfig extension, grow_policy=
     # leafwise, serial learner only): keep every leaf's rows physically
     # contiguous (the reference's DataPartition asymptotic,
@@ -592,8 +584,7 @@ class TreeConfig:
     # models/grower_leafcompact.py) so each split histograms only the
     # smaller child's rows instead of sweeping all N.  "auto" (default)
     # = on when the backend is TPU, off elsewhere (keeps CPU-golden
-    # tests on the masked grower); "true"/"false" force it.  When on it
-    # subsumes leafwise_segments: per-tree dispatches are already short.
+    # tests on the masked grower); "true"/"false" force it.
     leafwise_compact: str = "auto"
     # mixed-bin feature packing (TreeConfig extension, ISSUE 6): partition
     # features into bin-width classes at Dataset-attach time (narrow:
@@ -659,10 +650,6 @@ class TreeConfig:
             log.check(value in ("float32", "bfloat16", "int8"),
                       "hist_dtype must be float32, bfloat16 or int8")
             self.hist_dtype = value
-        self.leafwise_segments = _get_int(params, "leafwise_segments",
-                                          self.leafwise_segments)
-        log.check(self.leafwise_segments >= 1,
-                  "leafwise_segments should be >= 1")
         if "leafwise_compact" in params:
             value = params["leafwise_compact"].lower()
             log.check(value in ("auto", "true", "false"),
@@ -932,6 +919,13 @@ class OverallConfig:
 
     def set(self, params: Dict[str, str], require_data: bool = True) -> None:
         params = apply_aliases(params)
+        from .cli import KNOB_INVENTORY
+        for key in params:
+            # a key nothing reads (a typo, a knob since removed) trains
+            # as if it were absent: say so
+            if (key not in KNOB_INVENTORY and key not in ALIAS_TABLE
+                    and key != "config_file"):
+                log.warning("Unknown parameter %s" % key)
         self.num_threads = _get_int(params, "num_threads", self.num_threads)
         if "task" in params:
             value = params["task"].lower()

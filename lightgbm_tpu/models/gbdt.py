@@ -150,16 +150,6 @@ class GBDT:
         self.label_idx = train_data.label_idx
         self.sigmoid = objective.sigmoid if objective is not None else -1.0
         self._learner = learner or _serial_learner
-        if (learner is not None
-                and getattr(self.tree_config, "leafwise_segments", 1) > 1
-                and not getattr(learner, "supports_leafwise_segments",
-                                False)):
-            # the data-parallel learner segments its shard_map'd split
-            # loop (learners._segmented_grow); the feature-parallel one
-            # still runs whole-tree dispatches — say so instead of
-            # silently ignoring the setting
-            log.warning("leafwise_segments is not supported by %s; "
-                        "ignored" % type(learner).__name__)
 
         N = train_data.num_data
         self.num_bins_max = int(train_data.num_bins.max())
@@ -1813,17 +1803,14 @@ class GBDT:
         """run_training's chunking decision: chunk_supported AND a
         chunk-safe grower/histogram combination.
 
-        The round-1 "leaf-wise chunk crash" was root-caused to this
-        environment's ~60 s per-dispatch execution watchdog (BASELINE.md;
-        a plain matmul fori_loop reproduces it — not a grower bug): a
-        fused leaf-wise chunk is ONE dispatch of k x 254 histogram passes
-        and crosses the cap at production shapes (f32: k=3 x 500k; int8:
-        k~22 x 1M).  Fused leaf-wise is also measured SLOWER than the
-        per-iteration leaf-wise path (int8 in-scan 2.95 s/iter at 1M vs
-        0.63 s/iter per-iteration f32 — per-pass quantization overhead
-        dominates the C=1 passes), so leaf-wise stays per-iteration on
-        every count.  Direct train_chunk calls remain available for
-        leaf-wise on CPU (used by tests)."""
+        Leaf-wise growth stays on the per-iteration loop because the
+        fused leaf-wise chunk was the slower of the two when last
+        measured, on the r05 runtime (int8 in-scan 2.95 s/iter at 1M
+        rows against 0.63 s/iter per-iteration f32: the per-pass
+        quantization dominates its one-column passes), and has not been
+        measured since; ROADMAP B-W3's cell measures it.  Direct
+        train_chunk calls remain available for leaf-wise on CPU (used
+        by tests)."""
         return (self.chunk_supported(is_eval)
                 and self.tree_config.grow_policy == "depthwise")
 
@@ -3024,9 +3011,6 @@ def _serial_learner(gbdt: GBDT, bins, grad, hess, row_mask, feature_mask):
                                        feature_mask, gbdt.num_bins_device,
                                        **kwargs)
     if leafwise_compact_on(gbdt.tree_config):
-        # compacted growth subsumes leafwise_segments: each split touches
-        # only the smaller child's rows, so whole-tree dispatches stay
-        # short even at bench scale (grower_leafcompact.py)
         from ..ops.compact import pallas_partition_ok, partition_overlap_on
         from .grower_leafcompact import grow_tree_leafcompact
         # both bits are jit STATICS, so an env flip re-dispatches here
@@ -3036,12 +3020,6 @@ def _serial_learner(gbdt: GBDT, bins, grad, hess, row_mask, feature_mask):
             use_pallas_partition=pallas_partition_ok(gbdt.num_features),
             partition_overlap=partition_overlap_on(),
             **kwargs)
-    segments = getattr(gbdt.tree_config, "leafwise_segments", 1)
-    if segments > 1:
-        from .grower import grow_tree_segmented
-        return grow_tree_segmented(
-            bins, grad, hess, row_mask, feature_mask, gbdt.num_bins_device,
-            segments=segments, **kwargs)
     return grow_tree(
         bins, grad, hess, row_mask, feature_mask, gbdt.num_bins_device,
         **kwargs)

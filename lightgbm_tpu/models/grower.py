@@ -4,13 +4,12 @@ The three grower modules were collapsed into ONE schedule-parameterized
 grower (ISSUE 9): growth policy (leafwise/depthwise/leafcompact) and a
 declarative :class:`~.grower_unified.SeamSchedule` are parameters there;
 this module keeps the historical leaf-wise entry points (``grow_tree``,
-``grow_tree_impl`` with keyword seams, ``grow_tree_segmented``) plus the
-patchable ``build_histogram`` attribute, and nothing else — the graftlint
-AST pass (ISSUE 10) proved the old ``BIG``/``TreeArrays``/``_GrowState``/
-``_grow_init``/``_grow_segment`` re-exports unreferenced outside
-``grower_unified`` itself, and tests/test_graftlint.py pins this surface
-so dead exports cannot regrow.  New code should import from
-``grower_unified`` directly.
+``grow_tree_impl`` with keyword seams) plus the patchable
+``build_histogram`` attribute, and nothing else — the graftlint AST pass
+(ISSUE 10) proved the old ``BIG``/``TreeArrays``/``_GrowState``
+re-exports unreferenced outside ``grower_unified`` itself, and
+tests/test_graftlint.py pins this surface so dead exports cannot regrow.
+New code should import from ``grower_unified`` directly.
 """
 from __future__ import annotations
 
@@ -21,7 +20,7 @@ import jax.numpy as jnp
 from ..ops.histogram import build_histogram  # noqa: F401
 
 from .grower_unified import (  # noqa: F401
-    SeamSchedule, grow_tree, grow_tree_segmented, grow_tree_unified)
+    SeamSchedule, grow_tree, grow_tree_unified)
 
 
 def grow_tree_impl(bins, grad, hess, row_mask, feature_mask, num_bins, *,
@@ -32,9 +31,7 @@ def grow_tree_impl(bins, grad, hess, row_mask, feature_mask, num_bins, *,
                    packing=None,
                    hist_reduce=None, hist_axis=None, int_hist_reduce=None,
                    split_finder=None, partition_bins=None,
-                   stat_reduce=None, own_slice=None, root_hist_reduce=None,
-                   init_state=None, loop_count=None,
-                   return_state: bool = False):
+                   stat_reduce=None, own_slice=None, root_hist_reduce=None):
     """Historical keyword-seam surface over
     ``grow_tree_unified(policy="leafwise")`` — the individual seam kwargs
     assemble into one SeamSchedule."""
@@ -50,6 +47,4 @@ def grow_tree_impl(bins, grad, hess, row_mask, feature_mask, num_bins, *,
         min_sum_hessian_in_leaf=min_sum_hessian_in_leaf,
         max_depth=max_depth, hist_backend=hist_backend,
         hist_chunk=hist_chunk, compute_dtype=compute_dtype,
-        packing=packing, schedule=schedule, partition_bins=partition_bins,
-        init_state=init_state, loop_count=loop_count,
-        return_state=return_state)
+        packing=packing, schedule=schedule, partition_bins=partition_bins)

@@ -36,8 +36,7 @@ def grow_tree_leafcompact_impl(bins, grad, hess, row_mask, feature_mask,
                                hist_reduce=None, hist_axis=None,
                                int_hist_reduce=None, split_finder=None,
                                stat_reduce=None, own_slice=None,
-                               root_hist_reduce=None,
-                               return_state: bool = False):
+                               root_hist_reduce=None):
     """Historical keyword-seam surface over
     ``grow_tree_unified(policy="leafcompact")``."""
     schedule = SeamSchedule(
@@ -55,4 +54,4 @@ def grow_tree_leafcompact_impl(bins, grad, hess, row_mask, feature_mask,
         packing=packing,
         use_pallas_partition=use_pallas_partition,
         partition_overlap=partition_overlap, interpret=interpret,
-        schedule=schedule, return_state=return_state)
+        schedule=schedule)

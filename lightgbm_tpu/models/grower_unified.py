@@ -56,7 +56,6 @@ Seam schedule — the parallel learners' customization surface
 """
 from __future__ import annotations
 
-import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -284,9 +283,7 @@ def grow_tree_unified(bins, grad, hess, row_mask, feature_mask, num_bins,
                       partition_overlap: bool = True,
                       interpret: bool = False,
                       schedule: Optional[SeamSchedule] = None,
-                      partition_bins=None,
-                      init_state=None, loop_count=None,
-                      return_state: bool = False):
+                      partition_bins=None):
     """Grow one tree (TreeLearner::Train) under any growth policy × seam
     schedule.  Not jitted; callers wrap it (the module-level jits below,
     the learners' shard closures, the chunk-program builders).
@@ -317,12 +314,6 @@ def grow_tree_unified(bins, grad, hess, row_mask, feature_mask, num_bins,
         blocked storage matrix via the GLOBAL canonical->storage map
     use_pallas_partition / partition_overlap / interpret : the compact
         policy's partition-kernel routing (ops/compact.partition_segment)
-    init_state / loop_count / return_state : the leaf-wise policy's
-        dispatch-segmentation seam (grow_tree_segmented): resume from a
-        carried _GrowState, run only ``loop_count`` split attempts,
-        return the full state.  The split body never reads the loop
-        index, so segmenting fori_loop(0, L-1) is EXACTLY the same
-        program.
     """
     if policy not in GROW_POLICIES:
         raise ValueError("unknown grow policy %r" % (policy,))
@@ -338,24 +329,17 @@ def grow_tree_unified(bins, grad, hess, row_mask, feature_mask, num_bins,
                                      if partition_packing is not None
                                      else packing))
     if policy == "depthwise":
-        if return_state or init_state is not None:
-            raise ValueError("dispatch segmentation is a leafwise seam")
         return _grow_depthwise(bins, grad, hess, row_mask, feature_mask,
                                num_bins, s, partition_bins, **kwargs)
     if policy == "leafcompact":
-        if init_state is not None or loop_count is not None:
-            raise ValueError("dispatch segmentation is a leafwise seam")
         return _grow_leafcompact(bins, grad, hess, row_mask, feature_mask,
                                  num_bins, s, hist_backend=hist_backend,
                                  use_pallas_partition=use_pallas_partition,
                                  partition_overlap=partition_overlap,
-                                 interpret=interpret,
-                                 return_state=return_state, **kwargs)
+                                 interpret=interpret, **kwargs)
     return _grow_leafwise(bins, grad, hess, row_mask, feature_mask,
                           num_bins, s, partition_bins,
-                          hist_backend=hist_backend,
-                          init_state=init_state, loop_count=loop_count,
-                          return_state=return_state, **kwargs)
+                          hist_backend=hist_backend, **kwargs)
 
 
 # ====================================================== leaf-wise policy
@@ -386,8 +370,7 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
                    num_bins_max: int, min_data_in_leaf: int,
                    min_sum_hessian_in_leaf: float, max_depth: int,
                    hist_backend: str, hist_chunk: int, compute_dtype,
-                   packing, partition_packing=None, init_state=None,
-                   loop_count=None, return_state: bool = False):
+                   packing, partition_packing=None):
     """Masked leaf-wise growth (the reference's TreeLearner::Train,
     serial_tree_learner.cpp:119-153): DataPartition's permuted index
     lists become a [N] leaf-id vector, the LRU histogram pool a dense
@@ -428,62 +411,58 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
     # histogram's, the leaf-id update row_route's, the node records
     # tree_pack's.
 
-    # ---- root init (BeforeTrain, serial_tree_learner.cpp:155-236);
-    # skipped entirely when resuming from a carried state (segmentation)
-    def _root_state() -> _GrowState:
-        full, root_hist = _root_hist_pair(
-            lambda: build_hist(bins, grad, hess, row_mask, B,
-                               backend=hist_backend, chunk=hist_chunk,
-                               compute_dtype=compute_dtype,
-                               axis_name=s.hist_axis, packing=packing,
-                               **_fg),
-            lambda: hist_of(row_mask), s, compute_dtype)
-        with phase_scope("histogram"):
-            root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
-                                        row_mask)
-        root_g, root_h, root_c = root_stats[0], root_stats[1], root_stats[2]
-        root_best = best_of(root_hist, root_g, root_h, root_c,
-                            jnp.asarray(1, jnp.int32), root=True)
-        with phase_scope("tree_pack"):
-            neg_inf = jnp.full((L,), -jnp.inf, dtype=f32)
-            zeros_i = jnp.zeros((L,), dtype=jnp.int32)
-            zeros_f = jnp.zeros((L,), dtype=f32)
+    # ---- root init (BeforeTrain, serial_tree_learner.cpp:155-236)
+    full, root_hist = _root_hist_pair(
+        lambda: build_hist(bins, grad, hess, row_mask, B,
+                           backend=hist_backend, chunk=hist_chunk,
+                           compute_dtype=compute_dtype,
+                           axis_name=s.hist_axis, packing=packing,
+                           **_fg),
+        lambda: hist_of(row_mask), s, compute_dtype)
+    with phase_scope("histogram"):
+        root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
+                                    row_mask)
+    root_g, root_h, root_c = root_stats[0], root_stats[1], root_stats[2]
+    root_best = best_of(root_hist, root_g, root_h, root_c,
+                        jnp.asarray(1, jnp.int32), root=True)
+    with phase_scope("tree_pack"):
+        neg_inf = jnp.full((L,), -jnp.inf, dtype=f32)
+        zeros_i = jnp.zeros((L,), dtype=jnp.int32)
+        zeros_f = jnp.zeros((L,), dtype=f32)
 
-            tree = TreeArrays(
-                num_leaves=jnp.asarray(1, jnp.int32),
-                split_feature=jnp.zeros((L - 1,), jnp.int32),
-                threshold_bin=jnp.zeros((L - 1,), jnp.int32),
-                split_gain=jnp.zeros((L - 1,), f32),
-                left_child=jnp.zeros((L - 1,), jnp.int32),
-                right_child=jnp.zeros((L - 1,), jnp.int32),
-                leaf_parent=jnp.full((L,), -1, jnp.int32),
-                leaf_value=zeros_f,
-                leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
-                leaf_ids=jnp.zeros((N,), jnp.int32),
-            )
-            return _GrowState(
-                tree=tree,
-                hist_cache=jnp.zeros((L,) + root_hist.shape,
-                                     f32).at[0].set(root_hist),
-                cand_gain=neg_inf.at[0].set(root_best.gain),
-                cand_feature=zeros_i.at[0].set(root_best.feature),
-                cand_threshold=zeros_i.at[0].set(root_best.threshold),
-                cand_left_out=zeros_f.at[0].set(root_best.left_output),
-                cand_right_out=zeros_f.at[0].set(root_best.right_output),
-                cand_left_cnt=zeros_i.at[0].set(root_best.left_count),
-                cand_right_cnt=zeros_i.at[0].set(root_best.right_count),
-                cand_left_g=zeros_f.at[0].set(root_best.left_sum_grad),
-                cand_left_h=zeros_f.at[0].set(root_best.left_sum_hess),
-                cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
-                cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
-                leaf_sum_g=zeros_f.at[0].set(root_g),
-                leaf_sum_h=zeros_f.at[0].set(root_h),
-                leaf_cnt=zeros_i.at[0].set(root_c.astype(jnp.int32)),
-                leaf_depth=zeros_i.at[0].set(1),
-                done=jnp.asarray(False),
-            )
-
-    state = init_state if init_state is not None else _root_state()
+        tree = TreeArrays(
+            num_leaves=jnp.asarray(1, jnp.int32),
+            split_feature=jnp.zeros((L - 1,), jnp.int32),
+            threshold_bin=jnp.zeros((L - 1,), jnp.int32),
+            split_gain=jnp.zeros((L - 1,), f32),
+            left_child=jnp.zeros((L - 1,), jnp.int32),
+            right_child=jnp.zeros((L - 1,), jnp.int32),
+            leaf_parent=jnp.full((L,), -1, jnp.int32),
+            leaf_value=zeros_f,
+            leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
+            leaf_ids=jnp.zeros((N,), jnp.int32),
+        )
+        state = _GrowState(
+            tree=tree,
+            hist_cache=jnp.zeros((L,) + root_hist.shape,
+                                 f32).at[0].set(root_hist),
+            cand_gain=neg_inf.at[0].set(root_best.gain),
+            cand_feature=zeros_i.at[0].set(root_best.feature),
+            cand_threshold=zeros_i.at[0].set(root_best.threshold),
+            cand_left_out=zeros_f.at[0].set(root_best.left_output),
+            cand_right_out=zeros_f.at[0].set(root_best.right_output),
+            cand_left_cnt=zeros_i.at[0].set(root_best.left_count),
+            cand_right_cnt=zeros_i.at[0].set(root_best.right_count),
+            cand_left_g=zeros_f.at[0].set(root_best.left_sum_grad),
+            cand_left_h=zeros_f.at[0].set(root_best.left_sum_hess),
+            cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
+            cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
+            leaf_sum_g=zeros_f.at[0].set(root_g),
+            leaf_sum_h=zeros_f.at[0].set(root_h),
+            leaf_cnt=zeros_i.at[0].set(root_c.astype(jnp.int32)),
+            leaf_depth=zeros_i.at[0].set(1),
+            done=jnp.asarray(False),
+        )
 
     def body(_, state: _GrowState) -> _GrowState:
         # pick the best leaf to split (FindBestSplitsForLeaves argmax,
@@ -632,9 +611,7 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         with jax.named_scope("leafwise_split"):
             return jax.lax.cond(should_split, do_split, no_split, state)
 
-    count = L - 1 if loop_count is None else loop_count
-    state = jax.lax.fori_loop(0, count, body, state)
-    return state if return_state else state.tree
+    return jax.lax.fori_loop(0, L - 1, body, state).tree
 
 
 # ====================================================== depthwise policy
@@ -990,8 +967,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                       hist_backend: str, hist_chunk: int, compute_dtype,
                       packing, partition_packing=None,
                       use_pallas_partition: bool,
-                      partition_overlap: bool, interpret: bool,
-                      return_state: bool = False):
+                      partition_overlap: bool, interpret: bool):
     """Compacted leaf-wise growth — reference-parity split order at the
     reference's geometric-series histogram cost (~N·log L instead of
     N·(L-1)): every leaf's rows stay contiguous in one [F+9, P] plane
@@ -1354,8 +1330,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
         with jax.named_scope("leafcompact_split"):
             return jax.lax.cond(should_split, do_split, no_split, state)
 
-    state = jax.lax.fori_loop(0, L - 1, body, state)
-    return state if return_state else state.tree
+    return jax.lax.fori_loop(0, L - 1, body, state).tree
 
 
 # ======================================================= jitted wrappers
@@ -1367,7 +1342,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
 # names, so recorded roofline/compile trajectories stay comparable.
 from .. import costmodel as _costmodel  # noqa: E402 (after jax imports)
 
-_SEG_STATICS = tuple(k for k in _GROW_STATICS if k != "policy")
+_JIT_STATICS = tuple(k for k in _GROW_STATICS if k != "policy")
 
 
 def _grow_tree_leafwise_fn(bins, grad, hess, row_mask, feature_mask,
@@ -1390,64 +1365,13 @@ def _grow_tree_leafcompact_fn(bins, grad, hess, row_mask, feature_mask,
 
 grow_tree = _costmodel.instrument(
     "grow/leafwise",
-    jax.jit(_grow_tree_leafwise_fn, static_argnames=_SEG_STATICS),
+    jax.jit(_grow_tree_leafwise_fn, static_argnames=_JIT_STATICS),
     phase="grow")
 grow_tree_depthwise_jit = _costmodel.instrument(
     "grow/depthwise",
-    jax.jit(_grow_tree_depthwise_fn, static_argnames=_SEG_STATICS),
+    jax.jit(_grow_tree_depthwise_fn, static_argnames=_JIT_STATICS),
     phase="grow")
 grow_tree_leafcompact = _costmodel.instrument(
     "grow/leafcompact",
-    jax.jit(_grow_tree_leafcompact_fn, static_argnames=_SEG_STATICS),
+    jax.jit(_grow_tree_leafcompact_fn, static_argnames=_JIT_STATICS),
     phase="grow")
-
-
-# ============================================== leaf-wise segmentation
-
-
-@functools.partial(jax.jit, static_argnames=_SEG_STATICS)
-def _grow_init(bins, grad, hess, row_mask, feature_mask, num_bins,
-               **kwargs) -> _GrowState:
-    return grow_tree_unified(bins, grad, hess, row_mask, feature_mask,
-                             num_bins, policy="leafwise", loop_count=0,
-                             return_state=True, **kwargs)
-
-
-# donate the carried state: without aliasing, input and output copies of
-# hist_cache [L,F,B,3] + leaf_ids [N] (~120 MB at bench scale) would both
-# be live at every segment boundary
-@functools.partial(jax.jit, static_argnames=_SEG_STATICS + ("loop_count",),
-                   donate_argnums=(6,))
-def _grow_segment(bins, grad, hess, row_mask, feature_mask, num_bins,
-                  state, *, loop_count, **kwargs) -> _GrowState:
-    return grow_tree_unified(bins, grad, hess, row_mask, feature_mask,
-                             num_bins, policy="leafwise", init_state=state,
-                             loop_count=loop_count, return_state=True,
-                             **kwargs)
-
-
-def grow_tree_segmented(bins, grad, hess, row_mask, feature_mask, num_bins,
-                        *, segments: int, **kwargs) -> TreeArrays:
-    """Leaf-wise growth split across ``segments`` device dispatches.
-
-    A 255-leaf leaf-wise tree is 254 sequential full-data histogram passes
-    in ONE XLA dispatch; at tens of millions of rows that single dispatch
-    can run minutes (and trips this environment's ~60 s per-dispatch
-    execution watchdog, BASELINE.md).  The split loop's body never reads
-    the loop index, so running fori_loop(0, L-1) as ceil((L-1)/segments)-
-    sized pieces with the _GrowState carried device-resident between
-    dispatches is program-identical — same trees, bit for bit.  Equal-size
-    segments share one compiled program (the count, not the start, is the
-    static)."""
-    L = kwargs["num_leaves"]
-    total = max(L - 1, 1)
-    per = -(-total // max(segments, 1))
-    state = _grow_init(bins, grad, hess, row_mask, feature_mask, num_bins,
-                       **kwargs)
-    done = 0
-    while done < total:
-        n = min(per, total - done)
-        state = _grow_segment(bins, grad, hess, row_mask, feature_mask,
-                              num_bins, state, loop_count=n, **kwargs)
-        done += n
-    return state.tree

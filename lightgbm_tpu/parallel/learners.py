@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .. import telemetry
-from ..models.grower_unified import (SeamSchedule, TreeArrays, _GrowState,
+from ..models.grower_unified import (SeamSchedule, TreeArrays,
                                      grow_tree_unified)
 from ..models.gbdt import _effective_num_leaves, _tuning_kwargs
 from ..ops.split import (SplitResult, find_best_split,
@@ -611,13 +611,12 @@ class DataParallelLearner(_ParallelLearnerBase):
                                    site_prefix="dp_rs/leafwise",
                                    loop=kwargs["num_leaves"] - 1)
 
-        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins,
-                       **extra):
+        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins):
             fmask_own, nbins_own, schedule = seams(fmask, nbins)
             return grow_tree_unified(
                 bins_s, grad_s, hess_s, mask_s, fmask_own, nbins_own,
                 policy="leafwise", schedule=schedule,
-                partition_bins=bins_s, **kwargs, **extra)
+                partition_bins=bins_s, **kwargs)
         return shard_grow
 
     def _scatter_grow_fn(self, kwargs, F: int, num_shards: int,
@@ -914,11 +913,6 @@ class DataParallelLearner(_ParallelLearnerBase):
         _DP_CHUNK_PROGRAMS[key] = prog
         return prog, num_shards
 
-    # the dispatch-segmentation seam (grower.grow_tree_segmented) exists
-    # under this learner: distributed leaf-wise training survives
-    # per-dispatch execution watchdogs at bench scale (VERDICT r4 #4)
-    supports_leafwise_segments = True
-
     def _leafwise_compact_enabled(self) -> bool:
         from ..models.gbdt import leafwise_compact_on
         return leafwise_compact_on(self.tree_config)
@@ -1013,11 +1007,10 @@ class DataParallelLearner(_ParallelLearnerBase):
                            lambda s: jax.lax.psum(s, DATA_AXIS),
                            kind="psum", loop=loop_scale))
 
-        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins,
-                       **extra):
+        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins):
             return grow_tree_unified(
                 bins_s, grad_s, hess_s, mask_s, fmask, nbins,
-                policy=policy, schedule=schedule, **kwargs, **extra)
+                policy=policy, schedule=schedule, **kwargs)
         return shard_grow
 
     def _grow_fn(self, kwargs, F: int, num_shards: int):
@@ -1045,77 +1038,6 @@ class DataParallelLearner(_ParallelLearnerBase):
                                   "depthwise" if depthwise else "leafwise",
                                   phase="train_chunk", loop_scale=k)
 
-    def _state_specs(self):
-        """shard_map specs of the carried _GrowState: leaf_ids row-sharded,
-        the hist cache feature-sharded under the ownership schedule (each
-        shard holds its owned block), everything else replicated."""
-        cache = (P(None, DATA_AXIS)
-                 if self._schedule() == "reduce_scatter" else P())
-        rep = P()
-        return _GrowState(
-            tree=_tree_out_specs(DATA_AXIS), hist_cache=cache,
-            cand_gain=rep, cand_feature=rep, cand_threshold=rep,
-            cand_left_out=rep, cand_right_out=rep, cand_left_cnt=rep,
-            cand_right_cnt=rep, cand_left_g=rep, cand_left_h=rep,
-            cand_right_g=rep, cand_right_h=rep, leaf_sum_g=rep,
-            leaf_sum_h=rep, leaf_cnt=rep, leaf_depth=rep, done=rep)
-
-    def _segmented_grow(self, gbdt, bins, grad, hess, row_mask,
-                        feature_mask, mesh, num_shards, segments: int):
-        """grow_tree_segmented under shard_map: the split fori_loop runs as
-        ceil((L-1)/segments) dispatches with the _GrowState carried
-        device-resident (and donated) between them — program-identical
-        trees, just short dispatches, exactly like the serial seam.  The
-        reference's N-machine leaf-wise mode has no dispatch-length
-        constraint to start with (serial_tree_learner.cpp:119-153); this
-        restores that property under runtime watchdogs."""
-        F, _ = bins.shape
-        kwargs = self._grow_kwargs(gbdt)
-        L = kwargs["num_leaves"]
-        total = max(L - 1, 1)
-        per = -(-total // max(segments, 1))
-        cache = getattr(self, "_seg_progs", None)
-        # the resolved mixed-bin layout rides the key like the jit_key in
-        # __call__ (graftlint R2: the traced per-class pass structure is
-        # baked into the segment programs)
-        seg_key = (F, num_shards, per, getattr(gbdt, "_pack_spec", None))
-        if cache is None or cache[0] != seg_key:
-            grow_fn = self._grow_fn(kwargs, F, num_shards)
-            in_specs = (P(None, DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
-                        P(DATA_AXIS), P(), P())
-            sspec = self._state_specs()
-
-            def shard_init(bins_s, grad_s, hess_s, mask_s, fmask, nbins):
-                return grow_fn(bins_s, grad_s, hess_s, mask_s, fmask,
-                               nbins, loop_count=0, return_state=True)
-
-            init_p = jax.jit(shard_map(shard_init, mesh=mesh,
-                                       in_specs=in_specs, out_specs=sspec))
-            seg_ps = {}
-            for n in {per, total - per * (total // per)} - {0}:
-                def shard_seg(bins_s, grad_s, hess_s, mask_s, fmask,
-                              nbins, state, _n=n):
-                    return grow_fn(bins_s, grad_s, hess_s, mask_s, fmask,
-                                   nbins, init_state=state, loop_count=_n,
-                                   return_state=True)
-                seg_ps[n] = jax.jit(
-                    shard_map(shard_seg, mesh=mesh,
-                              in_specs=in_specs + (sspec,),
-                              out_specs=sspec),
-                    donate_argnums=(6,))
-            cache = (seg_key, init_p, seg_ps)
-            self._seg_progs = cache
-        _, init_p, seg_ps = cache
-        args = (bins, grad, hess, row_mask, feature_mask,
-                gbdt.num_bins_device)
-        state = init_p(*args)
-        done = 0
-        while done < total:
-            n = min(per, total - done)
-            state = seg_ps[n](*args, state)
-            done += n
-        return state.tree
-
     # telemetry route tag ("dp"; the 2-D subclasses say "hybrid"/"voting")
     route_name = "dp"
 
@@ -1130,24 +1052,9 @@ class DataParallelLearner(_ParallelLearnerBase):
             hess = jnp.pad(hess, (0, pad))
             row_mask = jnp.pad(row_mask, (0, pad))
 
-        # compacted leaf-wise (EITHER schedule — _compact_grow_fn
-        # dispatches) subsumes segmentation: per-split dispatches are
-        # short by construction.  Only the masked-grower segmented path
-        # remains schedule-split.
         use_compact = (not self._depthwise
                        and self._leafwise_compact_enabled())
-        segments = getattr(self.tree_config, "leafwise_segments", 1)
         rt = self.route_name
-        if (not self._depthwise and segments > 1 and not use_compact
-                and self.supports_leafwise_segments):
-            telemetry.count_route("learner_" + rt,
-                                  "learner/%s_segmented" % rt)
-            tree = self._segmented_grow(gbdt, bins, grad, hess, row_mask,
-                                        feature_mask, mesh, num_shards,
-                                        segments)
-            if pad:
-                tree = tree._replace(leaf_ids=tree.leaf_ids[:N])
-            return tree
         telemetry.count_route(
             "learner_" + rt, "learner/%s_" % rt + (
                 "depthwise" if self._depthwise
@@ -1181,8 +1088,6 @@ class DataParallelLearner(_ParallelLearnerBase):
             elif use_compact:
                 shard_fn = self._compact_grow_fn(kwargs, F, num_shards)
             else:
-                # schedule-dispatching leaf-wise closure shared with the
-                # segmented path
                 shard_fn = self._grow_fn(kwargs, F, num_shards)
 
             from .. import costmodel
@@ -1283,14 +1188,13 @@ class HybridLearner(DataParallelLearner):
             F, fs, site_prefix="hybrid/%s" % policy, loop=loop,
             phase=phase, root_loop=loop_scale, pack=pack)
 
-        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins,
-                       **extra):
+        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins):
             own_s, fmask_own, nbins_own, schedule = seams(fmask, nbins)
             bins_own = jnp.take(bins_s, own_s, axis=0)
             return grow_tree_unified(
                 bins_own, grad_s, hess_s, mask_s, fmask_own, nbins_own,
                 policy=policy, schedule=schedule, partition_bins=bins_s,
-                **kw, **extra)
+                **kw)
         return shard_grow
 
     def _compact_grow_fn(self, kwargs, F: int, num_shards: int,
@@ -1317,12 +1221,6 @@ class HybridLearner(DataParallelLearner):
                 partition_overlap=overlap, **kwargs)
         return shard_grow
 
-    def _state_specs(self):
-        # the leaf-wise segmented carrier: the hist cache holds each
-        # shard's owned feature block -> sharded over the FEATURE axis
-        return super()._state_specs()._replace(
-            hist_cache=P(None, FEATURE_AXIS))
-
 
 class VotingLearner(HybridLearner):
     """Voting-parallel learner (ISSUE 9) — realizes the reference's
@@ -1344,12 +1242,6 @@ class VotingLearner(HybridLearner):
 
     route_name = "voting"
     voting = True
-    # f32 voting keeps LOCAL histogram caches (the voted exchange lives
-    # inside the finder), so the carried segmented _GrowState is not
-    # representable as one sharded global array — whole-tree dispatches
-    # only (gbdt warns and ignores leafwise_segments)
-    supports_leafwise_segments = False
-
     def _voting_seams(self, kwargs, F: int, site: str, loop: int,
                       phase: str, root_loop: int, lanes: int = 1,
                       pack=None):
@@ -1370,8 +1262,7 @@ class VotingLearner(HybridLearner):
         _, _, block_ids = _owned_block(F, self._feature_shards(),
                                        FEATURE_AXIS)
 
-        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins,
-                       **extra):
+        def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins):
             # pre-slice ``bins`` to the owned feature block (same as the
             # hybrid masked path): histogram compute and the [L, F, B, 3]
             # cache never touch un-owned features — the local caches and
@@ -1387,7 +1278,7 @@ class VotingLearner(HybridLearner):
                 bins_own, grad_s, hess_s, mask_s,
                 fmask[own_s] & ownok, jnp.take(nbins, own_s),
                 policy=policy, schedule=schedule, partition_bins=bins_s,
-                **kw, **extra)
+                **kw)
         return shard_grow
 
     def _compact_grow_fn(self, kwargs, F: int, num_shards: int,

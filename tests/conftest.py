@@ -25,7 +25,9 @@ from lightgbm_tpu import compile_cache
 
 compile_cache.configure(min_compile_secs=0.5)
 
+import faulthandler
 import shutil
+import signal
 import subprocess
 
 import numpy as np
@@ -35,6 +37,75 @@ REFERENCE_EXAMPLES = "/root/reference/examples"
 REFERENCE_SRC = "/root/reference"
 REFERENCE_BUILD = "/tmp/lightgbm_reference_build"
 REFERENCE_BINARY = os.path.join(REFERENCE_BUILD, "lightgbm")
+
+
+# The files that take over half a minute of a cold run, heaviest first
+# (ROADMAP "Tests" has each one's seconds; a file that passes half a minute
+# is added).  Under ``--dist loadfile`` a file is one worker's chain and the
+# run's wall is the last chain's start plus its length.  xdist starts the
+# files with the most tests first, which here are not the long ones (two
+# full-size compiles are 110 s), so these are collected first, in this
+# order, and xdist is told to keep the order it is given.
+HEAVY_FIRST = (
+    "test_tpu_compile_programs.py", "test_tpu_compile.py",
+    "test_multiprocess_dp.py", "test_parallel.py", "test_hist_int8_held.py",
+    "test_hist_int8_fold.py", "test_mixedbin.py", "test_wide_table.py",
+    "test_tpu_compile_wide.py", "test_hybrid_voting.py", "test_gbdt.py",
+    "test_streaming.py", "test_leafcompact.py",
+    "test_distributed_telemetry.py", "test_goss_chunk.py",
+    "test_depthwise.py", "test_hist_int8.py", "test_graftlint.py",
+    "test_mixedbin_hybrid.py", "test_hist_float_pallas.py",
+    "test_elastic.py", "test_health.py", "test_costmodel.py",
+    "test_serving.py", "test_grower_unified.py",
+)
+
+
+_STDERR_FD = 2
+
+
+def pytest_configure(config):
+    global _STDERR_FD
+    _STDERR_FD = os.dup(2)      # before any test's capture takes fd 2
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: at for at, name in enumerate(HEAVY_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+
+
+# twice the slowest test or fixture of a cold run (ROADMAP "Tests")
+TEST_LIMIT_S = 300
+TEST_LIMIT_GRACE_S = 30
+
+
+@pytest.fixture(autouse=True)
+def _test_time_limit(request):
+    """A test that runs past TEST_LIMIT_S is failed by name, so a wait
+    that never ends costs the run one test and not its clock.  The alarm
+    reaches the test because xdist runs tests on its worker's main
+    thread.  It cannot reach a main thread that waits inside one C call
+    (``test_held_onehot_same_trees`` stood so under XLA for twenty
+    minutes, twice).  For that case faulthandler's watchdog thread, which
+    needs no interpreter, writes every thread's stack to the run's log
+    TEST_LIMIT_GRACE_S later, so the log names the test and the call it
+    stands in.  It does not end the process: under ``--dist loadfile``
+    xdist hands a dead worker's file, the test that killed it included,
+    to a new worker, again and again."""
+    def expired(signum, frame):
+        pytest.fail("%s ran past the %d s a test may take"
+                    % (request.node.nodeid, TEST_LIMIT_S), pytrace=False)
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    faulthandler.dump_traceback_later(TEST_LIMIT_S + TEST_LIMIT_GRACE_S,
+                                      file=_STDERR_FD)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture(autouse=True)
@@ -136,9 +207,10 @@ def reference_binary():
     os.makedirs(bdir, exist_ok=True)
     try:
         subprocess.run(["cmake", "..", "-DCMAKE_BUILD_TYPE=Release"],
-                       cwd=bdir, check=True, capture_output=True)
+                       cwd=bdir, check=True, capture_output=True,
+                       timeout=60)
         subprocess.run(["make", f"-j{os.cpu_count()}"], cwd=bdir,
-                       check=True, capture_output=True)
+                       check=True, capture_output=True, timeout=180)
     except subprocess.CalledProcessError as e:  # pragma: no cover
         pytest.skip(f"reference build failed: {e.stderr[-500:]}")
     assert os.path.exists(REFERENCE_BINARY)

@@ -70,14 +70,14 @@ def reference_auc(reference_binary, parity_data, tmp_path_factory):
                 + "".join(f"{k}={v}\n" for k, v in CONF.items())
                 + f"metric_freq=1000\noutput_model={model}\n")
     subprocess.run([reference_binary, f"config={conf}"], check=True,
-                   capture_output=True, text=True)
+                   capture_output=True, text=True, timeout=180)
     pconf = str(d / "pred.conf")
     out = str(d / "pred.txt")
     with open(pconf, "w") as f:
         f.write(f"task=predict\ndata={te}\ninput_model={model}\n"
                 f"output_result={out}\nis_sigmoid=false\n")
     subprocess.run([reference_binary, f"config={pconf}"], check=True,
-                   capture_output=True, text=True)
+                   capture_output=True, text=True, timeout=180)
     return _auc(yte, np.loadtxt(out))
 
 

@@ -133,3 +133,38 @@ def test_metrics_out_option(tmp_path):
     assert cfg.io_config.metrics_out == str(tmp_path / "m.jsonl")
     assert cfg.io_config.metrics_fence is True
     assert _set({}).io_config.metrics_out == ""
+
+
+def test_unknown_key_warns_and_trains_the_same(tmp_path, capsys):
+    """A key nothing reads — here ``leafwise_segments``, a knob that was
+    removed — is named in a warning and changes nothing: the model text
+    is byte-equal to the run without it."""
+    import numpy as np
+    from lightgbm_tpu.io.dataset import Dataset
+    from lightgbm_tpu.models.gbdt import GBDT
+    from lightgbm_tpu.objectives import create_objective
+
+    rng = np.random.RandomState(5)
+    x = rng.randn(3000, 6)
+    y = (x[:, 0] + 0.4 * x[:, 1] > 0).astype(np.float64)
+    ds = Dataset.from_arrays(x, y, max_bin=63)
+
+    def train(extra):
+        cfg = _set({"objective": "binary", "num_leaves": "15",
+                    "num_iterations": "4", "min_data_in_leaf": "20",
+                    **extra})
+        booster = GBDT()
+        booster.init(cfg.boosting_config, ds,
+                     create_objective(cfg.objective_type,
+                                      cfg.objective_config))
+        for _ in range(4):
+            booster.train_one_iter(is_eval=False)
+        path = tmp_path / ("model_%d.txt" % len(extra))
+        booster.save_model_to_file(True, str(path))
+        return path.read_bytes()
+
+    plain = train({})
+    assert "Unknown parameter" not in capsys.readouterr().out
+    assert train({"leafwise_segments": "4"}) == plain
+    assert ("Unknown parameter leafwise_segments"
+            in capsys.readouterr().out)

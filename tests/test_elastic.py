@@ -345,7 +345,7 @@ def test_fault_kill_env_sigkills_training(tmp_path):
     env["LGBM_TPU_FAULT_AT"] = "3,kill"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", script], env=env,
-                         capture_output=True, text=True, timeout=300)
+                         capture_output=True, text=True, timeout=60)
     assert res.returncode == -signal.SIGKILL, (res.returncode, res.stderr)
     assert "NOT_KILLED" not in res.stdout
     latest = ckpt.latest_checkpoint(str(tmp_path / "ck"))

@@ -939,7 +939,7 @@ def test_graftlint_script_all_four_layers_exit_zero():
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "graftlint.py"),
          "--check"],
-        capture_output=True, text=True, cwd=REPO, timeout=300)
+        capture_output=True, text=True, cwd=REPO, timeout=60)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "ast+jaxpr+concurrency+drift" in r.stdout
 
@@ -1195,7 +1195,7 @@ def test_graftlint_script_explain_allowlist():
 SHIM_SURFACES = {
     "lightgbm_tpu.models.grower": {
         "build_histogram", "grow_tree", "grow_tree_impl",
-        "grow_tree_segmented", "grow_tree_unified", "SeamSchedule"},
+        "grow_tree_unified", "SeamSchedule"},
     "lightgbm_tpu.models.grower_depthwise": {
         "histogram_leafbatch", "grow_tree_depthwise",
         "grow_tree_depthwise_jit", "grow_tree_unified", "num_levels",
@@ -1230,3 +1230,49 @@ def test_shim_annotations_resolve():
                grower_depthwise.grow_tree_depthwise,
                grower_leafcompact.grow_tree_leafcompact_impl):
         typing.get_type_hints(fn)
+
+
+# ============================ the suite's own rule: no test holds the clock
+
+def test_a_test_past_the_time_limit_is_failed_by_name(tmp_path):
+    """tests/conftest.py's limit, loaded into a pytest of its own with
+    the limit set to 2 s and the grace after it to 2 s: the test that
+    sleeps is failed under its name and the one after it still runs;
+    the test the alarm cannot reach has its stack written to the log."""
+    (tmp_path / "conftest.py").write_text(textwrap.dedent("""
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "suite_conftest", %r)
+        suite = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(suite)
+        suite.TEST_LIMIT_S = suite.TEST_LIMIT_GRACE_S = 2
+        _test_time_limit = suite._test_time_limit
+        pytest_configure = suite.pytest_configure
+    """ % os.path.join(REPO, "tests", "conftest.py")))
+    (tmp_path / "test_sleeps.py").write_text(textwrap.dedent("""
+        import signal, time
+        def test_sleeps_past_the_limit():
+            time.sleep(60)
+        def test_after_it():
+            pass
+        def test_out_of_the_alarms_reach():
+            signal.pthread_sigmask(signal.SIG_BLOCK, {signal.SIGALRM})
+            try:
+                time.sleep(6)
+            finally:
+                signal.pthread_sigmask(signal.SIG_UNBLOCK,
+                                       {signal.SIGALRM})
+    """))
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:xdist", str(tmp_path)],
+        capture_output=True, text=True, cwd=str(tmp_path), timeout=120,
+        env=dict(os.environ, PYTHONPATH=REPO))
+    assert r.returncode == 1, r.stdout + r.stderr
+    assert "2 failed, 1 passed" in r.stdout, r.stdout
+    for name in ("test_sleeps_past_the_limit",
+                 "test_out_of_the_alarms_reach"):
+        assert ("test_sleeps.py::%s ran past the 2 s" % name
+                in r.stdout), r.stdout
+    assert "Timeout (0:00:04)!" in r.stderr, r.stderr
+    assert "in test_out_of_the_alarms_reach" in r.stderr, r.stderr

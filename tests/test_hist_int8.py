@@ -7,6 +7,11 @@ The reference accumulates in double (bin.h:15-17); LightGBM's later
 quantized-training work showed coarse gradient quantization preserves model
 quality — these tests pin the machinery, scripts/auc_parity.py pins quality
 at scale.
+
+The kernel's two layout rules have files of their own, each a rule, its
+counter and the bit-identity of the pass it re-shapes:
+``tests/test_hist_int8_fold.py`` (the bin fold of the narrow levels) and
+``tests/test_hist_int8_held.py`` (the held one-hot of the wide ones).
 """
 import numpy as np
 import jax
@@ -82,268 +87,6 @@ def test_uint8_bins_above_127_not_dropped():
     np.testing.assert_array_equal(np.asarray(via_xla), np.asarray(via_pl))
     # every row must land somewhere: total count == N per feature
     assert float(via_pl[..., 2].sum()) == float(N * F)
-
-
-# both sides of every fold boundary of ops/hist_pallas.hist_fold, the
-# 128 -> 192 lane step (42 | 43) and the widest single pass
-FOLD_COLS = (1, 2, 4, 5, 8, 10, 16, 17, 21, 32, 42, 43, 64)
-
-
-@pytest.mark.parametrize("B", [256, 64])
-@pytest.mark.parametrize("num_cols", FOLD_COLS)
-def test_bin_fold_bit_identical(num_cols, B):
-    """The bin fold (low bits of the bin code moved into the idle value
-    rows) sums every product into the cell it went to before, in int32:
-    the routed kernel equals the XLA oracle bit for bit, and the raw
-    kernel at every fold its layout allows equals itself at fold 1 —
-    with uint8 codes >= 128, a ragged last chunk and masked-out rows."""
-    from jax.experimental.pallas import tpu as pltpu
-    from lightgbm_tpu.ops.hist_pallas import (LANES, _hist_pallas_raw_fn,
-                                              _hist_quant_xla_one,
-                                              fold_options)
-    rng = np.random.RandomState(100 * num_cols + B)
-    F, N, chunk = 3, 2500, 1024
-    bins = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
-    grad = jnp.asarray(rng.randn(N).astype(np.float32))
-    hess = jnp.asarray(rng.rand(N).astype(np.float32))
-    cid = jnp.asarray(rng.randint(0, num_cols, N).astype(np.int32))
-    ok = jnp.asarray(rng.rand(N) < 0.8)
-    if num_cols <= 42:
-        via_xla = hist_quant_xla(bins, grad, hess, cid, ok, num_cols, B)
-    else:
-        # the oracle's wrapper splits at 42 columns and quantises each
-        # group apart; the Pallas route takes up to 64 in one pass
-        via_xla = _hist_quant_xla_one(bins, grad, hess, cid, ok, num_cols,
-                                      B, chunk=65536, rng_bits=None)
-    with pltpu.force_tpu_interpret_mode():
-        via_pl = hist_pallas_leafbatch(bins, grad, hess, cid, ok, num_cols,
-                                       B, chunk=chunk, dtype="int8")
-    np.testing.assert_array_equal(np.asarray(via_xla), np.asarray(via_pl))
-    assert float(via_pl[..., 2].sum()) == float(F * int(ok.sum()))
-
-    vals, _ = quantize_values(grad, hess, ok)
-    packed = jnp.concatenate(
-        [vals, jnp.where(ok, cid, -1).astype(jnp.int8)[None]], axis=0)
-    pad = (-N) % chunk
-    bins8 = jnp.pad(bins.astype(jnp.int8), ((0, 0), (0, pad)))
-    packed = jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1)
-    lanes = LANES if num_cols <= 42 else 192
-    # every fold in int8, the deepest one in the bf16 level mode too
-    runs = [(1, None, "int8")] + [
-        (fold, gw, "int8") for fold, gw, _ in
-        fold_options(3, num_cols, B, lanes)]
-    runs.append(runs[-1][:2] + ("bf16",))
-    with pltpu.force_tpu_interpret_mode():
-        raw = [np.asarray(_hist_pallas_raw_fn(
-            bins8, packed, B=B, chunk=chunk, dtype=dtype, lanes=lanes,
-            fold=fold, gw=gw)) for fold, gw, dtype in runs]
-    assert raw[0].shape == (F, B, lanes)
-    for run, acc in zip(runs, raw):
-        np.testing.assert_array_equal(acc, raw[0], err_msg=str(run))
-
-
-def test_bin_fold_rule_and_counters(monkeypatch):
-    """The rule's table at the cell's shapes, where it must not fold, and
-    the hist/pallas_fold_<k> counters of one traced level-wise tree."""
-    from lightgbm_tpu import telemetry
-    from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
-    from lightgbm_tpu.ops.hist_pallas import hist_fold
-    table = {1: (8, 3), 2: (8, 6), 3: (8, 9), 4: (4, 12), 5: (4, 16),
-             8: (4, 24), 10: (4, 30), 11: (2, 36), 16: (2, 48),
-             17: (1, None), 21: (1, None), 32: (1, None), 42: (1, None)}
-    for num_cols, want in table.items():
-        for B in (255, 256):
-            for dtype in ("int8", "bf16"):
-                assert hist_fold(3, num_cols, B, 128, dtype) == want, (
-                    num_cols, B, dtype)
-        # float gradients keep their summation shape
-        assert hist_fold(3, num_cols, 256, 128, "bf16v") == (1, None)
-        assert hist_fold(5, num_cols, 256, 128, "bf16v") == (1, None)
-    assert hist_fold(3, 43, 256, 192, "int8") == (1, None)
-    # a 64-bin class of the mixed-bin layout: the 32-row floor on the
-    # one-hot holds it to fold 2, and only while that saves an eighth
-    assert [hist_fold(3, c, 64, 128, "int8") for c in (1, 4, 5)] == [
-        (2, 4), (2, 12), (1, None)]
-
-    # one 255-leaf level-wise tree traced on the TPU route (shapes no other
-    # test traces: a cached trace would count nothing): eight passes of
-    # 1, 1, 2, 4, 8, 16, 32 and 64 leaf columns
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        n, f = 4104, 5
-        S = jax.ShapeDtypeStruct
-        jax.make_jaxpr(lambda *a: grow_tree_depthwise_jit(
-            *a, compute_dtype="int8", num_leaves=255, num_bins_max=255,
-            min_data_in_leaf=1, min_sum_hessian_in_leaf=1.0, max_depth=-1,
-            packing=None))(
-            S((f, n), jnp.uint8), S((n,), jnp.float32),
-            S((n,), jnp.float32), S((n,), jnp.bool_), S((f,), jnp.bool_),
-            S((f,), jnp.int32))
-        counters = telemetry.snapshot()["counters"]
-    finally:
-        telemetry.disable()
-        telemetry.reset()
-    folds = {k: v for k, v in counters.items()
-             if k.startswith("hist/pallas_fold_")}
-    assert folds == {"hist/pallas_fold_8": 3, "hist/pallas_fold_4": 2,
-                     "hist/pallas_fold_2": 1, "hist/pallas_fold_1": 2}
-    assert sum(folds.values()) == counters["hist/pallas_int8"] == 8
-
-
-def _level_inputs(rng, F, N, B, num_cols):
-    """int8 bins carrying uint8 codes up to B - 1, quantised levels and a
-    leaf column (or -1, masked out) per row."""
-    bins = rng.randint(0, B, (F, N)).astype(np.uint8)
-    cid = rng.randint(-1, num_cols, N)
-    vals = np.stack([rng.randint(-127, 128, N), rng.randint(0, 128, N),
-                     np.ones(N, np.int64)]) * (cid >= 0)
-    packed = np.concatenate([vals, cid[None]]).astype(np.int8)
-    return jnp.asarray(bins.astype(np.int8)), jnp.asarray(packed), cid
-
-
-@pytest.mark.parametrize("dtype", ["int8", "bf16"])
-@pytest.mark.parametrize("F", [28, 100])
-@pytest.mark.parametrize("B", [255, 256])
-@pytest.mark.parametrize("num_cols,held", [(64, 192), (43, 160), (32, 96)])
-def test_held_onehot_bit_identical(num_cols, held, B, F, dtype):
-    """A pass turned round (the one-hot the held operand, the live value
-    rows streamed, the accumulator transposed back and padded) sums every
-    product into the cell it went to with the one-hot streamed: the same
-    [F, B, lanes] int32 array, with uint8 codes >= 128 and masked rows,
-    in one block (F = 28) and on the rotating feature-block grid (F = 100,
-    the last block part padding)."""
-    from jax.experimental.pallas import tpu as pltpu
-    from lightgbm_tpu.ops.hist_pallas import (LANES, _hist_pallas_raw_fn,
-                                              feature_grid, held_onehot)
-    N, chunk = 1024, 512
-    lanes = LANES if num_cols <= 42 else 192
-    assert held_onehot(3, num_cols, B, lanes, dtype) == held
-    fb, blocks = feature_grid(F, B, lanes, chunk, held)
-    assert (blocks > 1) == (F > 28) and fb * blocks >= F
-    bins, packed, cid = _level_inputs(
-        np.random.RandomState(num_cols + B + F), F, N, B, num_cols)
-    with pltpu.force_tpu_interpret_mode():
-        streamed, turned = (np.asarray(_hist_pallas_raw_fn(
-            bins, packed, B=B, chunk=chunk, dtype=dtype, lanes=lanes,
-            held=rows)) for rows in (0, held))
-    assert turned.shape == (F, B, lanes) and turned.dtype == np.int32
-    np.testing.assert_array_equal(turned, streamed)
-    assert int(turned[:, :, 2:3 * num_cols:3].sum()) == F * int(
-        (cid >= 0).sum())
-    assert not turned[:, :, 3 * num_cols:].any()
-
-
-def test_held_onehot_rule():
-    """Which passes turn round, and how many value rows they stream, from
-    their static shapes: the integer modes where the live value rows (up
-    to the 32-row tile) times the one-hot's tiles are fewer than the
-    one-hot's rows times the value block's tiles.  Never float gradients,
-    not 33-42 columns (128 rows against two tiles: no fewer), and not the
-    64-bin classes of the mixed-bin layout, whose one-hot is half a
-    tile."""
-    from lightgbm_tpu.ops.hist_pallas import (feature_grid, held_onehot,
-                                              rotating_feature_block)
-    for dtype in ("int8", "bf16"):
-        for B in (255, 256):
-            assert [held_onehot(3, c, B, 128, dtype)
-                    for c in (17, 21, 22, 32, 33, 42)] == [
-                64, 64, 96, 96, 0, 0]
-            assert [held_onehot(3, c, B, 192, dtype)
-                    for c in (43, 53, 54, 64)] == [160, 160, 192, 192]
-        assert [held_onehot(3, 64, B, 192, dtype)
-                for B in (16, 64, 96, 128, 200)] == [0, 0, 0, 192, 192]
-        assert [held_onehot(3, 32, B, 128, dtype)
-                for B in (16, 64, 96, 128, 200)] == [0, 0, 0, 96, 96]
-    assert not any(held_onehot(3, c, 255, 128, "int8")
-                   for c in (1, 2, 4, 8, 16))          # hist_fold folds them
-    for stats, c, lanes in ((3, 1, 128), (3, 32, 128), (3, 64, 192),
-                            (5, 25, 128), (5, 38, 192)):
-        assert held_onehot(stats, c, 256, lanes, "bf16v") == 0
-    # the account of the rotating block follows the accumulator's layout:
-    # [held, 256] cells a feature turned round, [256, 256] streamed
-    assert rotating_feature_block(255, 192, 2048) == 24
-    assert rotating_feature_block(255, 192, 2048, 192) == 32
-    assert rotating_feature_block(255, 128, 2048) == 48
-    assert feature_grid(2000, 255, 192, 2048, 192) == (32, 63)
-    assert feature_grid(64, 255, 192, 2048, 192) == (64, 1)
-
-
-@pytest.mark.parametrize("num_leaves,want", [(255, 2), (127, 1), (63, 0)])
-def test_held_onehot_counter(monkeypatch, num_leaves, want):
-    """hist/pallas_held_onehot, counted once a pass at trace time: a
-    255-leaf level-wise tree has two unfolded passes that turn round
-    (level 6, 32 leaf columns, and level 7, 64), a 127-leaf tree the
-    first of them, a 63-leaf tree, every pass folded, none."""
-    from lightgbm_tpu import telemetry
-    from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        n, f = 4168 + num_leaves, 5       # shapes no other test traces
-        S = jax.ShapeDtypeStruct
-        jax.make_jaxpr(lambda *a: grow_tree_depthwise_jit(
-            *a, compute_dtype="int8", num_leaves=num_leaves,
-            num_bins_max=255, min_data_in_leaf=1,
-            min_sum_hessian_in_leaf=1.0, max_depth=-1, packing=None))(
-            S((f, n), jnp.uint8), S((n,), jnp.float32),
-            S((n,), jnp.float32), S((n,), jnp.bool_), S((f,), jnp.bool_),
-            S((f,), jnp.int32))
-        counters = telemetry.snapshot()["counters"]
-    finally:
-        telemetry.disable()
-        telemetry.reset()
-    assert counters["hist/pallas_held_onehot"] == want
-    assert counters["hist/pallas_int8"] == {255: 8, 127: 7, 63: 6}[num_leaves]
-
-
-def test_held_onehot_same_trees(monkeypatch):
-    """The grower over the turned-round pass and over the streamed one:
-    the model text of three 255-leaf level-wise iterations is byte-equal
-    (the kernel's ints being equal does not say so: the float histograms
-    behind it must come out in the same layout)."""
-    import lightgbm_tpu as lgb
-    from jax.experimental.pallas import tpu as pltpu
-    from lightgbm_tpu.io.dataset import Dataset
-    from lightgbm_tpu.models import gbdt as gbdt_mod
-    from lightgbm_tpu.ops import hist_pallas
-    rng = np.random.RandomState(31)
-    x = rng.randn(3001, 5)                # a shape no other test trains
-    y = ((x[:, 0] * x[:, 1] + 0.5 * x[:, 2] + 0.3 * rng.randn(3001)) > 0
-         ).astype(np.float32)
-    params = {"objective": "binary", "num_leaves": "255", "max_bin": "255",
-              "min_data_in_leaf": "1", "min_sum_hessian_in_leaf": "0.01",
-              "num_iterations": "3", "learning_rate": "0.2",
-              "grow_policy": "depthwise", "hist_dtype": "int8"}
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rule = hist_pallas.held_onehot
-
-    def train(turn):
-        # nothing traced before may answer: not jax's caches, not the
-        # booster's own table of chunk programs
-        jax.clear_caches()
-        monkeypatch.setattr(gbdt_mod, "_CHUNK_PROGRAMS", {})
-        ruled = []
-        monkeypatch.setattr(
-            hist_pallas, "held_onehot",
-            lambda *a: ruled.append(turn and rule(*a)) or ruled[-1])
-        with pltpu.force_tpu_interpret_mode():
-            booster = lgb.train(params,
-                                Dataset.from_arrays(x, y, max_bin=255))
-        return "\n".join(t.to_string() for t in booster.models), ruled
-
-    text, ruled = train(True)
-    # of the eight passes levels 6 and 7 were traced turned round, and
-    # level 7 decided splits: every tree grew past 128 leaves
-    assert set(ruled) == {0, 96, 192}
-    assert all(int(t.split()[0]) > 128
-               for t in text.split("num_leaves=")[1:])
-    text_streamed, ruled = train(False)
-    assert ruled and not any(ruled)
-    jax.clear_caches()
-    assert text == text_streamed
 
 
 def test_wide_bins_int16_dispatch():

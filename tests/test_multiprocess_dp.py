@@ -10,11 +10,18 @@ and the fused shard_map chunk program trains across both processes.
 
 Asserts the reference's own invariant — every worker ends with the
 IDENTICAL model — and serial equivalence of the distributed model.
+
+The rule this file keeps: every process it starts goes through
+``_run_pair`` — outputs to files, one deadline of PAIR_DEADLINE_S for the
+pair and its serial baseline together, a failed rank takes its peer down,
+a port that was taken is tried again.  No test waits on a process any
+other way.
 """
 import os
 import socket
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -80,18 +87,84 @@ output_model={model_out}
 """)
 
 
-def _run(conf, extra_env=None, n_devices=4, timeout=900):
+PAIR_DEADLINE_S = 180   # the slowest pair runs 37 s with a cold cache
+PEER_GRACE_S = 5        # what a rank gets to end by itself once one has failed
+
+
+def _spawn(conf, log, worker=WORKER, extra_env=None):
     env = dict(os.environ)
     env.pop("LGBM_TPU_COORDINATOR", None)
     env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     if extra_env:
         env.update(extra_env)
-    return subprocess.Popen(
-        [sys.executable, "-c", WORKER, f"config={conf}"],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        text=True)
+    with open(log, "w") as fh:
+        return subprocess.Popen(
+            [sys.executable, "-c", worker, f"config={conf}"],
+            env=env, stdout=fh, stderr=subprocess.STDOUT)
+
+
+def _run_pair(tmp_path, confs, serial=None, check=True,
+              workers=(WORKER, WORKER)):
+    """Run the two ranks of one jax.distributed job, ``confs[rank]`` each,
+    and beside them the one-process baseline ``serial`` if one is given.
+    -> ([out_rank0, out_rank1], out_serial or None, return codes).
+
+    Every process writes to a file under ``tmp_path``: a pipe that is
+    read one rank after the other can fill and block its writer inside a
+    collective the other rank waits in.  All of them share one deadline.
+    With ``check`` a process that exits non-zero takes the others down
+    PEER_GRACE_S later (a rank whose peer is gone waits in its collective
+    until the coordination service gives up on it), and the assertion
+    carries the tail of every output; without it, failing is what the
+    caller expects and each process runs to its own end.  A coordinator
+    that could not bind (another xdist worker took the port between
+    ``_free_port`` and the bind) is run again on a fresh port."""
+    for attempt in range(3):
+        port = _free_port()
+        logs = [str(tmp_path / f"out_r{rank}.{attempt}.log")
+                for rank in range(2)]
+        procs = [_spawn(confs[rank], logs[rank], workers[rank], {
+            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
+            "LGBM_TPU_NUM_PROCS": "2",
+            "LGBM_TPU_PROC_ID": str(rank),
+        }) for rank in range(2)]
+        if serial is not None:
+            logs.append(str(tmp_path / f"out_serial.{attempt}.log"))
+            procs.append(_spawn(serial, logs[-1]))
+        deadline = time.monotonic() + PAIR_DEADLINE_S
+        grace_given = False
+        try:
+            while (any(p.poll() is None for p in procs)
+                   and time.monotonic() < deadline):
+                if (check and not grace_given
+                        and any(p.poll() for p in procs)):
+                    grace_given = True
+                    deadline = min(deadline,
+                                   time.monotonic() + PEER_GRACE_S)
+                time.sleep(0.1)
+        finally:
+            for p in procs:
+                if p.poll() is None:
+                    p.kill()
+                p.wait()
+        outs = []
+        for log in logs:
+            with open(log, errors="replace") as fh:
+                outs.append(fh.read())
+        if "Failed to add port to server" not in outs[0]:
+            break
+    rcs = [p.returncode for p in procs]
+    if check:
+        names = ["rank 0", "rank 1", "serial"][:len(procs)]
+        ok = all(rc == 0 for rc in rcs) and all(
+            "POST process_count: 2" in out for out in outs[:2])
+        assert ok, "\n".join(
+            f"---- {name} (rc {rc}; -9: killed at the deadline or after "
+            f"a peer failed):\n{out[-3000:]}"
+            for name, rc, out in zip(names, rcs, outs))
+    return outs[:2], (outs[2] if serial is not None else None), rcs[:2]
 
 
 def _load_trees(model_path):
@@ -108,32 +181,17 @@ def test_two_process_data_parallel_matches_serial(tmp_path):
     np.savetxt(csv, np.column_stack([y, x]), fmt="%.7g", delimiter=",")
 
     # ---- 2-process distributed run
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "data", 2)
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = []
-    for p in procs:
-        out, _ = p.communicate(timeout=900)
-        outs.append(out)
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-3000:]}"
-        assert "POST process_count: 2" in out, (
-            f"rank {rank} never joined the distributed job:\n{out[-3000:]}")
+        confs.append(conf)
 
     # ---- serial baseline (same pipeline, one process)
     sconf = str(tmp_path / "train_serial.conf")
     _write_conf(sconf, csv, str(tmp_path / "model_serial.txt"), "serial", 1)
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-3000:]}"
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
 
     # reference invariant: every worker holds the identical model
     m0 = open(tmp_path / "model_r0.txt").read()
@@ -172,23 +230,15 @@ def test_two_process_bagging_workers_identical(tmp_path):
     csv = str(tmp_path / "train.csv")
     np.savetxt(csv, np.column_stack([y, x]), fmt="%.7g", delimiter=",")
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "data", 2,
                     extra="bagging_fraction=0.8\nbagging_freq=2\n"
                           "bagging_seed=9")
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-3000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
+    outs, _, _ = _run_pair(tmp_path, confs)
     m0 = open(tmp_path / "model_r0.txt").read()
     m1 = open(tmp_path / "model_r1.txt").read()
     assert m0 == m1, "workers diverged under bagging"
@@ -229,30 +279,19 @@ def _gen_valid_run(tmp_path, grow_policy, num_iterations, early_stop):
     if early_stop:
         extra += "early_stopping_round=3\n"
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "data", 2, grow_policy=grow_policy, extra=extra,
                     metric_freq=1, num_iterations=num_iterations)
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-4000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
 
     sconf = str(tmp_path / "train_serial.conf")
     _write_conf(sconf, csv, str(tmp_path / "model_serial.txt"),
                 "serial", 1, grow_policy=grow_policy, extra=extra,
                 metric_freq=1, num_iterations=num_iterations)
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-4000:]}"
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
     return outs, sout
 
 
@@ -380,26 +419,15 @@ output_model={model}
 {extra}
 """)
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"rank_r{rank}.conf")
         conf_for(conf, str(tmp_path / f"model_r{rank}.txt"), "data", 2)
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-4000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
 
     sconf = str(tmp_path / "rank_serial.conf")
     conf_for(sconf, str(tmp_path / "model_serial.txt"), "serial", 1)
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-4000:]}"
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
 
     m0 = open(tmp_path / "model_r0.txt").read()
     m1 = open(tmp_path / "model_r1.txt").read()
@@ -463,28 +491,17 @@ def test_two_process_feature_parallel_matches_serial(tmp_path):
     extra = (f"valid_data={vcsv}\nmetric=binary_logloss,auc\n"
              "is_training_metric=true\n")
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "feature", 2, extra=extra, metric_freq=1)
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-4000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
 
     sconf = str(tmp_path / "train_serial.conf")
     _write_conf(sconf, csv, str(tmp_path / "model_serial.txt"),
                 "serial", 1, extra=extra, metric_freq=1)
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-4000:]}"
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
 
     m0 = open(tmp_path / "model_r0.txt").read()
     m1 = open(tmp_path / "model_r1.txt").read()
@@ -517,21 +534,44 @@ def test_two_process_feature_parallel_leafwise_fails_loudly(tmp_path):
     csv = str(tmp_path / "train.csv")
     np.savetxt(csv, np.column_stack([y, x]), fmt="%.7g", delimiter=",")
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "feature", 2, grow_policy="leafwise")
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode != 0, f"rank {rank} unexpectedly succeeded"
+        confs.append(conf)
+    outs, _, rcs = _run_pair(tmp_path, confs, check=False)
+    for rank, (rc, out) in enumerate(zip(rcs, outs)):
+        assert rc != 0, f"rank {rank} unexpectedly succeeded"
         assert "multi-process feature-parallel training requires" in out
+
+
+def test_run_pair_fails_fast_when_a_peer_dies(tmp_path):
+    """Rank 1 joins the job and dies.  Rank 0, left in its first
+    collective, is taken down with it, and the failure carries both
+    outputs — in seconds, not at the deadline."""
+    rng = np.random.RandomState(5)
+    x = rng.randn(400, 4)
+    csv = str(tmp_path / "train.csv")
+    np.savetxt(csv, np.column_stack([(x[:, 0] > 0).astype(int), x]),
+               fmt="%.7g", delimiter=",")
+    confs = []
+    for rank in range(2):
+        conf = str(tmp_path / f"train_r{rank}.conf")
+        _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
+                    "data", 2)
+        confs.append(conf)
+    dying = WORKER.replace(
+        "from lightgbm_tpu.cli import main",
+        'print("rank 1 joined and dies", flush=True); os._exit(7)')
+    assert dying != WORKER
+    began = time.monotonic()
+    with pytest.raises(AssertionError) as failure:
+        _run_pair(tmp_path, confs, workers=(WORKER, dying))
+    assert time.monotonic() - began < 30
+    message = str(failure.value)
+    assert "rank 0 (rc -9" in message and "rank 1 (rc 7" in message
+    assert "rank 1 joined and dies" in message
 
 
 def test_two_process_dp_multiclass_matches_serial(tmp_path):
@@ -548,30 +588,19 @@ def test_two_process_dp_multiclass_matches_serial(tmp_path):
     extra = (f"num_class={k}\nmetric=multi_logloss\n"
              "is_training_metric=true\n")
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "data", 2, extra=extra, metric_freq=1,
                     objective="multiclass")
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-4000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
 
     sconf = str(tmp_path / "train_serial.conf")
     _write_conf(sconf, csv, str(tmp_path / "model_serial.txt"),
                 "serial", 1, extra=extra, metric_freq=1,
                 objective="multiclass")
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-4000:]}"
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
 
     m0 = open(tmp_path / "model_r0.txt").read()
     m1 = open(tmp_path / "model_r1.txt").read()
@@ -607,30 +636,19 @@ def test_two_process_dp_weighted_regression_matches_serial(tmp_path):
                fmt="%.5f")
     extra = "metric=l2\nis_training_metric=true\n"
 
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"train_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "data", 2, extra=extra, metric_freq=1,
                     objective="regression")
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-4000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
 
     sconf = str(tmp_path / "train_serial.conf")
     _write_conf(sconf, csv, str(tmp_path / "model_serial.txt"),
                 "serial", 1, extra=extra, metric_freq=1,
                 objective="regression")
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-4000:]}"
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
 
     m0 = open(tmp_path / "model_r0.txt").read()
     m1 = open(tmp_path / "model_r1.txt").read()
@@ -684,7 +702,7 @@ output_model={base_model}
 """)
     subprocess.run([reference_binary,
                     f"config={tmp_path / 'ref_base.conf'}"],
-                   check=True, capture_output=True, text=True)
+                   check=True, capture_output=True, text=True, timeout=60)
     assert os.path.exists(base_model)
 
     # 2) serial continued run: +5 trees on top of the reference model
@@ -692,26 +710,15 @@ output_model={base_model}
     sconf = str(tmp_path / "cont_serial.conf")
     _write_conf(sconf, csv, str(tmp_path / "model_serial.txt"), "serial",
                 1, num_iterations=5, extra=extra)
-    sp = _run(sconf)
-    sout, _ = sp.communicate(timeout=900)
-    assert sp.returncode == 0, f"serial failed:\n{sout[-4000:]}"
 
     # 3) 2-process DP continued run, same input model
-    port = _free_port()
-    procs = []
+    confs = []
     for rank in range(2):
         conf = str(tmp_path / f"cont_r{rank}.conf")
         _write_conf(conf, csv, str(tmp_path / f"model_r{rank}.txt"),
                     "data", 2, num_iterations=5, extra=extra)
-        procs.append(_run(conf, extra_env={
-            "LGBM_TPU_COORDINATOR": f"127.0.0.1:{port}",
-            "LGBM_TPU_NUM_PROCS": "2",
-            "LGBM_TPU_PROC_ID": str(rank),
-        }))
-    outs = [p.communicate(timeout=900)[0] for p in procs]
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out[-4000:]}"
-        assert "POST process_count: 2" in out
+        confs.append(conf)
+    outs, sout, _ = _run_pair(tmp_path, confs, serial=sconf)
 
     m0 = open(tmp_path / "model_r0.txt").read()
     m1 = open(tmp_path / "model_r1.txt").read()

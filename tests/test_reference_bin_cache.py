@@ -49,7 +49,7 @@ def _reference_save_bin(reference_binary, workdir, data_name):
          "objective=binary", "num_trees=1", "num_leaves=4",
          "min_data_in_leaf=5", "is_save_binary_file=true",
          "output_model=ref_model.txt"],
-        cwd=workdir, capture_output=True, text=True)
+        cwd=workdir, capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stderr + res.stdout
     bin_path = os.path.join(workdir, data_name + ".bin")
     assert os.path.exists(bin_path)
@@ -114,7 +114,7 @@ def test_train_from_reference_bin_without_text(synth_dir, tmp_path):
          "objective=binary", "num_trees=2", "num_leaves=4",
          "min_data_in_leaf=5", "output_model=model.txt"],
         cwd=str(tmp_path), capture_output=True, text=True, env=env,
-        timeout=600)
+        timeout=60)
     assert res.returncode == 0, res.stderr + res.stdout
     assert (tmp_path / "model.txt").exists()
     assert "reference-format binary" in res.stdout + res.stderr
@@ -200,7 +200,7 @@ def test_reference_rank_bin_cache_queries(reference_binary, tmp_path):
          "objective=lambdarank", "num_trees=1", "num_leaves=4",
          "min_data_in_leaf=5", "is_save_binary_file=true",
          "output_model=ref_model.txt"],
-        cwd=str(tmp_path), capture_output=True, text=True)
+        cwd=str(tmp_path), capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stderr + res.stdout
 
     text_dir = tmp_path / "text_only"
@@ -325,7 +325,7 @@ def test_reference_binary_trains_from_our_cache(reference_binary, tmp_path):
         [reference_binary, "task=train", "data=d.tsv", "objective=binary",
          "num_trees=4", "num_leaves=8", "min_data_in_leaf=20",
          "max_bin=32", "output_model=model_text.txt"],
-        cwd=str(text_dir), capture_output=True, text=True)
+        cwd=str(text_dir), capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stderr + res.stdout
 
     # reference trains from OUR reference-format cache, no text file
@@ -338,7 +338,8 @@ def test_reference_binary_trains_from_our_cache(reference_binary, tmp_path):
         [reference_binary, "task=train", "data=d.tsv", "objective=binary",
          "num_trees=4", "num_leaves=8", "min_data_in_leaf=20",
          "max_bin=32", "output_model=model_cache.txt"],
-        cwd=str(cache_dir), capture_output=True, text=True)
+        cwd=str(cache_dir), capture_output=True, text=True,
+        timeout=60)
     assert res2.returncode == 0, res2.stderr + res2.stdout
     assert not os.path.exists(cache_dir / "d.tsv"), "text file must be absent"
 

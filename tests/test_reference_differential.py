@@ -80,7 +80,8 @@ def _parse_model_trees(path):
 
 def _run_reference(binary, workdir, conf, extra):
     return subprocess.run([binary, f"config={conf}"] + extra, cwd=workdir,
-                          check=True, capture_output=True, text=True)
+                          check=True, capture_output=True, text=True,
+                          timeout=120)
 
 
 def _setup_example(tmp_path, task):

@@ -1,0 +1,199 @@
+"""Compile, for a described v5e, the narrow table's growers and the
+programs the system itself builds: F=28, 255 bins, 255 leaves, N=2**20
+(``tests/test_tpu_compile.py`` holds the rules these files keep).
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from tpu_described import (  # noqa: F401 (fixtures)
+    as_tpu, B, _Captured, _captured_chunk_program, _check, F, _grow_args,
+    _GROW_KW, HBM_BYTES, LEAVES, _like, N, no_persistent_cache, one_chip,
+    _shape, _tiny_binary_dataset, topo)
+
+
+# ------------------------------------------------------------- growers
+
+def test_grow_depthwise_int8_compiles(one_chip, as_tpu):
+    from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
+    compiled = grow_tree_depthwise_jit.lower(
+        *_grow_args(one_chip), compute_dtype="int8", **_GROW_KW).compile()
+    _check(compiled, custom_call=True)
+
+
+def test_grow_leafcompact_f32_compiles(one_chip, as_tpu):
+    """The default route of task=train on a TPU: compacted grower, Pallas
+    partition, Pallas float histogram."""
+    from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
+    from lightgbm_tpu.ops.compact import pallas_partition_ok
+    assert pallas_partition_ok(F)
+    compiled = grow_tree_leafcompact.lower(
+        *_grow_args(one_chip), use_pallas_partition=True,
+        partition_overlap=True, **_GROW_KW).compile()
+    ma = _check(compiled, custom_call=True)
+    # the route that holds an 11M-row table: well under 1 KB of temp/row
+    assert ma.temp_size_in_bytes / N < 1024, ma.temp_size_in_bytes
+
+
+def test_masked_leafwise_memory_per_row_is_pinned(one_chip, as_tpu):
+    """The finding that keeps big tables off this route: the MASKED
+    leaf-wise grower (leafwise_compact=false) needs ~2.7 KB of temp per
+    row (2.81 GB at 1M rows) — ~31 GB at the 11M-row Higgs table, twice a
+    v5e's HBM.  Only the compacted grower can hold that table; nobody
+    should route it here on a chip.  If this drops below the bound the
+    rule in gbdt.leafwise_compact_on deserves another look."""
+    from lightgbm_tpu.models.grower_unified import grow_tree
+    compiled = grow_tree.lower(*_grow_args(one_chip), **_GROW_KW).compile()
+    per_row = compiled.memory_analysis().temp_size_in_bytes / N
+    assert 1500 < per_row < 4000, per_row
+    assert per_row * 11_000_000 > HBM_BYTES
+
+
+# ----------------------------------------- programs built by the system
+
+def test_fused_chunk_program_compiles(one_chip, as_tpu, monkeypatch):
+    """chip_smoke phase (b): the depth-wise int8 chunk of 8 iterations,
+    built by GBDT.train_chunk itself, its real argument tree re-shaped to
+    N=2**20."""
+    n_tiny = 1000                      # no other axis has this length
+    prog, seen = _captured_chunk_program(
+        monkeypatch,
+        {"objective": "binary", "num_leaves": str(LEAVES),
+         "max_bin": str(B), "grow_policy": "depthwise",
+         "hist_dtype": "int8", "metric": "binary_logloss",
+         "is_training_metric": "true"},
+        _tiny_binary_dataset(n_tiny), is_eval=True)
+    args = _like(one_chip, seen, rows_from=n_tiny, rows_to=N)
+    _check(prog.lower(*args).compile(), custom_call=True)
+
+
+def test_data_parallel_chunk_program_compiles_for_four_chips(
+        topo, as_tpu, monkeypatch):
+    """chip_smoke --chips 4: the same chunk under tree_learner=data on a
+    (data,)=4 mesh of the described v5e:2x2 — collectives and the int8
+    Pallas kernel in one program, N/4 rows of every row-aligned input on
+    each chip."""
+    import lightgbm_tpu as lgb
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.metrics import create_metric
+    from lightgbm_tpu.objectives import create_objective
+    from lightgbm_tpu.parallel import create_parallel_learner
+    # the data-parallel program closes over the true row count (metric
+    # slices, padding), so its shapes cannot be re-sized after the fact:
+    # build the booster at the real N (placing 2**20 rows on the CPU
+    # devices is cheap) and only the compile targets the described chips
+    config = lgb.OverallConfig()
+    config.set({"objective": "binary", "num_leaves": str(LEAVES),
+                "max_bin": str(B), "grow_policy": "depthwise",
+                "hist_dtype": "int8", "metric": "binary_logloss",
+                "is_training_metric": "true", "tree_learner": "data",
+                "num_machines": "4"}, require_data=False)
+    learner = create_parallel_learner(config)
+    booster = lgb.GBDT()
+    booster.init(config.boosting_config, _tiny_binary_dataset(N),
+                 create_objective(config.objective_type,
+                                  config.objective_config),
+                 [create_metric(t, config.metric_config)
+                  for t in config.metric_types], learner=learner)
+    tpu_mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    seen = {}
+    real_chunk_program = learner.chunk_program
+
+    def capturing_chunk_program(*a, **kw):
+        # the shard_map is built over the described chips; everything
+        # around it (placing the tiny inputs) keeps the CPU mesh
+        with monkeypatch.context() as m:
+            m.setattr(learner, "_mesh", lambda: tpu_mesh)
+            prog, num_shards = real_chunk_program(*a, **kw)
+
+        def call(*args):
+            seen["prog"], seen["args"] = prog, args
+            raise _Captured
+        return call, num_shards
+
+    monkeypatch.setattr(learner, "chunk_program", capturing_chunk_program)
+    with pytest.raises(_Captured):
+        booster.train_chunk(8, is_eval=True)
+
+    def conv(a):
+        a = np.asarray(a) if not hasattr(a, "shape") else a
+        spec = P(*("data" if d == N else None for d in a.shape))
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(tpu_mesh, spec))
+
+    compiled = seen["prog"].lower(*jax.tree.map(conv, seen["args"])).compile()
+    ma = _check(compiled, custom_call=True)
+    text = compiled.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    # per chip: a quarter of the bin matrix, not all of it
+    assert ma.argument_size_in_bytes < F * N, ma.argument_size_in_bytes
+
+
+def _leafwise_tree(rng, leaves):
+    """A random tree in the model's own encoding, grown like the trainer
+    grows one: split k turns leaf l into node k with children ~l, ~(k+1)."""
+    from lightgbm_tpu.models.tree import Tree
+    n = leaves - 1
+    left, right = np.zeros(n, np.int32), np.zeros(n, np.int32)
+    leaf_parent = np.full(leaves, -1, np.int32)
+    for k in range(n):
+        leaf = rng.randint(0, k + 1)
+        p = leaf_parent[leaf]
+        if p >= 0:
+            if left[p] == ~leaf:
+                left[p] = k
+            else:
+                right[p] = k
+        left[k], right[k] = ~leaf, ~(k + 1)
+        leaf_parent[leaf] = leaf_parent[k + 1] = k
+    feat = rng.randint(0, F, n)
+    return Tree(leaves, feat, feat, rng.randint(0, B - 1, n),
+                rng.randn(n), np.ones(n), left, right, leaf_parent,
+                rng.randn(leaves) * 0.1)
+
+
+def test_serving_program_compiles(one_chip, as_tpu):
+    """chip_smoke phase (c): one bucketed breadth-first scoring program of
+    serving.ServingEngine at 255 leaves, with the donation the engine
+    resolves on a TPU."""
+    from lightgbm_tpu.serving import FlatEnsemble, ServingEngine
+    rng = np.random.RandomState(9)
+    flat = FlatEnsemble.from_models(
+        [_leafwise_tree(rng, LEAVES) for _ in range(16)], num_class=1)
+    engine = ServingEngine(flat)
+    assert engine.donate, "donation should resolve on for a TPU backend"
+    bucket = engine.buckets[-1]
+    codes = flat.encode(np.zeros((4, F)))
+    t = _like(one_chip, engine._device_tables())
+    compiled = engine._program("scores").lower(
+        _shape(one_chip, (codes.shape[0], bucket), codes.dtype),
+        t["sf"], t["tr"], t["lc"], t["rc"], t["lv"], t["root"], t["tc"],
+        max_depth=flat.max_depth, num_class=flat.num_class).compile()
+    _check(compiled, custom_call=False)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_ingest_update_program_compiles(topo, as_tpu, chips):
+    """io/streaming.DeviceRowWriter's donated update of the device-resident
+    [F, N] bin matrix with one 200k-row chunk: a dynamic_update_slice on
+    one chip; on four, each chip lands its own row block's part with no
+    collective (left to the partitioner, the sharded update took 27 s per
+    chunk on four v5e chips — PR 24)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.io.streaming import _update_program
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    placed = NamedSharding(mesh, P(None, "data") if chips > 1 else P())
+    replicated = NamedSharding(mesh, P())
+    compiled = _update_program(placed).lower(
+        jax.ShapeDtypeStruct((F, N), jnp.uint8, sharding=placed),
+        jax.ShapeDtypeStruct((F, 200_000), jnp.uint8, sharding=replicated),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)).compile()
+    ma = _check(compiled, custom_call=False)
+    text = compiled.as_text()
+    assert not any(op in text for op in ("all-gather", "all-reduce",
+                                         "collective-permute"))
+    # donated: updated in place (one padded copy of the local block on
+    # four chips), never a second copy of the whole matrix per device
+    assert ma.temp_size_in_bytes < F * N, ma.temp_size_in_bytes

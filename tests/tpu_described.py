@@ -1,0 +1,179 @@
+"""What the files that compile for a described v5e share: the topology
+fixtures, the shapes of the supported configurations, and the helpers that
+lower, capture and check a program.  ``tests/test_tpu_compile.py`` holds
+the rules; this module holds no test.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+F, B, LEAVES, N = 28, 255, 255, 1 << 20
+WIDE_F, WIDE_N = 2000, 400_000  # benchmarks/configs/epsilon-levelwise-int8
+HBM_BYTES = 16 * 1000 ** 3      # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        if "lockfile" in str(e):
+            # a skip here would be a silent loss of every test of the file
+            pytest.fail("another process holds the TPU library: the files "
+                        "that compile for a described chip run side by "
+                        "side only under ALLOW_MULTIPLE_LIBTPU_LOAD=1, as "
+                        "the driver's command sets it; without it run "
+                        "them in one process", pytrace=False)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch, no_persistent_cache):
+    """Make the backend-keyed routing rules take their TPU branch while
+    tracing (they ask jax.default_backend(), which still sees the CPU)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+
+def _like(one_chip, tree, rows_from=None, rows_to=None):
+    """ShapeDtypeStructs for a pytree of arrays, on the described chip;
+    every axis of length ``rows_from`` becomes ``rows_to``."""
+    def conv(a):
+        a = np.asarray(a) if not hasattr(a, "shape") else a
+        shape = tuple(rows_to if (rows_from and d == rows_from) else d
+                      for d in a.shape)
+        return _shape(one_chip, shape, a.dtype)
+    return jax.tree.map(conv, tree)
+
+
+def _check(compiled, custom_call: bool):
+    """tpu_custom_call present where a Pallas route is expected, and the
+    program fits one chip.  Returns memory_analysis()."""
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == custom_call, (
+        "Pallas custom call %s in the compiled program"
+        % ("missing" if custom_call else "unexpected"))
+    ma = compiled.memory_analysis()
+    total = ma.temp_size_in_bytes + ma.argument_size_in_bytes
+    assert total < HBM_BYTES, (ma.temp_size_in_bytes,
+                               ma.argument_size_in_bytes)
+    return ma
+
+
+def _grow_args(one_chip, n=N, f=F):
+    return (_shape(one_chip, (f, n), jnp.uint8),       # bins
+            _shape(one_chip, (n,), jnp.float32),       # grad
+            _shape(one_chip, (n,), jnp.float32),       # hess
+            _shape(one_chip, (n,), jnp.bool_),         # row_mask
+            _shape(one_chip, (f,), jnp.bool_),         # feature_mask
+            _shape(one_chip, (f,), jnp.int32))         # num_bins
+
+
+def _cell_size(ma):
+    """The size argument of the wide cell, pinned: what the compiler counts
+    for the program is over the 2 GiB a new cell has to hold with the chip
+    busy (it measured 3.24 GB of temporaries and 0.80 GB of arguments)."""
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes > 2 << 30, (
+        ma.temp_size_in_bytes, ma.argument_size_in_bytes)
+
+
+_GROW_KW = dict(num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=100,
+                min_sum_hessian_in_leaf=10.0, max_depth=-1, packing=None)
+
+
+def _pass_rules(dtype, lanes, stats, num_cols):
+    """(fold, gw, held) as ``_hist_pallas_one`` picks them for a pass."""
+    from lightgbm_tpu.ops.hist_pallas import held_onehot, hist_fold
+    return (*hist_fold(stats, num_cols, 256, lanes, dtype),
+            held_onehot(stats, num_cols, 256, lanes, dtype))
+
+
+def _lower_kernel(one_chip, features, dtype, lanes, stats, num_cols):
+    """The raw kernel, traced anew (a wrapper of its own, so that no
+    cached trace answers) and lowered for the described chip."""
+    from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn
+    fold, gw, held = _pass_rules(dtype, lanes, stats, num_cols)
+
+    def fresh(bins, packed):
+        return _hist_pallas_raw_fn(bins, packed, B=256, chunk=2048,
+                                   dtype=dtype, lanes=lanes, stats=stats,
+                                   fold=fold, gw=gw, held=held)
+    return jax.jit(fresh).lower(
+        _shape(one_chip, (features, 2048 * 4), jnp.int8),
+        _shape(one_chip, (stats + 1, 2048 * 4),
+               jnp.bfloat16 if dtype == "bf16v" else jnp.int8))
+
+
+class _Captured(Exception):
+    pass
+
+
+def _tiny_binary_dataset(n, f=F):
+    from lightgbm_tpu.io.dataset import Dataset
+    rng = np.random.RandomState(5)
+    x = rng.randn(n, f).astype(np.float32)
+    y = (x[:, 0] + 0.3 * rng.randn(n) > 0).astype(np.float32)
+    return Dataset.from_arrays(x, y, max_bin=B)
+
+
+def _captured_chunk_program(monkeypatch, params, dataset, is_eval):
+    """(program, arguments) of the chunk of 8 iterations as
+    GBDT.train_chunk itself builds and calls it: the call is intercepted
+    at the program boundary."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.models import gbdt as gbdt_mod
+    from lightgbm_tpu.metrics import create_metric
+    from lightgbm_tpu.objectives import create_objective
+    config = lgb.OverallConfig()
+    config.set(params, require_data=False)
+    booster = lgb.GBDT()
+    booster.init(config.boosting_config, dataset,
+                 create_objective(config.objective_type,
+                                  config.objective_config),
+                 [create_metric(t, config.metric_config)
+                  for t in config.metric_types] if is_eval else [])
+    assert booster.chunkable_for(is_eval)
+    seen = {}
+    real_get = gbdt_mod._get_chunk_program
+
+    def capturing_get(*a, **kw):
+        prog = real_get(*a, **kw)
+
+        def call(*args):
+            seen["prog"], seen["args"] = prog, args
+            raise _Captured
+        return call
+
+    monkeypatch.setattr(gbdt_mod, "_get_chunk_program", capturing_get)
+    with pytest.raises(_Captured):
+        booster.train_chunk(8, is_eval=is_eval)
+    return seen["prog"], seen["args"]
