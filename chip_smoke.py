@@ -70,6 +70,7 @@ AUC_TOL = 0.02
 
 ZERO_COUNTERS = ("hist/pallas_ineligible", "hist/env_no_pallas",
                  "hist/env_force_einsum", "partition/pallas_ineligible",
+                 "partition/route_xla",
                  "costmodel/aot_call_fallback", "costmodel/capture_failed")
 
 FAILURES: list = []

@@ -384,7 +384,28 @@ def instrument(name: str, fn, phase: Optional[str] = None) -> Instrumented:
     """Wrap a jitted program for cost capture.  ``phase`` is the
     telemetry span name whose measured seconds this program's static
     costs join against in ``roofline()``."""
+    _keep_out_of_source_locations()
     return Instrumented(name, fn, phase=phase)
+
+
+def _keep_out_of_source_locations() -> None:
+    """This module's frames stay out of the source locations JAX records
+    for the operations traced under a wrapper.  Armed, a wrapper traces
+    its program from ``_capture``, disarmed from ``__call__``: one frame
+    apart.  JAX keeps the innermost ten user frames of an operation, a
+    Pallas kernel is serialised with them into its custom call, and that
+    is hashed into the compile-cache key; a kernel eight frames under the
+    chunk program (ops/route_pallas.py) therefore made a traced run
+    (telemetry on) compile a program of its own where it should load the
+    timed run's (PERF.md section 6, PR 33)."""
+    global _out_of_source_locations
+    if not _out_of_source_locations:
+        from jax._src import source_info_util
+        source_info_util.register_exclusion(__file__)
+        _out_of_source_locations = True
+
+
+_out_of_source_locations = False
 
 
 # -------------------------------------------------------- analytic pass notes

@@ -36,8 +36,9 @@ from .utils import log
 # variable the package reads, its value shape, and what it does.
 HATCHES = {
     "LGBM_TPU_NO_PALLAS":
-        ("flag", "disable EVERY Pallas kernel (histogram + partition) — "
-                 "the mixed-backend escape hatch dryrun_multichip sets"),
+        ("flag", "disable EVERY Pallas kernel (histogram + partition + "
+                 "level-wise row routing) — the mixed-backend escape hatch "
+                 "dryrun_multichip sets"),
     "LGBM_TPU_HIST_EINSUM":
         ("flag", "force the XLA einsum histogram formulation for all "
                  "dtypes (A/B timing hatch)"),

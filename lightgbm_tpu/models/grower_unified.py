@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.histogram import build_histogram, histogram_leafbatch
+from ..ops.route_pallas import route_level_pallas, route_pallas_ok
 from ..ops.split import SplitResult, find_best_split
 from ..telemetry import phase_scope
 
@@ -616,6 +617,72 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
 
 # ====================================================== depthwise policy
 
+def _route_level_xla(partition_bins, slot_id, out_leaf, row_mask, feat_part,
+                     threshold, chosen, right_leaf, small_is_right, *,
+                     num_bins_max: int, num_leaves: int):
+    """One level's row routing in XLA: (new slot id, new leaf id, the
+    rows of every chosen slot's smaller child).  All per-slot attributes
+    a row needs (split feature, threshold, chosen flag, new right-leaf
+    id, smaller-child side) ride ONE [P, N] one-hot matmul instead of one
+    pass per attribute: the slot-select one-hot is the expensive object
+    (O(P·N) comparisons), so it is generated once and contracted against
+    a packed [P, K] table."""
+    f32 = jnp.float32
+    i32 = jnp.int32
+    P = chosen.shape[0]
+    table = jnp.stack([feat_part.astype(f32),
+                       threshold.astype(f32),
+                       chosen.astype(f32),
+                       right_leaf.astype(f32),
+                       small_is_right.astype(f32)], axis=1)      # [P, 5]
+    lsel = (slot_id[None, :] ==
+            jnp.arange(P, dtype=i32)[:, None]).astype(f32)       # [P, N]
+    # The table carries integer ids (feature, threshold, leaf).
+    # Default TPU matmul precision truncates f32 operands to bf16,
+    # which is EXACT for integers <= 256 — and exactly one lsel
+    # entry matches per row, so there is no accumulation error
+    # either.  Only configs with ids beyond 256 need the 6-pass
+    # HIGHEST decomposition (measured 2.27 ms vs 0.72 ms per level
+    # at 11M rows).  Feature ids are GLOBAL (split_finder returns
+    # canonical ids even when ``bins`` is an owned slice), so the
+    # guard must use the global width, not the sliced F.
+    Fg = partition_bins.shape[0]
+    ids_bf16_exact = max(Fg, num_bins_max, num_leaves) <= 256
+    attr_prec = (None if ids_bf16_exact
+                 else jax.lax.Precision.HIGHEST)
+    attrs = jnp.einsum("pn,pk->kn", lsel, table,
+                       precision=attr_prec,
+                       preferred_element_type=jnp.float32)       # [5, N]
+    feat_row = attrs[0].astype(i32)
+    thr_row = attrs[1].astype(i32)
+    in_chosen = attrs[2] > 0.5
+    rl_row = attrs[3].astype(i32)
+    small_right_row = attrs[4] > 0.5
+
+    # the row's bin on its slot's split feature: an O(F·N) feature
+    # one-hot avoids materializing the old [P, N] row gather, but
+    # its cost grows with the dataset width — for wide datasets a
+    # direct per-row gather is cheaper than F·N comparisons
+    if Fg <= 128:
+        fsel = (feat_row[None, :]
+                == jnp.arange(Fg, dtype=i32)[:, None])
+        # bins < 256 are bf16-exact and one fsel entry matches per
+        # row
+        row_bin = jnp.einsum(
+            "fn,fn->n", fsel.astype(f32), partition_bins.astype(f32),
+            precision=(None if num_bins_max <= 256
+                       else jax.lax.Precision.HIGHEST)).astype(i32)
+    else:
+        row_bin = jnp.take_along_axis(
+            partition_bins, feat_row[None, :], axis=0)[0].astype(i32)
+    go_right = row_bin > thr_row
+    out_leaf = jnp.where(in_chosen & go_right, rl_row, out_leaf)
+    slot_id = (2 * slot_id
+               + jnp.where(in_chosen, go_right.astype(i32), 0))
+    sel = in_chosen & (go_right == small_right_row) & row_mask
+    return slot_id, out_leaf, sel
+
+
 def num_levels(num_leaves: int, max_depth: int = -1) -> int:
     """Number of split levels.  Matches the leaf-wise depth rule (a leaf
     at depth >= max_depth cannot split, root depth 1), so max_depth
@@ -693,6 +760,7 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
                       in_axes=(0, 0, 0, 0, None, None, None, None))
     if partition_bins is None:
         partition_bins = bins
+    route_kernel = route_pallas_ok(partition_bins.dtype, B)
 
     # ---- root (BeforeTrain: serial_tree_learner.cpp:155-236).
     # named_scope per level (ISSUE 2): profile_dir= Perfetto traces show
@@ -806,13 +874,10 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
 
             n_nodes = n_nodes + n_chosen
 
-        # ---- partition rows (DataPartition::Split as fused masked passes)
-        # All per-slot attributes a row needs (split feature, threshold,
-        # chosen flag, new right-leaf id, smaller-child side) ride ONE
-        # [P, N] one-hot matmul instead of one pass per attribute: the
-        # slot-select one-hot is the expensive object (O(P·N) comparisons),
-        # so it is generated once and contracted against a packed [P, K]
-        # table.
+        # ---- partition rows (DataPartition::Split as fused masked
+        # passes): one streaming Pallas kernel a level on a TPU
+        # (ops/route_pallas.py), the XLA formulation ``_route_level_xla``
+        # everywhere else, the same integers either way.
         # The device name is "row_route": "partition" is the compacted
         # grower's stream-partition kernel.  The host span keeps its
         # canonical JSONL key.
@@ -822,55 +887,15 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
             # the per-slot partition feature must address that layout
             # (the recorded split_feature above stays canonical)
             feat_part = partition_feature(partition_packing, res.feature)
-            table = jnp.stack([feat_part.astype(f32),
-                               res.threshold.astype(f32),
-                               chosen.astype(f32),
-                               right_leaf.astype(f32),
-                               small_is_right.astype(f32)], axis=1)  # [P, 5]
-            lsel = (slot_id[None, :] ==
-                    jnp.arange(P, dtype=i32)[:, None]).astype(f32)   # [P, N]
-            # The table carries integer ids (feature, threshold, leaf).
-            # Default TPU matmul precision truncates f32 operands to bf16,
-            # which is EXACT for integers <= 256 — and exactly one lsel
-            # entry matches per row, so there is no accumulation error
-            # either.  Only configs with ids beyond 256 need the 6-pass
-            # HIGHEST decomposition (measured 2.27 ms vs 0.72 ms per level
-            # at 11M rows).  Feature ids are GLOBAL (split_finder returns
-            # canonical ids even when ``bins`` is an owned slice), so the
-            # guard must use the global width, not the sliced F.
-            ids_bf16_exact = max(partition_bins.shape[0], B, L) <= 256
-            attr_prec = (None if ids_bf16_exact
-                         else jax.lax.Precision.HIGHEST)
-            attrs = jnp.einsum("pn,pk->kn", lsel, table,
-                               precision=attr_prec,
-                               preferred_element_type=jnp.float32)   # [5, N]
-            feat_row = attrs[0].astype(i32)
-            thr_row = attrs[1].astype(i32)
-            in_chosen = attrs[2] > 0.5
-            rl_row = attrs[3].astype(i32)
-            small_right_row = attrs[4] > 0.5
-
-            # the row's bin on its slot's split feature: an O(F·N) feature
-            # one-hot avoids materializing the old [P, N] row gather, but
-            # its cost grows with the dataset width — for wide datasets a
-            # direct per-row gather is cheaper than F·N comparisons
-            Fg = partition_bins.shape[0]
-            if Fg <= 128:
-                fsel = (feat_row[None, :]
-                        == jnp.arange(Fg, dtype=i32)[:, None])
-                # bins < 256 are bf16-exact and one fsel entry matches per
-                # row
-                row_bin = jnp.einsum(
-                    "fn,fn->n", fsel.astype(f32), partition_bins.astype(f32),
-                    precision=(None if B <= 256
-                               else jax.lax.Precision.HIGHEST)).astype(i32)
-            else:
-                row_bin = jnp.take_along_axis(
-                    partition_bins, feat_row[None, :], axis=0)[0].astype(i32)
-            go_right = row_bin > thr_row
-            out_leaf = jnp.where(in_chosen & go_right, rl_row, out_leaf)
-            slot_id = (2 * slot_id
-                       + jnp.where(in_chosen, go_right.astype(i32), 0))
+            # counted per level, here, at trace time: the route is baked
+            # into the program like the histogram kernel's
+            telemetry.count("partition/route_pallas" if route_kernel
+                            else "partition/route_xla")
+            level = (partition_bins, slot_id, out_leaf, row_mask, feat_part,
+                     res.threshold, chosen, right_leaf, small_is_right)
+            slot_id, out_leaf, sel = (
+                route_level_pallas(*level) if route_kernel
+                else _route_level_xla(*level, num_bins_max=B, num_leaves=L))
             _sp.fence((out_leaf, slot_id))
 
         if d + 1 >= D:
@@ -900,7 +925,6 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # recount is needed at any scale.
         with phase_scope("row_route"):
             par_of_row = slot_id // 2
-            sel = in_chosen & (go_right == small_right_row) & row_mask
         # The masked full-N pass is the fastest smaller-child schedule
         # measured on v5e (1M and 11M rows): gathering the selected rows
         # into a compact N/2 buffer first (the masked-dense analog of the

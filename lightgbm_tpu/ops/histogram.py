@@ -45,9 +45,10 @@ def _pallas_hist_ok(num_bins_max: int) -> bool:
     if hatches.flag("LGBM_TPU_HIST_EINSUM"):
         telemetry.count("hist/env_force_einsum")
         return False
-    # LGBM_TPU_NO_PALLAS covers EVERY Pallas kernel (partition + these
-    # histogram kernels, ops/compact.pallas_partition_ok) — the
-    # mixed-backend escape hatch; HIST_EINSUM stays the A/B-timing hatch
+    # LGBM_TPU_NO_PALLAS covers EVERY Pallas kernel (partition, row
+    # routing + these histogram kernels, ops/compact.pallas_partition_ok,
+    # ops/route_pallas.route_pallas_ok) — the mixed-backend escape
+    # hatch; HIST_EINSUM stays the A/B-timing hatch
     if hatches.flag("LGBM_TPU_NO_PALLAS"):
         telemetry.count("hist/env_no_pallas")
         return False

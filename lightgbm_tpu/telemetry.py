@@ -327,6 +327,8 @@ COUNTER_FAMILIES = (
     "partition/pallas",
     "partition/pallas_eligible",
     "partition/pallas_ineligible",
+    "partition/route_pallas",     # level-wise row routing, once a level
+    "partition/route_xla",
     "partition/wide_f_fallback",
     "partition/xla",
     "serve/bucket_*",             # per-ladder-bucket dispatch counts

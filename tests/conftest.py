@@ -53,7 +53,8 @@ HEAVY_FIRST = (
     "test_tpu_compile_wide.py", "test_hybrid_voting.py", "test_gbdt.py",
     "test_streaming.py", "test_leafcompact.py",
     "test_distributed_telemetry.py", "test_goss_chunk.py",
-    "test_depthwise.py", "test_hist_int8.py", "test_graftlint.py",
+    "test_route_pallas.py", "test_depthwise.py", "test_hist_int8.py",
+    "test_graftlint.py",
     "test_mixedbin_hybrid.py", "test_hist_float_pallas.py",
     "test_elastic.py", "test_health.py", "test_costmodel.py",
     "test_serving.py", "test_grower_unified.py",

@@ -126,6 +126,7 @@ def test_held_onehot_same_trees(monkeypatch):
     behind it must come out in the same layout)."""
     import lightgbm_tpu as lgb
     from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu import costmodel, telemetry
     from lightgbm_tpu.io.dataset import Dataset
     from lightgbm_tpu.models import gbdt as gbdt_mod
     from lightgbm_tpu.ops import hist_pallas
@@ -149,17 +150,32 @@ def test_held_onehot_same_trees(monkeypatch):
         monkeypatch.setattr(
             hist_pallas, "held_onehot",
             lambda *a: ruled.append(turn and rule(*a)) or ruled[-1])
-        with pltpu.force_tpu_interpret_mode():
-            booster = lgb.train(params,
-                                Dataset.from_arrays(x, y, max_bin=255))
-            # nothing of this run may still be on the device when the
-            # next clear_caches() drops its programs.  Twice in loaded
-            # whole runs this test stood still here for good, its main
-            # thread waiting inside XLA; the interpreter's kernels call
-            # back into Python, so a program freed while one of its
-            # callbacks is due is the likely cause (inferred, not shown)
-            jax.block_until_ready(booster.score)
-            jax.effects_barrier()
+        # Fence mode: every span waits for its values, so the grower's
+        # program has ended before the loop dispatches the next
+        # operation.  The interpreter's kernels call back into Python,
+        # and a callback runs jax operations of its own (it iterates the
+        # grid index, an Array); one that the host dispatches meanwhile
+        # can wait on the program while the callback waits on it.  That
+        # is where this test stood still in whole runs of PRs 31 to 33
+        # (the stacks of PR 33's run: the main thread in
+        # ``exact_table_lookup``'s slice, the callback in
+        # ``interpret_pallas_call.get``).  The program is the same with
+        # telemetry on (tests/test_trace_scopes.py)
+        telemetry.enable(fence=True)
+        # the cost registry would keep the first run's executable and
+        # answer the second run with it
+        costmodel.disable()
+        try:
+            with pltpu.force_tpu_interpret_mode():
+                booster = lgb.train(params,
+                                    Dataset.from_arrays(x, y, max_bin=255))
+                # nothing of this run may still be on the device when
+                # the next clear_caches() drops its programs
+                jax.block_until_ready(booster.score)
+                jax.effects_barrier()
+        finally:
+            telemetry.disable()
+            telemetry.reset()
         return "\n".join(t.to_string() for t in booster.models), ruled
 
     text, ruled = train(True)

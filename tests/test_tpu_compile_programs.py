@@ -2,6 +2,8 @@
 programs the system itself builds: F=28, 255 bins, 255 leaves, N=2**20
 (``tests/test_tpu_compile.py`` holds the rules these files keep).
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -10,8 +12,10 @@ import jax.numpy as jnp
 
 from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, B, _Captured, _captured_chunk_program, _check, F, _grow_args,
-    _GROW_KW, HBM_BYTES, LEAVES, _like, N, no_persistent_cache, one_chip,
-    _shape, _tiny_binary_dataset, topo)
+    _GROW_KW, HBM_BYTES, LEAVES, _like, _lower_route_kernel, N,
+    no_persistent_cache, one_chip, _shape, _tiny_binary_dataset, topo)
+
+NARROW_N = 10_502_144    # benchmarks/configs/higgs-levelwise-int8, padded
 
 
 # ------------------------------------------------------------- growers
@@ -20,6 +24,23 @@ def test_grow_depthwise_int8_compiles(one_chip, as_tpu):
     from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
     compiled = grow_tree_depthwise_jit.lower(
         *_grow_args(one_chip), compute_dtype="int8", **_GROW_KW).compile()
+    _check(compiled, custom_call=True)
+    # the eight levels' row routing is the kernel's too, under the scope
+    assert len(re.findall(
+        r'"tpu_custom_call"[^\n]*/row_route/jit\(_route_pallas_fn\)',
+        compiled.as_text())) == 8
+
+
+@pytest.mark.parametrize("slots", [1, 128])
+def test_route_kernel_compiles_at_the_narrow_cell(one_chip, as_tpu, slots):
+    """The level-wise row routing of ``higgs-levelwise-int8.train`` at its
+    own rows, the root's level and the last: one block of 28 columns,
+    chunks of 32,768 rows, inside the VMEM a kernel may hold (the compiler
+    refuses one that is not)."""
+    from lightgbm_tpu.ops.route_pallas import route_grid
+    assert route_grid(F, NARROW_N) == (28, 1, 32768, 321)
+    compiled = _lower_route_kernel(one_chip, F, NARROW_N, slots).compile()
+    assert "_route_kernel" in compiled.as_text()
     _check(compiled, custom_call=True)
 
 

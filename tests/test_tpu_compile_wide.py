@@ -6,8 +6,12 @@ import os
 
 from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, _captured_chunk_program, _cell_size, _check, _grow_args,
-    _GROW_KW, _like, no_persistent_cache, one_chip, _tiny_binary_dataset,
-    topo, WIDE_F, WIDE_N)
+    _GROW_KW, _like, _lower_route_kernel, no_persistent_cache, one_chip,
+    _tiny_binary_dataset, topo, WIDE_F, WIDE_N)
+
+import pytest
+
+WIDE_N_PADDED = 401_408     # the cell's 400,000 rows in whole chunks
 
 
 def test_grow_depthwise_int8_compiles_on_the_wide_table(one_chip, as_tpu):
@@ -19,6 +23,20 @@ def test_grow_depthwise_int8_compiles_on_the_wide_table(one_chip, as_tpu):
         *_grow_args(one_chip, WIDE_N, WIDE_F), compute_dtype="int8",
         **_GROW_KW).compile()
     _cell_size(_check(compiled, custom_call=True))
+
+
+@pytest.mark.parametrize("slots", [1, 128])
+def test_route_kernel_compiles_at_the_wide_cell(one_chip, as_tpu, slots):
+    """The level-wise row routing over 2,000 columns: the feature-block
+    axis of the kernel's grid (four blocks of 512, the last ragged), the
+    row's bin carried in VMEM scratch, at the root's level and the
+    last."""
+    from lightgbm_tpu.ops.route_pallas import route_grid
+    assert route_grid(WIDE_F, WIDE_N_PADDED) == (512, 4, 4096, 98)
+    compiled = _lower_route_kernel(one_chip, WIDE_F, WIDE_N_PADDED,
+                                   slots).compile()
+    assert "_route_kernel" in compiled.as_text()
+    _check(compiled, custom_call=True)
 
 
 def test_fused_chunk_program_compiles_on_the_wide_table(

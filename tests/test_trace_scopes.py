@@ -241,3 +241,76 @@ def test_named_scopes_in_the_package_are_a_closed_set():
     assert not odd, odd
     with pytest.raises(ValueError):
         telemetry.phase_scope("not_a_phase")
+
+
+def test_route_kernel_is_under_row_route_traced_or_not(monkeypatch):
+    """The level-wise grower with its routing kernel (on a TPU the route
+    of every level; here the gate is steered and the kernel lowered by the
+    interpreter): the same text with telemetry on and off, the kernel's
+    operations under ``row_route`` and nowhere else."""
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(grower_unified, "route_pallas_ok", lambda *a: True)
+    with pltpu.force_tpu_interpret_mode():
+        off, on = [_grower_lowered("depthwise", flag)
+                   for flag in (False, True)]
+    assert on == off
+    # the interpreter moves the kernel's blocks through io_callbacks, and
+    # nothing else in this program has one
+    kernel = [path for _op, path in _operations(off)
+              if path.endswith("/io_callback")]
+    assert len(kernel) > 3 * grower_unified.num_levels(LEAVES)
+    assert all("/row_route/" in path for path in kernel), kernel[:5]
+
+
+def test_kernel_locations_do_not_depend_on_telemetry(monkeypatch):
+    """A Pallas kernel is serialised with its operations' source
+    locations, the innermost user frames JAX keeps, and that is hashed
+    into the compile-cache key.  Armed, the cost registry traces the chunk
+    program one frame deeper than disarmed, and the routing kernel sits
+    few enough frames under the chunk program for that frame to be among
+    the ones kept: the traced run then compiled a program of its own.  So
+    the frames JAX keeps at the kernel's call are the same with telemetry
+    on and off."""
+    from jax._src import source_info_util
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu.ops import route_pallas
+    kept = int(jax.config.jax_traceback_in_locations_limit)
+    assert kept > 0
+    seen = []
+    real = route_pallas.route_pallas_raw
+
+    def spy(*args):
+        frames = list(source_info_util.user_frames(
+            source_info_util.current().traceback))
+        seen.append([(f.file_name, f.function_name, f.start_line)
+                     for f in frames[1:kept + 1]])      # [0] is this spy
+        return real(*args)
+
+    monkeypatch.setattr(route_pallas, "route_pallas_raw", spy)
+    monkeypatch.setattr(grower_unified, "route_pallas_ok", lambda *a: True)
+    stacks = {}
+    for on in (False, True):
+        del seen[:]
+        jax.clear_caches()
+        gbdt_mod._CHUNK_PROGRAMS.clear()
+        if on:
+            telemetry.enable(fence=False)
+        config = lgb.OverallConfig()
+        config.set(dict({"objective": "binary", "num_leaves": str(LEAVES),
+                         "max_bin": str(B), "min_data_in_leaf": "5"},
+                        **POLICIES["depthwise"][0]), require_data=False)
+        booster = lgb.GBDT()
+        booster.init(config.boosting_config, _dataset(),
+                     create_objective(config.objective_type,
+                                      config.objective_config))
+        with pltpu.force_tpu_interpret_mode():
+            booster.train_chunk(2, is_eval=False)
+            jax.block_until_ready(booster.score)
+        telemetry.disable()
+        telemetry.reset()
+        assert len(seen) == grower_unified.num_levels(LEAVES)
+        stacks[on] = seen[0]
+    # the chunk program's own frame is among the kept ones: the case tests
+    # what it says
+    assert any(name.endswith(".chunk_fn") for _f, name, _l in stacks[False])
+    assert stacks[True] == stacks[False]

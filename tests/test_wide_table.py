@@ -180,6 +180,9 @@ def test_wide_program_took_the_feature_block_grid(wide_run):
     assert counters["hist/pallas_fblocks"] == passes // 8 * (7 * 3 + 4)
     assert counters["hist/pallas_held_onehot"] == passes // 8 * 2
     assert "hist/xla_int_kernel" not in counters
+    # and every level's row routing took the kernel
+    assert counters["partition/route_pallas"] == passes
+    assert "partition/route_xla" not in counters
 
 
 def test_wide_program_is_correct_by_the_cells_limits(wide_run):

@@ -133,6 +133,21 @@ def _lower_kernel(one_chip, features, dtype, lanes, stats, num_cols):
                jnp.bfloat16 if dtype == "bf16v" else jnp.int8))
 
 
+def _lower_route_kernel(one_chip, features, rows, slots):
+    """The level-wise grower's routing kernel at one level's shapes,
+    traced anew and lowered for the described chip."""
+    from lightgbm_tpu.ops.route_pallas import route_level_pallas
+    per_slot = [_shape(one_chip, (slots,), dt)
+                for dt in (jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
+                           jnp.bool_)]
+    return jax.jit(lambda *a: route_level_pallas(*a)).lower(
+        _shape(one_chip, (features, rows), jnp.uint8),   # partition_bins
+        _shape(one_chip, (rows,), jnp.int32),            # slot_id
+        _shape(one_chip, (rows,), jnp.int32),            # out_leaf
+        _shape(one_chip, (rows,), jnp.bool_),            # row_mask
+        *per_slot)
+
+
 class _Captured(Exception):
     pass
 
