@@ -1238,8 +1238,8 @@ class GBDT:
             with telemetry.span("score_update") as sp:
                 shrunk = jnp.where(tree_arrays.num_leaves > 1,
                                    tree_arrays.leaf_value * lr, 0.0)
-                self.score = self.score.at[cls].add(
-                    _leaf_lookup(shrunk, tree_arrays.leaf_ids))
+                self.score = _add_leaf_values(
+                    self.score, shrunk, tree_arrays.leaf_ids, cls=cls)
                 sp.fence(self.score)
             # valid scores via tree replay (gbdt.cpp:220-222); the grower's
             # arrays are already statically padded to num_leaves-1, so the
@@ -1382,8 +1382,8 @@ class GBDT:
             with telemetry.span("score_update") as sp:
                 shrunk = jnp.where(tree_arrays.num_leaves > 1,
                                    tree_arrays.leaf_value * lr, 0.0)
-                self.score = self.score.at[cls].add(
-                    _leaf_lookup(shrunk, tree_arrays.leaf_ids))
+                self.score = _add_leaf_values(
+                    self.score, shrunk, tree_arrays.leaf_ids, cls=cls)
                 sp.fence(self.score)
             if self.valid_datasets:
                 max_nodes = len(tree_arrays.split_feature)
@@ -1947,7 +1947,6 @@ class GBDT:
                 hist_dtype=self.tree_config.hist_dtype,
                 quant_rounding=self.tree_config.quant_rounding,
                 leafwise_compact=leafwise_compact_on(self.tree_config),
-                num_features=self.num_features,
                 packing=self._pack_spec,
                 has_bag=has_bag, has_ff=has_ff,
                 train_metric_fns=tuple(s[2] for s in train_specs),
@@ -2735,6 +2734,19 @@ class GBDT:
         return "\n".join(out) + "\n"
 
 
+@functools.partial(jax.jit, static_argnames=("cls",))
+def _add_leaf_values(score, shrunk, leaf_ids, *, cls):
+    """``score`` with each row's leaf value added to class ``cls``: the
+    per-tree loop's pass over the rows as ONE program under the
+    ``score_update`` device scope, as the chunk body has it (a scope
+    around eager operations does not enter their programs).  The shrunk
+    [num_leaves] values are made outside: one program with the multiply
+    in it rounds the add differently on a CPU, and the per-tree loop and
+    the fused chunk grow the same model bit for bit."""
+    with telemetry.phase_scope("score_update"):
+        return score.at[cls].add(_leaf_lookup(shrunk, leaf_ids))
+
+
 def _read_back(tree):
     """The model readback, ``jax.device_get(tree)``, in its three parts.
     With telemetry on the host first waits for the device under a span of
@@ -2863,7 +2875,6 @@ def _get_chunk_program(obj_key, grad_fn, num_class: int, lr: float,
                        hist_chunk: int = 0, hist_dtype: str = "float32",
                        quant_rounding: str = "nearest",
                        leafwise_compact: bool = False,
-                       num_features: int = 0,
                        packing=None,
                        has_bag: bool, has_ff: bool,
                        train_metric_fns: tuple = (),
@@ -2876,7 +2887,7 @@ def _get_chunk_program(obj_key, grad_fn, num_class: int, lr: float,
     # old kernel routing
     from ..ops.compact import pallas_partition_ok, partition_overlap_on
     use_pp = leafwise_compact and grow_policy != "depthwise" \
-        and pallas_partition_ok(num_features)
+        and pallas_partition_ok()
     key = (obj_key, id(grad_fn), num_class, lr, grow_policy, num_leaves,
            num_bins_max, min_data_in_leaf, min_sum_hessian_in_leaf,
            max_depth, hist_chunk, hist_dtype, quant_rounding,
@@ -3017,7 +3028,7 @@ def _serial_learner(gbdt: GBDT, bins, grad, hess, row_mask, feature_mask):
         # (the chunk-program caches carry them in their keys instead)
         return grow_tree_leafcompact(
             bins, grad, hess, row_mask, feature_mask, gbdt.num_bins_device,
-            use_pallas_partition=pallas_partition_ok(gbdt.num_features),
+            use_pallas_partition=pallas_partition_ok(),
             partition_overlap=partition_overlap_on(),
             **kwargs)
     return grow_tree(

@@ -984,6 +984,20 @@ class _CompactState(NamedTuple):
     done: jax.Array             # bool
 
 
+def _run_if(pred, fn, operand):
+    """``lax.cond(pred, fn, identity, operand)`` as a while loop of zero
+    trips or one.  XLA updates a while loop's carry in place; a
+    conditional's operand it copies whole before a branch may write to
+    it, so a split under ``lax.cond`` / ``lax.switch`` copied the plane
+    pane twice and the leaf histogram cache twice (809 MB and 1.56 GB
+    each at 400,000 x 2,000; PERF.md section 6, PR 34) to change a
+    range of the one and two rows of the other."""
+    return jax.lax.while_loop(
+        lambda c: c[0],
+        lambda c: (jnp.asarray(False), fn(c[1])),
+        (pred, operand))[1]
+
+
 def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                       s: SeamSchedule, *, num_leaves: int,
                       num_bins_max: int, min_data_in_leaf: int,
@@ -1000,7 +1014,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
     kernel on TPU, stable argsort oracle elsewhere) and histograms ONLY
     the physically-smaller child's bucketed range, deriving the sibling
     by subtraction.  Ranges are sliced at bucketed widths
-    (ops/compact.bucket_table) under a lax.switch; the histogram tier is
+    (ops/compact.bucket_table), one tier's branch a split; the histogram tier is
     pmax-synced over hist_axis so collectives inside the tier switch
     stay uniform across shards.  Equivalence to the masked policy:
     structure-exact, values within the documented cross-program ulp
@@ -1033,13 +1047,13 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
     _fg = ({"feat_gather": s.hist_feat_gather}
            if s.hist_feat_gather is not None else {})
 
-    def hist_of(hbins, hg, hh, hmask, salt=0):
+    def hist_of(hbins, hg, hh, hmask, salt=0, **extra):
         hist = build_hist(hbins, hg, hh, hmask, B,
                                backend=hist_backend, chunk=hist_chunk,
                                compute_dtype=compute_dtype,
                                axis_name=s.hist_axis,
                                int_reduce=s.int_hist_reduce, salt=salt,
-                               packing=packing, **_fg)
+                               packing=packing, **_fg, **extra)
         return _apply_hist_reduce(hist, s, compute_dtype)
 
     finder = s.split_finder or find_best_split
@@ -1146,8 +1160,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
         W = table[k]
 
         @phase_scope("partition")
-        def branch(op):
-            pane, start, cnt, feat, thr = op
+        def branch(pane, start, cnt, feat, thr):
             cs = jnp.minimum(start, P - W)        # clamp: slice stays
             delta = start - cs                    # in-pane; mask realigns
             seg = jax.lax.dynamic_slice(pane, (jnp.int32(0), cs), (R, W))
@@ -1184,7 +1197,9 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
             hbins, hg, hh, hvalid = unpack_values(hseg, F)
             lane2 = jnp.arange(W, dtype=jnp.int32)
             hmask = (lane2 >= d2) & (lane2 < d2 + scnt) & hvalid
-            return hist_of(hbins, hg, hh, hmask, salt=salt)
+            # a bucketed range is up to twice the leaf's rows wide: the
+            # kernel may pass over the chunks outside [d2, d2 + scnt)
+            return hist_of(hbins, hg, hh, hmask, salt=salt, skip_dead=True)
 
         return branch
 
@@ -1236,11 +1251,18 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                                      new_leaf, tree.leaf_ids)
 
             # --- partition the parent's lane range at ITS tier (local,
-            # collective-free: shards may take different branches)
+            # collective-free: shards may take different branches).  One
+            # tier runs; each is a loop of its own so that the pane is
+            # written in place (_run_if)
             with phase_scope("partition"):
-                pane2, plcnt = jax.lax.switch(
-                    state.seg_bucket[bl], partition_branches,
-                    (state.pane, start, cnt, feat, thr))
+                tier = state.seg_bucket[bl]
+                pane2, plcnt = state.pane, jnp.asarray(0, jnp.int32)
+                for k, branch in enumerate(partition_branches):
+                    pane2, plcnt = _run_if(
+                        tier == k,
+                        lambda c, branch=branch: branch(c[0], start, cnt,
+                                                        feat, thr),
+                        (pane2, plcnt))
                 prcnt = cnt - plcnt
 
             # --- smaller-child histogram at the CHILD's own tier.  The
@@ -1267,7 +1289,13 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                     bucket_of(hk_span), hist_branches,
                     (pane2, sstart, scnt, new_leaf))
 
-                parent_hist = state.hist_cache[bl]
+                # the parent's row in a buffer of its own before the
+                # cache is written: fused into the children's writes it
+                # is a read of the cache at another row than the one
+                # written, which XLA does not do in place (it copied the
+                # whole cache, twice a split)
+                parent_hist = jax.lax.optimization_barrier(
+                    state.hist_cache[bl])
                 large_hist = parent_hist - small_hist
                 lhist = jnp.where(left_small, small_hist, large_hist)
                 rhist = jnp.where(left_small, large_hist, small_hist)
@@ -1346,13 +1374,12 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                     leaf_depth=put(state.leaf_depth, depth, depth),
                 )
 
-        def no_split(state: _CompactState) -> _CompactState:
-            return state._replace(done=jnp.asarray(True))
-
         # profiler alignment (ISSUE 2): label the compacted split body so
         # profile_dir= traces group its partition/histogram ops per split
         with jax.named_scope("leafcompact_split"):
-            return jax.lax.cond(should_split, do_split, no_split, state)
+            state = _run_if(should_split, do_split, state)
+        with phase_scope("split_find"):
+            return state._replace(done=state.done | ~should_split)
 
     return jax.lax.fori_loop(0, L - 1, body, state).tree
 

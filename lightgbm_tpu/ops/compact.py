@@ -30,6 +30,12 @@ Cost per partitioned row: block x R int8 MACs (x1.5 with the overlap
 patch) + ~3 bytes of HBM traffic per plane — ~0.6% of the histogram MACs
 the compaction saves (PROFILE.md).
 
+A pane too tall for one block's VMEM working set (past 88 rows, 79
+columns) takes the same two schedules through ``_partition_kernel_rows``:
+grid = (lane blocks, row blocks), the selection one-hots made once a lane
+block — they depend on the mask alone — and kept in VMEM for its row
+blocks (``partition_grid``).
+
 The XLA oracle (CPU/tests): a stable argsort formulation with identical
 semantics — the kernel is differentially tested against it.
 """
@@ -42,62 +48,83 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 2048  # partition lane block; the kernel's VMEM working set at
-              # this block (pane slices, the [2176, 2048] one-hot
-              # selection matrix, the RMW window buffers and blend
+BLOCK = 2048  # partition lane block of a pane of one row block; the
+              # kernel's VMEM working set (pane slices, the one-hot
+              # selection matrices, the RMW window buffers and blend
               # temporaries) is priced by partition_vmem_bytes below,
-              # which gates eligibility at PARTITION_VMEM_BUDGET
+              # and partition_grid cuts a taller pane into row blocks
+              # that keep it under PARTITION_VMEM_BUDGET
+
+TALL_BLOCK = 512  # lane block of a pane cut into row blocks: the stored
+                  # one-hots are [block + 128, block] each, so a narrow
+                  # lane block leaves the budget to the pane's rows
 
 
 # VMEM ceiling for the partition kernel's working set.  Past it Mosaic
-# fails to ALLOCATE (wide-F datasets), so eligibility must be gated here
-# rather than discovered as a compile error.  12 MiB of the ~16 MiB/core
-# leaves headroom for Mosaic's own spills; with the overlap schedule's
-# temporary count the estimate admits pane heights up to R≈88 (F≈79) at
-# the default block.  Deliberately conservative: the fallback (XLA
-# argsort oracle) is correctness-neutral, an on-device allocation
-# failure is not.
+# fails to ALLOCATE, so the grid is sized here rather than discovered as
+# a compile error.  12 MiB of the ~16 MiB/core leaves headroom for
+# Mosaic's own spills; with the overlap schedule's temporary count the
+# estimate admits one row block up to R≈88 (F≈79) at the default lane
+# block.
 PARTITION_VMEM_BUDGET = 12 << 20
 
 
-def partition_vmem_bytes(num_features: int, block: int = BLOCK) -> int:
-    """Working-set estimate (bytes) of the partition kernel at this pane
-    height: double-buffered input blocks, the matmul operand matrices,
-    the RMW window buffers and the i32 shifted/keep/blend temporaries.
-    Sized for the default OVERLAP schedule, whose right-blend merge
-    keeps more [R, win] i32 temporaries live at once (merged/keep_lr/
-    shifted_r/keep_r around the blend) than the serialized kernel's
-    three."""
-    R = pane_rows(num_features)
+def partition_vmem_bytes(rows: int, block: int = BLOCK,
+                         held: int = 1) -> int:
+    """Working-set estimate (bytes) of the partition kernel at a row
+    block of ``rows`` pane rows: double-buffered input blocks, the
+    matmul operand matrices, the RMW window buffers and the i32
+    shifted/keep/blend temporaries.  Sized for the default OVERLAP
+    schedule, whose right-blend merge keeps more [rows, win] i32
+    temporaries live at once (merged/keep_lr/shifted_r/keep_r around the
+    blend) than the serialized kernel's three.  ``held``: the one-hot
+    selection matrices alive at once — one where each is built and
+    spent in turn, three where the row-blocked kernel keeps them for
+    every row block of a lane block."""
     win = block + 128
-    return (2 * (R + 1) * block     # pipelined seg+mask input blocks, int8
+    return (2 * (rows + 1) * block  # pipelined seg+mask input blocks, int8
             + block * block         # strict-lower-triangular operand, int8
-            + win * block           # one-hot selection matrix, int8
-            + 2 * R * win           # RMW window buffers, int8
-            + 4 * 4 * R * win)      # i32 temporaries live around the blend
+            + held * win * block    # one-hot selection matrices, int8
+            + 2 * rows * win        # RMW window buffers, int8
+            + 4 * 4 * rows * win)   # i32 temporaries live around the blend
 
 
-def pallas_partition_ok(num_features: int | None = None) -> bool:
+def partition_grid(rows: int, block: int = BLOCK):
+    """(lane block, row-block height, row blocks) of the partition
+    kernel's grid for a pane of ``rows`` rows, in the manner of
+    hist_pallas.feature_grid: a pane whose priced working set fits the
+    budget is one row block at ``block`` lanes (the program every narrow
+    table has always had); a taller one is cut into the fewest equal
+    row blocks, a multiple of the int8 sublane tile (32) high, that fit
+    at TALL_BLOCK lanes with the three one-hots held.  The selection
+    depends on the mask alone, so every row block of a lane block lands
+    its rows by the same one-hots."""
+    if partition_vmem_bytes(rows, block) <= PARTITION_VMEM_BUDGET:
+        return block, rows, 1
+    lanes = min(block, TALL_BLOCK)
+    fixed = partition_vmem_bytes(0, lanes, held=3)
+    per_row = partition_vmem_bytes(1, lanes, held=3) - fixed
+    most = (PARTITION_VMEM_BUDGET - fixed) // per_row // 32 * 32
+    count = -(-rows // most)
+    height = -(-rows // (count * 32)) * 32
+    return lanes, height, -(-rows // height)
+
+
+def pallas_partition_ok() -> bool:
     """Eligibility of the Pallas partition kernel: TPU default backend,
     unless LGBM_TPU_NO_PALLAS=1 — the escape hatch a mixed-backend
     process (TPU backend up, computation steered onto virtual CPU
     devices, e.g. __graft_entry__.dryrun_multichip) sets so kernels
-    never land on a CPU mesh.  ``num_features`` (when the caller knows
-    it) additionally gates on the kernel's VMEM working set: wide-F
-    datasets whose plane pane exceeds PARTITION_VMEM_BUDGET fall back to
-    the XLA argsort oracle instead of failing to compile.  Every outcome
-    is counted (telemetry) — the runtime record of which partition route
-    the process baked into its programs."""
+    never land on a CPU mesh.  A pane of any height is eligible
+    (partition_grid cuts it to fit VMEM).  Every outcome is counted
+    (telemetry) — the runtime record of which partition route the
+    process baked into its programs."""
     from .. import hatches, telemetry
     if hatches.flag("LGBM_TPU_NO_PALLAS"):
         # count_route: this rule is re-evaluated per tree by host code, so
         # counting per outcome CHANGE keeps the counter at per-decision
         # magnitude like the trace-time counters
         telemetry.count_route("partition_ok", "partition/env_no_pallas")
-        return False
-    if (num_features is not None
-            and partition_vmem_bytes(num_features) > PARTITION_VMEM_BUDGET):
-        telemetry.count_route("partition_ok", "partition/wide_f_fallback")
         return False
     ok = jax.default_backend() == "tpu"
     telemetry.count_route("partition_ok",
@@ -287,6 +314,139 @@ def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
     offs_ref[1] = offs_ref[1] + used_r
 
 
+def _partition_kernel_rows(mask_ref, scal_ref, seg_ref, out_ref, sel_ref,
+                           winl_ref, winr_ref, offs_ref, seml_ref,
+                           semr_ref, *, rows, block, overlap):
+    """Grid (lane blocks, row blocks), row blocks innermost: the pane of
+    a wide table, ``rows`` pane rows at a time (partition_grid).
+
+    What depends on the mask alone is done at a lane block's FIRST row
+    block and kept for the others: the two prefix counts, the stream
+    lengths (SMEM, beside the running offsets) and the one-hot selection
+    matrices (VMEM: left rows at the left window's lanes, right rows at
+    the right window's and, for the overlapped schedule, the left rows
+    at the right window's).  Every row block then lands its rows by the
+    same one-hots through its own row range of the same two windows, in
+    the schedule of the one-block kernels above (``overlap`` or
+    serialized); the offsets advance at the LAST row block.  Row blocks
+    touch disjoint rows of the output, so the one-block kernels' ordering
+    arguments hold within each row block as they stand."""
+    j = pl.program_id(0)
+    r = pl.program_id(1)
+
+    @pl.when((j == 0) & (r == 0))
+    def _():
+        offs_ref[0] = 0
+        offs_ref[1] = 0
+
+    delta = scal_ref[0]
+    plcnt = scal_ref[1]
+    win = block + 128
+    base_l = delta + offs_ref[0]
+    base_r = delta + plcnt + offs_ref[1]
+    p0l = (base_l // 128) * 128
+    p0r = (base_r // 128) * 128
+    shift_l = base_l - p0l
+    shift_r = base_r - p0r
+
+    @pl.when(r == 0)
+    def _():
+        m = mask_ref[...].astype(jnp.int32)                # [1, block]
+        iota_t = jax.lax.broadcasted_iota(jnp.int32, (win, block), 0)
+        lt = (jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+              < jax.lax.broadcasted_iota(
+                  jnp.int32, (block, block), 1)).astype(jnp.int8)
+
+        def stats(p):
+            mi = (m == 1 - p).astype(jnp.int32)
+            pos = jax.lax.dot_general(
+                mi.astype(jnp.int8), lt,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)          # [1, block]
+            offs_ref[2 + p] = jnp.sum(mi)
+            return mi, pos
+
+        def onehot(mi, pos, shift):
+            return ((jnp.broadcast_to(pos, (win, block)) + shift == iota_t)
+                    & jnp.broadcast_to(mi == 1, (win, block))).astype(
+                        jnp.int8)
+
+        mi_l, pos_l = stats(0)
+        mi_r, pos_r = stats(1)
+
+        @pl.when(offs_ref[2] + offs_ref[3] > 0)
+        def _():
+            sel_ref[0] = onehot(mi_l, pos_l, shift_l)
+            sel_ref[1] = onehot(mi_r, pos_r, shift_r)
+            if overlap:
+                sel_ref[2] = onehot(mi_l, pos_l, base_l - p0r)
+
+    used_l = offs_ref[2]
+    used_r = offs_ref[3]
+
+    # a lane block with no lane of the segment lands nothing (the
+    # bucketed range is up to twice the segment): its windows are left
+    # alone, so a pass costs the segment's lanes and not its bucket's
+    @pl.when(used_l + used_r > 0)
+    def _():
+        row0 = pl.multiple_of(r * rows, 32)
+        lane_w = jax.lax.broadcasted_iota(jnp.int32, (rows, win), 1)
+        pane = seg_ref[...]                                    # [rows, block]
+
+        def place(k, shift, used):
+            shifted = jax.lax.dot_general(
+                pane, sel_ref[k], dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.int32)              # [rows, win]
+            keep = ((lane_w >= shift) & (lane_w < shift + used)).astype(
+                jnp.int32)
+            return shifted, keep
+
+        def window(p0):
+            return out_ref.at[pl.ds(row0, rows), pl.ds(p0, win)]
+
+        if overlap:
+            in_l = pltpu.make_async_copy(window(p0l), winl_ref, seml_ref)
+            in_l.start()
+            in_r = pltpu.make_async_copy(window(p0r), winr_ref, semr_ref)
+            in_r.start()
+            shifted_l, keep_l = place(0, shift_l, used_l)
+            merged_l, keep_lr = place(2, base_l - p0r, used_l)
+            shifted_r, keep_r = place(1, shift_r, used_r)
+            in_l.wait()
+            blended_l = (shifted_l * keep_l
+                         + winl_ref[...].astype(jnp.int32) * (1 - keep_l))
+            winl_ref[...] = blended_l.astype(jnp.int8)
+            in_r.wait()
+            out_l = pltpu.make_async_copy(winl_ref, window(p0l), seml_ref)
+            out_l.start()
+            patched = (merged_l * keep_lr
+                       + winr_ref[...].astype(jnp.int32) * (1 - keep_lr))
+            blended_r = shifted_r * keep_r + patched * (1 - keep_r)
+            winr_ref[...] = blended_r.astype(jnp.int8)
+            out_l.wait()
+            out_r = pltpu.make_async_copy(winr_ref, window(p0r), semr_ref)
+            out_r.start()
+            out_r.wait()
+        else:
+            for k, p0, shift, used in ((0, p0l, shift_l, used_l),
+                                       (1, p0r, shift_r, used_r)):
+                shifted, keep = place(k, shift, used)
+                dma_in = pltpu.make_async_copy(window(p0), winl_ref, seml_ref)
+                dma_in.start()
+                dma_in.wait()
+                blended = (shifted * keep
+                           + winl_ref[...].astype(jnp.int32) * (1 - keep))
+                winl_ref[...] = blended.astype(jnp.int8)
+                dma_out = pltpu.make_async_copy(winl_ref, window(p0), seml_ref)
+                dma_out.start()
+                dma_out.wait()
+
+    @pl.when(r == pl.num_programs(1) - 1)
+    def _():
+        offs_ref[0] = offs_ref[0] + used_l
+        offs_ref[1] = offs_ref[1] + used_r
+
+
 def partition_overlap_on() -> bool:
     """Resolved DMA-overlap schedule bit (the
     LGBM_TPU_PARTITION_NO_OVERLAP=1 A/B hatch).  Resolved OUTSIDE every
@@ -336,14 +496,15 @@ def partition_segment(seg, mask3, delta, cnt, plcnt, *, block: int = BLOCK,
         # analytic per-pass cost (the Pallas kernel is a custom call XLA
         # cost analysis cannot see into): the pane is read and written
         # once per partition pass — plus the selection matmuls' MACs
-        # (R x W x block one-hot contractions; 3 per block overlapped,
-        # 2 serialized)
+        # (R x W x lane-block one-hot contractions; 3 per block
+        # overlapped, 2 serialized)
         R, W = seg.shape
+        lanes = partition_grid(R, block)[0]
         costmodel.note_traced_pass(
-            "partition", ("pane", R, W, block, bool(use_pallas),
+            "partition", ("pane", R, W, lanes, bool(use_pallas),
                           bool(overlap)),
             bytes_moved=2.0 * R * W,
-            macs=float(R) * W * block * (3 if overlap else 2))
+            macs=float(R) * W * lanes * (3 if overlap else 2))
     with telemetry.span("partition") as sp:
         return sp.fence(_partition_segment_jit(
             seg, mask3, delta, cnt, plcnt, block=block,
@@ -381,6 +542,41 @@ def _partition_segment_impl(seg, mask3, delta, cnt, plcnt, *, block,
             use_pallas=use_pallas, interpret=interpret, overlap=overlap)
 
 
+def _partition_rows_call(seg, mask3, scal, lanes, rows, nrb, overlap,
+                         interpret):
+    """The row-blocked kernel over ``seg``: [R, W] of its output.  The
+    output's rows are padded to whole row blocks, so a ragged last block
+    (whose input rows past R are the pipeline's padding) writes into rows
+    that are cut off here."""
+    R, W = seg.shape
+    win = lanes + 128
+    out = pl.pallas_call(
+        functools.partial(_partition_kernel_rows, rows=rows, block=lanes,
+                          overlap=overlap),
+        grid=(W // lanes, nrb),
+        in_specs=[
+            pl.BlockSpec((1, lanes), lambda j, r: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((rows, lanes), lambda j, r: (r, j)),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+        out_shape=jax.ShapeDtypeStruct((nrb * rows, W + lanes + 256),
+                                       jnp.int8),
+        scratch_shapes=[
+            pltpu.VMEM((3 if overlap else 2, win, lanes), jnp.int8),
+            pltpu.VMEM((rows, win), jnp.int8),
+            pltpu.VMEM((rows, win), jnp.int8),
+            pltpu.SMEM((4,), jnp.int32),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(mask3[None, :], scal, seg)
+    return out[:R, :W]
+
+
 def _partition_segment_scoped(seg, mask3, delta, cnt, plcnt, *, block,
                               use_pallas, interpret, overlap=True):
     R, W = seg.shape
@@ -389,7 +585,15 @@ def _partition_segment_scoped(seg, mask3, delta, cnt, plcnt, *, block,
     inseg = (lane >= delta) & (lane < delta + cnt)
 
     if use_pallas:
+        from .. import telemetry
         scal = jnp.stack([delta, plcnt]).astype(jnp.int32)
+        lanes, rows, nrb = partition_grid(R, block)
+        # trace-time, like hist/pallas_fblocks: row blocks of the grids of
+        # the partition kernels traced (1 a kernel on a narrow table)
+        telemetry.count("partition/pallas_rblocks", nrb)
+        if (lanes, rows) != (block, R):
+            return jnp.where(inseg[None, :], _partition_rows_call(
+                seg, mask3, scal, lanes, rows, nrb, overlap, interpret), seg)
         if overlap:
             kernel = functools.partial(_partition_kernel_overlap,
                                        R=R, block=block)

@@ -55,9 +55,8 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 
 
-def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
-                 compute_dtype, acc_dtype, stats=3, fold=1, gw=None,
-                 held=0):
+def _hist_kernel(bins_ref, packed_ref, out_ref, *, stats=3,
+                 skip_dead=False, **static):
     # grid = (feature_blocks, row_chunks), rows minor: each feature
     # block's accumulator lives in VMEM across its whole row sweep and is
     # written back to HBM once
@@ -67,6 +66,25 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
+    accumulate = functools.partial(_hist_accumulate, bins_ref, packed_ref,
+                                   out_ref, stats=stats, **static)
+    if skip_dead:
+        # a chunk with no live row (leaf id -1 throughout) adds zeros to
+        # every cell.  The compacted grower hands the kernel a leaf's
+        # range at a bucketed width, up to twice the leaf's rows: with
+        # the dead chunks skipped a pass costs the leaf's rows and not
+        # its bucket's (PERF.md section 6, PR 34)
+        live = jnp.max(packed_ref[stats:stats + 1, :].astype(
+            jnp.float32)) >= 0.0
+        pl.when(live)(accumulate)
+    else:
+        accumulate()
+
+
+def _hist_accumulate(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
+                     compute_dtype, acc_dtype, stats=3, fold=1, gw=None,
+                     held=0):
+    """One chunk of rows added into the feature block's accumulator."""
     # pure arithmetic (no jnp.where): Mosaic cannot relayout replicated
     # boolean vectors.  VPU math runs wide (8-bit vector arithmetic is
     # unsupported) and casts to compute_dtype only for the MXU operands.
@@ -133,7 +151,7 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
 def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
                         dtype: str = "int8", lanes: int = LANES,
                         stats: int = 3, fold: int = 1, gw: int = None,
-                        held: int = 0):
+                        held: int = 0, skip_dead: bool = False):
     """[F, B, lanes] accumulator from [F, N] bins and packed values.
 
     Rows must be pre-padded to a multiple of ``chunk`` (pad cid with -1).
@@ -162,7 +180,9 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     is transposed back and zero-padded.  Either way the result is the same
     [F, B, lanes] array, bit for bit, for the integer-level modes ("bf16v"
     neither folds nor turns round); ``fold=1, held=0`` is the kernel as it
-    always was.
+    always was.  ``skip_dead`` passes over a chunk with no live row (cid
+    -1 throughout): the caller's to ask for, where its rows end in such
+    chunks (the compacted grower's bucketed ranges); the same sums.
 
     Wide datasets ride a FEATURE-BLOCK grid axis: each block of Fb
     features sweeps the rows in turn with its [Fb, B, lanes] accumulator
@@ -204,7 +224,8 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     kernel = functools.partial(
         _hist_kernel, F=fb, B=Bk, chunk=chunk,
         lanes=lanes, compute_dtype=compute_dtype, acc_dtype=acc_dtype,
-        stats=stats, fold=fold, gw=gw, held=held)
+        stats=stats, fold=fold, gw=gw, held=held,
+        skip_dead=skip_dead)
     out = pl.pallas_call(
         kernel,
         grid=(n_fblocks, N // chunk),
@@ -251,7 +272,7 @@ hist_pallas_raw = _costmodel.instrument(
     "hist/pallas_raw",
     jax.jit(_hist_pallas_raw_fn,
             static_argnames=("B", "chunk", "dtype", "lanes", "stats", "fold",
-                             "gw", "held")),
+                             "gw", "held", "skip_dead")),
     phase="histogram")
 
 
@@ -671,7 +692,8 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
 def hist_pallas_float_leafbatch(bins, grad, hess, col_id, col_ok,
                                 num_cols: int, num_bins_max: int, *,
                                 chunk: int = 2048,
-                                precision: str = "bf16", packing=None):
+                                precision: str = "bf16", packing=None,
+                                skip_dead: bool = False):
     """Float-gradient Pallas histogram — [C, F, B, 3] f32, same contract as
     histogram_leafbatch's einsum formulation but hand-scheduled (and so
     immune to the environment's XLA einsum-lowering regression, BASELINE.md
@@ -695,7 +717,7 @@ def hist_pallas_float_leafbatch(bins, grad, hess, col_id, col_ok,
       bit-invisible; "f32x1"/"f32x2" force one variant (A/B tests).
 
     Counts are exact in every mode: ok rides as 1.0 (bf16-exact) and the
-    lo lanes carry zeros.
+    lo lanes carry zeros.  ``skip_dead``: see ``_hist_pallas_raw_fn``.
     """
     if precision == "f32":
         precision = "f32x1" if num_cols <= 38 else "f32x2"
@@ -704,26 +726,38 @@ def hist_pallas_float_leafbatch(bins, grad, hess, col_id, col_ok,
             return _grouped(_hist_float_one, bins, grad, hess, col_id,
                             col_ok, num_cols, num_bins_max, group_width=38,
                             chunk=chunk, precision=precision,
-                            packing=packing)
+                            packing=packing, skip_dead=skip_dead)
         return _grouped(_hist_float_one, bins, grad, hess, col_id, col_ok,
                         num_cols, num_bins_max, group_width=64, chunk=chunk,
-                        precision=precision, packing=packing)
+                        precision=precision, packing=packing,
+                        skip_dead=skip_dead)
+
+
+def _bf16_hi(x):
+    """The float32 nearest ``x`` that bfloat16 holds exactly: the hi half
+    of the hi/lo pair.  A rounding in its own right and not a pair of
+    converts, which XLA, allowed excess precision (its default), takes
+    for the identity: the lo half ``x - hi`` then carried nothing and the
+    float32 histogram summed bfloat16 values (PERF.md section 6,
+    PR 34)."""
+    return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
 def _hist_float_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
-                    chunk, precision, packing=None):
+                    chunk, precision, packing=None, skip_dead=False):
+    from .. import telemetry
     if _packing_on(packing):
         # one kernel launch per bin-width class over the class's feature
         # rows; f32 accumulation is per row-chunk in fixed grid order, so
         # every canonical cell sums in exactly the uniform pass's order
-        from .. import telemetry
         telemetry.count("hist/mixedbin_pallas_float")
         parts = []
         for start, cnt, width in packing.ranges:
             h = _hist_float_one(
                 jax.lax.slice_in_dim(bins, start, start + cnt, axis=0),
                 grad, hess, col_id, col_ok, num_cols, width,
-                chunk=chunk, precision=precision)        # [C, Fc, w, 3]
+                chunk=chunk, precision=precision,
+                skip_dead=skip_dead)                     # [C, Fc, w, 3]
             if width < B:
                 h = jnp.pad(h, ((0, 0), (0, 0), (0, B - width), (0, 0)))
             parts.append(h)
@@ -748,16 +782,18 @@ def _hist_float_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         if pad:
             packed = jnp.pad(packed, ((0, 0), (0, pad)),
                              constant_values=-1)
+        # counted per pass, as the int launch counts its own
+        telemetry.count("hist/pallas_fblocks",
+                        feature_grid(F, B, lanes, chunk)[1])
         return hist_pallas_raw(bins8, packed, B=B, chunk=chunk,
                                dtype="bf16v", lanes=lanes,
-                               stats=len(vals))
+                               stats=len(vals), skip_dead=skip_dead)
 
     lanes3 = LANES if num_cols <= 42 else 192
     if precision == "bf16":
         acc = run([g, h, okf], lanes3)
     elif precision == "f32x1":
-        g_hi = g.astype(jnp.bfloat16).astype(jnp.float32)
-        h_hi = h.astype(jnp.bfloat16).astype(jnp.float32)
+        g_hi, h_hi = _bf16_hi(g), _bf16_hi(h)
         lanes5 = LANES if num_cols <= 25 else 192
         acc5 = run([g_hi, g - g_hi, h_hi, h - h_hi, okf], lanes5)
         w = acc5[:, :, :num_cols * 5].reshape(F, B, num_cols, 5)
@@ -765,8 +801,7 @@ def _hist_float_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
                           w[..., 4]], axis=-1)
         return hist.transpose(2, 0, 1, 3)
     elif precision == "f32x2":
-        g_hi = g.astype(jnp.bfloat16).astype(jnp.float32)
-        h_hi = h.astype(jnp.bfloat16).astype(jnp.float32)
+        g_hi, h_hi = _bf16_hi(g), _bf16_hi(h)
         acc = (run([g_hi, h_hi, okf], lanes3)
                + run([g - g_hi, h - h_hi, jnp.zeros_like(okf)], lanes3))
     else:

@@ -306,7 +306,8 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         compute_dtype=jnp.bfloat16,
                         axis_name=None, int_reduce=None,
                         salt=0, packing=None,
-                        feat_gather=None) -> jax.Array:
+                        feat_gather=None,
+                        skip_dead: bool = False) -> jax.Array:
     """Build histograms for MANY leaves in ONE matmul pass.
 
     The single-leaf one-hot matmul starves the MXU: the value operand has
@@ -332,6 +333,10 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     is stored in packed (bin-width-class) feature order; every route below
     runs one pass per class at that class's width and returns the
     CANONICAL-order histogram, value-identical to the uniform pass.
+    ``skip_dead``: the caller's rows end in stretches no row of which
+    takes part (the compacted grower's bucketed ranges); the float Pallas
+    kernel then passes over chunks that are dead throughout.  The same
+    sums; the other routes take no notice.
     """
     if _packing_active(packing):
         telemetry.count("hist/mixedbin_leafbatch")
@@ -378,7 +383,8 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         with telemetry.span("histogram") as sp:
             return sp.fence(_feat_take(hist_pallas_float_leafbatch(
                 bins, grad, hess, col_id, col_ok, num_cols, num_bins_max,
-                precision=precision, packing=packing), feat_gather, 1))
+                precision=precision, packing=packing,
+                skip_dead=skip_dead), feat_gather, 1))
     telemetry.count("hist/xla_einsum")
     with jax.named_scope("histogram"), telemetry.span("histogram") as sp:
         if _packing_active(packing):
@@ -551,12 +557,13 @@ def build_histogram(bins, grad, hess, mask, num_bins_max, *,
                     backend: str = "matmul", chunk: int = 16384,
                     compute_dtype=jnp.float32, axis_name=None,
                     int_reduce=None, salt=0, packing=None,
-                    feat_gather=None) -> jax.Array:
+                    feat_gather=None, skip_dead: bool = False) -> jax.Array:
     """``int_reduce``: optional int-domain cross-shard reduction for the
     quantized path (feature axis 0) — the data-parallel reduce_scatter
     ownership schedule passes a psum_scatter here so the accumulators are
     scattered WITHOUT leaving the exact int domain.  ``packing``: static
-    mixed-bin layout spec (see histogram_leafbatch)."""
+    mixed-bin layout spec, ``skip_dead``: the mask ends in dead stretches
+    (both: see histogram_leafbatch)."""
     if str(compute_dtype).startswith("int8"):
         # single-leaf quantized pass == leaf-batched with one column
         N = bins.shape[1]
@@ -579,7 +586,8 @@ def build_histogram(bins, grad, hess, mask, num_bins_max, *,
                                       num_bins_max, chunk=chunk,
                                       compute_dtype=compute_dtype,
                                       packing=packing,
-                                      feat_gather=feat_gather)
+                                      feat_gather=feat_gather,
+                                      skip_dead=skip_dead)
             return out[0]
         return histogram_matmul(bins, grad, hess, mask, num_bins_max,
                                 chunk=chunk, compute_dtype=compute_dtype,

@@ -736,7 +736,7 @@ class DataParallelLearner(_ParallelLearnerBase):
         # LGBM_TPU_PARTITION_NO_OVERLAP, and a stale program would keep
         # the old kernel routing either way
         from ..ops.compact import pallas_partition_ok, partition_overlap_on
-        use_pp = use_compact and pallas_partition_ok(num_features)
+        use_pp = use_compact and pallas_partition_ok()
         key = (obj_key, id(grad_fn), num_shards, num_class, lr, depthwise,
                tuple(sorted(kwargs.items())), has_bag, has_ff, n_true,
                shard_layout, needs_global_score, use_scatter, use_compact,
@@ -935,7 +935,7 @@ class DataParallelLearner(_ParallelLearnerBase):
         allreduce — the multi-process default (dp_schedule=auto) no
         longer falls back to the masked N·(L-1)-sweep grower."""
         from ..ops.compact import pallas_partition_ok, partition_overlap_on
-        use_pallas = pallas_partition_ok(F)
+        use_pallas = pallas_partition_ok()
         overlap = partition_overlap_on()
         # per-split seams run once per split; x the fused-chunk length on
         # the chunk path (wire-metrics executed-calls estimate)
@@ -1069,7 +1069,7 @@ class DataParallelLearner(_ParallelLearnerBase):
         # LGBM_TPU_PARTITION_NO_OVERLAP) and a stale program would keep
         # the old kernel routing
         from ..ops.compact import pallas_partition_ok, partition_overlap_on
-        use_pp = use_compact and pallas_partition_ok(F)
+        use_pp = use_compact and pallas_partition_ok()
         # the resolved mixed-bin layout spec is a cache-key bit exactly
         # like the kernel-routing flags (graftlint R2): the traced
         # program bakes the per-class pass structure AND the canonical
@@ -1209,7 +1209,7 @@ class HybridLearner(DataParallelLearner):
         seams = hybrid_ownership_seams(
             F, fs, site_prefix="hybrid/leafcompact", loop=split_loop,
             phase=phase, root_loop=loop_scale, slice_hist=True)
-        use_pallas = pallas_partition_ok(F)
+        use_pallas = pallas_partition_ok()
         overlap = partition_overlap_on()
 
         def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins):
@@ -1290,7 +1290,7 @@ class VotingLearner(HybridLearner):
         # execution while the tracer records one lane's shape
         seams = self._voting_seams(kwargs, F, "voting/leafcompact",
                                    split_loop, phase, loop_scale, lanes=2)
-        use_pallas = pallas_partition_ok(F)
+        use_pallas = pallas_partition_ok()
         overlap = partition_overlap_on()
 
         def shard_grow(bins_s, grad_s, hess_s, mask_s, fmask, nbins):

@@ -292,7 +292,7 @@ COUNTER_FAMILIES = (
     "hist/mixedbin_xla_int",
     "hist/pallas_*",              # per-dtype kernel hits
     "hist/pallas_eligible",
-    "hist/pallas_fblocks",        # feature blocks of the kernel's grid, summed over int passes
+    "hist/pallas_fblocks",        # feature blocks of the kernel's grid, summed over Pallas passes
     "hist/pallas_held_onehot",    # int passes contracted with the one-hot held, the value rows streamed
     "hist/pallas_ineligible",
     "hist/pallas_int8",
@@ -327,9 +327,9 @@ COUNTER_FAMILIES = (
     "partition/pallas",
     "partition/pallas_eligible",
     "partition/pallas_ineligible",
+    "partition/pallas_rblocks",   # row blocks of the kernels' grids
     "partition/route_pallas",     # level-wise row routing, once a level
     "partition/route_xla",
-    "partition/wide_f_fallback",
     "partition/xla",
     "serve/bucket_*",             # per-ladder-bucket dispatch counts
     "serve/coalesced_batches",

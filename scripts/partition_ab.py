@@ -13,7 +13,10 @@ script reports the oracle timing and says exactly that, instead of
 printing a fake A/B.
 
 Usage: python scripts/partition_ab.py [--rows N] [--features F]
-Prints one JSON line.
+                                      [--row-blocked 1]
+Prints one JSON line.  ``--row-blocked 1`` also times the row-blocked
+kernel (ops/compact._partition_kernel_rows) forced onto the same pane,
+beside whichever kernel ``partition_grid`` picks for it.
 """
 from __future__ import annotations
 
@@ -34,6 +37,8 @@ def main() -> int:
                    help="segment lanes (bench scale: 1M)")
     p.add_argument("--features", type=int, default=28)
     p.add_argument("--left-frac", type=float, default=0.5)
+    p.add_argument("--row-blocked", type=int, choices=(0, 1), default=0,
+                   help="also time the row-blocked kernel on this pane")
     args = p.parse_args()
 
     import jax
@@ -43,7 +48,7 @@ def main() -> int:
     from tpu_timeit import device_time
 
     backend = jax.default_backend()
-    eligible = backend == "tpu" and compact.pallas_partition_ok(args.features)
+    eligible = backend == "tpu" and compact.pallas_partition_ok()
     R = compact.pane_rows(args.features)
     W = ((args.rows + compact.BLOCK - 1) // compact.BLOCK) * compact.BLOCK
     rng = np.random.RandomState(0)
@@ -64,6 +69,22 @@ def main() -> int:
                 interpret=False, overlap=overlap),
             seg, mask3)
 
+    def run_rows(overlap: bool) -> float:
+        # the row-blocked kernel forced onto this pane (partition_grid
+        # keeps a pane of 88 rows or fewer on the one-block kernels): one
+        # row block where the pane is that low, TALL_BLOCK lanes
+        lanes = compact.TALL_BLOCK
+        rows = min(-(-R // 32) * 32, compact.partition_grid(2016)[1])
+        nrb = -(-R // rows)
+
+        def op(s, m):
+            with jax.named_scope("partition"):
+                scal = jnp.stack([delta, jnp.int32(plcnt)])
+                inseg = jnp.arange(W, dtype=jnp.int32) < cnt
+                return jnp.where(inseg[None, :], compact._partition_rows_call(
+                    s, m, scal, lanes, rows, nrb, overlap, False), s)
+        return device_time(op, seg, mask3)
+
     out = {
         "backend": backend,
         "device_kind": str(jax.local_devices()[0].device_kind),
@@ -77,6 +98,11 @@ def main() -> int:
         out["overlap_on_ms"] = round(on * 1e3, 3)
         out["overlap_off_ms"] = round(off * 1e3, 3)
         out["overlap_speedup"] = round(off / on, 4) if on > 0 else None
+        out["grid"] = list(compact.partition_grid(R))
+        if args.row_blocked:
+            out["rows_kernel_overlap_on_ms"] = round(run_rows(True) * 1e3, 3)
+            out["rows_kernel_overlap_off_ms"] = round(
+                run_rows(False) * 1e3, 3)
     else:
         out["xla_oracle_ms"] = round(run(False, True) * 1e3, 3)
         out["note"] = (

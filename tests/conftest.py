@@ -51,7 +51,7 @@ HEAVY_FIRST = (
     "test_multiprocess_dp.py", "test_parallel.py", "test_hist_int8_held.py",
     "test_hist_int8_fold.py", "test_mixedbin.py", "test_wide_table.py",
     "test_tpu_compile_wide.py", "test_hybrid_voting.py", "test_gbdt.py",
-    "test_streaming.py", "test_leafcompact.py",
+    "test_streaming.py", "test_leafwise_wide.py", "test_leafcompact.py",
     "test_distributed_telemetry.py", "test_goss_chunk.py",
     "test_route_pallas.py", "test_depthwise.py", "test_hist_int8.py",
     "test_graftlint.py",

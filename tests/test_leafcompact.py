@@ -37,10 +37,20 @@ def test_partition_kernel_matches_oracle(delta, cnt):
     np.testing.assert_array_equal(oracle, kernel)
 
 
-@pytest.mark.parametrize("delta,cnt", [
-    (0, 8192), (777, 6000), (2047, 4097), (100, 3000), (4095, 2049),
-])
-def test_partition_dma_overlap_bit_identity(delta, cnt):
+# pane heights past one row block (ops/compact.partition_grid): features,
+# (lane block, row-block height, row blocks).  216 rows are one block of
+# the row-blocked kernel, padded; 1,016 two, the last one ragged (504 of
+# 512); 2,016 the wide cell's three, whole; 2,512 three with a ragged last
+TALL_PANES = [(200, (512, 224, 1)), (1000, (512, 512, 2)),
+              (2000, (512, 672, 3)), (2503, (512, 864, 3))]
+
+
+@pytest.mark.parametrize("delta,cnt,features", [
+    (0, 8192, None), (777, 6000, None), (2047, 4097, None),
+    (100, 3000, None), (4095, 2049, None),
+] + [(777, 6000, f) for f, _ in TALL_PANES]
+  + [(0, 8192, 2000), (4095, 2049, 1000), (123, 0, 1000)])
+def test_partition_dma_overlap_bit_identity(delta, cnt, features):
     """The overlapped-DMA kernel schedule (both window reads up front,
     left write-back under the right blend, VMEM-side merge of the fresh
     left lanes into the right window) must be BIT-identical to both the
@@ -49,6 +59,12 @@ def test_partition_dma_overlap_bit_identity(delta, cnt):
     the merge exists for) are genuinely exercised."""
     rng = np.random.RandomState(delta * 7 + cnt)
     R, W = 13, 8192
+    if features is not None:
+        # the row-blocked kernel: one-hots made at a lane block's first
+        # row block land every row block's rows, 16 lane blocks of 512
+        from lightgbm_tpu.ops.compact import pane_rows, partition_grid
+        R = pane_rows(features)
+        assert partition_grid(R) == dict(TALL_PANES)[features]
     seg, mask3, plcnt = _random_case(rng, R, W, delta, cnt)
     args = (jnp.asarray(seg), jnp.asarray(mask3), jnp.int32(delta),
             jnp.int32(cnt), jnp.int32(plcnt))
@@ -63,30 +79,64 @@ def test_partition_dma_overlap_bit_identity(delta, cnt):
     np.testing.assert_array_equal(oracle, overlap)
 
 
-def test_partition_wide_feature_eligibility(monkeypatch):
-    """Wide-feature datasets whose plane pane blows the kernel's VMEM
-    working set must fall back to the XLA argsort oracle at the
-    ELIGIBILITY rule (pallas_partition_ok), not as a Mosaic compile
-    error — and the fallback is a counted route."""
+@pytest.mark.parametrize("features,grid", [
+    (28, (BLOCK, 40, 1)), (79, (BLOCK, 88, 1)), (80, (512, 96, 1)),
+] + TALL_PANES + [(4000, (512, 832, 5))])
+def test_partition_grid_fits_any_pane(monkeypatch, features, grid):
+    """A pane of any height goes through the Pallas kernel: the grid
+    (partition_grid) cuts what one block cannot hold into row blocks whose
+    priced working set is under the budget, where the eligibility rule
+    used to send the table to the argsort oracle.  88 rows or fewer are
+    one block at the default lane block, the narrow tables' own program."""
     import jax
     from lightgbm_tpu import telemetry
-    from lightgbm_tpu.ops.compact import (PARTITION_VMEM_BUDGET,
+    from lightgbm_tpu.ops.compact import (PARTITION_VMEM_BUDGET, pane_rows,
                                           pallas_partition_ok,
+                                          partition_grid,
                                           partition_vmem_bytes)
-    # the byte estimate is monotone in F and crosses the budget in the
-    # F ≈ 100-200 band PROFILE.md flags
-    assert partition_vmem_bytes(28) < PARTITION_VMEM_BUDGET
-    assert partition_vmem_bytes(200) > PARTITION_VMEM_BUDGET
-    # the gate must hold even where the backend says yes
+    R = pane_rows(features)
+    lanes, rows, count = partition_grid(R)
+    assert (lanes, rows, count) == grid
+    one_block = partition_vmem_bytes(R) <= PARTITION_VMEM_BUDGET
+    assert one_block == (lanes == BLOCK) == (features < 80)
+    assert partition_vmem_bytes(
+        rows, lanes, held=1 if one_block else 3) <= PARTITION_VMEM_BUDGET
+    # whole row blocks of the int8 sublane tile cover the pane, the last
+    # one alone ragged
+    assert (count - 1) * rows < R <= count * rows
+    assert one_block or rows % 32 == 0
+    # the rule asks the backend and the hatch, and no width
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     telemetry.enable()
     try:
-        assert pallas_partition_ok(28) is True
-        assert pallas_partition_ok(200) is False
-        assert telemetry.counters().get(
-            "partition/wide_f_fallback", 0) > 0
-        # F-less callers (back-compat) keep the backend-only rule
         assert pallas_partition_ok() is True
+        assert "partition/wide_f_fallback" not in telemetry.counters()
+        assert "partition/wide_f_fallback" not in telemetry.COUNTER_FAMILIES
+    finally:
+        telemetry.disable()
+
+
+def test_partition_row_blocks_are_counted():
+    """``partition/pallas_rblocks``: the grid's row blocks, once a kernel
+    traced, beside ``partition/pallas``."""
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.ops.compact import pane_rows
+    rng = np.random.RandomState(11)
+    telemetry.enable()
+    try:
+        for features, blocks in ((4, 1), (1000, 2)):
+            before = dict(telemetry.counters())
+            seg, mask3, plcnt = _random_case(rng, pane_rows(features), 2048,
+                                             5, 2000)
+            partition_segment(jnp.asarray(seg), jnp.asarray(mask3),
+                              jnp.int32(5), jnp.int32(2000),
+                              jnp.int32(plcnt), use_pallas=True,
+                              interpret=True)
+            after = telemetry.counters()
+            assert after["partition/pallas"] \
+                - before.get("partition/pallas", 0) == 1
+            assert after["partition/pallas_rblocks"] \
+                - before.get("partition/pallas_rblocks", 0) == blocks
     finally:
         telemetry.disable()
 

@@ -42,8 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from tpu_described import (  # noqa: F401 (fixtures)
-    as_tpu, B, _check, F, _lower_kernel, N, no_persistent_cache, one_chip,
-    _pass_rules, _shape, topo, WIDE_F)
+    as_tpu, B, _check, F, _lower_kernel, _mosaic_kernels, N,
+    no_persistent_cache, one_chip, _pass_rules, _shape, topo, WIDE_F)
 
 
 # ------------------------------------------------------------- kernels
@@ -162,3 +162,28 @@ def test_partition_kernel_compiles(one_chip, as_tpu, overlap):
         block=compact.BLOCK, use_pallas=True, interpret=False,
         overlap=overlap).compile()
     _check(compiled, custom_call=True)
+
+
+@pytest.mark.parametrize("overlap,digest", [
+    (True, "ff9b0059156a6ac7"), (False, "f80c1067571d07f8")])
+def test_narrow_partition_kernel_is_the_program_it_was(one_chip, as_tpu,
+                                                       overlap, digest):
+    """A pane of one block is not on the path of the row-block grid
+    (``compact.partition_grid``): the F=28 kernel lowers, under either DMA
+    schedule, to the Mosaic program that the commit before the grid
+    (2b047c7) lowered it to.  The digests are that commit's, of the
+    kernel's text without locations, taken in this container."""
+    import hashlib
+    from lightgbm_tpu.ops import compact
+    R = compact.pane_rows(F)
+    assert compact.partition_grid(R) == (compact.BLOCK, R, 1)
+
+    def fresh(seg, mask3, delta, cnt, plcnt):
+        return compact._partition_segment_fn(
+            seg, mask3, delta, cnt, plcnt, block=compact.BLOCK,
+            use_pallas=True, interpret=False, overlap=overlap)
+    scalar = _shape(one_chip, (), jnp.int32)
+    (kernel,) = _mosaic_kernels(jax.jit(fresh).lower(
+        _shape(one_chip, (R, N), jnp.int8), _shape(one_chip, (N,), jnp.int8),
+        scalar, scalar, scalar).as_text())
+    assert hashlib.sha256(kernel.encode()).hexdigest()[:16] == digest

@@ -49,7 +49,7 @@ def test_grow_leafcompact_f32_compiles(one_chip, as_tpu):
     partition, Pallas float histogram."""
     from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
     from lightgbm_tpu.ops.compact import pallas_partition_ok
-    assert pallas_partition_ok(F)
+    assert pallas_partition_ok()
     compiled = grow_tree_leafcompact.lower(
         *_grow_args(one_chip), use_pallas_partition=True,
         partition_overlap=True, **_GROW_KW).compile()

@@ -56,3 +56,61 @@ def test_fused_chunk_program_compiles_on_the_wide_table(
         is_eval=False)
     args = _like(one_chip, seen, rows_from=n_tiny, rows_to=WIDE_N)
     _cell_size(_check(prog.lower(*args).compile(), custom_call=True))
+
+
+# ------------------------------------- best-first growth (epsilon-leafwise-f32)
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_partition_kernel_compiles_on_the_wide_table(one_chip, as_tpu,
+                                                     overlap):
+    """The root's partition over 2,000 columns: a pane of 2,016 rows in
+    three row blocks of 672 at 512 lanes, the one-hots held in VMEM for
+    the row blocks of a lane block (``compact.partition_grid``).  One block
+    of that pane is priced at 91 MiB."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import compact
+    from tpu_described import _shape
+    R = compact.pane_rows(WIDE_F)
+    assert compact.partition_vmem_bytes(R) > 90 << 20
+    assert compact.partition_grid(R) == (512, 672, 3)
+    fn = jax.jit(compact._partition_segment_fn,
+                 static_argnames=("block", "use_pallas", "interpret",
+                                  "overlap"))
+    scalar = _shape(one_chip, (), jnp.int32)
+    compiled = fn.lower(
+        _shape(one_chip, (R, WIDE_N_PADDED), jnp.int8),
+        _shape(one_chip, (WIDE_N_PADDED,), jnp.int8),
+        scalar, scalar, scalar,
+        block=compact.BLOCK, use_pallas=True, interpret=False,
+        overlap=overlap).compile()
+    _check(compiled, custom_call=True)
+
+
+def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
+    """One tree of the cell ``epsilon-leafwise-f32.train``: the compacted
+    grower, the row-blocked partition kernel at each of the nine bucket
+    widths, the float histogram kernel on the feature-block grid.  And
+    what the compiled program must keep: the hi half of the float32
+    gradient pair is a rounding XLA does not take for the identity (so the
+    lo half carries something), and a split writes the pane and the leaf
+    histogram cache in place (under ``lax.cond`` / ``lax.switch`` each was
+    copied whole, twice a split)."""
+    import re
+    from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
+    kw = dict(_GROW_KW, min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0)
+    compiled = grow_tree_leafcompact.lower(
+        *_grow_args(one_chip, WIDE_N, WIDE_F), use_pallas_partition=True,
+        partition_overlap=True, **kw).compile()
+    _cell_size(_check(compiled, custom_call=True))
+    text = compiled.as_text()
+    rounded = re.findall(r"(%[\w.\-]+) = f32\[[^ ]* reduce-precision\("
+                         r"(%[\w.\-]+)\), exponent_bits=8, mantissa_bits=7",
+                         text)
+    assert rounded
+    # the lo half: x - hi(x), in the same fusion
+    assert any(re.search(r"subtract\(%s, %s\)" % (re.escape(x),
+                                                  re.escape(hi)), text)
+               for hi, x in rounded)
+    assert not re.search(r"= s8\[2016,401408\][^ ]* copy\(", text)
+    assert not re.search(r"= f32\[255,2000,255,3\][^ ]* copy\(", text)

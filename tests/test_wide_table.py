@@ -12,17 +12,12 @@ cell, ``epsilon-levelwise-int8.train``, while the reference's answer in
 int4 does not.  The cell's own size (400,000 x 2,000) runs on the chip;
 ``tests/test_tpu_compile.py`` compiles it.
 """
-import argparse
-import os
-import sys
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "epsilon-levelwise-int8.train"
 # what the three assertions on the program need and no more: columns past
 # one feature block at either lane width (104: three blocks of 40 at 128
@@ -123,50 +118,9 @@ def test_feature_blocks_fit_the_scoped_vmem():
 @pytest.fixture(scope="module")
 def wide_run():
     """One run of the benchmark's own harness on the wide cell cut to
-    2,048 rows and 104 columns: its traffic kind builds the booster as the
-    CLI does, drives ``run_training`` in slices of 8 and hands the trees,
-    the scores and the binned table to the reference.  The histogram
-    routing is steered onto its TPU branch, the Pallas kernel run by the
-    interpreter; the registry is on so that the route can be read back."""
-    from jax.experimental.pallas import tpu as pltpu
-    from lightgbm_tpu import telemetry
-    from lightgbm_tpu.utils import log
-    added = [p for p in (os.path.join(ROOT, "benchmarks"), ROOT)
-             if p not in sys.path]
-    sys.path[:0] = added
-    import run as runner
-    real_load = runner.load_json
-
-    def cut(*parts):
-        loaded = real_load(*parts)
-        if parts[0] == "configs":
-            loaded = dict(loaded, features=COLUMNS)
-        elif parts[0] == "cells":
-            # the first slice compiles inside the window; nothing judged
-            # reads the clock
-            loaded = dict(loaded, params=dict(loaded["params"],
-                                              warmup_slices=0))
-        return loaded
-    args = argparse.Namespace(workload=CELL, seed=3000000019, seconds=0.5,
-                              trace=0, rows=ROWS, control=1)
-    mp = pytest.MonkeyPatch()
-    mp.setattr(runner, "load_json", cut)
-    mp.setattr(jax, "default_backend", lambda: "tpu")
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        with pltpu.force_tpu_interpret_mode():
-            line, code = runner.execute(args, require_chip=False)
-        counters = dict(telemetry.snapshot()["counters"])
-    finally:
-        telemetry.disable()
-        telemetry.reset()
-        mp.undo()
-        log.set_stream(None)        # the runner sends the log to stderr
-        for p in added:
-            sys.path.remove(p)
-    assert code == 0
-    return line, counters
+    2,048 rows and 104 columns (``bench_cut.run_cut_cell``)."""
+    from bench_cut import run_cut_cell
+    return run_cut_cell(CELL, 3000000019, ROWS, COLUMNS)
 
 
 def test_wide_program_took_the_feature_block_grid(wide_run):

@@ -148,6 +148,29 @@ def _lower_route_kernel(one_chip, features, rows, slots):
         *per_slot)
 
 
+def _mosaic_kernels(lowered_text):
+    """Every Pallas kernel of a lowered program as Mosaic MLIR text
+    without locations.  The lowered text carries each kernel serialized
+    with the file and line of every frame of its call stack, so an edit
+    anywhere above a kernel in its file changes that text and not the
+    kernel; this is what stays the same when the kernel does."""
+    import base64
+    import re
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    kernels = []
+    for found in re.finditer(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                             lowered_text):
+        context = ir.Context()
+        tpu.register_dialect(context)
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(found.group(1)))
+            kernels.append(module.operation.get_asm(
+                enable_debug_info=False))
+    return kernels
+
+
 class _Captured(Exception):
     pass
 
