@@ -26,8 +26,14 @@ peak and cannot use the int8 MXU path.  This kernel owns the schedule:
   the few live value rows, so the VPU builds ceil(B / k) + k * 3 * cols
   operand rows per feature instead of B (56 instead of 256 at the root)
   and the MXU contracts that much less.  Building those rows, not the
-  matmul, is what a pass of up to 128 lanes costs; the int32 sums land in
-  the same cells, so the histograms are bit-identical at every fold;
+  matmul, is what an integer pass of up to 128 lanes costs; the int32
+  sums land in the same cells, so the histograms are bit-identical at
+  every fold.  The float mode folds by the same rule (PR 35): its
+  unfolded one-column pass was MXU-bound on 256 one-hot rows against 128
+  value lanes of which 5 carried sums; folded, every cell's addends meet
+  at the same places of the same contraction, every other term an exact
+  zero, and the f32 sums are the unfolded pass's bit for bit, on the
+  CPU's interpreter and on a v5e alike (PERF.md section 6, PR 35);
 - ``dtype="int8"`` is the quantized-gradient variant: stochastically /
   nearest-rounded int8 grad/hess, int8xint8->int32 MXU at 2x the bf16
   rate, exact int32 counts — modern LightGBM's quantized-training idea
@@ -51,7 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 # default value-operand width, one MXU tile: 42 leaf columns x 3 stats + 2.
 # With the one-hot streamed a pass pays for B one-hot rows whatever part
 # of the 128 lanes is live; hist_fold moves bin bits into the idle part
-# when 16 columns or fewer are, held_onehot streams the live part alone.
+# when 16 columns or fewer are (9 of the float32 pair's five statistics),
+# held_onehot streams the live part of an integer pass alone.
 LANES = 128
 
 
@@ -152,7 +159,8 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
                         dtype: str = "int8", lanes: int = LANES,
                         stats: int = 3, fold: int = 1, gw: int = None,
                         held: int = 0, skip_dead: bool = False):
-    """[F, B, lanes] accumulator from [F, N] bins and packed values.
+    """[F, B, lanes] accumulator from [F, N] bins and packed values
+    ([F, B, gw] from a folded float pass).
 
     Rows must be pre-padded to a multiple of ``chunk`` (pad cid with -1).
     packed is [stats + 1, N]: ``stats`` values per leaf column followed by
@@ -178,9 +186,14 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     round: ``held`` value rows stream against the one-hot, held as whole
     128-wide tiles, into a [F, held, B up to whole tiles] accumulator that
     is transposed back and zero-padded.  Either way the result is the same
-    [F, B, lanes] array, bit for bit, for the integer-level modes ("bf16v"
-    neither folds nor turns round); ``fold=1, held=0`` is the kernel as it
-    always was.  ``skip_dead`` passes over a chunk with no live row (cid
+    [F, B, lanes] array, bit for bit, for the integer-level modes;
+    ``fold=1, held=0`` is the kernel as it always was.  "bf16v" folds and
+    does not turn round, and a folded "bf16v" pass hands back the unfolded
+    [F, B, gw] view, the live columns with no pad behind them (its one
+    consumer, ``_hist_float_one``, takes the leading stats * columns of
+    either shape): the same f32 sums bit for bit, measured on a v5e
+    (``hist_fold``).
+    ``skip_dead`` passes over a chunk with no live row (cid
     -1 throughout): the caller's to ask for, where its rows end in such
     chunks (the compacted grower's bucketed ranges); the same sums.
 
@@ -216,7 +229,7 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
         Bk = B
         out_block = (fb, B, lanes)
     else:
-        assert dtype != "bf16v" and fold & (fold - 1) == 0
+        assert fold & (fold - 1) == 0
         assert stats <= gw and fold * gw <= lanes
         Bh = -(-B // fold)
         Bk = Bh * fold
@@ -240,20 +253,24 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
             dimension_semantics=("arbitrary", "arbitrary")),
     )(bins, packed)
     if held or fold > 1:
-        # the narrow accumulator back to [F, B, lanes]: the transpose of
-        # the held one-hot's [held, B], or the unfold, cell
-        # (hi, lo * gw + jj) -> (hi * fold + lo, jj); value columns
-        # zero-padded back to ``lanes``.  The layout constraint hands the
-        # consumers what the plain kernel's custom call would, a row-major
-        # array: without it XLA lays the float histograms out after the
-        # narrow accumulator and the split search's sums round in another
-        # order (same ints, trees that differ in the last place of a
-        # gain).  The pad itself fuses away
+        # the transpose of the held one-hot's [held, B], or the unfold,
+        # cell (hi, lo * gw + jj) -> (hi * fold + lo, jj)
         out = (jnp.swapaxes(out, 1, 2) if held
                else out.reshape(-1, Bh * fold, gw))[:, :B]
-        out = with_layout_constraint(
-            jnp.pad(out, ((0, 0), (0, 0), (0, lanes - out.shape[2]))),
-            Layout(major_to_minor=(0, 1, 2)))
+        if dtype != "bf16v":
+            # value columns zero-padded back to ``lanes``.  The layout
+            # constraint hands the integer consumers what the plain
+            # kernel's custom call would, a row-major array: without it
+            # XLA lays the float histograms out after the narrow
+            # accumulator and the split search's sums round in another
+            # order (same ints, trees that differ in the last place of a
+            # gain).  The pad itself fuses away.  The float consumer
+            # takes the ``gw`` columns as they are: padded and pinned
+            # they were 264 MB a pass at 2,000 features for XLA to pick
+            # five lanes out of (PERF.md section 6, PR 35)
+            out = with_layout_constraint(
+                jnp.pad(out, ((0, 0), (0, 0), (0, lanes - out.shape[2]))),
+                Layout(major_to_minor=(0, 1, 2)))
     out = out[:F]
     if dtype in ("int8", "bf16v"):
         return out                       # int32 / f32 accumulator as-is
@@ -325,9 +342,12 @@ def held_onehot(stats: int, num_cols: int, B: int, lanes: int,
     which the VPU's build of the one-hot bounds, 57.3 -> 53.6; 21 columns
     (64 rows) 57.3 -> 50.0; 42 columns (128 rows against two tiles) 57.3
     -> 57.6 had it turned, the 64-bin class 29.8 -> 41.7.  Only the
-    integer-level modes, whose sums are exact and order-free: "bf16v"
-    (float gradients) keeps its summation shape."""
-    if dtype == "bf16v" or hist_fold(stats, num_cols, B, lanes, dtype)[0] > 1:
+    integer-level modes, whose sums are exact and order-free: turned
+    round, a "bf16v" cell's addends would meet in another operand's
+    order, which nobody has held against the streamed one on a chip
+    (PERF.md section 7, PR 31 (d)); its passes fold or stay as they
+    were."""
+    if dtype == "bf16v" or hist_fold(stats, num_cols, B, lanes)[0] > 1:
         return 0
     rows = stats * num_cols + (-stats * num_cols) % 32
     if rows * -(-B // LANES) < (B + (-B) % 32) * -(-lanes // LANES):
@@ -400,7 +420,7 @@ def fold_options(stats: int, num_cols: int, B: int, lanes: int):
             yield fold, gw, hi_rows + fold * gw
 
 
-def hist_fold(stats: int, num_cols: int, B: int, lanes: int, dtype: str):
+def hist_fold(stats: int, num_cols: int, B: int, lanes: int):
     """(fold, gw) of one histogram pass, from its static shapes.
 
     A pass with few leaf columns leaves most of the value operand's lanes
@@ -412,10 +432,22 @@ def hist_fold(stats: int, num_cols: int, B: int, lanes: int, dtype: str):
     feature where fold 1, the unfolded kernel, builds B.  This picks the
     fold with the fewest rows (the larger on a tie: a smaller
     accumulator) if it saves an eighth of them or more, else fold 1.
-    Only the modes whose accumulation is exact and order-free fold:
-    "bf16v" (float gradients) keeps its summation shape."""
+    Every mode folds alike: the integer modes' sums are exact and
+    order-free, and the float mode's ("bf16v": 3 statistics a column, or
+    the float32 pair's 5, whose one-column pass folds by 8 into a
+    [32, 40] accumulator a feature where it filled 5 lanes of
+    [256, 128]) keep their order, because cell
+    (hi, lo * gw + jj) of the folded product receives the products that
+    cell (hi * fold + lo, jj) of the unfolded one receives, at the same
+    positions of the same chunk-long contraction, every other term an
+    exact zero, and chunks are added in the same grid order.  So it
+    was on a v5e: folded and unfolded accumulators equal cell for cell
+    at [28, 10,502,144] and [2000, 401,408], one column of five
+    statistics, and at one, two and four columns of three and of five
+    (``scripts/hist_kernel_bench.py --float-fold``; PERF.md section 6,
+    PR 35), 100.6 -> 14.8 and 276.0 -> 39.6 ms a pass."""
     best, best_rows = (1, None), B - B // 8
-    if dtype != "bf16v" and lanes == LANES:
+    if lanes == LANES:
         for fold, gw, rows in fold_options(stats, num_cols, B, lanes):
             if rows <= best_rows:
                 best, best_rows = (fold, gw), rows
@@ -641,7 +673,7 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
     from .. import telemetry
 
     def launch(rows, width):
-        fold, gw = hist_fold(3, num_cols, width, lanes, dtype)
+        fold, gw = hist_fold(3, num_cols, width, lanes)
         held = held_onehot(3, num_cols, width, lanes, dtype)
         # counted per pass, here: two passes of one shape share one trace
         # of the jitted kernel, so its own counters see them once
@@ -716,6 +748,11 @@ def hist_pallas_float_leafbatch(bins, grad, hess, col_id, col_ok,
       accumulate identical per-lane f32 partial sums, so the choice is
       bit-invisible; "f32x1"/"f32x2" force one variant (A/B tests).
 
+    A 128-lane pass of few columns folds the bin code into its idle
+    lanes like the integer kernels' (``hist_fold``: up to 16 columns of
+    three statistics, 9 of five; the leaf-wise growers' one-column pass
+    by 8), the same f32 sums bit for bit.
+
     Counts are exact in every mode: ok rides as 1.0 (bf16-exact) and the
     lo lanes carry zeros.  ``skip_dead``: see ``_hist_pallas_raw_fn``.
     """
@@ -782,12 +819,15 @@ def _hist_float_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         if pad:
             packed = jnp.pad(packed, ((0, 0), (0, pad)),
                              constant_values=-1)
+        fold, gw = hist_fold(len(vals), num_cols, B, lanes)
         # counted per pass, as the int launch counts its own
+        telemetry.count("hist/pallas_fold_" + str(fold))
         telemetry.count("hist/pallas_fblocks",
                         feature_grid(F, B, lanes, chunk)[1])
         return hist_pallas_raw(bins8, packed, B=B, chunk=chunk,
                                dtype="bf16v", lanes=lanes,
-                               stats=len(vals), skip_dead=skip_dead)
+                               stats=len(vals), fold=fold, gw=gw,
+                               skip_dead=skip_dead)  # [F, B, gw | lanes]
 
     lanes3 = LANES if num_cols <= 42 else 192
     if precision == "bf16":

@@ -136,7 +136,7 @@ def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
     cells = float(B) * lanes                 # accumulator cells a feature
     if int_levels and groups == 1:
         from .hist_pallas import held_onehot, hist_fold
-        fold, gw = hist_fold(3, num_cols, B, int(lanes), "int8")
+        fold, gw = hist_fold(3, num_cols, B, int(lanes))
         held = held_onehot(3, num_cols, B, int(lanes), "int8")
         if fold > 1:
             cells = float(-(-B // fold)) * fold * gw

@@ -14,6 +14,13 @@ same leaf-batched pass at B=63, B=255, and the MIXED per-class schedule
 (narrow features at 64 bins + wide at 255 via a PackSpec) so the packing
 threshold (io/binning.NARROW_BINS) can be re-derived from measurement when
 kernel economics change, instead of folklore.
+
+``--float-fold`` (PR 35) times the raw float kernel ("bf16v", the float32
+pair's five statistics of ONE leaf column: the leaf-wise pass) unfolded
+against the fold ``hist_fold`` picks, at the benchmark's two tables,
+[28, 10,502,144] and [2000, 401,408], and says whether the two
+accumulators are bit-equal on this device (and at one, two and four
+columns of three and five statistics at [28, 1,048,576]).
 """
 from __future__ import annotations
 
@@ -47,6 +54,10 @@ def main():
     p.add_argument("--narrow-frac", type=float, default=6 / 7,
                    help="fraction of features in the narrow class for "
                         "the mixed lane of --sweep-classes")
+    p.add_argument("--float-fold", action="store_true",
+                   help="the float kernel's one-column pass unfolded "
+                        "against its bin fold, at both benchmark tables, "
+                        "and whether the accumulators are bit-equal")
     args = p.parse_args()
 
     rng = np.random.RandomState(0)
@@ -54,6 +65,8 @@ def main():
 
     if args.sweep_classes:
         return sweep_classes(args, rng)
+    if args.float_fold:
+        return float_fold(args, rng)
     bins = jnp.asarray(rng.randint(0, B, size=(F, N), dtype=np.int32)
                        .astype(np.int8))
     grad = jnp.asarray(rng.randn(N).astype(np.float32) * 0.3)
@@ -125,6 +138,58 @@ def sweep_classes(args, rng):
               f"{t*1e3:8.2f} ms/pass  ({gbps:6.1f} GB/s effective)")
     print(f"mixed vs b255 speedup: {results['b255'] / results['mixed']:.2f}x"
           f"  (b63 bound: {results['b255'] / results['b63']:.2f}x)")
+    return 0
+
+
+def float_fold(args, rng):
+    """The raw "bf16v" kernel at fold 1 and at the rule's fold: ms a pass
+    where timed, and the folded accumulator against the unfolded one's
+    live lanes, cell for cell."""
+    from lightgbm_tpu.ops.hist_pallas import hist_fold, hist_pallas_raw
+    B, chunk = 255, args.pallas_chunk           # the cells' bins
+
+    def table(F, N, stats, cols):
+        bins = jnp.asarray(rng.randint(0, B, size=(F, N), dtype=np.uint8)
+                           .view(np.int8))
+        cid = np.where(rng.rand(N) < 0.9, rng.randint(0, cols, N), -1)
+        vals = rng.randn(stats, N).astype(np.float32) * 0.3 * (cid >= 0)
+        vals[stats - 1] = cid >= 0              # the count row
+        packed = jnp.asarray(np.concatenate([vals, cid[None]]),
+                             jnp.bfloat16)
+        return bins, packed
+
+    def compare(F, N, stats, cols, timed):
+        bins, packed = table(F, N, stats, cols)
+        fold, gw = hist_fold(stats, cols, B, 128)
+        runs = {}
+        for k, g in ((1, None), (fold, gw)):
+            # the table an argument and not a constant of the timed
+            # program, which would carry its 800 MB
+            op = lambda p, b, k=k, g=g: hist_pallas_raw(
+                b, p, B=B, chunk=chunk, dtype="bf16v", lanes=128,
+                stats=stats, fold=k, gw=g)
+            ms = (device_time(op, packed, bins, key_arg=0, reps=(2, 6))
+                  * 1e3 if timed else float("nan"))
+            runs[k] = (ms, np.asarray(op(packed, bins)))
+        (ms1, plain), (msk, folded) = runs[1], runs[fold]
+        live = stats * cols
+        equal = (np.array_equal(plain[:, :, :live], folded[:, :, :live])
+                 and not plain[:, :, live:].any()
+                 and not folded[:, :, live:].any())
+        gap = np.abs(plain[:, :, :live] - folded[:, :, :live]).max()
+        print(f"bf16v [{F}, {N}] stats={stats} cols={cols} B={B}: "
+              f"fold 1 {ms1:8.2f} ms {plain.shape}, fold {fold} gw {gw} "
+              f"{msk:8.2f} ms {folded.shape}, "
+              f"{'BIT-EQUAL' if equal else 'NOT EQUAL, max gap %g' % gap}",
+              flush=True)
+        return equal
+
+    ok = [compare(F, N, 5, 1, timed=True)
+          for F, N in ((28, 10_502_144), (2000, 401_408))]
+    ok += [compare(28, 1 << 20, stats, cols, timed=False)
+           for stats in (3, 5) for cols in (1, 2, 4)]
+    print("bf16v folded == unfolded on %s: %s" % (
+        jax.devices()[0].device_kind, all(ok)))
     return 0
 
 

@@ -161,6 +161,96 @@ def test_wide_dataset_feature_grid():
                                rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("skip_dead", [False, True])
+@pytest.mark.parametrize("B", [255, 256])
+@pytest.mark.parametrize("precision", ["bf16", "f32x1"])
+@pytest.mark.parametrize("num_cols", [1, 2, 4])
+def test_folded_float_pass_equals_the_unfolded(monkeypatch, num_cols,
+                                               precision, B, skip_dead):
+    """The float mode under the bin fold (``hist_fold``: three statistics
+    a column at fold 8, 8, 4 for 1, 2, 4 columns, the float32 pair's five
+    at 8, 4, 4) against the same pass with fold 1 forced: uint8 codes
+    >= 128, a ragged last chunk, masked-out rows and, for ``skip_dead``, a
+    tail of chunks with no live row.  Counts are exactly the oracle's and
+    sums within the tolerance this file holds the unfolded kernel to.
+    Under the interpreter the two are bit-equal as well: every cell's
+    non-zero addends meet at the same places of the same contraction
+    (whether the chip's MXU agrees is a chip run's to say: PERF.md
+    section 6, PR 35)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.ops import hist_pallas
+    rng = np.random.RandomState(1000 * num_cols + B + skip_dead)
+    F, N, chunk = 3, 3500, 1024
+    bins = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
+    grad = jnp.asarray((rng.randn(N) * 0.4).astype(np.float32))
+    hess = jnp.asarray((rng.rand(N) * 0.25).astype(np.float32))
+    cid = jnp.asarray(rng.randint(0, num_cols, N).astype(np.int32))
+    # the bucketed range of the compacted grower: its rows in front, a
+    # dead tail behind (the whole last chunk and more)
+    live = N - 1500 if skip_dead else N
+    ok = jnp.asarray((rng.rand(N) < 0.85) & (np.arange(N) < live))
+    stats = 3 if precision == "bf16" else 5
+    fold = hist_pallas.hist_fold(stats, num_cols, B, 128)[0]
+    assert fold == {(3, 1): 8, (3, 2): 8, (3, 4): 4,
+                    (5, 1): 8, (5, 2): 4, (5, 4): 4}[stats, num_cols]
+
+    def run():
+        telemetry.reset()
+        telemetry.enable()
+        try:
+            with pltpu.force_tpu_interpret_mode():
+                got = np.asarray(hist_pallas.hist_pallas_float_leafbatch(
+                    bins, grad, hess, cid, ok, num_cols, B, chunk=chunk,
+                    precision=precision, skip_dead=skip_dead))
+            counters = telemetry.snapshot()["counters"]
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+        return got, {k: v for k, v in counters.items()
+                     if k.startswith("hist/pallas_fold_")}
+    folded, counted = run()
+    assert counted == {"hist/pallas_fold_%d" % fold: 1}
+    monkeypatch.setattr(hist_pallas, "hist_fold", lambda *a: (1, None))
+    unfolded, counted = run()
+    assert counted == {"hist/pallas_fold_1": 1}
+    assert folded.shape == (num_cols, F, B, 3)
+    np.testing.assert_array_equal(folded, unfolded)
+    if precision == "bf16":
+        g = grad.astype(jnp.bfloat16).astype(jnp.float32)
+        h = hess.astype(jnp.bfloat16).astype(jnp.float32)
+        tol = dict(rtol=1e-5, atol=1e-4)
+    else:
+        g, h, tol = grad, hess, dict(rtol=1e-4, atol=1e-3)
+    want = np.asarray(histogram_leafbatch_segsum(bins, g, h, cid, ok,
+                                                 num_cols, B))
+    np.testing.assert_array_equal(folded[..., 2], want[..., 2])
+    np.testing.assert_allclose(folded, want, **tol)
+
+
+@pytest.mark.parametrize("num_cols", [1, 2, 4])
+def test_f32x1_bit_identical_to_f32x2_folded(num_cols):
+    """The claim below where the passes fold (255 bins): one column folds
+    both packings by 8, two and four columns fold the five statistics by 4
+    and the three by 8 and 4.  The interpreter's dot gives a cell the same
+    sum at either fold, so the packings stay bit-equal here whatever their
+    folds; on the chip that rests on the folded accumulator equalling the
+    unfolded one (PERF.md section 6, PR 35)."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.RandomState(29 + num_cols)
+    F, N, B = 3, 3000, 255
+    bins = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
+    grad = jnp.asarray((rng.randn(N) * 0.4).astype(np.float32))
+    hess = jnp.asarray((rng.rand(N) * 0.25).astype(np.float32))
+    cid = jnp.asarray(rng.randint(0, num_cols, N).astype(np.int32))
+    ok = jnp.asarray(rng.rand(N) < 0.85)
+    with pltpu.force_tpu_interpret_mode():
+        one, two = (np.asarray(hist_pallas_float_leafbatch(
+            bins, grad, hess, cid, ok, num_cols, B, chunk=1024,
+            precision=precision)) for precision in ("f32x1", "f32x2"))
+    np.testing.assert_array_equal(one, two)
+
+
 def test_f32x1_bit_identical_to_f32x2(hist_inputs):
     """The single-pass 5-stat packing accumulates the same per-lane f32
     partial sums as the two-pass variant — outputs must be bit-equal

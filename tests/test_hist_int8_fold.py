@@ -1,8 +1,10 @@
-"""The int histogram kernel's bin fold (``ops/hist_pallas.hist_fold``): on
+"""The histogram kernel's bin fold (``ops/hist_pallas.hist_fold``): on
 the narrow levels the low bits of the bin code move into the idle value
-rows.  The folded pass equals the unfolded one and the XLA oracle bit for
-bit at every fold its layout allows; the rule's table; the counters of a
-traced tree.  Moved whole out of ``tests/test_hist_int8.py``.
+rows.  The folded integer pass equals the unfolded one and the XLA oracle
+bit for bit at every fold its layout allows; the rule's table, the float
+mode's ("bf16v", three and five statistics a column) beside the integer
+modes'; the counters of a traced tree.  The folded float pass against the
+unfolded one: ``tests/test_hist_float_pallas.py``.
 """
 import numpy as np
 import jax
@@ -76,22 +78,33 @@ def test_bin_fold_rule_and_counters(monkeypatch):
     the hist/pallas_fold_<k> counters of one traced level-wise tree."""
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
-    from lightgbm_tpu.ops.hist_pallas import hist_fold
+    from lightgbm_tpu.ops.hist_pallas import fold_options, hist_fold
     table = {1: (8, 3), 2: (8, 6), 3: (8, 9), 4: (4, 12), 5: (4, 16),
              8: (4, 24), 10: (4, 30), 11: (2, 36), 16: (2, 48),
              17: (1, None), 21: (1, None), 32: (1, None), 42: (1, None)}
+    # three statistics a column: the integer modes, and float gradients
+    # ("bf16v") riding single bf16 operands
     for num_cols, want in table.items():
         for B in (255, 256):
-            for dtype in ("int8", "bf16"):
-                assert hist_fold(3, num_cols, B, 128, dtype) == want, (
-                    num_cols, B, dtype)
-        # float gradients keep their summation shape
-        assert hist_fold(3, num_cols, 256, 128, "bf16v") == (1, None)
-        assert hist_fold(5, num_cols, 256, 128, "bf16v") == (1, None)
-    assert hist_fold(3, 43, 256, 192, "int8") == (1, None)
+            assert hist_fold(3, num_cols, B, 128) == want, (num_cols, B)
+    # the float32 pair's five statistics a column (g_hi, g_lo, h_hi, h_lo,
+    # count), written out from fold_options: the leaf-wise pass of one
+    # column builds 32 + 8 * 5 = 72 operand rows a feature where it built
+    # 256, two columns 64 + 4 * 10 = 104; nine columns (gw 48) are the
+    # last that save an eighth, and 192 lanes never fold
+    rows5 = {(c, fold): rows for c in (1, 2)
+             for fold, _gw, rows in fold_options(5, c, 256, 128)}
+    assert (rows5[1, 8], rows5[2, 4]) == (72, 104)
+    table5 = {1: (8, 5), 2: (4, 10), 3: (4, 16), 4: (4, 20), 5: (4, 26),
+              8: (2, 40), 9: (2, 48), 10: (1, None), 25: (1, None)}
+    for num_cols, want in table5.items():
+        for B in (255, 256):
+            assert hist_fold(5, num_cols, B, 128) == want, (num_cols, B)
+    assert hist_fold(3, 43, 256, 192) == (1, None)
+    assert hist_fold(5, 26, 256, 192) == (1, None)
     # a 64-bin class of the mixed-bin layout: the 32-row floor on the
     # one-hot holds it to fold 2, and only while that saves an eighth
-    assert [hist_fold(3, c, 64, 128, "int8") for c in (1, 4, 5)] == [
+    assert [hist_fold(3, c, 64, 128) for c in (1, 4, 5)] == [
         (2, 4), (2, 12), (1, None)]
 
     # one 255-leaf level-wise tree traced on the TPU route (shapes no other

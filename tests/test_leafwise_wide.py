@@ -107,30 +107,57 @@ def _table(rows, columns, num_bin, seed):
     return bins, (p - y).astype(np.float32), (p * (1 - p)).astype(np.float32)
 
 
-@pytest.mark.parametrize("rows,columns,leaves,overlap,grid", [
+@pytest.mark.parametrize("rows,columns,leaves,overlap,grid,num_bin", [
     # the width tests/test_wide_table.py drives the harness at: past the
     # one block of the narrow tables' kernel, one block of the row-blocked
-    (2048, 104, 63, True, (512, 128, 1)),
+    (2048, 104, 63, True, (512, 128, 1), 64),
     # past one row block: two of 512 rows, the last one ragged, under
     # either schedule
-    (1024, 1000, 15, True, (512, 512, 2)),
-    (1024, 1000, 15, False, (512, 512, 2)),
+    (1024, 1000, 15, True, (512, 512, 2), 64),
+    (1024, 1000, 15, False, (512, 512, 2), 64),
+    # the cell's 255 bins with the histograms the float kernel's, run by
+    # the interpreter as a TPU would route them: five statistics of one
+    # leaf column a pass, the bin code folded by 8 (``hist_fold``), dead
+    # chunks skipped, three feature blocks of 40
+    (2048, 104, 63, True, (512, 128, 1), 255),
 ])
-def test_compact_grower_grows_the_plain_growers_tree(rows, columns, leaves,
-                                                     overlap, grid):
+def test_compact_grower_grows_the_plain_growers_tree(
+        monkeypatch, rows, columns, leaves, overlap, grid, num_bin):
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu import telemetry
     from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
     from lightgbm_tpu.ops.compact import pane_rows, partition_grid
     assert partition_grid(pane_rows(columns)) == grid
-    num_bin, min_data, min_hess = 64, 4, 1.0
+    min_data, min_hess = 4, 1.0
     bins, grad, hess = _table(rows, columns, num_bin, seed=rows + columns)
-    tree = grow_tree_leafcompact(
-        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
-        jnp.ones(rows, bool), jnp.ones(columns, bool),
-        jnp.full(columns, num_bin, jnp.int32), num_leaves=leaves,
-        num_bins_max=num_bin, min_data_in_leaf=min_data,
-        min_sum_hessian_in_leaf=min_hess, compute_dtype=jnp.float32,
-        use_pallas_partition=True, partition_overlap=overlap,
-        interpret=True)
+
+    def grow():
+        return grow_tree_leafcompact(
+            jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.ones(rows, bool), jnp.ones(columns, bool),
+            jnp.full(columns, num_bin, jnp.int32), num_leaves=leaves,
+            num_bins_max=num_bin, min_data_in_leaf=min_data,
+            min_sum_hessian_in_leaf=min_hess, compute_dtype=jnp.float32,
+            use_pallas_partition=True, partition_overlap=overlap,
+            interpret=True)
+    if num_bin == 255:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        telemetry.reset()
+        telemetry.enable(fence=True)
+        try:
+            with pltpu.force_tpu_interpret_mode():
+                tree = jax.block_until_ready(grow())
+            counters = telemetry.snapshot()["counters"]
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+        # the root's pass and one a bucket width, every one folded
+        assert counters["hist/pallas_f32"] == counters[
+            "hist/pallas_fold_8"] >= 2
+        assert "hist/pallas_fold_1" not in counters
+        assert "hist/xla_einsum" not in counters
+    else:
+        tree = grow()
     splits, values, counts = plain_best_first(
         bins, grad, hess, num_bin, leaves, min_data, min_hess)
     assert len(splits) == leaves - 1 == int(tree.num_leaves) - 1
@@ -183,6 +210,10 @@ def test_leafwise_program_took_the_kernels(leafwise_run):
     assert "partition/wide_f_fallback" not in counters
     assert counters["hist/pallas_f32"] >= 2
     assert "hist/xla_einsum" not in counters
+    # every float pass (one leaf column of five statistics, 255 bins)
+    # folds the bin code by 8
+    assert counters["hist/pallas_fold_8"] == counters["hist/pallas_f32"]
+    assert "hist/pallas_fold_1" not in counters
 
 
 def test_leafwise_program_is_correct_by_the_cells_limits(leafwise_run):

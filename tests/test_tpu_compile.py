@@ -50,7 +50,11 @@ from tpu_described import (  # noqa: F401 (fixtures)
 
 @pytest.mark.parametrize("dtype,lanes,stats,num_cols", [
     ("int8", 128, 3, 32), ("int8", 192, 3, 64),
-    ("bf16v", 128, 3, 1), ("bf16v", 192, 5, 38),
+    # float gradients: one column folds by 8 like the integer modes, three
+    # statistics and the float32 pair's five (the leaf-wise cell's pass,
+    # a [32, 40] accumulator a feature); 38 columns of five at 192 lanes
+    # keep the one-hot streamed and unfolded
+    ("bf16v", 128, 3, 1), ("bf16v", 128, 5, 1), ("bf16v", 192, 5, 38),
     # the bin fold's shapes at the cell's levels (hist_fold: fold 8, 8,
     # 4, 4, 2 for 1, 2, 4, 8, 16 leaf columns), value blocks of 24 to 96
     # rows against one-hots of 32 to 128
@@ -64,7 +68,7 @@ def test_hist_kernel_compiles(one_chip, as_tpu, dtype, lanes, stats,
     from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn
     packed_dtype = jnp.bfloat16 if dtype == "bf16v" else jnp.int8
     fold, gw, held = _pass_rules(dtype, lanes, stats, num_cols)
-    assert (fold > 1) == (dtype != "bf16v" and num_cols <= 16)
+    assert (fold > 1) == (lanes == 128 and num_cols <= 16)
     # the integer modes' unfolded passes hold the one-hot and stream the
     # live value rows, 96 of 128 lanes and all 192; nothing else does
     assert held == (0 if dtype == "bf16v" or fold > 1 else 3 * num_cols)
@@ -90,6 +94,10 @@ def test_hist_kernel_compiles(one_chip, as_tpu, dtype, lanes, stats,
     # [256, 192 -> 256] cells a feature, 24 a block (the compiler refused
     # 32 of those: 16.12 MiB of windows)
     ("bf16v", 192, 5, 38, (24, 84)),
+    # the leaf-wise cell's pass, one column of five statistics folded by
+    # 8: the unfolded pass's block (the fold's narrower accumulator is
+    # not counted), 42 blocks of 48
+    ("bf16v", 128, 5, 1, (48, 42)),
 ])
 def test_hist_kernel_compiles_on_the_feature_block_grid(
         one_chip, as_tpu, dtype, lanes, stats, num_cols, grid):
@@ -110,13 +118,14 @@ def test_narrow_kernels_lower_as_before_the_feature_block_repair(
     of this file lowers to the same text.  And the held one-hot
     (``held_onehot``) is the integer modes' unfolded passes and no other:
     with the rule switched off every folded kernel and every "bf16v"
-    kernel lowers to the same text, at F=28 and on the wide table, and
-    the 32- and 64-column int8 passes do not."""
+    kernel, folded or not, lowers to the same text, at F=28 and on the
+    wide table, and the 32- and 64-column int8 passes do not."""
     from lightgbm_tpu.ops import hist_pallas
     shapes = [("int8", 128, 3, 32), ("int8", 192, 3, 64),
               ("bf16v", 128, 3, 1), ("bf16v", 192, 5, 38),
               ("int8", 128, 3, 1), ("int8", 128, 3, 2), ("int8", 128, 3, 4),
-              ("int8", 128, 3, 8), ("int8", 128, 3, 16), ("bf16", 128, 3, 1)]
+              ("int8", 128, 3, 8), ("int8", 128, 3, 16), ("bf16", 128, 3, 1),
+              ("bf16v", 128, 5, 1)]
     turned = (0, 1)
 
     def before(b, lanes, _chunk, _held=0):

@@ -59,17 +59,22 @@ def test_grow_leafcompact_f32_compiles(one_chip, as_tpu):
 
 
 def test_masked_leafwise_memory_per_row_is_pinned(one_chip, as_tpu):
-    """The finding that keeps big tables off this route: the MASKED
-    leaf-wise grower (leafwise_compact=false) needs ~2.7 KB of temp per
-    row (2.81 GB at 1M rows) — ~31 GB at the 11M-row Higgs table, twice a
-    v5e's HBM.  Only the compacted grower can hold that table; nobody
-    should route it here on a chip.  If this drops below the bound the
-    rule in gbdt.leafwise_compact_on deserves another look."""
+    """What the MASKED leaf-wise grower (leafwise_compact=false) holds in
+    temporaries at 1M rows: 0.40 GB, 383 bytes a row, since the float
+    kernel folds its bin code (PR 35).  Until then it read 2.81 GB, taken
+    for 2.7 KB a row and ~31 GB at the 11M-row Higgs table, the finding
+    that kept big tables off this route; it was the leaf histogram cache,
+    ``f32[255,28,255,3]`` laid out with its three statistics padded to
+    128 lanes (936 MB a buffer), and not the rows.  Behind the folded
+    kernel's narrow accumulator XLA lays the cache out bins-minor (133
+    MB).  The route has not run on a chip since; if this leaves the band
+    either way, the rule in gbdt.leafwise_compact_on and PERF.md
+    section 7 (PR 35) deserve another look."""
     from lightgbm_tpu.models.grower_unified import grow_tree
     compiled = grow_tree.lower(*_grow_args(one_chip), **_GROW_KW).compile()
     per_row = compiled.memory_analysis().temp_size_in_bytes / N
-    assert 1500 < per_row < 4000, per_row
-    assert per_row * 11_000_000 > HBM_BYTES
+    assert 250 < per_row < 600, per_row
+    assert "f32[255,28,255,3]{3,2,1,0" not in compiled.as_text()
 
 
 # ----------------------------------------- programs built by the system

@@ -2,7 +2,9 @@
 benchmark's second configuration, F=2,000 at N=400,000
 (``tests/test_tpu_compile.py`` holds the rules these files keep).
 """
+import math
 import os
+import re
 
 from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, _captured_chunk_program, _cell_size, _check, _grow_args,
@@ -90,13 +92,13 @@ def test_partition_kernel_compiles_on_the_wide_table(one_chip, as_tpu,
 def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
     """One tree of the cell ``epsilon-leafwise-f32.train``: the compacted
     grower, the row-blocked partition kernel at each of the nine bucket
-    widths, the float histogram kernel on the feature-block grid.  And
+    widths, the float histogram kernel on the feature-block grid with
+    its bin code folded into the idle value lanes (``hist_fold``).  And
     what the compiled program must keep: the hi half of the float32
     gradient pair is a rounding XLA does not take for the identity (so the
     lo half carries something), and a split writes the pane and the leaf
     histogram cache in place (under ``lax.cond`` / ``lax.switch`` each was
     copied whole, twice a split)."""
-    import re
     from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
     kw = dict(_GROW_KW, min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0)
     compiled = grow_tree_leafcompact.lower(
@@ -114,3 +116,31 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
                for hi, x in rounded)
     assert not re.search(r"= s8\[2016,401408\][^ ]* copy\(", text)
     assert not re.search(r"= f32\[255,2000,255,3\][^ ]* copy\(", text)
+    # the float pass folds the bin code by 8: ten kernels (the root's pass
+    # and nine bucket widths) of a [32, 40] accumulator a feature.  No
+    # 128-lane accumulator, and nothing of its size either: beside the
+    # leaf histogram cache no float32 buffer, as laid out, holds 64 MB
+    # (the unfolded pass left four of 262 - 264 MB a pass, whatever the
+    # leaf's size: the accumulator and XLA's views of five value lanes a
+    # bin, the bins or the lanes padded to a tile)
+    assert len(re.findall(r"= f32\[2016,32,40\][^ ]* custom-call\(",
+                          text)) == 10
+    assert not re.search(r"f32\[2016,25[56],128\]", text)
+    large = {found.group(0) for found in _F32_BUFFER.finditer(text)
+             if _bytes_as_laid_out(found) >= 64 << 20}
+    assert large and all(name.startswith("f32[255,2000,255,3]")
+                         for name in large), large
+
+
+_F32_BUFFER = re.compile(r"f32\[([\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\)")
+
+
+def _bytes_as_laid_out(found):
+    """Bytes of a tiled float32 buffer of the compiled text: its two
+    minor-most dimensions padded to whole ``T(rows, lanes)`` tiles."""
+    dims = [int(d) for d in found.group(1).split(",")]
+    minor_to_major = [int(d) for d in found.group(2).split(",")]
+    for at, tile in zip(minor_to_major, (int(found.group(4)),
+                                         int(found.group(3)))):
+        dims[at] += (-dims[at]) % tile
+    return 4 * math.prod(dims)

@@ -60,7 +60,7 @@ def test_multi_block_kernel_equals_exact_histograms(F, lanes, num_cols,
                                               feature_grid, held_onehot,
                                               hist_fold)
     B, N, chunk = 255, 1024, 512
-    fold, gw = hist_fold(3, num_cols, B, lanes, "int8")
+    fold, gw = hist_fold(3, num_cols, B, lanes)
     assert (fold > 1) == (num_cols <= 16)
     held = held_onehot(3, num_cols, B, lanes, "int8")
     assert (held > 0) == (num_cols in (32, 64))

@@ -113,7 +113,7 @@ _GROW_KW = dict(num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=100,
 def _pass_rules(dtype, lanes, stats, num_cols):
     """(fold, gw, held) as ``_hist_pallas_one`` picks them for a pass."""
     from lightgbm_tpu.ops.hist_pallas import held_onehot, hist_fold
-    return (*hist_fold(stats, num_cols, 256, lanes, dtype),
+    return (*hist_fold(stats, num_cols, 256, lanes),
             held_onehot(stats, num_cols, 256, lanes, dtype))
 
 
