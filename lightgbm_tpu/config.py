@@ -556,12 +556,14 @@ class TreeConfig:
     # a single pass (grad/hess rounded to 8 mantissa bits; ~2x f32 speed
     # at a fraction of int8's quantization error), "int8" the
     # quantized-gradient kernel on the int8 MXU — fastest, grad/hess
-    # rounded to 1/127 of their per-pass max; counts stay exact in every
-    # mode.  hist_chunk tunes the XLA scan paths only; the Pallas kernels
+    # rounded to 1/127 of their max over the tree's rows; counts stay
+    # exact in every mode.  hist_chunk tunes the XLA scan paths only; the Pallas kernels
     # use their own fixed VMEM block.
-    # int8 is capped at ~16.9M GLOBAL rows (int32 accumulator: 127 x rows
-    # can wrap past 2^31 when rows concentrate in one bin — see
-    # models/gbdt.check_int8_row_capacity, which refuses loudly).
+    # int8 past ~16.9M GLOBAL rows (one int32 accumulator: 127 x rows can
+    # wrap past 2^31 when rows concentrate in one bin) sums every pass in
+    # row ranges with an accumulator each, added exactly
+    # (ops/hist_pallas.accum_ranges; a route that does not range refuses
+    # loudly, check_int8_row_capacity there).
     hist_chunk: int = 0
     hist_dtype: str = "float32"
     # data-parallel histogram reduction schedule (TreeConfig extension):

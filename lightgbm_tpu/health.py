@@ -61,7 +61,7 @@ TREE_HEALTH_KEYS = ("zero_gain_splits", "empty_leaves", "degenerate_trees")
 
 # Keys whose nonzero value is an ANOMALY under the on_anomaly policy.
 # quant_sat and zero_gain/empty-leaf counts are gauges, not faults: the int8
-# per-pass max scale saturates its max row by construction, and zero-gain
+# max scale saturates its max row by construction, and zero-gain
 # nodes appear in healthy late training.
 ANOMALY_KEYS = ("grad_nan", "grad_inf", "hess_nan", "hess_inf",
                 "score_nan", "score_inf")
@@ -79,7 +79,7 @@ def health_vector(grad, hess, score, *, quantized: bool = False,
     grad/hess: [C, N] (or [N]) gradients/hessians; score: [C, N] raw
     scores AFTER this iteration's update.  ``quantized`` adds the int8
     saturation gauge (ops/hist_pallas.quant_saturation_count — rows whose
-    magnitude quantizes to the ±127 ceiling under the per-pass max scale).
+    magnitude quantizes to the ±127 ceiling under the max scale).
     ``axis_name``: under shard_map, counts are psum'd and the watermark
     pmax'd so every shard carries the identical global vector.
     """

@@ -28,30 +28,9 @@ from .. import hatches, telemetry, tracing
 from ..utils import log
 from ..ops.scoring import add_tree_score
 from ..ops.lookup import exact_table_lookup as _leaf_lookup
+from ..ops.hist_pallas import INT8_HIST_MAX_ROWS, accum_ranges
 from .grower import grow_tree
 from .tree import Tree
-
-
-# int8 histogram row ceiling: a histogram cell accumulates int8 values in
-# an int32, and a cell's magnitude is bounded by 127 x rows-in-cell —
-# saturated at iteration 0 of binary logloss, where hessians are uniform
-# and every row quantizes to exactly 127; a constant (single-bin) feature
-# then concentrates ALL rows into one cell.  Rows beyond 2^31/127 can
-# therefore wrap the accumulator (and the int-domain psum across shards
-# sums into the same int32 range, so the bound is on GLOBAL rows).
-INT8_HIST_MAX_ROWS = (1 << 31) // 127
-
-
-def check_int8_row_capacity(num_rows: int) -> None:
-    """Refuse int8 histograms beyond the int32 accumulator's capacity
-    (silent wraparound would corrupt every split)."""
-    if num_rows > INT8_HIST_MAX_ROWS:
-        log.fatal(
-            "hist_dtype=int8 supports at most %d rows (int32 histogram "
-            "accumulator: 127 x rows can wrap past 2^31 when rows "
-            "concentrate in one bin); got %d rows — use "
-            "hist_dtype=float32 or bfloat16 at this scale"
-            % (INT8_HIST_MAX_ROWS, num_rows))
 
 
 class GBDT:
@@ -334,11 +313,13 @@ class GBDT:
                 score0 = np.zeros((self.num_class, N), np.float32)
             self.score = _arr0(score0)
 
-        if self.tree_config.hist_dtype == "int8":
-            # num_data is the GLOBAL (padded) row count in every mode —
-            # the int-domain psum sums all shards' int32 accumulators into
-            # the same int32 range, so the capacity bound is global
-            check_int8_row_capacity(self.num_data)
+        if (self.tree_config.hist_dtype == "int8"
+                and accum_ranges(self.num_data) > 1):
+            # num_data is the GLOBAL (padded) row count in every mode
+            log.info("int8 histograms over %d rows: every pass sums in %d "
+                     "accumulation ranges of at most %d rows"
+                     % (self.num_data, accum_ranges(self.num_data),
+                        INT8_HIST_MAX_ROWS))
 
         # bagging state (gbdt.cpp:77-88)
         self._bag_rng = np.random.RandomState(boosting_config.bagging_seed)

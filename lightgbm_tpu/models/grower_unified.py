@@ -165,6 +165,19 @@ def _is_int8(compute_dtype) -> bool:
     return str(compute_dtype).startswith("int8")
 
 
+def _tree_quant_max(compute_dtype, grad, hess, row_mask, s):
+    """The int8 routes' one scale a tree, as the keyword to hand every
+    histogram pass of the tree (``ops/hist_pallas.quant_max_of``: a
+    sibling derived by subtraction is only the histogram of its rows if
+    they rounded alike in both passes); nothing for the float routes."""
+    if not _is_int8(compute_dtype):
+        return {}
+    from ..ops.hist_pallas import quant_max_of
+    with phase_scope("histogram"):
+        return {"quant_max": quant_max_of(grad, hess, row_mask,
+                                          s.hist_axis)}
+
+
 def _patchable(module_name: str, attr: str, default):
     """Resolve a histogram entry through its historical compat module at
     trace time: tests monkeypatch ``grower.build_histogram`` /
@@ -387,6 +400,7 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         partition_bins = bins
     _fg = ({"feat_gather": s.hist_feat_gather}
            if s.hist_feat_gather is not None else {})
+    _fg.update(_tree_quant_max(compute_dtype, grad, hess, row_mask, s))
 
     def hist_of(mask, salt=0):
         hist = build_hist(bins, grad, hess, mask, B,
@@ -721,6 +735,7 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
     minh = float(min_sum_hessian_in_leaf)
     leafbatch = _patchable("grower_depthwise", "histogram_leafbatch",
                            histogram_leafbatch)
+    tree_scale = _tree_quant_max(compute_dtype, grad, hess, row_mask, s)
 
     def batch_hist_rows(b, g, h, col_id, col_ok, C, level=False, salt=0):
         # level passes may use the scatter schedule; the root pass always
@@ -730,6 +745,7 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # (histogram_leafbatch_segsum, test/profiling stubs) don't take
         # them
         extra = {"int_reduce": int_red} if int_red is not None else {}
+        extra.update(tree_scale)
         if s.hist_feat_gather is not None:
             extra["feat_gather"] = s.hist_feat_gather
         if salt and compute_dtype == "int8_sr":
@@ -1046,6 +1062,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                             build_histogram)
     _fg = ({"feat_gather": s.hist_feat_gather}
            if s.hist_feat_gather is not None else {})
+    _fg.update(_tree_quant_max(compute_dtype, grad, hess, row_mask, s))
 
     def hist_of(hbins, hg, hh, hmask, salt=0, **extra):
         hist = build_hist(hbins, hg, hh, hmask, B,

@@ -61,13 +61,125 @@ from jax.experimental.pallas import tpu as pltpu
 # held_onehot streams the live part of an integer pass alone.
 LANES = 128
 
+# int8 histogram row ceiling: a histogram cell accumulates int8 values in
+# an int32, and a cell's magnitude is bounded by 127 x rows-in-cell —
+# saturated at iteration 0 of binary logloss, where hessians are uniform
+# and every row quantizes to exactly 127; a constant (single-bin) feature
+# then concentrates ALL rows into one cell.  More rows than 2^31/127 can
+# therefore wrap ONE accumulator, so no accumulator of any route sums
+# more: a longer table is cut into ``accum_ranges`` row ranges with an
+# int32 accumulator each, combined exactly as an integer pair
+# (``range_sum``), and an int-domain psum across shards, which sums every
+# shard's accumulator into one int32 range, rides the same pair.  A route
+# that does not range refuses (``check_int8_row_capacity``).
+INT8_HIST_MAX_ROWS = (1 << 31) // 127
+
+
+def accum_ranges(rows: int, chunk: int = 1) -> int:
+    """THE rule: int32 accumulators a sum over ``rows`` rows takes,
+    ceil(rows / INT8_HIST_MAX_ROWS) with a range held to whole chunks of
+    ``chunk`` rows.  1 up to 16.9M rows: the program every table under
+    the cap always ran."""
+    per_range = INT8_HIST_MAX_ROWS // chunk * chunk
+    assert per_range > 0, (INT8_HIST_MAX_ROWS, chunk)
+    return max(1, -(-rows // per_range))
+
+
+def check_int8_row_capacity(num_rows: int, route: str = "this route") -> None:
+    """Refuse int8 histograms beyond ONE int32 accumulator's capacity on
+    a route that does not cut its rows into ranges (silent wraparound
+    would corrupt every split).  The histogram routes of
+    ``histogram_leafbatch`` range (the Pallas kernel and the XLA int
+    formulation, serial and under the data-parallel learners' int
+    reductions) and never call this."""
+    if accum_ranges(num_rows) > 1:
+        from ..utils import log
+        log.fatal(
+            "hist_dtype=int8: %s sums all its rows into one int32 "
+            "accumulator and supports at most %d rows (127 x rows can "
+            "wrap past 2^31 when rows concentrate in one bin); got %d "
+            "rows.  The histogram routes of task=train (Pallas and XLA "
+            "int, serial and data-parallel) cut their rows into ranges "
+            "and have no such limit"
+            % (route, INT8_HIST_MAX_ROWS, num_rows))
+
+
+def range_sum(acc):
+    """[ranges, ...] int32 accumulators -> their exact sum as an integer
+    pair (hi, lo), hi * 65536 + lo, each an int32 sum of the ranges'
+    halves (not carried: lo may pass 65535, so pairs add, and psum, like
+    plain integers, up to 32,768 ranges and shards in all)."""
+    with jax.named_scope("range_sum"):
+        return (jnp.sum(acc >> 16, axis=0),
+                jnp.sum(acc & 0xFFFF, axis=0))
+
+
+def pair_to_f32(hi, lo):
+    """The float32 nearest hi * 65536 + lo: the carried high half is
+    exact in float32 up to 2^40 (8.6 billion rows of 127), its product
+    with 65536 exact, and the one addition rounds once.  A function of
+    the sum alone: however the rows were cut into ranges and shards, the
+    same float32."""
+    with jax.named_scope("range_sum"):
+        hi = hi + (lo >> 16)
+        lo = lo & 0xFFFF
+        return (hi.astype(jnp.float32) * jnp.float32(65536.0)
+                + lo.astype(jnp.float32))
+
+
+def _reduce_int(acc, keep, paired, int_reduce, axis_name, pallas):
+    """An int accumulator ([F, B, K], or [ranges, F, B, K] of a ranged
+    pass) summed across its ranges and shards: float32
+    [F, B, keep], the live value columns.  One range in all: the int32
+    sum itself, reduced in the int domain as it always was, then cast.
+    More: the integer pair, each half reduced by the same collective, so
+    what a shard contributes is exact whatever the other shards hold and
+    serial == data-parallel stays bit for bit."""
+    from .. import telemetry
+    if paired and acc.ndim == 3:
+        acc = acc[None]            # one range a shard, several shards
+    halves = range_sum(acc[..., :keep]) if paired else (acc,)
+    if int_reduce is not None:
+        # ownership schedule: psum_scatter the INT accumulators by feature
+        # block (feature axis 0) — still int-domain, still bit-exact
+        halves = tuple(int_reduce(h) for h in halves)
+    elif axis_name is not None:
+        # reduce the INT accumulators across shards: dequantize-then-psum
+        # would round (sum of 8 f32 products != int-sum x scale) and break
+        # the bit-identical serial == data-parallel invariant
+        telemetry.record_collective(
+            "hist/int8_pallas_psum" if pallas else "hist/int8_xla_psum",
+            "psum", axis_name, telemetry._tree_nbytes(halves))
+        halves = tuple(jax.lax.psum(h, axis_name) for h in halves)
+    if paired:
+        return pair_to_f32(*halves)
+    return halves[0][..., :keep].astype(jnp.float32)
+
+
+def _ranged_rows(N: int, chunk: int, axis_name=None):
+    """(ranges, rows as padded, paired) of one shard's pass over ``N``
+    rows in chunks of ``chunk``: the ranges balanced, each of whole
+    chunks; ``paired`` where the ranges, or the shards of the
+    reduction's axis together, pass one accumulator's rows."""
+    from .. import telemetry
+    n_chunks = -(-N // chunk)
+    ranges = accum_ranges(n_chunks * chunk, chunk)
+    padded = ranges * -(-n_chunks // ranges) * chunk
+    shards = 1 if axis_name is None else jax.lax.axis_size(axis_name)
+    # counted per pass at trace time, like hist/pallas_fblocks
+    telemetry.count("hist/accum_ranges", ranges)
+    return ranges, padded, accum_ranges(padded * shards, chunk) > 1
+
 
 def _hist_kernel(bins_ref, packed_ref, out_ref, *, stats=3,
-                 skip_dead=False, **static):
+                 skip_dead=False, row_axis=1, **static):
     # grid = (feature_blocks, row_chunks), rows minor: each feature
     # block's accumulator lives in VMEM across its whole row sweep and is
-    # written back to HBM once
-    j = pl.program_id(1)
+    # written back to HBM once.  A ranged pass puts the ranges between
+    # the two, (feature_blocks, ranges, row_chunks of a range): the
+    # accumulator lives across ONE range's sweep and every range writes
+    # its own
+    j = pl.program_id(row_axis)
 
     @pl.when(j == 0)
     def _():
@@ -158,9 +270,11 @@ def _hist_accumulate(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
 def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
                         dtype: str = "int8", lanes: int = LANES,
                         stats: int = 3, fold: int = 1, gw: int = None,
-                        held: int = 0, skip_dead: bool = False):
+                        held: int = 0, skip_dead: bool = False,
+                        ranges: int = 1):
     """[F, B, lanes] accumulator from [F, N] bins and packed values
-    ([F, B, gw] from a folded float pass).
+    ([F, B, gw] from a folded float pass; [ranges, F, B, lanes] from a
+    ranged integer pass).
 
     Rows must be pre-padded to a multiple of ``chunk`` (pad cid with -1).
     packed is [stats + 1, N]: ``stats`` values per leaf column followed by
@@ -196,6 +310,12 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     ``skip_dead`` passes over a chunk with no live row (cid
     -1 throughout): the caller's to ask for, where its rows end in such
     chunks (the compacted grower's bucketed ranges); the same sums.
+    ``ranges`` > 1 (``_ranged_rows`` picks it, for the integer modes past
+    ``INT8_HIST_MAX_ROWS`` rows; N a multiple of ranges * chunk) cuts the
+    row sweep into that many equal ranges on a grid axis of their own,
+    each with its own int32 accumulator: the same kernel body, zeroed at
+    a range's first chunk, and no copy of a row.  ``ranges=1`` is the
+    two-axis grid as it always was.
 
     Wide datasets ride a FEATURE-BLOCK grid axis: each block of Fb
     features sweeps the rows in turn with its [Fb, B, lanes] accumulator
@@ -212,12 +332,12 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     from .. import telemetry
     telemetry.count("hist/pallas_kernel_" + dtype)
     F, N = bins.shape
-    assert N % chunk == 0 and packed.shape == (stats + 1, N)
+    assert N % (ranges * chunk) == 0 and packed.shape == (stats + 1, N)
     compute_dtype = jnp.int8 if dtype == "int8" else jnp.bfloat16
     acc_dtype = jnp.int32 if dtype == "int8" else jnp.float32
     if dtype == "bf16v":
-        assert packed.dtype == jnp.bfloat16, packed.dtype
-    fb, n_fblocks = feature_grid(F, B, lanes, chunk, held)
+        assert packed.dtype == jnp.bfloat16 and ranges == 1, packed.dtype
+    fb, n_fblocks = feature_grid(F, B, lanes, chunk, held, ranges)
     if n_fblocks * fb > F:
         bins = jnp.pad(bins, ((0, n_fblocks * fb - F), (0, 0)))
     if held:
@@ -238,20 +358,39 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
         _hist_kernel, F=fb, B=Bk, chunk=chunk,
         lanes=lanes, compute_dtype=compute_dtype, acc_dtype=acc_dtype,
         stats=stats, fold=fold, gw=gw, held=held,
-        skip_dead=skip_dead)
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_fblocks, N // chunk),
-        in_specs=[
+        skip_dead=skip_dead, row_axis=1 if ranges == 1 else 2)
+    out_shape = (n_fblocks * out_block[0],) + out_block[1:]
+    if ranges == 1:
+        grid = (n_fblocks, N // chunk)
+        in_specs = [
             pl.BlockSpec((fb, chunk), lambda i, j: (i, j)),
             pl.BlockSpec((stats + 1, chunk), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec(out_block, lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n_fblocks * out_block[0],) + out_block[1:], acc_dtype),
+        ]
+        out_specs = pl.BlockSpec(out_block, lambda i, j: (i, 0, 0))
+    else:
+        per = N // chunk // ranges           # chunks of one range
+        grid = (n_fblocks, ranges, per)
+        in_specs = [
+            pl.BlockSpec((fb, chunk), lambda i, r, j: (i, r * per + j)),
+            pl.BlockSpec((stats + 1, chunk),
+                         lambda i, r, j: (0, r * per + j)),
+        ]
+        out_specs = pl.BlockSpec((None,) + out_block,
+                                 lambda i, r, j: (r, i, 0, 0))
+        out_shape = (ranges,) + out_shape
+    out = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=jax.ShapeDtypeStruct(out_shape, acc_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",) * len(grid)),
     )(bins, packed)
+    if ranges > 1:
+        # the views below are the one-range kernel's, over the feature
+        # axis and behind it: the ranges ride stacked on that axis
+        out = out.reshape((-1,) + out_shape[2:])
     if held or fold > 1:
         # the transpose of the held one-hot's [held, B], or the unfold,
         # cell (hi, lo * gw + jj) -> (hi * fold + lo, jj)
@@ -271,7 +410,10 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
             out = with_layout_constraint(
                 jnp.pad(out, ((0, 0), (0, 0), (0, lanes - out.shape[2]))),
                 Layout(major_to_minor=(0, 1, 2)))
-    out = out[:F]
+    if ranges > 1:
+        out = out.reshape((ranges, -1) + out.shape[1:])[:, :F]
+    else:
+        out = out[:F]
     if dtype in ("int8", "bf16v"):
         return out                       # int32 / f32 accumulator as-is
     return out.astype(jnp.int32)
@@ -289,7 +431,7 @@ hist_pallas_raw = _costmodel.instrument(
     "hist/pallas_raw",
     jax.jit(_hist_pallas_raw_fn,
             static_argnames=("B", "chunk", "dtype", "lanes", "stats", "fold",
-                             "gw", "held", "skip_dead")),
+                             "gw", "held", "skip_dead", "ranges")),
     phase="histogram")
 
 
@@ -355,18 +497,24 @@ def held_onehot(stats: int, num_cols: int, B: int, lanes: int,
     return 0
 
 
-def feature_grid(F: int, B: int, lanes: int, chunk: int, held: int = 0):
+def feature_grid(F: int, B: int, lanes: int, chunk: int, held: int = 0,
+                 ranges: int = 1):
     """(features per block, blocks) of one pass over F features."""
-    if F <= feature_block(B, lanes):
+    if ranges == 1 and F <= feature_block(B, lanes):
         # single block: the output window is constant across the grid, so
         # Mosaic keeps ONE VMEM copy (the round-2 kernel ran exactly this
         # shape)
         return F, 1
     # multi-block: the output window rotates with grid axis i, which
-    # Mosaic DOUBLE-BUFFERS.  Blocks are balanced: with 48 a block
+    # Mosaic DOUBLE-BUFFERS; so it does with the ranges of a ranged
+    # pass, whose one block is what the rotating account lets fit.
+    # Blocks are balanced: with 48 a block
     # (B=256, lanes=128), 100 features run as 3 x 40 (20 pad) instead of
     # 48+48+48 (44 pad) — padded features cost full matmul passes
-    n_fblocks = -(-F // rotating_feature_block(B, lanes, chunk, held))
+    rotating = rotating_feature_block(B, lanes, chunk, held)
+    if ranges > 1 and F <= rotating:
+        return F, 1
+    n_fblocks = -(-F // rotating)
     fb = -(-F // n_fblocks)
     return fb + (-fb) % 8, n_fblocks          # sublane-tile multiple
 
@@ -475,7 +623,7 @@ def stochastic_bits(x, other, salt):
     automatically because the gradients do.  Rows sharing the exact
     (grad, hess) pair round identically (iteration 0's uniform hessians
     are the worst case — but there grad/hess quantize near-exactly by
-    construction of the per-pass max scale); from iteration 1 on the
+    construction of the max scale); from iteration 1 on the
     score fan-out makes the pairs effectively unique per row."""
     ix = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
     io = jax.lax.bitcast_convert_type(other.astype(jnp.float32),
@@ -484,9 +632,48 @@ def stochastic_bits(x, other, salt):
                   ^ _mix32(jnp.uint32(salt) + jnp.uint32(0x9E3779B9)))
 
 
+def quant_max_of(grad, hess, row_ok, axis_name=None):
+    """[2] f32: max |grad| and max |hess| over the participating rows,
+    what the int8 scales are taken from.
+
+    The scale must come from PARTICIPATING rows only: multi-process
+    phantom padding rows can carry arbitrary score-residual gradients
+    (their scores still accumulate leaf values) and would inflate the
+    scale, collapsing quantization resolution and breaking the
+    serial == distributed bit-identity.
+
+    ``axis_name``: under shard_map, pmax over the data axis so every
+    shard quantizes identically — int32 accumulation is then order-free,
+    making data-parallel histograms BIT-identical to serial (the
+    quantized analog of the reference's every-worker-identical-split
+    invariant, data_parallel_tree_learner.cpp:237-243).
+
+    The growers take it ONCE A TREE, over the tree's rows, and hand it to
+    every pass (``quant_max``): a row then rounds to the same code in the
+    root's pass, in its level's and in its leaf's, so a sibling derived
+    by subtraction is the histogram a pass of its own would have built.
+    With a scale a pass (PERF.md section 6, PR 36) every re-quantized row
+    left its two roundings' difference in the derived histogram: in the
+    first trees, where the rows share a few hundred values, whole leaves
+    flip together, and a candidate with a handful of rows on one side
+    saw sums of hundreds where the rows' own are under one."""
+    okf = row_ok.astype(jnp.float32)
+    m = jnp.stack([jnp.max(jnp.abs(grad) * okf),
+                   jnp.max(jnp.abs(hess) * okf)])
+    if axis_name is not None:
+        from .. import telemetry
+        telemetry.record_collective("hist/quant_scale_pmax", "pmax",
+                                    axis_name, telemetry._tree_nbytes(m))
+        m = jax.lax.pmax(m, axis_name)
+    return m
+
+
 def quantize_values(grad, hess, col_ok, rng_bits=None, axis_name=None,
-                    stochastic=False, salt=0):
-    """int8 quantization of grad/hess with a per-pass global scale.
+                    stochastic=False, salt=0, quant_max=None):
+    """int8 quantization of grad/hess with one global scale: the tree's
+    (``quant_max``, from ``quant_max_of`` over the tree's rows) where the
+    caller hands it, else the pass's own, over ``col_ok``'s rows
+    (``axis_name`` as in ``quant_max_of``).
 
     Round-to-nearest by default; unbiased stochastic rounding
     (floor(y+u), u uniform in [0,1)) with ``stochastic=True`` — the
@@ -494,30 +681,12 @@ def quantize_values(grad, hess, col_ok, rng_bits=None, axis_name=None,
     (``stochastic_bits``), or from explicit ``rng_bits`` [2, N] uint32.
     Returns (vals [3, N] int8 lane-major, scale [3] f32) — the count row
     is exact by construction.
-
-    ``axis_name``: under shard_map, pmax the scale over the data axis so
-    every shard quantizes identically — int32 accumulation is then
-    order-free, making data-parallel histograms BIT-identical to serial
-    (the quantized analog of the reference's every-worker-identical-split
-    invariant, data_parallel_tree_learner.cpp:237-243).
     """
     okf = col_ok.astype(jnp.float32)
-    # the scale must come from PARTICIPATING rows only: multi-process
-    # phantom padding rows can carry arbitrary score-residual gradients
-    # (their scores still accumulate leaf values) and would inflate the
-    # scale, collapsing quantization resolution and breaking the
-    # serial == distributed bit-identity
-    ag = jnp.max(jnp.abs(grad) * okf)
-    ah = jnp.max(jnp.abs(hess) * okf)
-    if axis_name is not None:
-        from .. import telemetry
-        telemetry.record_collective("hist/quant_scale_pmax", "pmax",
-                                    axis_name,
-                                    telemetry._tree_nbytes((ag, ah)))
-        ag = jax.lax.pmax(ag, axis_name)
-        ah = jax.lax.pmax(ah, axis_name)
-    gs = jnp.maximum(ag, 1e-30) / 127.0
-    hs = jnp.maximum(ah, 1e-30) / 127.0
+    if quant_max is None:
+        quant_max = quant_max_of(grad, hess, col_ok, axis_name)
+    gs = jnp.maximum(quant_max[0], 1e-30) / 127.0
+    hs = jnp.maximum(quant_max[1], 1e-30) / 127.0
 
     def quant(x, s, bits):
         y = x / s
@@ -542,20 +711,21 @@ def quantize_values(grad, hess, col_ok, rng_bits=None, axis_name=None,
 
 def quant_saturation_count(grad, hess, axis_name=None):
     """Health gauge: how many grad/hess entries quantize to the ±127
-    ceiling under quantize_values' per-pass max scale (|x| > 126.5·s with
+    ceiling under quantize_values' max scale (|x| > 126.5·s with
     s = max|x|/127).  The scale construction pins the max row at 127 by
     design, so a handful of saturated rows is normal; a LARGE count means
     the magnitude distribution has collapsed onto the ceiling — iteration
-    0's uniform hessians are the canonical case, and the precondition for
-    the int32 accumulator wraparound models/gbdt.check_int8_row_capacity
-    bounds.  Kept next to quantize_values so the two can never drift.
+    0's uniform hessians are the canonical case, and what fills an int32
+    accumulator to the row where ``accum_ranges`` starts another.  Kept
+    next to quantize_values so the two can never drift.
 
     Uses the finite global max per channel (the health monitor evaluates
-    once per iteration over ALL rows).  Histogram passes quantize with
-    per-pass MASKED scales ≤ this global max, so a pass whose local max
-    sits below the global one saturates MORE of its entries than the
-    gauge counts — read the gauge as a floor, not a ceiling: nonzero
-    means at-least-this-much concentration at the representable limit.
+    once per iteration over ALL rows).  A tree's passes quantize with
+    the max over the tree's own rows (``quant_max_of``: the bag's, under
+    bagging) ≤ this global max, so a tree whose max sits below the
+    global one saturates MORE of its entries than the gauge counts —
+    read the gauge as a floor, not a ceiling: nonzero means
+    at-least-this-much concentration at the representable limit.
     ``axis_name``: pmax the scale across shards before counting, psum the
     count — every shard reports the identical global gauge."""
     f32 = jnp.float32
@@ -594,9 +764,10 @@ def _grouped(fn, bins, grad, hess, col_id, col_ok, num_cols, B, *,
     return jnp.concatenate(parts, axis=0)
 
 
-def _class_acc_assemble(parts, packing, B: int):
+def _class_acc_assemble(parts, packing, B: int, feat_axis: int = 0):
     """Per-class accumulators (packed feature order, feature axis 0, bin
-    axis 1) -> ONE canonical-order accumulator padded to B bins.  Stays in
+    axis 1; both one further back behind the ranges of a ranged pass)
+    -> ONE canonical-order accumulator padded to B bins.  Stays in
     the accumulator's own domain (int32 for the quantized kernels), so the
     ownership psum_scatter / cross-shard psum that follows operates on
     canonical contiguous feature blocks exactly as in the uniform path —
@@ -605,7 +776,8 @@ def _class_acc_assemble(parts, packing, B: int):
     is the bit-identity-critical step, so every kernel route must share
     it."""
     from .histogram import _assemble_classes
-    return _assemble_classes(parts, packing, B, feat_axis=0, bin_axis=1)
+    return _assemble_classes(parts, packing, B, feat_axis=feat_axis,
+                             bin_axis=feat_axis + 1)
 
 
 def _packing_on(packing) -> bool:
@@ -618,7 +790,7 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
                           dtype: str = "int8", rng_bits=None,
                           axis_name=None, int_reduce=None,
                           stochastic=False, salt=0, packing=None,
-                          feat_gather=None):
+                          feat_gather=None, quant_max=None):
     """Drop-in histogram_leafbatch equivalent on the Pallas kernel.
 
     ``bins`` is the usual [F, N] matrix (int8 or uint8).  The int32
@@ -648,13 +820,14 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
             num_cols, num_bins_max, group_width=64, chunk=chunk,
             dtype=dtype, rng_bits=rng_bits, axis_name=axis_name,
             int_reduce=int_reduce, stochastic=stochastic, salt=salt,
-            packing=packing, feat_gather=feat_gather))
+            packing=packing, feat_gather=feat_gather,
+            quant_max=quant_max))
 
 
 def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
                      chunk, dtype, rng_bits, axis_name=None,
                      int_reduce=None, stochastic=False, salt=0,
-                     packing=None, feat_gather=None):
+                     packing=None, feat_gather=None, quant_max=None):
     F, N = bins.shape
     lanes = LANES if num_cols <= 42 else 192
     # ONE quantization for every class pass: the scale comes from the same
@@ -662,11 +835,13 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
     # passes quantize identically (bit-identity precondition)
     vals, scale = quantize_values(grad, hess, col_ok, rng_bits,
                                   axis_name=axis_name,
-                                  stochastic=stochastic, salt=salt)
+                                  stochastic=stochastic, salt=salt,
+                                  quant_max=quant_max)
     cid8 = jnp.where(col_ok, col_id, -1).astype(jnp.int8)
     packed = jnp.concatenate([vals, cid8[None, :]], axis=0)  # [4, N] int8
 
-    pad = (-N) % chunk
+    ranges, padded, paired = _ranged_rows(N, chunk, axis_name)
+    pad = padded - N
     if pad:
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         packed = jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1)
@@ -681,18 +856,19 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         telemetry.count("hist/pallas_held_onehot", int(held > 0))
         telemetry.count("hist/pallas_fblocks",
                         feature_grid(rows.shape[0], width, lanes, chunk,
-                                     held)[1])
+                                     held, ranges)[1])
+        # [F, width, lanes], behind its ranges where the pass is ranged
         return hist_pallas_raw(rows.astype(jnp.int8), packed, B=width,
                                chunk=chunk, dtype=dtype, lanes=lanes,
-                               fold=fold, gw=gw,
-                               held=held)                # [F, width, lanes]
+                               fold=fold, gw=gw, held=held, ranges=ranges)
 
+    fa = int(ranges > 1)                 # the accumulator's feature axis
     if _packing_on(packing):
         telemetry.count("hist/mixedbin_pallas_int")
         parts = [launch(jax.lax.slice_in_dim(bins, start, start + cnt,
                                              axis=0), width)
                  for start, cnt, width in packing.ranges]
-        acc = _class_acc_assemble(parts, packing, B)         # [F, B, lanes]
+        acc = _class_acc_assemble(parts, packing, B, fa)     # [F, B, lanes]
     else:
         acc = launch(bins, B)
     if feat_gather is not None:
@@ -703,21 +879,10 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         # layout's (XLA contraction choices cannot diverge — ISSUE 12)
         assert int_reduce is None, \
             "feat_gather does not compose with the ownership int scatter"
-        acc = jnp.take(acc, feat_gather, axis=0)
-    if int_reduce is not None:
-        # ownership schedule: psum_scatter the INT accumulators by feature
-        # block (feature axis 0) — still int-domain, still bit-exact
-        acc = int_reduce(acc)
-        F = acc.shape[0]
-    elif axis_name is not None:
-        # reduce the INT accumulators across shards: dequantize-then-psum
-        # would round (sum of 8 f32 products != int-sum x scale) and break
-        # the bit-identical serial == data-parallel invariant
-        telemetry.record_collective("hist/int8_pallas_psum", "psum",
-                                    axis_name, telemetry._tree_nbytes(acc))
-        acc = jax.lax.psum(acc, axis_name)
-    hist = acc[:, :, :num_cols * 3].astype(jnp.float32)
-    hist = hist.reshape(F, B, num_cols, 3).transpose(2, 0, 1, 3)
+        acc = jnp.take(acc, feat_gather, axis=fa)
+    hist = _reduce_int(acc, num_cols * 3, paired, int_reduce, axis_name,
+                       pallas=True)
+    hist = hist.reshape(-1, B, num_cols, 3).transpose(2, 0, 1, 3)
     return hist * scale
 
 
@@ -854,7 +1019,7 @@ def hist_quant_xla(bins, grad, hess, col_id, col_ok, num_cols: int,
                    num_bins_max: int, *, chunk: int = 65536, rng_bits=None,
                    axis_name=None, int_reduce=None,
                    stochastic=False, salt=0, packing=None,
-                   feat_gather=None):
+                   feat_gather=None, quant_max=None):
     """XLA reference of the SAME quantized-gradient math as the Pallas int8
     kernel (bit-identical output) — the CPU-testable oracle and the
     fallback on non-TPU backends.  ``packing``: per-class int accumulators
@@ -869,11 +1034,15 @@ def hist_quant_xla(bins, grad, hess, col_id, col_ok, num_cols: int,
             num_cols, num_bins_max, chunk=chunk, rng_bits=rng_bits,
             axis_name=axis_name, int_reduce=int_reduce,
             feat_gather=feat_gather,
-            stochastic=stochastic, salt=salt, packing=packing))
+            stochastic=stochastic, salt=salt, packing=packing,
+            quant_max=quant_max))
 
 
-def _quant_xla_acc(bins, vals, cid, B: int, C: int, chunk: int):
-    """One class's raw [F, B, C*3] int32 accumulator (rows pre-padded)."""
+def _quant_xla_acc(bins, vals, cid, B: int, C: int, chunk: int,
+                   ranges: int = 1):
+    """One class's raw [F, B, C*3] int32 accumulator (rows pre-padded),
+    or its [ranges, F, B, C*3]: the chunks cut into that many equal
+    ranges, each summed from zero."""
     F = bins.shape[0]
     N = bins.shape[1]
     n_chunks = N // chunk
@@ -894,50 +1063,55 @@ def _quant_xla_acc(bins, vals, cid, B: int, C: int, chunk: int):
         return carry + out, None
 
     init = jnp.zeros((F, B, C * 3), jnp.int32)
-    hist, _ = jax.lax.scan(body, init, (bins_c, vals_c, cid_c))
+    if ranges == 1:
+        hist, _ = jax.lax.scan(body, init, (bins_c, vals_c, cid_c))
+        return hist
+    _, hist = jax.lax.scan(
+        lambda _, xs: (None, jax.lax.scan(body, init, xs)[0]), None,
+        jax.tree.map(lambda x: x.reshape((ranges, -1) + x.shape[1:]),
+                     (bins_c, vals_c, cid_c)))
     return hist
 
 
 def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
                         chunk, rng_bits, axis_name=None, int_reduce=None,
                         stochastic=False, salt=0, packing=None,
-                        feat_gather=None):
+                        feat_gather=None, quant_max=None):
     F, N = bins.shape
     C = num_cols
     # don't pad a small input up to a full default chunk
     chunk = min(chunk, max(256, -(-N // 256) * 256))
     vals, scale = quantize_values(grad, hess, col_ok, rng_bits,
                                   axis_name=axis_name,
-                                  stochastic=stochastic, salt=salt)
+                                  stochastic=stochastic, salt=salt,
+                                  quant_max=quant_max)
     cid = jnp.where(col_ok, col_id, -1).astype(jnp.int32)
-    pad = (-N) % chunk
+    ranges, padded, paired = _ranged_rows(N, chunk, axis_name)
+    pad = padded - N
     if pad:
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         vals = jnp.pad(vals, ((0, 0), (0, pad)))
         cid = jnp.pad(cid, (0, pad), constant_values=-1)
+
+    fa = int(ranges > 1)                 # the accumulator's feature axis
     if _packing_on(packing):
         from .. import telemetry
         telemetry.count("hist/mixedbin_xla_int")
         parts = [_quant_xla_acc(
             jax.lax.slice_in_dim(bins, start, start + cnt, axis=0),
-            vals, cid, width, C, chunk)
+            vals, cid, width, C, chunk, ranges)
             for start, cnt, width in packing.ranges]
-        hist = _class_acc_assemble(parts, packing, B)    # [F, B, C*3] i32
+        hist = _class_acc_assemble(parts, packing, B, fa)  # [F, B, C*3] i32
     else:
-        hist = _quant_xla_acc(bins, vals, cid, B, C, chunk)
+        hist = _quant_xla_acc(bins, vals, cid, B, C, chunk, ranges)
     if feat_gather is not None:
         # storage->canonical reorder IN the int domain, before the
         # cross-shard psum (commutes elementwise) — see _hist_pallas_one
         assert int_reduce is None, \
             "feat_gather does not compose with the ownership int scatter"
-        hist = jnp.take(hist, feat_gather, axis=0)
-    if int_reduce is not None:
-        hist = int_reduce(hist)                # int-domain feature scatter
-        F = hist.shape[0]
-    elif axis_name is not None:
-        from .. import telemetry
-        telemetry.record_collective("hist/int8_xla_psum", "psum",
-                                    axis_name, telemetry._tree_nbytes(hist))
-        hist = jax.lax.psum(hist, axis_name)   # int-domain cross-shard sum
-    hist = hist.reshape(F, B, C, 3).transpose(2, 0, 1, 3).astype(jnp.float32)
+        hist = jnp.take(hist, feat_gather, axis=fa)
+    # int-domain feature scatter or cross-shard sum, then float32
+    hist = _reduce_int(hist, C * 3, paired, int_reduce, axis_name,
+                       pallas=False)
+    hist = hist.reshape(-1, B, C, 3).transpose(2, 0, 1, 3)
     return hist * scale

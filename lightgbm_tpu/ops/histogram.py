@@ -307,7 +307,8 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         axis_name=None, int_reduce=None,
                         salt=0, packing=None,
                         feat_gather=None,
-                        skip_dead: bool = False) -> jax.Array:
+                        skip_dead: bool = False,
+                        quant_max=None) -> jax.Array:
     """Build histograms for MANY leaves in ONE matmul pass.
 
     The single-leaf one-hot matmul starves the MXU: the value operand has
@@ -337,6 +338,8 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     takes part (the compacted grower's bucketed ranges); the float Pallas
     kernel then passes over chunks that are dead throughout.  The same
     sums; the other routes take no notice.
+    ``quant_max``: the int8 routes' scale, the tree's own
+    (``hist_pallas.quant_max_of``) in place of this pass's.
     """
     if _packing_active(packing):
         telemetry.count("hist/mixedbin_leafbatch")
@@ -358,14 +361,15 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     bins, grad, hess, col_id, col_ok, num_cols,
                     num_bins_max, axis_name=axis_name,
                     int_reduce=int_reduce, stochastic=stochastic,
-                    salt=salt, packing=packing, feat_gather=feat_gather))
+                    salt=salt, packing=packing, feat_gather=feat_gather,
+                    quant_max=quant_max))
         telemetry.count("hist/xla_int8")
         with telemetry.span("histogram") as sp:
             return sp.fence(hist_quant_xla(
                 bins, grad, hess, col_id, col_ok, num_cols, num_bins_max,
                 chunk=chunk, axis_name=axis_name, int_reduce=int_reduce,
                 stochastic=stochastic, salt=salt, packing=packing,
-                feat_gather=feat_gather))
+                feat_gather=feat_gather, quant_max=quant_max))
     # float dtypes on TPU: hand-scheduled Pallas kernel with bf16 operands
     # (f32 rides a hi/lo operand split — one 5-stat pass for narrow
     # levels, two 3-stat passes wider).  This routes AROUND the XLA
@@ -505,21 +509,27 @@ def histogram_leafbatch_segsum(bins, grad, hess, col_id, col_ok,
 def hist_quant_segsum(bins, grad, hess, col_id, col_ok, num_cols: int,
                       num_bins_max: int, chunk: int = 0, rng_bits=None,
                       compute_dtype=None, axis_name=None, int_reduce=None,
-                      salt=0, packing=None, feat_gather=None):
+                      salt=0, packing=None, feat_gather=None,
+                      quant_max=None):
     """Scatter-add variant of the quantized-gradient histogram — exact
     int32 accumulation, so it is bit-identical to hist_pallas/hist_quant_xla
     (ops/hist_pallas.py) at any summation order; the CPU-fast oracle for
     int8-path quality tests."""
-    from .hist_pallas import quantize_values
+    from .hist_pallas import check_int8_row_capacity, quantize_values
     if _packing_active(packing):
         bins = _unpack_bins(bins, packing)
     F, N = bins.shape
+    # one int32 segment sum over every row, and every shard's: not ranged
+    check_int8_row_capacity(
+        N * (1 if axis_name is None else jax.lax.axis_size(axis_name)),
+        "the scatter-add oracle hist_quant_segsum")
     B = num_bins_max
     C = num_cols
     vals, scale = quantize_values(grad, hess, col_ok, rng_bits,
                                   axis_name=axis_name,
                                   stochastic=(compute_dtype == "int8_sr"),
-                                  salt=salt)                # [3, N] i8
+                                  salt=salt,
+                                  quant_max=quant_max)      # [3, N] i8
     cid = jnp.where(col_ok, col_id, C).astype(jnp.int32)
     ids = (cid[None, :] * F + jnp.arange(F, dtype=jnp.int32)[:, None]) * B \
         + bins.astype(jnp.int32)
@@ -557,13 +567,15 @@ def build_histogram(bins, grad, hess, mask, num_bins_max, *,
                     backend: str = "matmul", chunk: int = 16384,
                     compute_dtype=jnp.float32, axis_name=None,
                     int_reduce=None, salt=0, packing=None,
-                    feat_gather=None, skip_dead: bool = False) -> jax.Array:
+                    feat_gather=None, skip_dead: bool = False,
+                    quant_max=None) -> jax.Array:
     """``int_reduce``: optional int-domain cross-shard reduction for the
     quantized path (feature axis 0) — the data-parallel reduce_scatter
     ownership schedule passes a psum_scatter here so the accumulators are
     scattered WITHOUT leaving the exact int domain.  ``packing``: static
-    mixed-bin layout spec, ``skip_dead``: the mask ends in dead stretches
-    (both: see histogram_leafbatch)."""
+    mixed-bin layout spec, ``skip_dead``: the mask ends in dead stretches,
+    ``quant_max``: the tree's int8 scale (all three: see
+    histogram_leafbatch)."""
     if str(compute_dtype).startswith("int8"):
         # single-leaf quantized pass == leaf-batched with one column
         N = bins.shape[1]
@@ -573,7 +585,8 @@ def build_histogram(bins, grad, hess, mask, num_bins_max, *,
                                   compute_dtype=compute_dtype,
                                   axis_name=axis_name,
                                   int_reduce=int_reduce, salt=salt,
-                                  packing=packing, feat_gather=feat_gather)
+                                  packing=packing, feat_gather=feat_gather,
+                                  quant_max=quant_max)
         return out[0]
     if backend == "matmul":
         if _pallas_hist_ok(num_bins_max):

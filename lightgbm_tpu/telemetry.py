@@ -280,6 +280,7 @@ COUNTER_FAMILIES = (
     "goss/iterations",
     "health/*",                   # per-anomaly-kind counters (health.py)
     "health/anomalous_iterations",
+    "hist/accum_ranges",          # int32 accumulation ranges, summed over the int passes
     "hist/env_force_einsum",
     "hist/env_no_pallas",
     "hist/mixedbin_blocked",
@@ -394,7 +395,9 @@ SPAN_FAMILIES = (
 # rows plus a remainder that is a number (the benchmark's
 # ``unscoped_ms_per_iter``), not a guess.  ``level<d>``, ``leafwise_split``
 # and ``leafcompact_split`` are OUTER grouping scopes and the objectives'
-# ``gradient_<objective>`` nest inside ``gradient``.  XLA gives a fusion
+# ``gradient_<objective>`` nest inside ``gradient``; ``range_sum`` nests
+# inside ``histogram`` (ops/hist_pallas.py: the int8 accumulation ranges
+# of a table past 16.9M rows added as an integer pair).  XLA gives a fusion
 # the metadata of its root operation, so a boundary between two phases is
 # exact only where the compiler did not fuse across it.
 DEVICE_PHASES = (

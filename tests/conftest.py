@@ -48,12 +48,15 @@ REFERENCE_BINARY = os.path.join(REFERENCE_BUILD, "lightgbm")
 # order, and xdist is told to keep the order it is given.
 HEAVY_FIRST = (
     "test_tpu_compile_programs.py", "test_tpu_compile.py",
+    "test_hist_int8_ranged_trees.py",
     "test_multiprocess_dp.py", "test_parallel.py", "test_hist_int8_held.py",
     "test_hist_int8_fold.py", "test_mixedbin.py", "test_wide_table.py",
     "test_tpu_compile_wide.py", "test_hybrid_voting.py", "test_gbdt.py",
     "test_streaming.py", "test_leafwise_wide.py", "test_leafcompact.py",
+    "test_tpu_compile_airline.py", "test_airline_cell.py",
     "test_distributed_telemetry.py", "test_goss_chunk.py",
     "test_route_pallas.py", "test_depthwise.py", "test_hist_int8.py",
+    "test_hist_int8_ranges.py",
     "test_graftlint.py",
     "test_mixedbin_hybrid.py", "test_hist_float_pallas.py",
     "test_elastic.py", "test_health.py", "test_costmodel.py",

@@ -338,6 +338,12 @@ def test_fault_kill_env_sigkills_training(tmp_path):
         b = GBDT()
         b.init(cfg.boosting_config, ds,
                create_objective(cfg.objective_type, cfg.objective_config))
+        # two iterations to their end first: run_training closes with a
+        # synchronous checkpoint, so one is on disk before the kill.  The
+        # periodic ones ride a writer thread, and three iterations of a
+        # few milliseconds could all be killed before it had written any
+        # (one run in four under a loaded machine)
+        b.run_training(2, is_eval=False)
         b.run_training(8, is_eval=False)
         print("NOT_KILLED")
     """ % str(tmp_path / "ck"))

@@ -2,7 +2,8 @@
 exact counts, and end-to-end training sanity.
 
 The int8 path is the TPU throughput option (ops/hist_pallas.py): grad/hess
-are rounded to 1/127 of their per-pass max and contracted on the int8 MXU.
+are rounded to 1/127 of their max over the tree's rows (a pass called
+alone: over its own) and contracted on the int8 MXU.
 The reference accumulates in double (bin.h:15-17); LightGBM's later
 quantized-training work showed coarse gradient quantization preserves model
 quality — these tests pin the machinery, scripts/auc_parity.py pins quality
@@ -167,17 +168,40 @@ def test_train_depthwise_int8_quality(synthetic_binary):
 
 
 def test_int8_row_capacity_guard():
-    """ADVICE r2 (medium): a histogram cell's int32 accumulator holds at
-    most 2^31/127 rows (iteration-0 binary hessians all quantize to 127,
-    and a single-bin feature concentrates every row into one cell) —
-    beyond that the booster must refuse int8 loudly, not wrap silently."""
-    from lightgbm_tpu.models.gbdt import (check_int8_row_capacity,
-                                          INT8_HIST_MAX_ROWS)
+    """ADVICE r2 (medium), rewritten by PR 36: a histogram cell's int32
+    accumulator holds at most 2^31/127 rows (iteration-0 binary hessians
+    all quantize to 127, and a single-bin feature concentrates every row
+    into one cell).  Past that the histogram routes cut the rows into
+    ranges and build, with no refusal at GBDT.init; a route that sums
+    every row into one accumulator must still refuse loudly, not wrap
+    silently."""
+    from lightgbm_tpu.ops.hist_pallas import (INT8_HIST_MAX_ROWS,
+                                              _ranged_rows,
+                                              check_int8_row_capacity)
+    from lightgbm_tpu.ops.histogram import hist_quant_segsum
     from lightgbm_tpu.utils.log import LightGBMError
     check_int8_row_capacity(INT8_HIST_MAX_ROWS)       # at the limit: fine
     check_int8_row_capacity(11_000_000)               # bench scale: fine
-    with pytest.raises(LightGBMError):
+    with pytest.raises(LightGBMError, match="one int32 accumulator"):
         check_int8_row_capacity(INT8_HIST_MAX_ROWS + 1)
+    # traced, not run: the ranged route lowers past the cap, seven ranges
+    # at the airline table's rows; the scatter-add oracle refuses there
+    rows = 115_000_000
+    args = (jax.ShapeDtypeStruct((2, rows), jnp.int8),
+            jax.ShapeDtypeStruct((rows,), jnp.float32),
+            jax.ShapeDtypeStruct((rows,), jnp.float32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_))
+    out = jax.eval_shape(
+        lambda *a: hist_quant_xla(*a, 1, 4, chunk=65536), *args)
+    assert out.shape == (1, 2, 4, 3)
+    assert _ranged_rows(rows, 65536)[0] == 7
+    with pytest.raises(LightGBMError, match="hist_quant_segsum"):
+        jax.eval_shape(lambda *a: hist_quant_segsum(*a, 1, 4), *args)
+    jax.eval_shape(lambda *a: hist_quant_segsum(*a, 1, 4),
+                   *jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+                       tuple(1000 if d == rows else d for d in s.shape),
+                       s.dtype), args))
 
 
 def test_stochastic_rounding_unbiased_and_deterministic():
@@ -261,3 +285,91 @@ def test_stochastic_int8_dp_bit_identical_to_serial():
             np.testing.assert_array_equal(t1.threshold_bin,
                                           t2.threshold_bin,
                                           err_msg=f"{sched} tree {k}")
+
+
+def test_one_scale_a_tree_makes_the_derived_sibling_exact():
+    """``hists - hist_small`` is the larger child's histogram only if a
+    row rounds to one code in both passes.  Rows that share a few values,
+    as in a run's first trees, and a smaller child without the row of the
+    largest gradient: with the tree's scale (``quant_max_of`` over all
+    rows) the derived sibling is, code for code, the histogram a pass of
+    its own builds; with a scale a pass (the parent's arithmetic, PERF.md
+    section 6, PR 36) whole groups of rows flip and it is not."""
+    from lightgbm_tpu.ops.hist_pallas import quant_max_of
+    rng = np.random.RandomState(11)
+    F, N, B = 3, 6000, 16
+    bins = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.int8))
+    values = np.array([-1.07, -0.93, -0.51, 0.22, 0.64, 0.98, 1.1],
+                      np.float32)
+    pick = rng.randint(0, len(values), N)
+    grad = jnp.asarray(values[pick])
+    hess = jnp.asarray(np.abs(values[pick]) * (2 - np.abs(values[pick])))
+    everyone = jnp.ones((N,), bool)
+    small = jnp.asarray((rng.rand(N) < 0.3) & (pick != len(values) - 1)
+                        & (pick != 0))
+    large = everyone & ~small
+    cid = jnp.zeros((N,), jnp.int32)
+    qmax = quant_max_of(grad, hess, everyone)
+    scale = np.append(np.asarray(qmax) / 127.0, 1.0)
+
+    def codes(ok, **kw):
+        hist = hist_quant_xla(bins, grad, hess, cid, ok, 1, B, chunk=512,
+                              **kw)[0]
+        return np.rint(np.asarray(hist, np.float64) / scale).astype(np.int64)
+
+    tree = dict(quant_max=qmax)
+    np.testing.assert_array_equal(
+        codes(everyone, **tree) - codes(small, **tree), codes(large, **tree))
+    # a scale a pass: the smaller child's own max is another, its rows
+    # round anew, and the difference stays in the derived sibling
+    assert np.asarray(quant_max_of(grad, hess, small))[0] < float(qmax[0])
+    assert np.any(codes(everyone) - codes(small) != codes(large, **tree))
+
+
+@pytest.mark.parametrize("policy", ["depthwise", "leafwise"])
+def test_growers_hand_every_pass_the_trees_scale(monkeypatch, policy):
+    """Every int8 histogram pass of a tree gets the one ``quant_max``
+    the grower took over the tree's rows; a float tree gets none."""
+    from lightgbm_tpu.config import OverallConfig
+    from lightgbm_tpu.io.dataset import Dataset
+    from lightgbm_tpu.models.gbdt import GBDT
+    from lightgbm_tpu.models import grower as grower_mod
+    from lightgbm_tpu.models import grower_depthwise as gd_mod
+    from lightgbm_tpu.objectives import create_objective
+    from lightgbm_tpu.ops import histogram as hist_mod
+
+    seen = []
+
+    def spy(real):
+        def call(*args, **kw):
+            seen.append(kw.get("quant_max"))
+            return real(*args, **kw)
+        return call
+    monkeypatch.setattr(gd_mod, "histogram_leafbatch",
+                        spy(hist_mod.histogram_leafbatch))
+    monkeypatch.setattr(grower_mod, "build_histogram",
+                        spy(hist_mod.build_histogram))
+    rng = np.random.RandomState(5)
+    x = rng.randn(1500, 4)
+    y = (x[:, 0] + 0.3 * rng.randn(1500) > 0).astype(np.float32)
+
+    def passes(hist_dtype):
+        del seen[:]
+        cfg = OverallConfig()
+        cfg.set({"objective": "binary", "num_leaves": "7", "max_bin": "16",
+                 "min_data_in_leaf": "5", "hist_dtype": hist_dtype,
+                 "grow_policy": policy, "leafwise_compact": "false"},
+                require_data=False)
+        booster = GBDT()
+        booster.init(cfg.boosting_config,
+                     Dataset.from_arrays(x, y, max_bin=16),
+                     create_objective(cfg.objective_type,
+                                      cfg.objective_config))
+        booster.train_one_iter(is_eval=False)
+        assert booster.models[0].num_leaves > 2
+        return list(seen)
+
+    int8 = passes("int8")
+    assert len(int8) >= 2 and int8[0] is not None
+    assert all(q is int8[0] for q in int8)
+    assert passes("float32") and all(q is None for q in seen)

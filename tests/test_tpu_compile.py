@@ -23,17 +23,19 @@ not by a new option.
 
 Where such tests may live: in this file (the kernels),
 ``tests/test_tpu_compile_programs.py`` (the narrow table's growers and
-the programs the system builds) and ``tests/test_tpu_compile_wide.py``
-(the wide table's), which share ``tests/tpu_described.py`` and nothing
-else.  Each file is one xdist worker's chain, so a new compile goes to
-the file of its table, and a file that passes four minutes of a cold run
-is split again (ROADMAP "Tests").  Three workers can describe the
+the programs the system builds), ``tests/test_tpu_compile_wide.py``
+(the wide table's) and ``tests/test_tpu_compile_airline.py`` (the long
+table's: the ranged kernel and its chunk program), which share
+``tests/tpu_described.py`` and nothing else.  Each file is one xdist
+worker's chain, so a new compile goes to the file of its table, and a
+file that passes four minutes of a cold run is split again (ROADMAP
+"Tests").  Several workers can describe the
 topology at once only because the driver's command sets
 ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` (tried on this tree: three processes
 described ``v5e:2x2`` and compiled side by side; without the variable the
 second one is refused for the library's lock file).  The repository does
 not set it.  Where it is missing the ``topo`` fixture fails the file by
-name and does not skip it; one process (``-p no:xdist``) runs all three
+name and does not skip it; one process (``-p no:xdist``) runs all four
 files with no variable at all.
 """
 import pytest
@@ -195,4 +197,35 @@ def test_narrow_partition_kernel_is_the_program_it_was(one_chip, as_tpu,
     (kernel,) = _mosaic_kernels(jax.jit(fresh).lower(
         _shape(one_chip, (R, N), jnp.int8), _shape(one_chip, (N,), jnp.int8),
         scalar, scalar, scalar).as_text())
+    assert hashlib.sha256(kernel.encode()).hexdigest()[:16] == digest
+
+
+# ------------------------------- accumulation ranges (ops/hist_pallas.py)
+
+@pytest.mark.parametrize("features,shape,digest", [
+    # the narrow cell's eight passes (seven shapes) and the wide cell's
+    # largest two, the leaf-wise cell's folded float pass
+    (F, ("int8", 128, 3, 1), "93a51d1b466040e1"),
+    (F, ("int8", 128, 3, 2), "ef6d690b0dc71735"),
+    (F, ("int8", 128, 3, 4), "4da1110c461f64a3"),
+    (F, ("int8", 128, 3, 8), "a4aa1be5dd724cc9"),
+    (F, ("int8", 128, 3, 16), "1cdefdef40ae6979"),
+    (F, ("int8", 128, 3, 32), "3ae66f6404759ce5"),
+    (F, ("int8", 192, 3, 64), "e48939e86d588acd"),
+    (WIDE_F, ("int8", 128, 3, 32), "21e1d12aa024ca14"),
+    (WIDE_F, ("int8", 192, 3, 64), "00db8c089905341e"),
+    (WIDE_F, ("bf16v", 128, 5, 1), "d0801cd0a5de57fa"),
+])
+def test_one_range_kernels_are_the_programs_they_were(one_chip, as_tpu,
+                                                      features, shape,
+                                                      digest):
+    """A table under ``INT8_HIST_MAX_ROWS`` rows is not on the path of the
+    accumulation ranges: every pass of the three cells the benchmark had
+    lowers to the Mosaic program that the commit before the ranges
+    (0d73194) lowered it to, on the two-axis grid.  The digests are that
+    commit's, of the kernel's text without locations, taken in this
+    container."""
+    import hashlib
+    (kernel,) = _mosaic_kernels(
+        _lower_kernel(one_chip, features, *shape).as_text())
     assert hashlib.sha256(kernel.encode()).hexdigest()[:16] == digest
