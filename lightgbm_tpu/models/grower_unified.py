@@ -979,7 +979,8 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
 
 class _CompactState(NamedTuple):
     tree: TreeArrays
-    pane: jax.Array             # [F+9, P] int8 — partitioned plane pane
+    pane: jax.Array             # [2, rows, lanes] int8 — the partitioned
+                                # plane pane's two sides (compact.pack_planes)
     seg_start: jax.Array        # [L] i32 — leaf -> lane range start
     seg_cnt: jax.Array          # [L] i32 — physical lane count
     seg_bucket: jax.Array       # [L] i32 — static width tier
@@ -1024,20 +1025,26 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                       partition_overlap: bool, interpret: bool):
     """Compacted leaf-wise growth — reference-parity split order at the
     reference's geometric-series histogram cost (~N·log L instead of
-    N·(L-1)): every leaf's rows stay contiguous in one [F+9, P] plane
-    pane (bin rows + grad/hess bit-planes + validity), each split stably
-    partitions the parent's lane range (Pallas MXU selection-matmul
-    kernel on TPU, stable argsort oracle elsewhere) and histograms ONLY
-    the physically-smaller child's bucketed range, deriving the sibling
-    by subtraction.  Ranges are sliced at bucketed widths
-    (ops/compact.bucket_table), one tier's branch a split; the histogram tier is
-    pmax-synced over hist_axis so collectives inside the tier switch
-    stay uniform across shards.  Equivalence to the masked policy:
-    structure-exact, values within the documented cross-program ulp
-    budget (XLA CPU contracts the int8 dequantize into split-dependent
-    FMAs; see tests/test_leafcompact.py)."""
+    N·(L-1)): every leaf's rows stay contiguous in a plane pane of two
+    sides, [2, F+9 (padded), P (padded)] (bin rows + grad/hess bit-planes
+    + validity; ops/compact.pack_planes).  A leaf's range lies on the side
+    its depth's parity names; each split stably partitions the parent's
+    lane range INSIDE the pane, read from the parent's side and written
+    to the same lanes of the other (one aliased Pallas MXU
+    selection-matmul call on TPU, the stable argsort oracle elsewhere:
+    no slice out, no write-back), and histograms ONLY the
+    physically-smaller child's bucketed range, sliced from the child's
+    side, deriving the sibling by subtraction.  Ranges are handled at
+    bucketed widths (ops/compact.bucket_table), one tier's branch a
+    split; the histogram tier is pmax-synced over hist_axis so
+    collectives inside the tier switch stay uniform across shards.
+    Equivalence to the masked policy: structure-exact, values within the
+    documented cross-program ulp budget (XLA CPU contracts the int8
+    dequantize into split-dependent FMAs; see
+    tests/test_leafcompact.py)."""
     from ..ops.compact import (BLOCK, bucket_table, pack_planes, pane_rows,
-                               partition_segment, unpack_values)
+                               partition_segment, range_origin,
+                               unpack_values)
     from .. import telemetry as _tl
 
     F, N = bins.shape
@@ -1120,8 +1127,8 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
     # Device phases, as in the masked policy: the candidate and
     # segment tables are split_find's, the cache and the sibling
     # subtraction histogram's, the original-order leaf ids row_route's,
-    # the pane (packing it, slicing a range out and writing it back)
-    # partition's, the node records tree_pack's.
+    # the pane (packing it, a split's mask and its kernel) partition's,
+    # the node records tree_pack's.
     with phase_scope("histogram"):
         root_stats = _root_stats_of(full, s, compute_dtype, grad, hess,
                                     row_mask)
@@ -1177,26 +1184,26 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
         W = table[k]
 
         @phase_scope("partition")
-        def branch(pane, start, cnt, feat, thr):
-            cs = jnp.minimum(start, P - W)        # clamp: slice stays
-            delta = start - cs                    # in-pane; mask realigns
-            seg = jax.lax.dynamic_slice(pane, (jnp.int32(0), cs), (R, W))
+        def branch(pane, side, start, cnt, feat, thr):
+            # the one row of the parent's side that decides, over the
+            # lanes the kernel will read; the range itself stays where
+            # it lies
+            cs, lanes = range_origin(pane, start, W)
             pfeat = feat if c2p_arr is None else c2p_arr[feat]
-            fbin = jax.lax.dynamic_index_in_dim(
-                seg[:F], pfeat, axis=0, keepdims=False).astype(jnp.int32)
-            fbin = fbin & 255                     # int8 pane -> uint8 bin
-            lane = jnp.arange(W, dtype=jnp.int32)
-            inseg = (lane >= delta) & (lane < delta + cnt)
+            fbin = jax.lax.dynamic_slice(
+                pane, (side, pfeat, cs), (1, 1, lanes)).reshape(lanes)
+            fbin = fbin.astype(jnp.int32) & 255   # int8 pane -> uint8 bin
+            lane = cs + jnp.arange(lanes, dtype=jnp.int32)
+            inseg = (lane >= start) & (lane < start + cnt)
             go_right = fbin > thr
             mask3 = jnp.where(inseg,
                               jnp.where(go_right, 0, 1), -1).astype(jnp.int8)
             plcnt = jnp.sum(inseg & ~go_right).astype(jnp.int32)
-            new_seg = partition_segment(seg, mask3, delta, cnt, plcnt,
-                                        use_pallas=use_pallas_partition,
-                                        overlap=partition_overlap,
-                                        interpret=interpret)
-            pane2 = jax.lax.dynamic_update_slice(pane, new_seg,
-                                                 (jnp.int32(0), cs))
+            pane2 = partition_segment(pane, mask3, side, start, cnt, plcnt,
+                                      width=W,
+                                      use_pallas=use_pallas_partition,
+                                      overlap=partition_overlap,
+                                      interpret=interpret)
             return pane2, plcnt
 
         return branch
@@ -1206,11 +1213,16 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
 
         @phase_scope("histogram")
         def branch(op):
-            pane2, sstart, scnt, salt = op
+            pane2, side2, sstart, scnt, salt = op
             cs2 = jnp.minimum(sstart, P - W)
             d2 = sstart - cs2
-            hseg = jax.lax.dynamic_slice(pane2, (jnp.int32(0), cs2),
-                                         (R, W))
+            # the two sides as one [2 * rows, lanes] array (a bitcast):
+            # a two-dimensional slice fuses into the unpacking, where one
+            # of [1, R, W] is cut out first and unpacked after
+            stored = pane2.shape[1]
+            hseg = jax.lax.dynamic_slice(
+                pane2.reshape(2 * stored, pane2.shape[2]),
+                (side2 * stored, cs2), (R, W))
             hbins, hg, hh, hvalid = unpack_values(hseg, F)
             lane2 = jnp.arange(W, dtype=jnp.int32)
             hmask = (lane2 >= d2) & (lane2 < d2 + scnt) & hvalid
@@ -1273,12 +1285,15 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
             # written in place (_run_if)
             with phase_scope("partition"):
                 tier = state.seg_bucket[bl]
+                # a leaf lies on the side its depth's parity names: the
+                # root on side 0, a child on its parent's other side
+                side = (state.leaf_depth[bl] - 1) & 1
                 pane2, plcnt = state.pane, jnp.asarray(0, jnp.int32)
                 for k, branch in enumerate(partition_branches):
                     pane2, plcnt = _run_if(
                         tier == k,
-                        lambda c, branch=branch: branch(c[0], start, cnt,
-                                                        feat, thr),
+                        lambda c, branch=branch: branch(c[0], side, start,
+                                                        cnt, feat, thr),
                         (pane2, plcnt))
                 prcnt = cnt - plcnt
 
@@ -1304,7 +1319,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                     hk_span = jax.lax.pmax(hk_span, s.hist_axis)
                 small_hist = jax.lax.switch(
                     bucket_of(hk_span), hist_branches,
-                    (pane2, sstart, scnt, new_leaf))
+                    (pane2, 1 - side, sstart, scnt, new_leaf))
 
                 # the parent's row in a buffer of its own before the
                 # cache is written: fused into the children's writes it

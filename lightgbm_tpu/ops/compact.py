@@ -11,6 +11,22 @@ of the indices: the [R, N] int8 plane matrix (bin rows + grad/hess
 bit-planes + validity) is kept physically partitioned, and each split
 stably partitions the parent's lane range in one streaming sweep.
 
+The pane has TWO SIDES, [2, rows, lanes] (``pack_planes``), and a split
+partitions its range INSIDE it: one Pallas call whose blocked operand is
+the pane itself, offset to the range's first tile of 128 lanes by a
+prefetched scalar, and whose output is the same storage
+(``input_output_aliases``).  The kernel reads the parent's lanes where they lie on one side and lands
+the children, through its read-modify-write windows at pane lanes, where
+they will lie on the other.  Two sides, because the right stream runs
+ahead of the read: right rows land at ``start + plcnt + ...``, lanes a
+range partitioned onto itself would not have read yet.  Live leaves have
+disjoint lane ranges whatever their side, and the lanes a split writes
+last held an ancestor that is split and dead, so nothing live is
+overwritten; a leaf's side is the parity of its depth, which the grower
+carries anyway.  Nothing is sliced out of the pane around the kernel and
+nothing written back (until PR 37 three XLA passes over a bucketed range
+a split, more than half of the partition's time on the wide table).
+
 The Pallas kernel (TPU): grid = (lane blocks,), sequential; BOTH streams
 (left rows, then right rows) run inside each grid step, so one sweep over
 the data compacts both sides.  Per block the lane compaction is pure MXU:
@@ -18,9 +34,11 @@ an exclusive prefix-sum of the selection mask via a strict-lower-
 triangular int8 matmul, a one-hot selection matrix built by an iota
 compare, and an int8 x int8 -> int32 selection matmul that moves whole
 [R, block] panes (f32 grad/hess travel bit-exactly as 4 int8 planes).
-Each stream's compacted lanes are DMA'd to the output through a
-read-modify-write window at a running lane offset carried in SMEM.  By
-default the per-block window DMAs are OVERLAPPED (both window reads
+Each stream's compacted lanes are DMA'd to the written side through a
+read-modify-write window at a running lane offset carried in SMEM; the
+window's blend keeps every lane outside the stream's fresh ones, so the
+neighbours' bytes are rewritten with themselves.  By default the
+per-block window DMAs are OVERLAPPED (both window reads
 issue up front and the left write-back flies under the right blend): the
 two streams' fresh lane ranges are always disjoint, but their
 128-aligned RMW padding can overlap, so the right blend patches this
@@ -36,8 +54,9 @@ grid = (lane blocks, row blocks), the selection one-hots made once a lane
 block — they depend on the mask alone — and kept in VMEM for its row
 blocks (``partition_grid``).
 
-The XLA oracle (CPU/tests): a stable argsort formulation with identical
-semantics — the kernel is differentially tested against it.
+The XLA oracle (CPU/tests): a stable argsort formulation of the same
+contract (a slice of the read side, a sort, an update into the written
+side) — the kernels are differentially tested against it.
 """
 from __future__ import annotations
 
@@ -67,6 +86,17 @@ TALL_BLOCK = 512  # lane block of a pane cut into row blocks: the stored
 # estimate admits one row block up to R≈88 (F≈79) at the default lane
 # block.
 PARTITION_VMEM_BUDGET = 12 << 20
+
+
+# Where the pane lives as the kernels' aliased output.  HBM, not ANY: an
+# ANY operand XLA may place in VMEM, where dynamic DMA lane offsets
+# (128-aligned here) are disallowed.  jax 0.9's TPU interpreter keeps a
+# kernel argument in the call's own buffer only where its space reads ANY
+# (one declared HBM it copies into a kernel buffer of its own, which for
+# an output aliased to its input starts uninitialised), so the tests that
+# run a program's kernels under ``pltpu.force_tpu_interpret_mode`` patch
+# this to ``pl.ANY``; ``interpret=True`` alone runs it as it stands.
+PANE_SPACE = pltpu.HBM
 
 
 def partition_vmem_bytes(rows: int, block: int = BLOCK,
@@ -133,9 +163,12 @@ def pallas_partition_ok() -> bool:
     return ok
 
 
-def _partition_kernel(mask_ref, scal_ref, seg_ref, out_ref, win_ref,
+def _partition_kernel(scal_ref, mask_ref, seg_ref, out_ref, win_ref,
                       offs_ref, sem_ref, *, R, block):
     """Grid (nblocks,): both streams (left then right) per lane block.
+    ``seg_ref`` is this step's lane block of the parent's side of the
+    pane, ``out_ref`` the whole pane in HBM (the same buffer: the call
+    aliases it), ``scal_ref`` the prefetched ``_scalars``.
 
     Mosaic requires dynamic DMA lane offsets to be 128-aligned, so each
     stream writes a read-modify-write WINDOW at the aligned-down offset:
@@ -151,8 +184,9 @@ def _partition_kernel(mask_ref, scal_ref, seg_ref, out_ref, win_ref,
         offs_ref[0] = 0
         offs_ref[1] = 0
 
-    delta = scal_ref[0]
-    plcnt = scal_ref[1]
+    written = 1 - scal_ref[1]
+    start = scal_ref[2]
+    plcnt = scal_ref[3]
     win = block + 128
 
     # mask3 lanes: 1 = left, 0 = right, -1 = outside the segment.  All
@@ -164,7 +198,7 @@ def _partition_kernel(mask_ref, scal_ref, seg_ref, out_ref, win_ref,
     lt = (iota_s < jax.lax.broadcasted_iota(
         jnp.int32, (block, block), 1)).astype(jnp.int8)
     lane_w = jax.lax.broadcasted_iota(jnp.int32, (R, win), 1)
-    pane = seg_ref[...]                                    # [R, block] int8
+    pane = seg_ref[0]                                      # [R, block] int8
 
     for p in (0, 1):
         mi = (m == 1 - p).astype(jnp.int32)                # [1, block]
@@ -176,7 +210,7 @@ def _partition_kernel(mask_ref, scal_ref, seg_ref, out_ref, win_ref,
             preferred_element_type=jnp.int32)              # [1, block]
         # compact + shift in ONE one-hot matmul: source lane s lands at
         # window lane pos[s] + shift
-        base = delta + p * plcnt + offs_ref[p]
+        base = start + p * plcnt + offs_ref[p]             # pane lane
         p0 = (base // 128) * 128                           # aligned window
         shift = base - p0
         sel = ((jnp.broadcast_to(pos, (win, block)) + shift == iota_t)
@@ -186,7 +220,7 @@ def _partition_kernel(mask_ref, scal_ref, seg_ref, out_ref, win_ref,
             preferred_element_type=jnp.int32)              # [R, win] i32
         # RMW: read the aligned window, blend lanes [shift, shift+used)
         dma_in = pltpu.make_async_copy(
-            out_ref.at[:, pl.ds(p0, win)], win_ref, sem_ref)
+            out_ref.at[written, :, pl.ds(p0, win)], win_ref, sem_ref)
         dma_in.start()
         dma_in.wait()
         keep = ((lane_w >= shift) & (lane_w < shift + used)).astype(
@@ -195,13 +229,13 @@ def _partition_kernel(mask_ref, scal_ref, seg_ref, out_ref, win_ref,
                    + win_ref[...].astype(jnp.int32) * (1 - keep))
         win_ref[...] = blended.astype(jnp.int8)
         dma_out = pltpu.make_async_copy(
-            win_ref, out_ref.at[:, pl.ds(p0, win)], sem_ref)
+            win_ref, out_ref.at[written, :, pl.ds(p0, win)], sem_ref)
         dma_out.start()
         dma_out.wait()
         offs_ref[p] = offs_ref[p] + used
 
 
-def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
+def _partition_kernel_overlap(scal_ref, mask_ref, seg_ref, out_ref,
                               winl_ref, winr_ref, offs_ref,
                               seml_ref, semr_ref, *, R, block):
     """Grid (nblocks,): both streams per lane block, window DMAs
@@ -227,8 +261,9 @@ def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
         offs_ref[0] = 0
         offs_ref[1] = 0
 
-    delta = scal_ref[0]
-    plcnt = scal_ref[1]
+    written = 1 - scal_ref[1]
+    start = scal_ref[2]
+    plcnt = scal_ref[3]
     win = block + 128
 
     m = mask_ref[...].astype(jnp.int32)                    # [1, block]
@@ -237,20 +272,21 @@ def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
           < jax.lax.broadcasted_iota(
               jnp.int32, (block, block), 1)).astype(jnp.int8)
     lane_w = jax.lax.broadcasted_iota(jnp.int32, (R, win), 1)
-    pane = seg_ref[...]                                    # [R, block] int8
+    pane = seg_ref[0]                                      # [R, block] int8
 
-    base_l = delta + offs_ref[0]
-    base_r = delta + plcnt + offs_ref[1]
+    base_l = start + offs_ref[0]                           # pane lanes
+    base_r = start + plcnt + offs_ref[1]
     p0l = (base_l // 128) * 128
     p0r = (base_r // 128) * 128
 
+    def window(p0):
+        return out_ref.at[written, :, pl.ds(p0, win)]
+
     # both RMW window reads start immediately and fly under the matmuls;
     # neither depends on the other stream's write
-    in_l = pltpu.make_async_copy(out_ref.at[:, pl.ds(p0l, win)], winl_ref,
-                                 seml_ref)
+    in_l = pltpu.make_async_copy(window(p0l), winl_ref, seml_ref)
     in_l.start()
-    in_r = pltpu.make_async_copy(out_ref.at[:, pl.ds(p0r, win)], winr_ref,
-                                 semr_ref)
+    in_r = pltpu.make_async_copy(window(p0r), winr_ref, semr_ref)
     in_r.start()
 
     def stats(p):
@@ -290,13 +326,12 @@ def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
     # the right read may cover lanes the left write is about to touch:
     # it must have landed before that write starts
     in_r.wait()
-    out_l = pltpu.make_async_copy(winl_ref, out_ref.at[:, pl.ds(p0l, win)],
-                                  seml_ref)
+    out_l = pltpu.make_async_copy(winl_ref, window(p0l), seml_ref)
     out_l.start()
     # right blend (overlapping the left write-back): right rows where
     # they land, this block's fresh left rows where THEY land, pre-step
     # HBM bytes everywhere else.  keep_r and keep_lr are disjoint — all
-    # fresh left lanes precede delta + plcnt <= base_r.
+    # fresh left lanes precede start + plcnt <= base_r.
     patched = (merged_l * keep_lr
                + winr_ref[...].astype(jnp.int32) * (1 - keep_lr))
     blended_r = shifted_r * keep_r + patched * (1 - keep_r)
@@ -305,8 +340,7 @@ def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
     # differing bytes (stale left-window tail vs merged right window) —
     # the right window's bytes must win
     out_l.wait()
-    out_r = pltpu.make_async_copy(winr_ref, out_ref.at[:, pl.ds(p0r, win)],
-                                  semr_ref)
+    out_r = pltpu.make_async_copy(winr_ref, window(p0r), semr_ref)
     out_r.start()
     out_r.wait()
 
@@ -314,7 +348,7 @@ def _partition_kernel_overlap(mask_ref, scal_ref, seg_ref, out_ref,
     offs_ref[1] = offs_ref[1] + used_r
 
 
-def _partition_kernel_rows(mask_ref, scal_ref, seg_ref, out_ref, sel_ref,
+def _partition_kernel_rows(scal_ref, mask_ref, seg_ref, out_ref, sel_ref,
                            winl_ref, winr_ref, offs_ref, seml_ref,
                            semr_ref, *, rows, block, overlap):
     """Grid (lane blocks, row blocks), row blocks innermost: the pane of
@@ -339,11 +373,12 @@ def _partition_kernel_rows(mask_ref, scal_ref, seg_ref, out_ref, sel_ref,
         offs_ref[0] = 0
         offs_ref[1] = 0
 
-    delta = scal_ref[0]
-    plcnt = scal_ref[1]
+    written = 1 - scal_ref[1]
+    start = scal_ref[2]
+    plcnt = scal_ref[3]
     win = block + 128
-    base_l = delta + offs_ref[0]
-    base_r = delta + plcnt + offs_ref[1]
+    base_l = start + offs_ref[0]                           # pane lanes
+    base_r = start + plcnt + offs_ref[1]
     p0l = (base_l // 128) * 128
     p0r = (base_r // 128) * 128
     shift_l = base_l - p0l
@@ -391,7 +426,7 @@ def _partition_kernel_rows(mask_ref, scal_ref, seg_ref, out_ref, sel_ref,
     def _():
         row0 = pl.multiple_of(r * rows, 32)
         lane_w = jax.lax.broadcasted_iota(jnp.int32, (rows, win), 1)
-        pane = seg_ref[...]                                    # [rows, block]
+        pane = seg_ref[0]                                      # [rows, block]
 
         def place(k, shift, used):
             shifted = jax.lax.dot_general(
@@ -402,7 +437,7 @@ def _partition_kernel_rows(mask_ref, scal_ref, seg_ref, out_ref, sel_ref,
             return shifted, keep
 
         def window(p0):
-            return out_ref.at[pl.ds(row0, rows), pl.ds(p0, win)]
+            return out_ref.at[written, pl.ds(row0, rows), pl.ds(p0, win)]
 
         if overlap:
             in_l = pltpu.make_async_copy(window(p0l), winl_ref, seml_ref)
@@ -458,20 +493,63 @@ def partition_overlap_on() -> bool:
     return not hatches.flag("LGBM_TPU_PARTITION_NO_OVERLAP")
 
 
-def partition_segment(seg, mask3, delta, cnt, plcnt, *, block: int = BLOCK,
-                      use_pallas: bool = False, interpret: bool = False,
-                      overlap: bool = True):
-    """Stable in-segment partition of ``seg``'s lanes [delta, delta+cnt).
+def pane_layout(rows: int, width: int, block: int = BLOCK):
+    """(stored rows, stored lanes) of one side of the two-sided pane that
+    holds ``rows`` plane rows over ``width`` lanes (``width`` a multiple of
+    ``block``: the root's bucket).  The rows are padded to whole row
+    blocks of the kernel's grid, so a ragged last block reads and writes
+    rows that exist; the lanes by two lane blocks, so that the block
+    after the widest range and a window (a lane block and 128) that
+    starts at the last lane lie inside the array, and the array is whole
+    blocks of the blocked read (the TPU interpreter pads an operand whose
+    last block is ragged and hands the aliased output the padded
+    shape)."""
+    lanes, height, count = partition_grid(rows, block)
+    return height * count, width + 2 * lanes
 
-    seg : [R, W] int8 plane pane (W a multiple of ``block``)
-    mask3 : [W] int8 — 1 = goes left, 0 = goes right, -1 = outside the
-        segment (those lanes are preserved untouched)
-    delta, cnt, plcnt : i32 scalars — segment offset within the pane, its
-        lane count, and the number of mask3==1 lanes
 
-    Returns the pane with lanes [delta, delta+plcnt) holding the left rows
-    in original relative order, [delta+plcnt, delta+cnt) the right rows,
-    everything else byte-identical to the input.
+def range_origin(pane, start, width: int, block: int = BLOCK):
+    """``(cs, lanes)``: the pane lane at which a split of the range that
+    starts at ``start`` and lies in a bucket of ``width`` lanes is read,
+    and how many lanes from there its mask covers.  ``cs`` is ``start``
+    rounded down to 128 lanes (the tile a dynamic lane offset must keep:
+    the blocked read is indexed by elements, not by whole lane blocks, so
+    the range starts within a tile of its first block and does not
+    straddle one lane block more than it has to) and clamped so that the
+    bucket ends inside the root's; the mask is one lane block longer than
+    the bucket, because a range that starts after ``cs`` may end after
+    ``cs + width``."""
+    lanes = partition_grid(pane.shape[1], block)[0]
+    root_width = pane.shape[2] - 2 * lanes
+    cs = jnp.minimum(start // 128 * 128, root_width - width)
+    return cs.astype(jnp.int32), width + lanes
+
+
+def partition_segment(pane, mask3, side, start, cnt, plcnt, *, width: int,
+                      block: int = BLOCK, use_pallas: bool = False,
+                      interpret: bool = False, overlap: bool = True):
+    """Stable partition of a leaf's lane range, inside the pane.
+
+    pane : [2, rows', lanes'] int8, two sides of plane rows (``pack_planes``)
+    mask3 : [lanes] int8 over the pane lanes ``[cs, cs + lanes)`` of
+        ``range_origin(pane, start, width)`` — 1 = goes left, 0 = goes
+        right, -1 = outside the range
+    side : i32 scalar, 0 or 1 — the side the range is read from; the
+        children are written to the other
+    start, cnt, plcnt : i32 scalars — the range's first pane lane, its lane
+        count, and the number of mask3==1 lanes
+    width : the range's bucket (static), a multiple of ``block``
+
+    Returns the pane with, on side ``1 - side``, lanes [start, start+plcnt)
+    holding the left rows in original relative order and [start+plcnt,
+    start+cnt) the right rows.  Every other byte of both sides is what it
+    was: the Pallas call aliases the pane to its output, reads the
+    parent's lane blocks where they lie (a blocked read offset by the
+    prefetched ``cs``) and lands the children through its read-modify-
+    write windows where they will lie, so nothing is sliced out of the
+    pane and nothing written back.  Two sides because the right stream
+    runs ahead of the read: partitioned onto itself a range would
+    overwrite lanes it has not read yet.
 
     ``overlap`` (Pallas path only): overlapped window DMAs (default; the
     serialized schedule remains as the A/B reference and the
@@ -494,149 +572,137 @@ def partition_segment(seg, mask3, delta, cnt, plcnt, *, block: int = BLOCK,
                         else "partition/dma_serial")
     if costmodel.enabled():
         # analytic per-pass cost (the Pallas kernel is a custom call XLA
-        # cost analysis cannot see into): the pane is read and written
+        # cost analysis cannot see into): the range is read and written
         # once per partition pass — plus the selection matmuls' MACs
         # (R x W x lane-block one-hot contractions; 3 per block
         # overlapped, 2 serialized)
-        R, W = seg.shape
+        R = pane.shape[1]
         lanes = partition_grid(R, block)[0]
         costmodel.note_traced_pass(
-            "partition", ("pane", R, W, lanes, bool(use_pallas),
+            "partition", ("pane", R, width, lanes, bool(use_pallas),
                           bool(overlap)),
-            bytes_moved=2.0 * R * W,
-            macs=float(R) * W * lanes * (3 if overlap else 2))
+            bytes_moved=2.0 * R * width,
+            macs=float(R) * width * lanes * (3 if overlap else 2))
     with telemetry.span("partition") as sp:
-        return sp.fence(_partition_segment_jit(
-            seg, mask3, delta, cnt, plcnt, block=block,
+        return sp.fence(_partition_in_pane_jit(
+            pane, mask3, side, start, cnt, plcnt, width=width, block=block,
             use_pallas=use_pallas, interpret=interpret, overlap=overlap))
 
 
-def _partition_segment_fn(seg, mask3, delta, cnt, plcnt, *, block,
-                          use_pallas, interpret, overlap):
-    return _partition_segment_impl(
-        seg, mask3, delta, cnt, plcnt, block=block,
-        use_pallas=use_pallas, interpret=interpret, overlap=overlap)
-
-
-# jitted + wrapped in the cost registry: standalone (eager) partition
-# calls — tests, micro-benchmarks — self-report compile seconds and
-# memory analysis; under an outer trace the wrapper passes through
-from .. import costmodel as _costmodel_mod  # noqa: E402
-
-_partition_segment_jit = _costmodel_mod.instrument(
-    "partition/kernel",
-    jax.jit(_partition_segment_fn,
-            static_argnames=("block", "use_pallas", "interpret",
-                             "overlap")),
-    phase="partition")
-
-
-def _partition_segment_impl(seg, mask3, delta, cnt, plcnt, *, block,
-                            use_pallas, interpret, overlap=True):
+def _partition_in_pane_fn(pane, mask3, side, start, cnt, plcnt, *, width,
+                          block, use_pallas, interpret, overlap):
     # unconditional named_scope: profile_dir= traces label the kernel /
     # oracle ops "partition", matching the telemetry span and JSONL phase
     # key whether or not telemetry is armed (ISSUE 2 profiler alignment)
     with jax.named_scope("partition"):
-        return _partition_segment_scoped(
-            seg, mask3, delta, cnt, plcnt, block=block,
-            use_pallas=use_pallas, interpret=interpret, overlap=overlap)
+        cs, lanes = range_origin(pane, start, width, block)
+        assert mask3.shape == (lanes,), (mask3.shape, lanes)
+        if use_pallas:
+            return _partition_call(pane, mask3, side, cs, start, plcnt,
+                                   block, overlap, interpret)
+        return _partition_oracle(pane, mask3, side, cs, start, cnt)
 
 
-def _partition_rows_call(seg, mask3, scal, lanes, rows, nrb, overlap,
-                         interpret):
-    """The row-blocked kernel over ``seg``: [R, W] of its output.  The
-    output's rows are padded to whole row blocks, so a ragged last block
-    (whose input rows past R are the pipeline's padding) writes into rows
-    that are cut off here."""
-    R, W = seg.shape
+# jitted + wrapped in the cost registry: standalone (eager) partition
+# calls — tests, micro-benchmarks — self-report compile seconds and
+# memory analysis; under an outer trace the wrapper passes through.  The
+# function's name is what the trace knows the kernels by: their custom
+# calls lie under ``partition/jit(_partition_in_pane_fn)``
+# (benchmarks/metrics/partition_kernel_ms_per_iter.json).
+from .. import costmodel as _costmodel_mod  # noqa: E402
+
+_partition_in_pane_jit = _costmodel_mod.instrument(
+    "partition/kernel",
+    jax.jit(_partition_in_pane_fn,
+            static_argnames=("width", "block", "use_pallas", "interpret",
+                             "overlap")),
+    phase="partition")
+
+
+def _partition_call(pane, mask3, side, cs, start, plcnt, block, overlap,
+                    interpret):
+    """One Pallas call over the pane itself: the pane is the blocked
+    operand the parent's lanes are read through (``cs`` and ``side``
+    prefetched into the index map) and, aliased, the HBM output the
+    windows land in.  Which of the three kernels is ``partition_grid``'s
+    to say, from the pane's rows."""
+    from .. import telemetry
+    lanes, rows, nrb = partition_grid(pane.shape[1], block)
+    assert pane.shape[1] == rows * nrb, (pane.shape, rows, nrb)
+    # trace-time, like hist/pallas_fblocks: row blocks of the grids of
+    # the partition kernels traced (1 a kernel on a narrow table), and
+    # the kernels that read and write the pane itself
+    telemetry.count("partition/pallas_rblocks", nrb)
+    telemetry.count("partition/in_pane")
     win = lanes + 128
-    out = pl.pallas_call(
-        functools.partial(_partition_kernel_rows, rows=rows, block=lanes,
-                          overlap=overlap),
-        grid=(W // lanes, nrb),
-        in_specs=[
-            pl.BlockSpec((1, lanes), lambda j, r: (0, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((rows, lanes), lambda j, r: (r, j)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
-        out_shape=jax.ShapeDtypeStruct((nrb * rows, W + lanes + 256),
-                                       jnp.int8),
-        scratch_shapes=[
-            pltpu.VMEM((3 if overlap else 2, win, lanes), jnp.int8),
-            pltpu.VMEM((rows, win), jnp.int8),
-            pltpu.VMEM((rows, win), jnp.int8),
-            pltpu.SMEM((4,), jnp.int32),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
+    nblocks = mask3.shape[0] // lanes
+    # prefetched: the first lane read in tiles of 128, the side read, the
+    # range's first lane and the left stream's length
+    scal = jnp.stack([cs // 128, side, start, plcnt]).astype(jnp.int32)
+    tall = lanes != block or nrb > 1
+    if tall:
+        kernel = functools.partial(_partition_kernel_rows, rows=rows,
+                                   block=lanes, overlap=overlap)
+        grid = (nblocks, nrb)
+    else:
+        kernel = functools.partial(
+            _partition_kernel_overlap if overlap else _partition_kernel,
+            R=rows, block=lanes)
+        grid = (nblocks,)
+
+    def lane_block(j, *at):
+        # grid indices, then the prefetched scalars: (r, s) or (s,); the
+        # block's first element along each axis (pl.Element), so that the
+        # read may start on any tile of 128 lanes
+        *r, s = at
+        return (s[1], (r[0] * rows if r else 0),
+                pl.multiple_of(s[0] * 128 + j * lanes, 128))
+    in_specs = [pl.BlockSpec((1, lanes), lambda j, *_: (0, j)),
+                pl.BlockSpec((pl.Element(1), pl.Element(rows),
+                              pl.Element(lanes)), lane_block)]
+    # the held one-hots (row-blocked kernel), one RMW window a stream in
+    # flight with its semaphore, the running offsets (and stream lengths)
+    windows = 2 if overlap or tall else 1
+    scratch = (
+        [pltpu.VMEM((3 if overlap else 2, win, lanes), jnp.int8)] * tall
+        + [pltpu.VMEM((rows, win), jnp.int8)] * windows
+        + [pltpu.SMEM((4 if tall else 2,), jnp.int32)]
+        + [pltpu.SemaphoreType.DMA(())] * windows)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=pl.BlockSpec(memory_space=PANE_SPACE),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct(pane.shape, jnp.int8),
+        # operand 2 (after the scalars and the mask) is output 0: the
+        # windows are read from and written to the pane's own storage
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
-    )(mask3[None, :], scal, seg)
-    return out[:R, :W]
+    )(scal, mask3[None, :], pane)
 
 
-def _partition_segment_scoped(seg, mask3, delta, cnt, plcnt, *, block,
-                              use_pallas, interpret, overlap=True):
-    R, W = seg.shape
-    assert W % block == 0, (W, block)
-    lane = jnp.arange(W, dtype=jnp.int32)
-    inseg = (lane >= delta) & (lane < delta + cnt)
-
-    if use_pallas:
-        from .. import telemetry
-        scal = jnp.stack([delta, plcnt]).astype(jnp.int32)
-        lanes, rows, nrb = partition_grid(R, block)
-        # trace-time, like hist/pallas_fblocks: row blocks of the grids of
-        # the partition kernels traced (1 a kernel on a narrow table)
-        telemetry.count("partition/pallas_rblocks", nrb)
-        if (lanes, rows) != (block, R):
-            return jnp.where(inseg[None, :], _partition_rows_call(
-                seg, mask3, scal, lanes, rows, nrb, overlap, interpret), seg)
-        if overlap:
-            kernel = functools.partial(_partition_kernel_overlap,
-                                       R=R, block=block)
-            scratch = [
-                pltpu.VMEM((R, block + 128), jnp.int8),
-                pltpu.VMEM((R, block + 128), jnp.int8),
-                pltpu.SMEM((2,), jnp.int32),
-                pltpu.SemaphoreType.DMA(()),
-                pltpu.SemaphoreType.DMA(()),
-            ]
-        else:
-            kernel = functools.partial(_partition_kernel, R=R, block=block)
-            scratch = [
-                pltpu.VMEM((R, block + 128), jnp.int8),
-                pltpu.SMEM((2,), jnp.int32),
-                pltpu.SemaphoreType.DMA(()),
-            ]
-        out = pl.pallas_call(
-            kernel,
-            grid=(W // block,),
-            in_specs=[
-                pl.BlockSpec((1, block), lambda j: (0, j)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((R, block), lambda j: (0, j)),
-            ],
-            # HBM, not ANY: Mosaic may place ANY in VMEM, where dynamic
-            # DMA lane offsets (128-aligned here) are disallowed
-            out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
-            out_shape=jax.ShapeDtypeStruct((R, W + block + 256), jnp.int8),
-            scratch_shapes=scratch,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=interpret,
-        )(mask3[None, :], scal, seg)
-        return jnp.where(inseg[None, :], out[:, :W], seg)
-
-    # XLA oracle: stable sort by class (left 0, right 1, outside 2) puts
-    # left+right compacted at the FRONT of the sorted pane; rolling by
-    # ``delta`` aligns them with the segment's true position
+def _partition_oracle(pane, mask3, side, cs, start, cnt):
+    """The XLA oracle of the same contract: stable sort by class (left 0,
+    right 1, outside 2) puts left+right compacted at the FRONT of the
+    sorted range; rolling by ``start - cs`` aligns them with the range's
+    true position; lanes outside it keep the written side's bytes."""
+    _, rows, _ = pane.shape
+    lanes = mask3.shape[0]
+    zero = jnp.int32(0)
+    read = jax.lax.dynamic_slice(pane, (side, zero, cs), (1, rows, lanes))
+    kept = jax.lax.dynamic_slice(pane, (1 - side, zero, cs),
+                                 (1, rows, lanes))
+    lane = cs + jnp.arange(lanes, dtype=jnp.int32)
+    inseg = (lane >= start) & (lane < start + cnt)
     keys = jnp.where(mask3 == 1, 0, jnp.where(mask3 == 0, 1, 2))
     order = jnp.argsort(keys, stable=True)
-    permuted = jnp.roll(jnp.take(seg, order, axis=1), delta, axis=1)
-    return jnp.where(inseg[None, :], permuted, seg)
+    permuted = jnp.roll(jnp.take(read, order, axis=2), start - cs, axis=2)
+    return jax.lax.dynamic_update_slice(
+        pane, jnp.where(inseg[None, None, :], permuted, kept),
+        (1 - side, zero, cs))
 
 
 def pane_rows(num_features: int) -> int:
@@ -647,24 +713,33 @@ def pane_rows(num_features: int) -> int:
     return -(-r // 8) * 8
 
 
-def pack_planes(bins, grad, hess, row_mask, width: int) -> jax.Array:
-    """[pane_rows(F), width] int8 plane pane: bin rows, grad/hess as 4
-    int8 bit-planes each (bit-exact f32 transport through the int8
-    selection matmul), validity, zero rows up to the sublane tile.  Lane
-    padding beyond N is garbage — every consumer masks by segment
-    extent."""
+def pack_planes(bins, grad, hess, row_mask, width: int,
+                block: int = BLOCK) -> jax.Array:
+    """The pane a tree starts from: ``[2, rows', lanes']`` int8
+    (``pane_layout`` of ``pane_rows(F)`` rows over ``width`` lanes).  Side
+    0 holds the root's planes: bin rows, grad/hess as 4 int8 bit-planes
+    each (bit-exact f32 transport through the int8 selection matmul),
+    validity; everything else is zeros, side 1 too.  A split reads its
+    parent from one side and writes the children into the same lanes of
+    the other, so side 1 is read where nothing was written yet only as a
+    window's padding and as the masked lanes of a bucketed histogram
+    range: zeros are finite gradients, what uninitialised memory need not
+    be.  Written in place into the zeros (the table's rows once, the nine
+    value rows once), not concatenated and padded: three passes over the
+    pane's bytes fewer a tree."""
     F, N = bins.shape
-    planes = [jax.lax.bitcast_convert_type(bins.astype(jnp.uint8),
-                                           jnp.int8)]
+    values = []
     for v in (grad, hess):
         u = jax.lax.bitcast_convert_type(v.astype(jnp.float32), jnp.uint32)
         for k in range(4):
-            planes.append(jax.lax.bitcast_convert_type(
+            values.append(jax.lax.bitcast_convert_type(
                 ((u >> (8 * k)) & 0xFF).astype(jnp.uint8), jnp.int8))
-    planes.append(row_mask.astype(jnp.int8))
-    pane = jnp.concatenate(
-        [p if p.ndim == 2 else p[None, :] for p in planes], axis=0)
-    return jnp.pad(pane, ((0, pane_rows(F) - (F + 9)), (0, width - N)))
+    values.append(row_mask.astype(jnp.int8))
+    pane = jnp.zeros((2,) + pane_layout(pane_rows(F), width, block),
+                     jnp.int8)
+    pane = pane.at[0, :F, :N].set(jax.lax.bitcast_convert_type(
+        bins.astype(jnp.uint8), jnp.int8))
+    return pane.at[0, F:F + 9, :N].set(jnp.stack(values))
 
 
 def unpack_values(pane_slice, F: int):

@@ -325,6 +325,7 @@ COUNTER_FAMILIES = (
     "partition/dma_overlap",
     "partition/dma_serial",
     "partition/env_no_pallas",
+    "partition/in_pane",          # kernels that read and write the pane
     "partition/pallas",
     "partition/pallas_eligible",
     "partition/pallas_ineligible",

@@ -52,6 +52,11 @@ def run_cut_cell(cell, seed, rows, columns, fence=False):
     mp = pytest.MonkeyPatch()
     mp.setattr(runner, "load_json", cut)
     mp.setattr(jax, "default_backend", lambda: "tpu")
+    # the TPU interpreter keeps the partition kernels' aliased pane in
+    # the call's own buffer only as an ANY argument (compact.PANE_SPACE)
+    from jax.experimental import pallas as pl
+    from lightgbm_tpu.ops import compact
+    mp.setattr(compact, "PANE_SPACE", pl.ANY)
     telemetry.reset()
     telemetry.enable(fence=fence)
     try:

@@ -11,29 +11,61 @@ import pytest
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.compact import (BLOCK, bucket_table, pack_planes,
-                                      partition_segment, unpack_values)
+                                      pane_layout, partition_segment,
+                                      range_origin, unpack_values)
 
 
-def _random_case(rng, R, W, delta, cnt):
-    seg = rng.randint(-128, 128, (R, W)).astype(np.int8)
-    m = rng.randint(0, 2, W).astype(np.int8)
-    lane = np.arange(W)
-    mask3 = np.where((lane >= delta) & (lane < delta + cnt), m, -1)
-    return seg, mask3.astype(np.int8), int((mask3 == 1).sum())
+def _pane_case(rng, R, P, width, start, cnt, side, left=None):
+    """A random two-sided pane of ``R`` plane rows over a root bucket of
+    ``P`` lanes, and the mask of the range [start, start + cnt) in a
+    bucket of ``width``: (pane, mask3, plcnt, what the call must
+    return).  ``left``: every lane's direction, or None for a coin."""
+    rows, lanes_total = pane_layout(R, P)
+    pane = rng.randint(-128, 128, (2, rows, lanes_total)).astype(np.int8)
+    cs, lanes = range_origin(pane, start, width)
+    lane = int(cs) + np.arange(lanes)
+    m = (rng.randint(0, 2, lanes) if left is None
+         else np.full(lanes, left)).astype(np.int8)
+    mask3 = np.where((lane >= start) & (lane < start + cnt), m,
+                     -1).astype(np.int8)
+    plcnt = int((mask3 == 1).sum())
+    # the contract, in NumPy: the children on the other side, left rows
+    # then right rows in their order, and every other byte of both sides
+    # what it was
+    want = pane.copy()
+    inner = pane[side][:, start:start + cnt]
+    went = mask3[start - int(cs):start - int(cs) + cnt]
+    want[1 - side][:, start:start + plcnt] = inner[:, went == 1]
+    want[1 - side][:, start + plcnt:start + cnt] = inner[:, went == 0]
+    return pane, mask3, plcnt, want
+
+
+def _partition(pane, mask3, side, start, cnt, plcnt, width, **kw):
+    return np.asarray(partition_segment(
+        jnp.asarray(pane), jnp.asarray(mask3), jnp.int32(side),
+        jnp.int32(start), jnp.int32(cnt), jnp.int32(plcnt), width=width,
+        **kw))
+
+
+KERNELS = {"oracle": {},
+           "serial": dict(use_pallas=True, interpret=True, overlap=False),
+           "overlap": dict(use_pallas=True, interpret=True, overlap=True)}
 
 
 @pytest.mark.parametrize("delta,cnt", [
     (0, 4096), (0, 4000), (100, 3000), (4095, 1), (0, 1), (123, 0),
 ])
 def test_partition_kernel_matches_oracle(delta, cnt):
+    """The cases the out-of-pane call had over a [11, 4096] range, now a
+    bucket of 4,096 lanes that starts 2,048 lanes into side 1 of a root
+    of 8,192."""
     rng = np.random.RandomState(delta + cnt)
-    R, W = 11, 4096
-    seg, mask3, plcnt = _random_case(rng, R, W, delta, cnt)
-    args = (jnp.asarray(seg), jnp.asarray(mask3), jnp.int32(delta),
-            jnp.int32(cnt), jnp.int32(plcnt))
-    oracle = np.asarray(partition_segment(*args, block=2048))
-    kernel = np.asarray(partition_segment(*args, block=2048,
-                                          use_pallas=True, interpret=True))
+    R, P, W = 11, 8192, 4096
+    pane, mask3, plcnt, want = _pane_case(rng, R, P, W, 2048 + delta, cnt, 1)
+    args = (pane, mask3, 1, 2048 + delta, cnt, plcnt, W)
+    oracle = _partition(*args)
+    kernel = _partition(*args, use_pallas=True, interpret=True)
+    np.testing.assert_array_equal(oracle, want)
     np.testing.assert_array_equal(oracle, kernel)
 
 
@@ -54,29 +86,111 @@ def test_partition_dma_overlap_bit_identity(delta, cnt, features):
     """The overlapped-DMA kernel schedule (both window reads up front,
     left write-back under the right blend, VMEM-side merge of the fresh
     left lanes into the right window) must be BIT-identical to both the
-    serialized schedule and the oracle.  W=8192 runs 4 lane blocks, so
-    the running offsets and the cross-block window overlaps (the lanes
-    the merge exists for) are genuinely exercised."""
+    serialized schedule and the oracle.  A bucket of 8,192 lanes runs 5
+    lane blocks (17 of the row-blocked kernel's), so the running offsets
+    and the cross-block window overlaps (the lanes the merge exists for)
+    are genuinely exercised.  The range starts 4,096 lanes into side 0 of
+    a root of 16,384."""
     rng = np.random.RandomState(delta * 7 + cnt)
-    R, W = 13, 8192
+    R, P, W = 13, 16384, 8192
     if features is not None:
         # the row-blocked kernel: one-hots made at a lane block's first
-        # row block land every row block's rows, 16 lane blocks of 512
+        # row block land every row block's rows, 17 lane blocks of 512
         from lightgbm_tpu.ops.compact import pane_rows, partition_grid
         R = pane_rows(features)
         assert partition_grid(R) == dict(TALL_PANES)[features]
-    seg, mask3, plcnt = _random_case(rng, R, W, delta, cnt)
-    args = (jnp.asarray(seg), jnp.asarray(mask3), jnp.int32(delta),
-            jnp.int32(cnt), jnp.int32(plcnt))
-    oracle = np.asarray(partition_segment(*args, block=2048))
-    serial = np.asarray(partition_segment(*args, block=2048,
-                                          use_pallas=True, interpret=True,
-                                          overlap=False))
-    overlap = np.asarray(partition_segment(*args, block=2048,
-                                           use_pallas=True, interpret=True,
-                                           overlap=True))
-    np.testing.assert_array_equal(oracle, serial)
-    np.testing.assert_array_equal(oracle, overlap)
+    start = 4096 + delta
+    pane, mask3, plcnt, want = _pane_case(rng, R, P, W, start, cnt, 0)
+    args = (pane, mask3, 0, start, cnt, plcnt, W)
+    np.testing.assert_array_equal(_partition(*args), want)
+    np.testing.assert_array_equal(_partition(*args, **KERNELS["serial"]),
+                                  want)
+    np.testing.assert_array_equal(_partition(*args, **KERNELS["overlap"]),
+                                  want)
+
+
+# (root lanes, bucket, start, cnt, every lane's direction or a coin)
+IN_PANE_CASES = {
+    "aligned": (8192, 4096, 2048, 3000, None),
+    "unaligned": (8192, 4096, 2048 + 777, 3000, None),
+    # the bucket clamped against the root's end, the range's last lane
+    # the pane's last: the right window starts in the lane padding
+    "ends_at_last_lane": (8192, 2048, 8192 - 1500, 1500, None),
+    "last_lane_alone": (8192, 2048, 8191, 1, 0),
+    "one_lane": (8192, 2048, 3000, 1, 1),
+    "none_left": (8192, 4096, 2500, 3000, 0),
+    "all_left": (8192, 4096, 2500, 3000, 1),
+    "bucket_twice_the_range": (8192, 4096, 4000, 2048, None),
+    "root": (4096, 4096, 0, 4096, None),
+}
+
+
+@pytest.mark.parametrize("features", [4, 100])
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+@pytest.mark.parametrize("case", sorted(IN_PANE_CASES))
+@pytest.mark.parametrize("side", [0, 1])
+def test_partition_in_pane_contract(side, case, kernel, features):
+    """One contract for the oracle, the one-block kernels under both DMA
+    schedules (4 columns) and the row-blocked kernel (100 columns: a pane
+    past 88 rows): the children's lanes on the written side hold the
+    stable partition; EVERY OTHER BYTE OF BOTH SIDES is what it was (what
+    the ``where`` over the sliced-out range used to enforce), so the read
+    side is untouched."""
+    from lightgbm_tpu.ops.compact import pane_rows, partition_grid
+    R = pane_rows(features)
+    assert (partition_grid(R)[0] == BLOCK) == (features == 4)
+    P, W, start, cnt, left = IN_PANE_CASES[case]
+    rng = np.random.RandomState(len(case) * 31 + side)
+    pane, mask3, plcnt, want = _pane_case(rng, R, P, W, start, cnt, side,
+                                          left)
+    assert plcnt == {None: plcnt, 0: 0, 1: cnt}[left]
+    out = _partition(pane, mask3, side, start, cnt, plcnt, W,
+                     **KERNELS[kernel])
+    np.testing.assert_array_equal(out[side], pane[side])
+    np.testing.assert_array_equal(out, want)
+
+
+def test_partition_chain_reads_each_child_from_its_side():
+    """Three splits in turn, as the grower makes them: the root (side 0
+    to side 1), its right child (side 1 to side 0), that child's left
+    child (side 0 to side 1).  Each range read from the side it was
+    written to holds the rows a NumPy replay puts there; the same lanes
+    of the wrong side hold a dead ancestor's."""
+    rng = np.random.RandomState(17)
+    R, N, P = 12, 5000, 6144
+    rows = rng.randint(-128, 128, (R, N)).astype(np.int8)
+    pane = np.zeros((2,) + pane_layout(R, P), np.int8)
+    pane[0, :R, :N] = rows
+    pane = jnp.asarray(pane)
+    table = bucket_table(N)
+    replay = rows.copy()          # columns in partitioned order
+    start, cnt, side = 0, N, 0
+    for by, take_left in enumerate((False, True, True)):
+        width = min(w for w in table if w >= cnt)
+        cs, lanes = range_origin(pane, start, width)
+        lane = int(cs) + np.arange(lanes)
+        inseg = (lane >= start) & (lane < start + cnt)
+        go_left = np.zeros(lanes, bool)
+        go_left[inseg] = replay[by, start:start + cnt] < 0
+        mask3 = np.where(inseg, go_left, -1).astype(np.int8)
+        plcnt = int(go_left.sum())
+        assert 0 < plcnt < cnt
+        pane = partition_segment(
+            pane, jnp.asarray(mask3), jnp.int32(side), jnp.int32(start),
+            jnp.int32(cnt), jnp.int32(plcnt), width=width, use_pallas=True,
+            interpret=True)
+        seg = replay[:, start:start + cnt]
+        left = seg[by] < 0
+        replay[:, start:start + cnt] = np.concatenate(
+            [seg[:, left], seg[:, ~left]], axis=1)
+        side = 1 - side
+        got = np.asarray(pane)
+        np.testing.assert_array_equal(got[side, :R, start:start + cnt],
+                                      replay[:, start:start + cnt])
+        assert not np.array_equal(got[1 - side, :R, start:start + cnt],
+                                  replay[:, start:start + cnt])
+        start, cnt = ((start, plcnt) if take_left
+                      else (start + plcnt, cnt - plcnt))
 
 
 @pytest.mark.parametrize("features,grid", [
@@ -118,46 +232,56 @@ def test_partition_grid_fits_any_pane(monkeypatch, features, grid):
 
 def test_partition_row_blocks_are_counted():
     """``partition/pallas_rblocks``: the grid's row blocks, once a kernel
-    traced, beside ``partition/pallas``."""
+    traced, beside ``partition/pallas``; and ``partition/in_pane``, one
+    a kernel that reads and writes the pane itself, which is every one."""
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.ops.compact import pane_rows
     rng = np.random.RandomState(11)
     telemetry.enable()
     try:
+        assert "partition/in_pane" in telemetry.COUNTER_FAMILIES
         for features, blocks in ((4, 1), (1000, 2)):
             before = dict(telemetry.counters())
-            seg, mask3, plcnt = _random_case(rng, pane_rows(features), 2048,
-                                             5, 2000)
-            partition_segment(jnp.asarray(seg), jnp.asarray(mask3),
-                              jnp.int32(5), jnp.int32(2000),
-                              jnp.int32(plcnt), use_pallas=True,
-                              interpret=True)
+            # shapes no other test of a worker's chain traces: a counter
+            # of trace time does not move when the trace is cached
+            pane, mask3, plcnt, _ = _pane_case(
+                rng, pane_rows(features), 6144, 2048, 5, 2000, 0)
+            _partition(pane, mask3, 0, 5, 2000, plcnt, 2048,
+                       use_pallas=True, interpret=True)
             after = telemetry.counters()
-            assert after["partition/pallas"] \
-                - before.get("partition/pallas", 0) == 1
-            assert after["partition/pallas_rblocks"] \
-                - before.get("partition/pallas_rblocks", 0) == blocks
+
+            def added(name):
+                return after[name] - before.get(name, 0)
+            assert added("partition/pallas") == 1
+            assert added("partition/in_pane") == 1
+            assert added("partition/pallas_rblocks") == blocks
+        before = dict(telemetry.counters())
+        _partition(pane, mask3, 0, 5, 2000, plcnt, 2048)
+        after = telemetry.counters()
+        assert after["partition/xla"] - before.get("partition/xla", 0) == 1
+        assert after["partition/in_pane"] == before["partition/in_pane"]
     finally:
         telemetry.disable()
 
 
 def test_partition_oracle_semantics():
-    """Stable partition of the in-segment lanes; everything else
-    preserved byte for byte."""
+    """Stable partition of the range's lanes onto the other side;
+    everything else preserved byte for byte."""
     rng = np.random.RandomState(3)
-    R, W, delta, cnt = 5, 8192, 777, 6000
-    seg, mask3, plcnt = _random_case(rng, R, W, delta, cnt)
-    out = np.asarray(partition_segment(
-        jnp.asarray(seg), jnp.asarray(mask3), jnp.int32(delta),
-        jnp.int32(cnt), jnp.int32(plcnt)))
-    m = mask3[delta:delta + cnt]
-    inner = seg[:, delta:delta + cnt]
-    np.testing.assert_array_equal(out[:, delta:delta + plcnt],
+    R, P, W, start, cnt = 5, 16384, 8192, 4096 + 777, 6000
+    pane, mask3, plcnt, _ = _pane_case(rng, R, P, W, start, cnt, 1)
+    out = _partition(pane, mask3, 1, start, cnt, plcnt, W)
+    cs = int(range_origin(pane, start, W)[0])
+    m = mask3[start - cs:start - cs + cnt]
+    inner = pane[1][:, start:start + cnt]
+    np.testing.assert_array_equal(out[0][:, start:start + plcnt],
                                   inner[:, m == 1])
-    np.testing.assert_array_equal(out[:, delta + plcnt:delta + cnt],
+    np.testing.assert_array_equal(out[0][:, start + plcnt:start + cnt],
                                   inner[:, m == 0])
-    np.testing.assert_array_equal(out[:, :delta], seg[:, :delta])
-    np.testing.assert_array_equal(out[:, delta + cnt:], seg[:, delta + cnt:])
+    np.testing.assert_array_equal(out[0][:, :start], pane[0][:, :start])
+    np.testing.assert_array_equal(out[0][:, start + cnt:],
+                                  pane[0][:, start + cnt:])
+    np.testing.assert_array_equal(out[1], pane[1])
 
 
 def test_plane_pack_roundtrip():
@@ -170,9 +294,15 @@ def test_plane_pack_roundtrip():
     from lightgbm_tpu.ops.compact import pane_rows
     pane = pack_planes(jnp.asarray(bins), jnp.asarray(grad),
                        jnp.asarray(hess), jnp.asarray(mask), 2048)
-    assert pane.shape == (pane_rows(F), 2048)
+    # two sides of whole row blocks and the root's bucket and two lane
+    # blocks; the root on side 0, zeros (finite gradients) everywhere else
+    assert pane.shape == (2,) + pane_layout(pane_rows(F), 2048) \
+        == (2, 16, 2048 + 2 * BLOCK)
     assert pane_rows(F) % 8 == 0
-    b, g, h, v = unpack_values(pane[:, :N], F)
+    assert not np.asarray(pane[1]).any()
+    assert not np.asarray(pane[0, :, N:]).any()
+    assert not np.asarray(pane[0, F + 9:]).any()
+    b, g, h, v = unpack_values(pane[0, :, :N], F)
     np.testing.assert_array_equal(np.asarray(b), bins)
     np.testing.assert_array_equal(np.asarray(g), grad)   # bit-exact planes
     np.testing.assert_array_equal(np.asarray(h), hess)
@@ -241,6 +371,33 @@ def test_compact_grower_matches_masked_grower(dtype, bagging):
         np.testing.assert_allclose(np.asarray(t1.leaf_value),
                                    np.asarray(t2.leaf_value),
                                    rtol=1e-4, atol=1e-7)
+
+
+def test_compact_grower_reads_each_child_from_its_side():
+    """A leaf at an odd depth and one at an even depth are split in turn:
+    the root (side 0 of the pane) writes its children to side 1, a child
+    split from there writes to side 0, a grandchild back to side 1, and
+    each smaller child's histogram is taken from the side it was written
+    to.  A grower that read the wrong side would histogram a dead
+    ancestor's rows in those lanes and still run; its counts and values
+    would not be the masked grower's, which keeps no pane at all."""
+    t1, t2 = _grow_both(29, compute_dtype=jnp.float32, bagging=False,
+                        num_leaves=9)
+    left, right = np.asarray(t2.left_child), np.asarray(t2.right_child)
+    depth_of, split_depths = {0: 1}, set()
+    for node in range(int(t2.num_leaves) - 1):
+        split_depths.add(depth_of[node])
+        for child in (left[node], right[node]):
+            if child >= 0:
+                depth_of[int(child)] = depth_of[node] + 1
+    # leaves were split at depths of both parities, past the root's
+    assert {1, 2, 3} <= split_depths, split_depths
+    assert int(t1.num_leaves) == int(t2.num_leaves) == 9
+    for field in ("split_feature", "threshold_bin", "left_child",
+                  "right_child", "leaf_count", "leaf_ids", "leaf_value"):
+        np.testing.assert_array_equal(np.asarray(getattr(t1, field)),
+                                      np.asarray(getattr(t2, field)),
+                                      err_msg=field)
 
 
 def _manual_replay(bins, grad, hess, row_mask, num_bins, feature_mask, *,

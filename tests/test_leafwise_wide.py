@@ -141,7 +141,11 @@ def test_compact_grower_grows_the_plain_growers_tree(
             use_pallas_partition=True, partition_overlap=overlap,
             interpret=True)
     if num_bin == 255:
+        from jax.experimental import pallas as pl
+        from lightgbm_tpu.ops import compact
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        # as bench_cut.run_cut_cell: the TPU interpreter's ANY
+        monkeypatch.setattr(compact, "PANE_SPACE", pl.ANY)
         telemetry.reset()
         telemetry.enable(fence=True)
         try:
@@ -206,6 +210,8 @@ def test_leafwise_program_took_the_kernels(leafwise_run):
     assert counters["partition/pallas"] >= 1
     assert counters["partition/pallas_rblocks"] == counters[
         "partition/pallas"]
+    # every one of them reads and writes the pane itself (PR 37)
+    assert counters["partition/in_pane"] == counters["partition/pallas"]
     assert "partition/xla" not in counters
     assert "partition/wide_f_fallback" not in counters
     assert counters["hist/pallas_f32"] >= 2
@@ -267,6 +273,26 @@ def test_partition_metrics_read_what_is_there():
         assert readers.read("partition_roofline", state) is None
         state.counters["partition/pallas_rblocks"] = 27
         assert readers.read("partition_row_blocks", state) == 27
+        # PR 37's two: the kernels' own time, by the name of the jitted
+        # function their custom calls lie under, and the kernels that
+        # partition inside the pane; nothing on a program that has neither
+        assert readers.read("partition_in_pane_kernels", state) is None
+        assert readers.read("partition_kernel_ms_per_iter", state) is None
+        state.counters["partition/in_pane"] = 9
+        assert readers.read("partition_in_pane_kernels", state) == 9
+        seen = []
+        state.traced_iterations = 8
+        state.summary = types.SimpleNamespace(
+            scoped_seconds=lambda rx: seen.append(rx) or 1.2)
+        assert readers.read("partition_kernel_ms_per_iter", state) == 150.0
+        (pattern,) = seen
+        from lightgbm_tpu.ops import compact
+        import re
+        assert re.search(pattern, "leafcompact_split/while/body/partition/"
+                         "jit(%s)/partition/pallas_call"
+                         % compact._partition_in_pane_fn.__name__)
+        assert not re.search(pattern, "partition/jit(_partition_segment_fn)"
+                             "/partition/pallas_call")
     finally:
         for p in added:
             sys.path.remove(p)

@@ -44,8 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from tpu_described import (  # noqa: F401 (fixtures)
-    as_tpu, B, _check, F, _lower_kernel, _mosaic_kernels, N,
-    no_persistent_cache, one_chip, _pass_rules, _shape, topo, WIDE_F)
+    as_tpu, B, _check, F, _lower_kernel, _lower_partition, _mosaic_kernels,
+    N, no_persistent_cache, one_chip, _pass_rules, _shape, topo, WIDE_F)
 
 
 # ------------------------------------------------------------- kernels
@@ -160,43 +160,33 @@ def test_narrow_kernels_lower_as_before_the_feature_block_repair(
 
 @pytest.mark.parametrize("overlap", [True, False])
 def test_partition_kernel_compiles(one_chip, as_tpu, overlap):
-    from lightgbm_tpu.ops import compact
-    R = compact.pane_rows(F)
-    fn = jax.jit(compact._partition_segment_fn,
-                 static_argnames=("block", "use_pallas", "interpret",
-                                  "overlap"))
-    scalar = _shape(one_chip, (), jnp.int32)
-    compiled = fn.lower(
-        _shape(one_chip, (R, N), jnp.int8),            # seg pane
-        _shape(one_chip, (N,), jnp.int8),              # mask3
-        scalar, scalar, scalar,
-        block=compact.BLOCK, use_pallas=True, interpret=False,
-        overlap=overlap).compile()
+    """The root's split at 2**20 rows of 28 columns: the one-block kernel
+    reads the pane through a blocked operand offset by a prefetched
+    scalar and writes it, aliased, through its windows."""
+    compiled = _lower_partition(one_chip, F, N, N, overlap).compile()
     _check(compiled, custom_call=True)
+    assert "output_to_operand_aliasing={{}: (2, {})}" in compiled.as_text()
 
 
 @pytest.mark.parametrize("overlap,digest", [
-    (True, "ff9b0059156a6ac7"), (False, "f80c1067571d07f8")])
+    (True, "adf8b187faf1ab35"), (False, "5f913a79bb50370d")])
 def test_narrow_partition_kernel_is_the_program_it_was(one_chip, as_tpu,
                                                        overlap, digest):
-    """A pane of one block is not on the path of the row-block grid
-    (``compact.partition_grid``): the F=28 kernel lowers, under either DMA
-    schedule, to the Mosaic program that the commit before the grid
-    (2b047c7) lowered it to.  The digests are that commit's, of the
-    kernel's text without locations, taken in this container."""
+    """The F=28 kernel (a pane of one block, ``compact.partition_grid``)
+    lowers, under either DMA schedule, to the Mosaic program that PR 37's
+    commit lowered it to: the kernel that partitions a range inside the
+    two-sided pane (side and first lane block prefetched, the windows at
+    pane lanes).  The digests are that commit's, of the kernel's text
+    without locations, taken in this container; until then they were
+    those of 2b047c7, the commit before the row-block grid, whose
+    out-of-pane kernel is gone.  A PR that does not mean to change the
+    narrow tables' kernel leaves them as they are."""
     import hashlib
     from lightgbm_tpu.ops import compact
     R = compact.pane_rows(F)
     assert compact.partition_grid(R) == (compact.BLOCK, R, 1)
-
-    def fresh(seg, mask3, delta, cnt, plcnt):
-        return compact._partition_segment_fn(
-            seg, mask3, delta, cnt, plcnt, block=compact.BLOCK,
-            use_pallas=True, interpret=False, overlap=overlap)
-    scalar = _shape(one_chip, (), jnp.int32)
-    (kernel,) = _mosaic_kernels(jax.jit(fresh).lower(
-        _shape(one_chip, (R, N), jnp.int8), _shape(one_chip, (N,), jnp.int8),
-        scalar, scalar, scalar).as_text())
+    (kernel,) = _mosaic_kernels(
+        _lower_partition(one_chip, F, N, N, overlap).as_text())
     assert hashlib.sha256(kernel.encode()).hexdigest()[:16] == digest
 
 

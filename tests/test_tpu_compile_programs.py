@@ -13,7 +13,8 @@ import jax.numpy as jnp
 from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, B, _Captured, _captured_chunk_program, _check, F, _grow_args,
     _GROW_KW, HBM_BYTES, LEAVES, _like, _lower_route_kernel, N,
-    no_persistent_cache, one_chip, _shape, _tiny_binary_dataset, topo)
+    no_persistent_cache, one_chip, _range_passes, _shape,
+    _tiny_binary_dataset, topo, _TracedCounters)
 
 NARROW_N = 10_502_144    # benchmarks/configs/higgs-levelwise-int8, padded
 
@@ -46,14 +47,20 @@ def test_route_kernel_compiles_at_the_narrow_cell(one_chip, as_tpu, slots):
 
 def test_grow_leafcompact_f32_compiles(one_chip, as_tpu):
     """The default route of task=train on a TPU: compacted grower, Pallas
-    partition, Pallas float histogram."""
+    partition, Pallas float histogram.  Every partition kernel (the
+    one-block kernel, one a bucket width) reads and writes the pane
+    itself, and XLA makes no pass over a split's range around it."""
     from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
     from lightgbm_tpu.ops.compact import pallas_partition_ok
     assert pallas_partition_ok()
-    compiled = grow_tree_leafcompact.lower(
-        *_grow_args(one_chip), use_pallas_partition=True,
-        partition_overlap=True, **_GROW_KW).compile()
+    with _TracedCounters() as traced:
+        compiled = grow_tree_leafcompact.lower(
+            *_grow_args(one_chip), use_pallas_partition=True,
+            partition_overlap=True, **_GROW_KW).compile()
+    assert traced["partition/in_pane"] == traced["partition/pallas"] > 1
+    assert traced["partition/pallas_rblocks"] == traced["partition/pallas"]
     ma = _check(compiled, custom_call=True)
+    assert _range_passes(compiled.as_text(), F) == []
     # the route that holds an 11M-row table: well under 1 KB of temp/row
     assert ma.temp_size_in_bytes / N < 1024, ma.temp_size_in_bytes
 

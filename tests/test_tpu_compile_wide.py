@@ -8,8 +8,9 @@ import re
 
 from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, _captured_chunk_program, _cell_size, _check, _grow_args,
-    _GROW_KW, _like, _lower_route_kernel, no_persistent_cache, one_chip,
-    _tiny_binary_dataset, topo, WIDE_F, WIDE_N)
+    _GROW_KW, _like, _lower_partition, _lower_route_kernel,
+    no_persistent_cache, one_chip, _range_passes, _tiny_binary_dataset,
+    topo, _TracedCounters, WIDE_F, WIDE_N)
 
 import pytest
 
@@ -69,24 +70,15 @@ def test_partition_kernel_compiles_on_the_wide_table(one_chip, as_tpu,
     three row blocks of 672 at 512 lanes, the one-hots held in VMEM for
     the row blocks of a lane block (``compact.partition_grid``).  One block
     of that pane is priced at 91 MiB."""
-    import jax
-    import jax.numpy as jnp
     from lightgbm_tpu.ops import compact
-    from tpu_described import _shape
     R = compact.pane_rows(WIDE_F)
     assert compact.partition_vmem_bytes(R) > 90 << 20
     assert compact.partition_grid(R) == (512, 672, 3)
-    fn = jax.jit(compact._partition_segment_fn,
-                 static_argnames=("block", "use_pallas", "interpret",
-                                  "overlap"))
-    scalar = _shape(one_chip, (), jnp.int32)
-    compiled = fn.lower(
-        _shape(one_chip, (R, WIDE_N_PADDED), jnp.int8),
-        _shape(one_chip, (WIDE_N_PADDED,), jnp.int8),
-        scalar, scalar, scalar,
-        block=compact.BLOCK, use_pallas=True, interpret=False,
-        overlap=overlap).compile()
+    assert compact.pane_layout(R, WIDE_N_PADDED) == (2016, 402_432)
+    compiled = _lower_partition(one_chip, WIDE_F, WIDE_N_PADDED,
+                                WIDE_N_PADDED, overlap).compile()
     _check(compiled, custom_call=True)
+    assert "output_to_operand_aliasing={{}: (2, {})}" in compiled.as_text()
 
 
 def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
@@ -98,14 +90,25 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
     gradient pair is a rounding XLA does not take for the identity (so the
     lo half carries something), and a split writes the pane and the leaf
     histogram cache in place (under ``lax.cond`` / ``lax.switch`` each was
-    copied whole, twice a split)."""
+    copied whole, twice a split).  Since PR 37 a split's range is
+    partitioned inside the pane: every partition kernel reads and writes
+    the pane itself, and XLA makes no pass over a range around it (the
+    slice out, the ``where`` and the ``dynamic_update_slice`` back were
+    164 of the cell's 314 ms of ``partition``)."""
     from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
     kw = dict(_GROW_KW, min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0)
-    compiled = grow_tree_leafcompact.lower(
-        *_grow_args(one_chip, WIDE_N, WIDE_F), use_pallas_partition=True,
-        partition_overlap=True, **kw).compile()
+    with _TracedCounters() as traced:
+        compiled = grow_tree_leafcompact.lower(
+            *_grow_args(one_chip, WIDE_N, WIDE_F), use_pallas_partition=True,
+            partition_overlap=True, **kw).compile()
+    assert traced["partition/in_pane"] == traced["partition/pallas"] == 9
+    assert traced["partition/pallas_rblocks"] == 27
     _cell_size(_check(compiled, custom_call=True))
     text = compiled.as_text()
+    assert _range_passes(text, WIDE_F) == []
+    assert len(re.findall(
+        r'"tpu_custom_call"[^\n]*/partition/jit\(_partition_in_pane_fn\)',
+        text)) == 9
     rounded = re.findall(r"(%[\w.\-]+) = f32\[[^ ]* reduce-precision\("
                          r"(%[\w.\-]+)\), exponent_bits=8, mantissa_bits=7",
                          text)
@@ -114,7 +117,7 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
     assert any(re.search(r"subtract\(%s, %s\)" % (re.escape(x),
                                                   re.escape(hi)), text)
                for hi, x in rounded)
-    assert not re.search(r"= s8\[2016,401408\][^ ]* copy\(", text)
+    assert not re.search(r"= s8\[2,2016,402432\][^ ]* copy\(", text)
     assert not re.search(r"= f32\[255,2000,255,3\][^ ]* copy\(", text)
     # the float pass folds the bin code by 8: ten kernels (the root's pass
     # and nine bucket widths) of a [32, 40] accumulator a feature.  No

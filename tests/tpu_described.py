@@ -148,6 +148,72 @@ def _lower_route_kernel(one_chip, features, rows, slots):
         *per_slot)
 
 
+def _lower_partition(one_chip, features, root_lanes, width, overlap):
+    """One split's partition of a bucket of ``width`` lanes inside the
+    two-sided pane of a table of ``features`` columns and ``root_lanes``
+    padded rows, traced anew and lowered for the described chip."""
+    from lightgbm_tpu.ops import compact
+    pane = (2,) + compact.pane_layout(compact.pane_rows(features),
+                                      root_lanes)
+    lanes = width + compact.partition_grid(pane[1])[0]
+
+    def fresh(pane, mask3, side, start, cnt, plcnt):
+        return compact._partition_in_pane_fn(
+            pane, mask3, side, start, cnt, plcnt, width=width,
+            block=compact.BLOCK, use_pallas=True, interpret=False,
+            overlap=overlap)
+    scalar = _shape(one_chip, (), jnp.int32)
+    return jax.jit(fresh, donate_argnums=0).lower(
+        _shape(one_chip, pane, jnp.int8), _shape(one_chip, (lanes,), jnp.int8),
+        scalar, scalar, scalar, scalar)
+
+
+def _range_passes(text, features):
+    """Lines of a compiled leaf-wise tree program in which XLA passes over
+    a split's range or the pane: a ``copy``, ``select``, ``dynamic-slice``
+    or ``dynamic-update-slice`` of a split's ``partition`` scope whose
+    result has the pane's rows and a lane block or more (a split's mask
+    is one row), and a ``copy`` of both sides under any scope or none.
+    The histogram branch's own slice of the child's range is under
+    ``histogram``, and the root's nine value rows are written into the
+    pane once a tree, outside the split loop: neither is one of them."""
+    import re
+    from lightgbm_tpu.ops import compact
+    R = compact.pane_rows(features)
+    heights = (R, compact.pane_layout(R, compact.BLOCK)[0])
+    found = []
+    for line in text.splitlines():
+        op = re.search(r"= s8\[([\d,]+)\][^ ]* (copy|select|dynamic-slice|"
+                       r"dynamic-update-slice)\(", line)
+        if not op:
+            continue
+        dims = [int(d) for d in op.group(1).split(",")]
+        if (len(dims) < 2 or dims[-2] not in heights
+                or dims[-1] < compact.TALL_BLOCK):
+            continue
+        both_sides = len(dims) == 3 and dims[0] == 2
+        if (both_sides and op.group(2) == "copy") or re.search(
+                r'op_name="[^"]*leafcompact_split/[^"]*/partition[/"]', line):
+            found.append(line.strip()[:200])
+    return found
+
+
+class _TracedCounters(dict):
+    """The telemetry counters a ``with`` block's traces added."""
+
+    def __enter__(self):
+        from lightgbm_tpu import telemetry
+        telemetry.enable()
+        self._before = dict(telemetry.counters())
+        return self
+
+    def __exit__(self, *exc):
+        from lightgbm_tpu import telemetry
+        self.update({name: count - self._before.get(name, 0)
+                     for name, count in telemetry.counters().items()})
+        telemetry.disable()
+
+
 def _mosaic_kernels(lowered_text):
     """Every Pallas kernel of a lowered program as Mosaic MLIR text
     without locations.  The lowered text carries each kernel serialized
