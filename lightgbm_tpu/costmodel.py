@@ -65,7 +65,9 @@ marked ``warm`` (their compile was paid by a previous run).
 """
 from __future__ import annotations
 
+import collections
 import os
+import re
 import subprocess
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -321,9 +323,12 @@ class Instrumented:
         # from the measured phase seconds — attained rates must price
         # execution, not compilation, or cold-vs-warm-cache rounds would
         # read as kernel regressions (perf_gate false positives)
+        # "_compiled": what op_phases() prints and parses at its first call
+        # (never here: a capture may lie inside a timed slice)
         rec = {"name": self.name, "phase": self.phase,
                "compile_seconds": dt, "capture_seconds": dt,
-               "calls": 0, "warm": warm_hint, "gen": _generation}
+               "calls": 0, "warm": warm_hint, "gen": _generation,
+               "_compiled": compiled}
         rec.update(_analyze(compiled))
         _records.append(rec)
         try:
@@ -532,13 +537,340 @@ def phase_program_records(phase: str) -> List[dict]:
     repeated calls at a bucket shape bump ``calls`` on existing records
     and never add a new one (tests/test_serving.py, bench.py
     bench_predict lane)."""
-    return [dict(r) for r in _records if r.get("phase") == phase]
+    return [{k: v for k, v in r.items() if not k.startswith("_")}
+            for r in _records if r.get("phase") == phase]
+
+
+# ------------------------------------- phases of the operations no scope holds
+
+XLA = "xla"     # the label of what the compiler put in itself
+
+# what no device runs as an operation of its own (a loop does: one of no
+# trips is an event of its own in a trace, the condition's evaluation)
+_NO_OPERATION = frozenset((
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+    "after-all", "partition-id", "replica-id", "opt-barrier"))
+# what only moves or allocates: without metadata, the compiler's own
+_MOVES = frozenset((
+    "copy", "copy-start", "copy-done", "slice-start", "slice-done",
+    "async-start", "async-update", "async-done", "custom-call"))
+# a fusion of nothing but these is a move too
+_LAYOUT_ONLY = frozenset((
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast", "copy",
+    "transpose", "reshape"))
+# the neighbours of step (d) are not looked for on the far side of these
+_BOUNDARY = frozenset(("while", "conditional", "call", "parameter"))
+_NEIGHBOUR_STEPS = 8
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8, "c64": 8, "c128": 16}
+_ARRAY = re.compile(r"\b([a-z]\w*)\[([\d,]*)\]")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_ASSIGNED = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_OPCODE = re.compile(r"([\w\-]+)\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_CALLED = re.compile(r"\b(?:calls|to_apply|body|condition|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_CALLED_LIST = re.compile(r"\b(?:branch_computations|called_computations)="
+                          r"\{([^}]*)\}")
+
+
+_Instr = collections.namedtuple(
+    "_Instr", "name opcode shape operands op_name called root")
+
+
+def _closing(text: str, at: int) -> int:
+    """Index of the parenthesis that closes the one at ``at``."""
+    depth = 0
+    for i in range(at, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(text) - 1
+
+
+def _shape_bytes(shape: str, opcode: str = "") -> int:
+    """Bytes of a result as the text gives its type: every array of a
+    tuple, but of an asynchronous start's ``(destination, source,
+    context)`` or ``((operands), result, context)`` the destination alone."""
+    if opcode.endswith("-start"):
+        if shape.startswith("(("):
+            shape = shape[_closing(shape, 1) + 1:]
+        arrays = _ARRAY.findall(shape)[:1]
+    else:
+        arrays = _ARRAY.findall(shape)
+    total = 0
+    for dtype, dims in arrays:
+        n = _DTYPE_BYTES.get(dtype, 1 if dtype.startswith("f8") else 0)
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        total += n
+    return total
+
+
+def _parse_hlo(text: str):
+    """({computation: {instruction name: _Instr}}, entry) of an HLO module
+    as ``compiled.as_text()`` prints it; instructions in their order."""
+    computations, entry, current = {}, None, None
+    for line in text.splitlines():
+        if current is None:
+            head = _COMPUTATION.match(line)
+            if head:
+                current = computations[head.group(2)] = {}
+                if head.group(1):
+                    entry = head.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        lhs = _ASSIGNED.match(line)
+        if not lhs:
+            continue
+        rest = line[lhs.end():]
+        if rest.startswith("("):        # a tuple's type holds spaces
+            end = _closing(rest, 0)
+            shape, rest = rest[:end + 1], rest[end + 1:].lstrip()
+        else:
+            shape, _, rest = rest.partition(" ")
+        opcode = _OPCODE.match(rest)
+        if not opcode:
+            continue
+        end = _closing(rest, opcode.end() - 1)
+        attrs = rest[end + 1:]
+        op_name = _OP_NAME.search(attrs)
+        called = _CALLED.findall(attrs)
+        for listed in _CALLED_LIST.findall(attrs):
+            called += [c.strip().lstrip("%") for c in listed.split(",")
+                       if c.strip()]
+        current[lhs.group(2)] = _Instr(
+            lhs.group(2), opcode.group(1), shape,
+            _OPERAND.findall(rest[opcode.end():end]),
+            op_name.group(1) if op_name else None, called,
+            bool(lhs.group(1)))
+    return computations, entry
+
+
+def _phase_pattern():
+    from . import telemetry
+    return re.compile(r"(^|/)(%s)(/|$)" % "|".join(telemetry.DEVICE_PHASES))
+
+
+def _produced_phase(body: dict, start: str, named) -> Optional[str]:
+    """Step (b): breadth-first from ``start`` towards the parameters of a
+    fused computation, the first instruction whose ``op_name`` holds a
+    phase."""
+    seen, queue = set(), collections.deque([start])
+    while queue:
+        name = queue.popleft()
+        ins = body.get(name)
+        if ins is None or name in seen:
+            continue
+        seen.add(name)
+        phase = named(ins.op_name)
+        if phase:
+            return phase
+        queue.extend(ins.operands)
+    return None
+
+
+def _fusion_phase(body: dict, named) -> Optional[str]:
+    """A fusion has its root's metadata: carried through a tuple root (the
+    phase its outputs agree on, else that of the largest output that has
+    one) and through instructions that have none."""
+    root = next((i for i in body.values() if i.root), None)
+    if root is None:
+        return None
+    if root.opcode != "tuple" or named(root.op_name):
+        return _produced_phase(body, root.name, named)
+    outputs = []
+    for name in root.operands:
+        phase = _produced_phase(body, name, named)
+        if phase:
+            outputs.append((_shape_bytes(body[name].shape), phase))
+    return max(outputs, key=lambda o: o[0])[1] if outputs else None
+
+
+def _neighbour_phase(body: dict, users: dict, start: str, direct: dict):
+    """Step (d): the nearest user's phase, then the nearest producer's,
+    through instructions that have none, ``_NEIGHBOUR_STEPS`` at most."""
+    for edges in (lambda n: users.get(n, ()), lambda n: body[n].operands):
+        frontier, seen = [start], {start}
+        for _ in range(_NEIGHBOUR_STEPS):
+            reached = []
+            for name in frontier:
+                for other in edges(name):
+                    if other in seen or other not in body:
+                        continue
+                    seen.add(other)
+                    if direct.get(other):
+                        return direct[other]
+                    if body[other].opcode not in _BOUNDARY:
+                        reached.append(other)
+            frontier = reached
+            if not frontier:
+                break
+    return None
+
+
+def label_unscoped_ops(text: str) -> Dict[str, tuple]:
+    """{instruction name: (label, opcode, result type, result bytes)} for
+    the instructions of one optimised HLO module that the device runs as
+    operations of their own and whose own ``op_name`` holds no name of
+    ``telemetry.DEVICE_PHASES``; see ``op_phases`` for the rule."""
+    pattern = _phase_pattern()
+
+    def named(op_name):
+        found = pattern.search(op_name or "")
+        return found.group(2) if found else None
+
+    computations, entry = _parse_hlo(text)
+    if entry is None:
+        return {}
+    run, todo = {}, [entry]     # the entry and what it loops over and calls
+    while todo:
+        comp = todo.pop()
+        if comp in run or comp not in computations:
+            continue
+        run[comp] = True
+        for ins in computations[comp].values():
+            if ins.opcode in ("while", "conditional", "call", "async-start"):
+                todo.extend(ins.called)
+    out = {}
+    for comp in run:
+        body = computations[comp]
+        users: Dict[str, list] = {}
+        direct, inserted = {}, set()
+        for ins in body.values():
+            for operand in ins.operands:
+                users.setdefault(operand, []).append(ins.name)
+            phase = named(ins.op_name)                          # (a)
+            fused = (computations.get(ins.called[0])
+                     if ins.opcode == "fusion" and ins.called else None)
+            if phase is None and fused:                         # (b)
+                phase = _fusion_phase(fused, named)
+            direct[ins.name] = phase
+            # (c) no metadata of any kind, inside it neither, and it only
+            # moves or allocates
+            if not ins.op_name and (
+                    ins.opcode in _MOVES if fused is None else
+                    all(not i.op_name and i.opcode in _LAYOUT_ONLY
+                        for i in fused.values())):
+                inserted.add(ins.name)
+        for ins in body.values():
+            if ins.opcode in _NO_OPERATION or named(ins.op_name):
+                continue
+            label = direct[ins.name]
+            if label is None and ins.name not in inserted:      # (d)
+                label = _neighbour_phase(body, users, ins.name, direct)
+            out[ins.name] = (label or XLA, ins.opcode,
+                             _LAYOUT.sub("", ins.shape),
+                             _shape_bytes(ins.shape, ins.opcode))
+    return out
+
+
+def _unscoped_of(rec: dict) -> Optional[Dict[str, tuple]]:
+    """``label_unscoped_ops`` of one record's executable: printed and
+    parsed at the first call and kept on the record; None where the text
+    cannot be had (no executable, one that will not print)."""
+    if "_unscoped" not in rec:
+        labels = None
+        try:
+            text = rec["_compiled"].as_text()
+            if text:
+                labels = label_unscoped_ops(text)
+        except Exception:
+            pass
+        rec["_unscoped"] = labels
+    return rec["_unscoped"]
+
+
+def op_phases(describe: bool = False) -> Dict[str, Dict[str, Any]]:
+    """{program name: {HLO instruction name: label}} for every captured
+    program of this generation whose optimised text can be had: the
+    instructions the device runs as operations of their own (in the entry
+    computation and the bodies of ``while``, ``call`` and ``conditional``)
+    **whose own ``op_name`` holds no name of ``telemetry.DEVICE_PHASES``**,
+    which is what a device trace shows under no scope.  The label is one
+    of ``DEVICE_PHASES`` or ``"xla"``, resolved in this order:
+
+    (a) the instruction's own ``op_name``: with a phase on its scope path
+        it is not in the map at all, the trace already has it;
+    (b) what it produces: XLA's own rule, "a fusion has its root's
+        metadata", carried through a tuple root and through instructions
+        that have none, breadth-first from the root of the fused
+        computation towards its parameters to the first instruction whose
+        ``op_name`` holds a phase; a tuple root whose outputs disagree
+        takes the phase of its largest output;
+    (c) an instruction that only moves or allocates (``copy``,
+        ``copy-start`` / ``-done``, ``slice-start`` / ``-done``, a buffer's
+        custom call, a fusion of nothing but copies, bitcasts, transposes
+        and reshapes) and has no metadata of any kind, inside it neither:
+        what the compiler inserts itself, in front of a conditional,
+        between two layouts or two memories.  It is ``"xla"`` at once and
+        takes no neighbour's name (the whole-pane copies XLA once put in
+        front of every split would else have read ``partition``);
+    (d) what came from the program's own code and lost its scope path (a
+        cloned fusion; the pieces of a decomposed cumulative sum, which on
+        a TPU are bare ``pad``, ``reduce-window``, ``slice`` and
+        ``reverse`` instructions with no metadata, elsewhere fusions whose
+        inner instructions say ``reduce_window_sum`` and no more) takes its
+        neighbours' in the enclosing computation: the nearest user's
+        phase, then the nearest producer's, through instructions that have
+        no phase either, ``_NEIGHBOUR_STEPS`` steps at most and not
+        across a loop, a call or a parameter; else ``"xla"``.
+
+    With ``describe`` a value is ``(label, opcode, result type, result
+    bytes)``, so that a whole-buffer copy reads as ``copy
+    s8[2,2016,402432]`` and not as ``copy.17``.  Two records of one name
+    (two signatures) are kept apart as ``name``, ``name#2``.  A trace's
+    operation has a name and a scope but no program: a reader that looks
+    names up across programs has to leave out a name that two programs
+    label differently (``benchmarks/harness/hidden.py`` does).  The text
+    is printed and parsed at the first call and once per record, never at
+    capture; a program whose text cannot be had has no entry."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for rec in _records:
+        labels = _unscoped_of(rec)
+        if labels is None:
+            continue
+        name, n = rec["name"], 1
+        while name in out:
+            n += 1
+            name = "%s#%d" % (rec["name"], n)
+        out[name] = dict(labels) if describe else {
+            op: found[0] for op, found in labels.items()}
+    return out
+
+
+def _unscoped_summary(labels: Dict[str, tuple]) -> dict:
+    """Operations per label, and the largest result of the compiler's own:
+    ``{"histogram": 37, "xla": 5, "xla_largest": ["copy", 1622605824]}``."""
+    out: Dict[str, Any] = {}
+    largest = None
+    for label, opcode, _shape, nbytes in labels.values():
+        out[label] = out.get(label, 0) + 1
+        if label == XLA and (largest is None or nbytes > largest[1]):
+            largest = [opcode, nbytes]
+    if largest is not None:
+        out["xla_largest"] = largest
+    return out
 
 
 def compile_block() -> dict:
     """Run-level compile observability: captured-program inventory,
     total cold-compile seconds, and the telemetry compile counters
-    (true backend compiles, persistent-cache hits, mid-run recompiles)."""
+    (true backend compiles, persistent-cache hits, mid-run recompiles).
+    A program whose optimised text can be had carries ``unscoped_ops``:
+    how many of its operations lie under no device phase, by the phase
+    ``op_phases`` gives them, and under ``xla_largest`` the opcode and the
+    bytes of the largest result the compiler put in itself (a copy of a
+    whole buffer shows here, without a chip and without reading HLO)."""
     from . import telemetry
     programs = []
     for rec in _records:
@@ -550,6 +882,9 @@ def compile_block() -> dict:
                 p[field] = rec[field]
         if rec.get("warm"):
             p["warm"] = True
+        labels = _unscoped_of(rec)
+        if labels is not None:
+            p["unscoped_ops"] = _unscoped_summary(labels)
         programs.append(p)
     counters = telemetry.counters()
     return {

@@ -369,7 +369,23 @@ def _compile_lines(comp):
                    else "%8s" % "-")
                 + ("  [warm]" if p.get("warm") else "")
                 + ("  [%s]" % p["error"] if p.get("error") else ""))
+        for p in progs:
+            if p.get("unscoped_ops"):
+                out.append(_unscoped_line(p.get("name", "?"),
+                                          p["unscoped_ops"]))
     return out
+
+
+def _unscoped_line(name, ops):
+    """A program's operations under no device phase, by the phase the
+    program's own map gives them, and the largest result the compiler
+    inserted itself (costmodel.op_phases)."""
+    counts = ", ".join("%s %d" % (label, n) for label, n in sorted(
+        ops.items()) if label != "xla_largest")
+    largest = ops.get("xla_largest")
+    return "%s  under no phase: %s%s" % (
+        name, counts, "; largest of xla: %s %.1f MB" % (
+            largest[0], largest[1] / 1e6) if largest else "")
 
 
 def report(path: str, as_json: bool = False) -> int:

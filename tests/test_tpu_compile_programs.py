@@ -14,7 +14,7 @@ from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, B, _Captured, _captured_chunk_program, _check, F, _grow_args,
     _GROW_KW, HBM_BYTES, LEAVES, _like, _lower_route_kernel, N,
     no_persistent_cache, one_chip, _range_passes, _shape,
-    _tiny_binary_dataset, topo, _TracedCounters)
+    _tiny_binary_dataset, topo, _TracedCounters, _unlabelled)
 
 NARROW_N = 10_502_144    # benchmarks/configs/higgs-levelwise-int8, padded
 
@@ -86,20 +86,83 @@ def test_masked_leafwise_memory_per_row_is_pinned(one_chip, as_tpu):
 
 # ----------------------------------------- programs built by the system
 
-def test_fused_chunk_program_compiles(one_chip, as_tpu, monkeypatch):
+_CHUNK = {}     # the compiled chunk program, once a module (a minute)
+
+
+def _chunk_compiled(one_chip, monkeypatch):
     """chip_smoke phase (b): the depth-wise int8 chunk of 8 iterations,
     built by GBDT.train_chunk itself, its real argument tree re-shaped to
-    N=2**20."""
-    n_tiny = 1000                      # no other axis has this length
-    prog, seen = _captured_chunk_program(
-        monkeypatch,
-        {"objective": "binary", "num_leaves": str(LEAVES),
-         "max_bin": str(B), "grow_policy": "depthwise",
-         "hist_dtype": "int8", "metric": "binary_logloss",
-         "is_training_metric": "true"},
-        _tiny_binary_dataset(n_tiny), is_eval=True)
-    args = _like(one_chip, seen, rows_from=n_tiny, rows_to=N)
-    _check(prog.lower(*args).compile(), custom_call=True)
+    N=2**20.  Call it under ``as_tpu``."""
+    if not _CHUNK:
+        n_tiny = 1000                  # no other axis has this length
+        prog, seen = _captured_chunk_program(
+            monkeypatch,
+            {"objective": "binary", "num_leaves": str(LEAVES),
+             "max_bin": str(B), "grow_policy": "depthwise",
+             "hist_dtype": "int8", "metric": "binary_logloss",
+             "is_training_metric": "true"},
+            _tiny_binary_dataset(n_tiny), is_eval=True)
+        args = _like(one_chip, seen, rows_from=n_tiny, rows_to=N)
+        _CHUNK["compiled"] = prog.lower(*args).compile()
+    return _CHUNK["compiled"]
+
+
+def test_fused_chunk_program_compiles(one_chip, as_tpu, monkeypatch):
+    _check(_chunk_compiled(one_chip, monkeypatch), custom_call=True)
+
+
+def _chunk_labels(one_chip, monkeypatch):
+    from lightgbm_tpu import costmodel
+    text = _chunk_compiled(one_chip, monkeypatch).as_text()
+    return text, costmodel.label_unscoped_ops(text)
+
+
+def test_every_unscoped_operation_of_the_chunk_program_has_a_label(
+        one_chip, as_tpu, monkeypatch):
+    """What a device trace of the narrow cell shows under no scope
+    (``unscoped_ms_per_iter``), the program names itself
+    (``costmodel.op_phases``): no operation of the entry computation or the
+    chunk's loop is left without a phase or ``xla``."""
+    from lightgbm_tpu import costmodel
+    from lightgbm_tpu.telemetry import DEVICE_PHASES
+    text, labels = _chunk_labels(one_chip, monkeypatch)
+    assert _unlabelled(text, labels) == []
+    assert len(labels) > 300
+    assert {found[0] for found in labels.values()} <= set(
+        DEVICE_PHASES) | {costmodel.XLA}
+    # the compiler's own are copies, prefetches and buffers, none as large
+    # as the table
+    summary = costmodel._unscoped_summary(labels)
+    assert summary["xla"] > 100 and summary["xla_largest"][1] < F * N, summary
+
+
+def test_quantise_fusions_of_the_chunk_program_read_histogram(
+        one_chip, as_tpu, monkeypatch):
+    """The int8 codes of a level's gradients and hessians (the value rows
+    of every histogram kernel) come out of multi-output fusions that carry
+    no metadata; the gradients are fused into the first."""
+    _text, labels = _chunk_labels(one_chip, monkeypatch)
+    quantise = {name: found for name, found in labels.items()
+                if found[1] == "fusion" and "s8[1,%d]" % N in found[2]}
+    assert len(quantise) >= 8, quantise
+    assert {found[0] for found in quantise.values()} <= {
+        "histogram", "gradient"}, quantise
+
+
+def test_decomposed_cumulative_sums_of_the_chunk_program_read_split_find(
+        one_chip, as_tpu, monkeypatch):
+    """The threshold scan's cumulative sums reach the device as bare
+    ``pad``, ``reduce-window``, ``slice`` and ``reverse`` instructions
+    with no metadata: each takes the name of the split search that reads
+    it, not of the histogram it is computed from."""
+    _text, labels = _chunk_labels(one_chip, monkeypatch)
+    windows = {name: found for name, found in labels.items()
+               if found[1] == "reduce-window" and found[2].startswith("f32")}
+    assert len(windows) >= 8 * 3, windows
+    assert {found[0] for found in windows.values()} == {"split_find"}, windows
+    around = {found[0] for found in labels.values()
+              if found[1] in ("pad", "reverse")}
+    assert around == {"split_find"}, around
 
 
 def test_data_parallel_chunk_program_compiles_for_four_chips(

@@ -10,7 +10,7 @@ from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, _captured_chunk_program, _cell_size, _check, _grow_args,
     _GROW_KW, _like, _lower_partition, _lower_route_kernel,
     no_persistent_cache, one_chip, _range_passes, _tiny_binary_dataset,
-    topo, _TracedCounters, WIDE_F, WIDE_N)
+    topo, _TracedCounters, _unlabelled, WIDE_F, WIDE_N)
 
 import pytest
 
@@ -104,7 +104,7 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
     assert traced["partition/in_pane"] == traced["partition/pallas"] == 9
     assert traced["partition/pallas_rblocks"] == 27
     _cell_size(_check(compiled, custom_call=True))
-    text = compiled.as_text()
+    text = _LEAFCOMPACT["text"] = compiled.as_text()
     assert _range_passes(text, WIDE_F) == []
     assert len(re.findall(
         r'"tpu_custom_call"[^\n]*/partition/jit\(_partition_in_pane_fn\)',
@@ -133,6 +133,46 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
              if _bytes_as_laid_out(found) >= 64 << 20}
     assert large and all(name.startswith("f32[255,2000,255,3]")
                          for name in large), large
+
+
+_LEAFCOMPACT = {}   # the tree program's compiled text, once a module
+
+
+def test_the_compiler_put_nothing_of_a_panes_size_into_grow_leafcompact(
+        one_chip, as_tpu):
+    """The number form of "XLA put no copy in" (PR 37): of what the
+    compiler inserted itself into one tree's program on the wide table
+    (``compile.programs[].unscoped_ops`` of a ``metrics_out`` record), the
+    largest result is a leaf's histogram row, far under one side of the
+    pane; PR 34's parent copied the whole pane twice a split.  And every
+    operation a trace of ``epsilon-leafwise-f32.train`` shows under no
+    scope has its label."""
+    from lightgbm_tpu import costmodel
+    from lightgbm_tpu.ops import compact
+    from lightgbm_tpu.telemetry import DEVICE_PHASES
+    if not _LEAFCOMPACT:
+        from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
+        kw = dict(_GROW_KW, min_data_in_leaf=1,
+                  min_sum_hessian_in_leaf=100.0)
+        _LEAFCOMPACT["text"] = grow_tree_leafcompact.lower(
+            *_grow_args(one_chip, WIDE_N, WIDE_F), use_pallas_partition=True,
+            partition_overlap=True, **kw).compile().as_text()
+    text = _LEAFCOMPACT["text"]
+    labels = costmodel.label_unscoped_ops(text)
+    assert _unlabelled(text, labels) == []
+    assert {found[0] for found in labels.values()} <= set(
+        DEVICE_PHASES) | {costmodel.XLA}
+    summary = costmodel._unscoped_summary(labels)
+    rows, lanes = compact.pane_layout(compact.pane_rows(WIDE_F),
+                                      WIDE_N_PADDED)
+    assert (rows, lanes) == (2016, 402_432)
+    opcode, nbytes = summary["xla_largest"]
+    assert summary["xla"] > 10 and nbytes < rows * lanes // 16, summary
+    # a row of the leaf histogram cache, prefetched for the subtraction
+    assert nbytes == WIDE_F * 255 * 3 * 4, (opcode, nbytes)
+    # the table's int8 view for the pane's packing, once a tree
+    assert any(found[0] == "partition" and found[1] == "fusion"
+               for found in labels.values())
 
 
 _F32_BUFFER = re.compile(r"f32\[([\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\)")

@@ -126,7 +126,10 @@ def _chunk_lowered(policy: str, on: bool, monkeypatch) -> str:
     return text
 
 
-def _grower_lowered(policy: str, on: bool) -> str:
+def _grower_lowered(policy: str, on: bool, ask_map: bool = False) -> str:
+    """``ask_map``: the grower has been captured and run, and the phases of
+    its unscoped operations asked for (``costmodel.op_phases``), before
+    it is lowered."""
     jax.clear_caches()
     if on:
         telemetry.enable(fence=False)
@@ -136,10 +139,18 @@ def _grower_lowered(policy: str, on: bool) -> str:
             jnp.ones((N,), jnp.float32),
             jnp.ones((N,), jnp.bool_), jnp.ones((F,), jnp.bool_),
             jnp.full((F,), B, jnp.int32))
-    text = GROWERS[policy].lower(
-        *args, num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=5,
-        min_sum_hessian_in_leaf=1.0, max_depth=-1,
-        packing=None).as_text(debug_info=True)
+    kw = dict(num_leaves=LEAVES, num_bins_max=B, min_data_in_leaf=5,
+              min_sum_hessian_in_leaf=1.0, max_depth=-1, packing=None)
+    if ask_map:
+        from lightgbm_tpu import costmodel
+        jax.block_until_ready(GROWERS[policy](*args, **kw))
+        labels = costmodel.op_phases()[GROWERS[policy].name]
+        assert labels and set(labels.values()) <= set(DEVICE_PHASES) | {
+            costmodel.XLA}
+        # a trace of its own for the lowering below, from the same line of
+        # this file as the other side's (locations are part of the text)
+        jax.clear_caches()
+    text = GROWERS[policy].lower(*args, **kw).as_text(debug_info=True)
     telemetry.disable()
     telemetry.reset()
     return text
@@ -196,6 +207,15 @@ def test_chunk_program_lowers_alike_with_telemetry_on_and_off(
 def test_grower_lowers_alike_with_telemetry_on_and_off(policy):
     off, on = [_grower_lowered(policy, flag) for flag in (False, True)]
     assert "stablehlo" in off
+    assert on == off
+
+
+def test_asking_for_the_map_of_unscoped_operations_changes_no_program():
+    """``costmodel.op_phases`` reads the compiled text of a program that
+    ran; what the grower lowers to afterwards is what it lowers to with
+    telemetry off."""
+    off, on = [_grower_lowered("depthwise", flag, ask_map=flag)
+               for flag in (False, True)]     # one line: one location
     assert on == off
 
 

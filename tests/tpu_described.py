@@ -198,6 +198,54 @@ def _range_passes(text, features):
     return found
 
 
+def _unlabelled(text, labels):
+    """Operations of a compiled program that ``costmodel.op_phases`` owes a
+    label and ``labels`` (``costmodel.label_unscoped_ops(text)``) does not
+    have: counted here on their own, line by line, from the entry
+    computation and the computations its loops and conditionals name.
+    An operation is owed one if the device runs it by itself and its own
+    ``op_name`` holds no device phase."""
+    import re
+    from lightgbm_tpu.telemetry import DEVICE_PHASES
+    phase = re.compile(r'op_name="([^"]*/)?(%s)[/"]' % "|".join(DEVICE_PHASES))
+    no_operation = {"parameter", "constant", "tuple", "get-tuple-element",
+                    "bitcast", "after-all"}
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    run, todo, missing = set(), ["ENTRY"], []
+    while todo:
+        name = todo.pop()
+        if name in run:
+            continue
+        run.add(name)
+        for line in bodies[name]:
+            op = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (?:\(.*?\)|\S+) "
+                          r"([\w\-]+)\(", line)
+            if not op:
+                continue
+            if op.group(2) in ("while", "conditional", "call"):
+                # a loop of no trips is an event of its own in a trace
+                todo += re.findall(
+                    r"(?:body|condition|to_apply|true_computation|"
+                    r"false_computation)=%([\w.\-]+)", line)
+                for listed in re.findall(r"branch_computations=\{([^}]*)\}",
+                                         line):
+                    todo += [c.strip(" %") for c in listed.split(",")]
+            if (op.group(2) not in no_operation and not phase.search(line)
+                    and op.group(1) not in labels):
+                missing.append(line.strip()[:160])
+    assert len(run) > 1, "no loop body was walked"
+    return missing
+
+
 class _TracedCounters(dict):
     """The telemetry counters a ``with`` block's traces added."""
 
