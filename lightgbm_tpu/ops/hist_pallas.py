@@ -668,45 +668,49 @@ def quant_max_of(grad, hess, row_ok, axis_name=None):
     return m
 
 
-def quantize_values(grad, hess, col_ok, rng_bits=None, axis_name=None,
-                    stochastic=False, salt=0, quant_max=None):
-    """int8 quantization of grad/hess with one global scale: the tree's
-    (``quant_max``, from ``quant_max_of`` over the tree's rows) where the
-    caller hands it, else the pass's own, over ``col_ok``'s rows
-    (``axis_name`` as in ``quant_max_of``).
+def quantize_values(grad, hess, col_ok, col_id, rng_bits=None,
+                    axis_name=None, stochastic=False, salt=0, quant_max=None):
+    """The int8 kernels' value operand: ``[4, N]`` int8 codes of grad and
+    hess under one global scale each, the count row (``col_ok``) and the
+    row's leaf column (``col_id``, -1 where not ``col_ok``), with the
+    scale ``[3]`` f32 that dequantizes the three sums (the count row is
+    exact by construction).  The scale is the tree's (``quant_max``, from
+    ``quant_max_of`` over the tree's rows) where the caller hands it, else
+    the pass's own, over ``col_ok``'s rows (``axis_name`` as in
+    ``quant_max_of``).
 
     Round-to-nearest by default; unbiased stochastic rounding
     (floor(y+u), u uniform in [0,1)) with ``stochastic=True`` — the
     uniform bits come from a deterministic value-keyed hash
     (``stochastic_bits``), or from explicit ``rng_bits`` [2, N] uint32.
-    Returns (vals [3, N] int8 lane-major, scale [3] f32) — the count row
-    is exact by construction.
+
+    The four rows are ONE elementwise expression over ``[4, N]``, the
+    column id selected in the integer domain, narrowed by one convert: a
+    1-D int8 row made apart and stacked after costs a compiled relayout
+    loop a row wherever N is no whole number of tiles (PERF.md section 6,
+    PR 39: 28 a tree, 140 ms at 115M rows).
     """
-    okf = col_ok.astype(jnp.float32)
     if quant_max is None:
         quant_max = quant_max_of(grad, hess, col_ok, axis_name)
     gs = jnp.maximum(quant_max[0], 1e-30) / 127.0
     hs = jnp.maximum(quant_max[1], 1e-30) / 127.0
-
-    def quant(x, s, bits):
-        y = x / s
-        if bits is None:
-            q = jnp.round(y)
-        else:
-            u = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-            q = jnp.floor(y + u)
-        return jnp.clip(q, -127, 127)
-
-    gbits = hbits = None
-    if rng_bits is not None:
-        gbits, hbits = rng_bits[0], rng_bits[1]
-    elif stochastic:
-        gbits = stochastic_bits(grad, hess, salt)
-        hbits = stochastic_bits(hess, grad, salt + 0x51ED)
-    gq = quant(grad, gs, gbits)
-    hq = quant(hess, hs, hbits)
-    vals = jnp.stack([gq * okf, hq * okf, okf], axis=0).astype(jnp.int8)
-    return vals, jnp.stack([gs, hs, jnp.float32(1.0)])
+    row = jax.lax.broadcasted_iota(jnp.int32, (4, 1), 0)
+    first = row == 0
+    y = jnp.where(first, grad, hess) / jnp.where(first, gs, hs)
+    if rng_bits is None and not stochastic:
+        q = jnp.round(y)
+    else:
+        if rng_bits is None:
+            rng_bits = (stochastic_bits(grad, hess, salt),
+                        stochastic_bits(hess, grad, salt + 0x51ED))
+        bits = jnp.where(first, rng_bits[0], rng_bits[1])
+        u = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+        q = jnp.floor(y + u)
+    q = jnp.clip(q, -127, 127).astype(jnp.int32)
+    codes = jnp.where(row < 2, q,
+                      jnp.where(row == 2, 1, col_id.astype(jnp.int32)))
+    codes = jnp.where(col_ok, codes, jnp.where(row == 3, -1, 0))
+    return codes.astype(jnp.int8), jnp.stack([gs, hs, jnp.float32(1.0)])
 
 
 def quant_saturation_count(grad, hess, axis_name=None):
@@ -833,12 +837,10 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
     # ONE quantization for every class pass: the scale comes from the same
     # grad/hess/col_ok whatever the feature layout, so packed and uniform
     # passes quantize identically (bit-identity precondition)
-    vals, scale = quantize_values(grad, hess, col_ok, rng_bits,
-                                  axis_name=axis_name,
-                                  stochastic=stochastic, salt=salt,
-                                  quant_max=quant_max)
-    cid8 = jnp.where(col_ok, col_id, -1).astype(jnp.int8)
-    packed = jnp.concatenate([vals, cid8[None, :]], axis=0)  # [4, N] int8
+    packed, scale = quantize_values(grad, hess, col_ok, col_id, rng_bits,
+                                    axis_name=axis_name,
+                                    stochastic=stochastic, salt=salt,
+                                    quant_max=quant_max)  # [4, N] int8
 
     ranges, padded, paired = _ranged_rows(N, chunk, axis_name)
     pad = padded - N
@@ -1038,18 +1040,20 @@ def hist_quant_xla(bins, grad, hess, col_id, col_ok, num_cols: int,
             quant_max=quant_max))
 
 
-def _quant_xla_acc(bins, vals, cid, B: int, C: int, chunk: int,
+def _quant_xla_acc(bins, packed, B: int, C: int, chunk: int,
                    ranges: int = 1):
-    """One class's raw [F, B, C*3] int32 accumulator (rows pre-padded),
-    or its [ranges, F, B, C*3]: the chunks cut into that many equal
-    ranges, each summed from zero."""
+    """One class's raw [F, B, C*3] int32 accumulator over the kernel's
+    operand (``quantize_values``' [4, N] codes; rows pre-padded, with a
+    column id of -1), or its [ranges, F, B, C*3]: the chunks cut into
+    that many equal ranges, each summed from zero."""
     F = bins.shape[0]
     N = bins.shape[1]
     n_chunks = N // chunk
     bins_c = bins.astype(jnp.int32).reshape(F, n_chunks,
                                             chunk).transpose(1, 0, 2)
-    vals_c = vals.astype(jnp.int32).T.reshape(n_chunks, chunk, 3)
-    cid_c = cid.reshape(n_chunks, chunk)
+    packed = packed.astype(jnp.int32)
+    vals_c = packed[:3].T.reshape(n_chunks, chunk, 3)
+    cid_c = packed[3].reshape(n_chunks, chunk)
     ib = jnp.arange(B, dtype=jnp.int32)
     ic = jnp.arange(C, dtype=jnp.int32)
 
@@ -1081,17 +1085,15 @@ def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
     C = num_cols
     # don't pad a small input up to a full default chunk
     chunk = min(chunk, max(256, -(-N // 256) * 256))
-    vals, scale = quantize_values(grad, hess, col_ok, rng_bits,
-                                  axis_name=axis_name,
-                                  stochastic=stochastic, salt=salt,
-                                  quant_max=quant_max)
-    cid = jnp.where(col_ok, col_id, -1).astype(jnp.int32)
+    packed, scale = quantize_values(grad, hess, col_ok, col_id, rng_bits,
+                                    axis_name=axis_name,
+                                    stochastic=stochastic, salt=salt,
+                                    quant_max=quant_max)
     ranges, padded, paired = _ranged_rows(N, chunk, axis_name)
     pad = padded - N
     if pad:
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
-        vals = jnp.pad(vals, ((0, 0), (0, pad)))
-        cid = jnp.pad(cid, (0, pad), constant_values=-1)
+        packed = jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1)
 
     fa = int(ranges > 1)                 # the accumulator's feature axis
     if _packing_on(packing):
@@ -1099,11 +1101,11 @@ def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         telemetry.count("hist/mixedbin_xla_int")
         parts = [_quant_xla_acc(
             jax.lax.slice_in_dim(bins, start, start + cnt, axis=0),
-            vals, cid, width, C, chunk, ranges)
+            packed, width, C, chunk, ranges)
             for start, cnt, width in packing.ranges]
         hist = _class_acc_assemble(parts, packing, B, fa)  # [F, B, C*3] i32
     else:
-        hist = _quant_xla_acc(bins, vals, cid, B, C, chunk, ranges)
+        hist = _quant_xla_acc(bins, packed, B, C, chunk, ranges)
     if feat_gather is not None:
         # storage->canonical reorder IN the int domain, before the
         # cross-shard psum (commutes elementwise) — see _hist_pallas_one

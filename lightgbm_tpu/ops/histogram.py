@@ -525,11 +525,11 @@ def hist_quant_segsum(bins, grad, hess, col_id, col_ok, num_cols: int,
         "the scatter-add oracle hist_quant_segsum")
     B = num_bins_max
     C = num_cols
-    vals, scale = quantize_values(grad, hess, col_ok, rng_bits,
-                                  axis_name=axis_name,
-                                  stochastic=(compute_dtype == "int8_sr"),
-                                  salt=salt,
-                                  quant_max=quant_max)      # [3, N] i8
+    codes, scale = quantize_values(grad, hess, col_ok, col_id, rng_bits,
+                                   axis_name=axis_name,
+                                   stochastic=(compute_dtype == "int8_sr"),
+                                   salt=salt, quant_max=quant_max)
+    vals = codes[:3]          # [3, N] i8; its own column ids pass 127
     cid = jnp.where(col_ok, col_id, C).astype(jnp.int32)
     ids = (cid[None, :] * F + jnp.arange(F, dtype=jnp.int32)[:, None]) * B \
         + bins.astype(jnp.int32)

@@ -88,8 +88,7 @@ def check(rows: int, seed: int) -> dict:
                             / np.maximum(np.abs(want_f32), 1.0)))
 
     # the kernel's own accumulators, range by range
-    vals, _ = hist_pallas.quantize_values(d_grad, d_hess, ok)
-    packed = jnp.concatenate([vals, cid.astype(jnp.int8)[None]], axis=0)
+    packed, _ = hist_pallas.quantize_values(d_grad, d_hess, ok, cid)
     pad = padded - rows
     fold, gw = hist_pallas.hist_fold(3, 1, B, 128)
     acc = hist_pallas.hist_pallas_raw(

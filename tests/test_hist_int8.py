@@ -112,12 +112,93 @@ def test_stochastic_rounding_unbiased(hist_inputs):
     bins, grad, hess, cid, ok, F, N, B, C = hist_inputs
     key = jax.random.PRNGKey(0)
     bits = jax.random.bits(key, (2, N), jnp.uint32)
-    vals, scale = quantize_values(grad, hess, ok, rng_bits=bits)
+    vals, scale = quantize_values(grad, hess, ok, cid, rng_bits=bits)
     # SR keeps values within 1 ulp and is mean-preserving to ~sqrt(N) noise
     g_deq = np.asarray(vals[0], np.float32) * float(scale[0])
     gm = np.asarray(grad) * np.asarray(ok, np.float32)
     assert np.abs(g_deq - gm).max() <= float(scale[0]) + 1e-7
     assert abs((g_deq - gm).sum()) < float(scale[0]) * np.sqrt(N) * 4
+
+
+def _numpy_mix32(x):
+    with np.errstate(over="ignore"):       # uint32 products wrap
+        x = x ^ (x >> np.uint32(16))
+        x = x * np.uint32(0x85EBCA6B)
+        x = x ^ (x >> np.uint32(13))
+        x = x * np.uint32(0xC2B2AE35)
+        return x ^ (x >> np.uint32(16))
+
+
+def _numpy_codes(grad, hess, ok, cid, rounding, bits, salt, quant_max):
+    """The kernel's [4, N] operand stated plainly: rows 0-1
+    clip(round(x / s), -127, 127) where ok else 0, row 2 ok, row 3 the
+    column id where ok else -1; float32 arithmetic throughout."""
+    f32 = np.float32
+    okf = ok.astype(f32)
+    if quant_max is None:
+        quant_max = [np.max(np.abs(grad) * okf), np.max(np.abs(hess) * okf)]
+    scales = [np.maximum(f32(m), f32(1e-30)) / f32(127.0) for m in quant_max]
+    if rounding == "stochastic":
+        def hashed(x, other, s):
+            ix, io = x.view(np.uint32), other.view(np.uint32)
+            with np.errstate(over="ignore"):
+                key = _numpy_mix32(np.uint32(s) + np.uint32(0x9E3779B9))
+            return _numpy_mix32(ix ^ _numpy_mix32(io) ^ key)
+        bits = [hashed(grad, hess, salt), hashed(hess, grad, salt + 0x51ED)]
+    rows = []
+    for k, (x, s) in enumerate(zip((grad, hess), scales)):
+        y = x / s
+        if rounding == "nearest":
+            q = np.rint(y)
+        else:
+            u = (bits[k] >> np.uint32(8)).astype(f32) * f32(1.0 / (1 << 24))
+            q = np.floor(y + u)
+        rows.append(np.where(ok, np.clip(q, -127, 127), 0))
+    rows += [ok, np.where(ok, cid, -1)]
+    return np.stack(rows).astype(np.int8), np.array(scales + [1.0], f32)
+
+
+@pytest.mark.parametrize("n", [1000, 4099])
+@pytest.mark.parametrize("rounding,scale", [
+    ("nearest", "own"), ("nearest", "tree"), ("stochastic", "own"),
+    ("rng_bits", "own")])
+def test_operand_is_the_plain_statement_of_the_codes(n, rounding, scale):
+    """``quantize_values`` makes the kernel's whole [4, N] int8 operand as
+    one expression (PR 39); it is the plain NumPy statement of the codes
+    bit for bit: ties at half steps, values past a tree's scale, masked
+    rows, column ids -1 and N no multiple of 1,024."""
+    rng = np.random.RandomState(n)
+    grad = (rng.randn(n) * 4).astype(np.float32)
+    hess = rng.rand(n).astype(np.float32)
+    # the pass's own scale 0.125 exactly, so that k + 0.5 quanta are ties
+    grad = np.clip(grad, -15.875, 15.875)
+    grad[0], hess[0] = 15.875, 1.0
+    k = rng.randint(-126, 126, n // 4)
+    grad[1:1 + n // 4] = (k + 0.5) * 0.125
+    ok = rng.rand(n) < 0.8
+    ok[0] = True
+    cid = rng.randint(0, 64, n).astype(np.int32)
+    cid[rng.rand(n) < 0.1] = -1
+    quant_max = None if scale == "own" else np.array([9.0, 0.5], np.float32)
+    bits = (rng.randint(0, 1 << 32, (2, n), dtype=np.uint64)
+            .astype(np.uint32) if rounding == "rng_bits" else None)
+    kw = dict(stochastic=rounding == "stochastic", salt=3,
+              rng_bits=None if bits is None else jnp.asarray(bits),
+              quant_max=None if quant_max is None
+              else jnp.asarray(quant_max))
+    codes, got_scale = jax.jit(lambda *a: quantize_values(*a, **kw))(
+        *(jnp.asarray(a) for a in (grad, hess, ok, cid)))
+    want, want_scale = _numpy_codes(grad, hess, ok, cid, rounding, bits, 3,
+                                    quant_max)
+    assert codes.shape == (4, n) and codes.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(codes), want)
+    np.testing.assert_array_equal(np.asarray(got_scale), want_scale)
+    if rounding == "nearest" and scale == "own":
+        # the ties went to even
+        assert (np.abs(want[0, 1:1 + n // 4]) % 2 == 0).all()
+    if scale == "tree":
+        # a tree's scale below the pass's own max saturates
+        assert (np.abs(want[0]) == 127).sum() > n // 20
 
 
 def test_train_multiclass_int8(synthetic_binary):
@@ -216,9 +297,11 @@ def test_stochastic_rounding_unbiased_and_deterministic():
     hess = (0.1 + rng.rand(n)).astype(np.float32)
     ok = np.ones(n, bool)
     v1, s1 = quantize_values(jnp.asarray(grad), jnp.asarray(hess),
-                             jnp.asarray(ok), stochastic=True, salt=7)
+                             jnp.asarray(ok), jnp.zeros(n, jnp.int32),
+                             stochastic=True, salt=7)
     v2, s2 = quantize_values(jnp.asarray(grad), jnp.asarray(hess),
-                             jnp.asarray(ok), stochastic=True, salt=7)
+                             jnp.asarray(ok), jnp.zeros(n, jnp.int32),
+                             stochastic=True, salt=7)
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
 
     # unbiasedness: the mean signed quantization error of the SUM is tiny
@@ -231,7 +314,8 @@ def test_stochastic_rounding_unbiased_and_deterministic():
 
     # different salt -> different rounding realization (not a constant fn)
     v3, _ = quantize_values(jnp.asarray(grad), jnp.asarray(hess),
-                            jnp.asarray(ok), stochastic=True, salt=8)
+                            jnp.asarray(ok), jnp.zeros(n, jnp.int32),
+                            stochastic=True, salt=8)
     assert (np.asarray(v3)[0] != np.asarray(v1)[0]).any()
 
 

@@ -52,9 +52,7 @@ def test_bin_fold_bit_identical(num_cols, B):
     np.testing.assert_array_equal(np.asarray(via_xla), np.asarray(via_pl))
     assert float(via_pl[..., 2].sum()) == float(F * int(ok.sum()))
 
-    vals, _ = quantize_values(grad, hess, ok)
-    packed = jnp.concatenate(
-        [vals, jnp.where(ok, cid, -1).astype(jnp.int8)[None]], axis=0)
+    packed, _ = quantize_values(grad, hess, ok, cid)
     pad = (-N) % chunk
     bins8 = jnp.pad(bins.astype(jnp.int8), ((0, 0), (0, pad)))
     packed = jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1)

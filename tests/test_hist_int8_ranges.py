@@ -86,15 +86,15 @@ def test_ranges_are_balanced_whole_chunks(monkeypatch, rows, cap, ranges,
 def test_xla_int_route_ranged_is_the_int64_sum(monkeypatch, cap, rows):
     bins, grad, hess, cid, ok = _table(rows)
     whole = hist_quant_xla(bins, grad, hess, cid, ok, C, B, chunk=CHUNK)
-    vals, scale = quantize_values(grad, hess, ok)
+    packed, scale = quantize_values(grad, hess, ok, cid)
     monkeypatch.setattr(hist_pallas, "INT8_HIST_MAX_ROWS", cap)
     ranges, padded, _ = _ranged_rows(rows, CHUNK)
     pad = padded - rows
     acc = _quant_xla_acc(
-        jnp.pad(bins, ((0, 0), (0, pad))), jnp.pad(vals, ((0, 0), (0, pad))),
-        jnp.pad(jnp.where(ok, cid, -1), (0, pad), constant_values=-1),
+        jnp.pad(bins, ((0, 0), (0, pad))),
+        jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1),
         B, C, CHUNK, ranges)
-    want = _int64_sums(bins, vals, cid, ok)
+    want = _int64_sums(bins, packed, cid, ok)
     if ranges == 1:
         np.testing.assert_array_equal(np.asarray(acc), want)
     else:
@@ -150,15 +150,13 @@ def test_pallas_kernel_ranged_is_the_int64_sum(monkeypatch, cap, num_cols,
             return
         # the kernel's own accumulators, range by range
         ranges, padded, _ = _ranged_rows(rows, CHUNK)
-        vals, _ = quantize_values(grad, hess, ok)
-        packed = jnp.concatenate(
-            [vals, jnp.where(ok, cid, -1).astype(jnp.int8)[None]], axis=0)
+        packed, _ = quantize_values(grad, hess, ok, cid)
         pad = padded - rows
         acc = done(hist_pallas_raw(
             jnp.pad(bins, ((0, 0), (0, pad))).astype(jnp.int8),
             jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1),
             B=B, chunk=CHUNK, dtype="int8", ranges=ranges))
-    want = _int64_sums(bins, vals, cid, ok)
+    want = _int64_sums(bins, packed, cid, ok)
     if ranges == 1:
         assert acc.shape == (F, B, 128)
         np.testing.assert_array_equal(np.asarray(acc)[..., :C * 3], want)
@@ -207,17 +205,18 @@ def test_one_row_past_the_cap_is_the_int64_sum():
     np.testing.assert_allclose(got, want, rtol=3e-7)
     assert (got[0] > 0).all()
     # and in the integer domain, exactly
-    vals, _ = quantize_values(ones, ones, jnp.ones((rows,), bool))
-    assert np.asarray(vals[:, :4]).tolist() == [[127] * 4, [127] * 4,
-                                                [1] * 4]
+    packed, _ = quantize_values(ones, ones, jnp.ones((rows,), bool),
+                                jnp.zeros((rows,), jnp.int32))
+    assert np.asarray(packed[:, :4]).tolist() == [[127] * 4, [127] * 4,
+                                                  [1] * 4, [0] * 4]
     chunk = 65536
     ranges, padded, paired = _ranged_rows(rows, chunk)
     assert (ranges, paired) == (2, True)
     pad = padded - rows
     acc = _quant_xla_acc(
-        jnp.pad(bins, ((0, 0), (0, pad))), jnp.pad(vals, ((0, 0), (0, pad))),
-        jnp.pad(jnp.zeros((rows,), jnp.int32), (0, pad),
-                constant_values=-1), 2, 1, chunk, ranges)
+        jnp.pad(bins, ((0, 0), (0, pad))),
+        jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1),
+        2, 1, chunk, ranges)
     np.testing.assert_array_equal(
         _pair_as_int64(*range_sum(acc))[0],
         np.array([[127 * rows, 127 * rows, rows], [0, 0, 0]], np.int64))

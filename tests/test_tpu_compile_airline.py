@@ -12,8 +12,9 @@ import jax
 import jax.numpy as jnp
 
 from tpu_described import (  # noqa: F401 (fixtures)
-    as_tpu, _captured_chunk_program, _check, _like, no_persistent_cache,
-    one_chip, _pass_rules, _shape, _tiny_binary_dataset, topo)
+    as_tpu, _captured_chunk_program, _cell_config, _check, _like,
+    no_persistent_cache, one_chip, _operand_producers, _pass_rules,
+    _row_relayouts, _shape, _tiny_binary_dataset, topo)
 
 AIRLINE_F, AIRLINE_N = 13, 7 * 8022 * 2048   # 115,000,000 rows as padded
 
@@ -56,17 +57,14 @@ def test_fused_chunk_program_compiles_at_the_airline_cell(
     """The program of the cell ``airline-levelwise-int8.train``: the
     configuration's own ``key=value`` pairs, 13 columns, its argument tree
     re-shaped to the 115,000,000 rows.  Seven accumulation ranges a pass
-    (every histogram custom call hands on ``s32[7, 13, ...]``), and the
-    size argument of the cell: the compiler counted 9.22 GB of
-    temporaries and 3.22 GB of arguments, 80 and 28 bytes a row (PR 36),
-    and a per-row float32 temporary too many would be another 0.46 GB of
-    the 3.6 GB that are left of the chip."""
-    import json
-    import os
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmarks", "configs",
-            "airline-levelwise-int8.json")) as fh:
-        conf = json.load(fh)
+    (every histogram custom call hands on ``s32[7, 13, ...]``), each
+    pass's ``[4, N]`` int8 operand made by one fusion with no row
+    relayout around it, and the size argument of the cell: the compiler
+    counts 7.38 GB of temporaries and 3.22 GB of arguments, 64 and 28
+    bytes a row (PR 39; 9.22 GB, 80 bytes, with the 28 relayouts a tree
+    of PR 36 - 38), and a per-row float32 temporary too many would be
+    another 0.46 GB."""
+    conf = _cell_config("airline-levelwise-int8")
     rows, columns = conf["rows"], conf["features"]
     assert (rows, columns) == (115_000_000, 13)
     n_tiny = 1000
@@ -82,9 +80,13 @@ def test_fused_chunk_program_compiles_at_the_airline_cell(
                        r"_hist_pallas_raw_fn", text)
     assert len(calls) == 8 and all(c.startswith("s32[7,13,") for c in calls)
     assert "/histogram/range_sum/" in text
+    assert _row_relayouts(text, rows) == []
+    producers = _operand_producers(text, rows)
+    assert len(producers) == 8 and all(
+        "/histogram/" in line for _name, line in producers), producers
     per_row = (ma.temp_size_in_bytes + ma.argument_size_in_bytes) / rows
-    assert 95 < per_row < 116, (ma.temp_size_in_bytes,
-                                ma.argument_size_in_bytes)
+    assert 88 < per_row < 97, (ma.temp_size_in_bytes,
+                               ma.argument_size_in_bytes)
     # over the 4.00 GiB line on its bytes alone, and 5% of the chip free
     assert ma.temp_size_in_bytes + ma.argument_size_in_bytes > 4 << 30
     assert (ma.temp_size_in_bytes + ma.argument_size_in_bytes

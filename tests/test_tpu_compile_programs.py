@@ -11,10 +11,11 @@ import jax
 import jax.numpy as jnp
 
 from tpu_described import (  # noqa: F401 (fixtures)
-    as_tpu, B, _Captured, _captured_chunk_program, _check, F, _grow_args,
-    _GROW_KW, HBM_BYTES, LEAVES, _like, _lower_route_kernel, N,
-    no_persistent_cache, one_chip, _range_passes, _shape,
-    _tiny_binary_dataset, topo, _TracedCounters, _unlabelled)
+    as_tpu, B, _Captured, _captured_chunk_program, _cell_config, _check, F,
+    _grow_args, _GROW_KW, HBM_BYTES, LEAVES, _like, _lower_route_kernel, N,
+    no_persistent_cache, one_chip, _operand_producers, _range_passes,
+    _row_relayouts, _shape, _tiny_binary_dataset, topo, _TracedCounters,
+    _unlabelled)
 
 NARROW_N = 10_502_144    # benchmarks/configs/higgs-levelwise-int8, padded
 
@@ -138,15 +139,44 @@ def test_every_unscoped_operation_of_the_chunk_program_has_a_label(
 
 def test_quantise_fusions_of_the_chunk_program_read_histogram(
         one_chip, as_tpu, monkeypatch):
-    """The int8 codes of a level's gradients and hessians (the value rows
-    of every histogram kernel) come out of multi-output fusions that carry
-    no metadata; the gradients are fused into the first."""
-    _text, labels = _chunk_labels(one_chip, monkeypatch)
-    quantise = {name: found for name, found in labels.items()
-                if found[1] == "fusion" and "s8[1,%d]" % N in found[2]}
-    assert len(quantise) >= 8, quantise
-    assert {found[0] for found in quantise.values()} <= {
-        "histogram", "gradient"}, quantise
+    """The int8 operand of every histogram kernel (``[4, N]``: the codes of
+    a level's gradients and hessians, its count row and leaf columns) is
+    one fusion a pass, and a trace puts it under ``histogram``: its own
+    name holds the scope (PR 39).  Before, the rows came out of
+    multi-output fusions with no metadata, which the program's map named
+    ``histogram`` and ``gradient``."""
+    text, labels = _chunk_labels(one_chip, monkeypatch)
+    producers = _operand_producers(text, N)
+    assert len(producers) == 8, producers
+    for name, line in producers:
+        assert "/histogram/" in line and name not in labels, line
+
+
+def test_chunk_program_at_the_narrow_cell_relayouts_no_int8_row(
+        one_chip, as_tpu, monkeypatch):
+    """The program of ``higgs-levelwise-int8.train`` at its own
+    10,500,000 rows, no whole number of the 1-D int8 layout's tiles.
+    Each pass's ``[4, N]`` operand is one fusion under ``histogram``, and
+    the compiler moves no int8 row: until PR 39 the rows were made apart
+    and stacked, and 28 one-trip loops a tree carried each into an
+    ``s8[1,1,N]`` (12.7 ms an iteration on the chip, PERF.md section 5);
+    the temporaries were 1.136 GB, now 0.976."""
+    conf = _cell_config("higgs-levelwise-int8")
+    rows, columns = conf["rows"], conf["features"]
+    assert (rows, columns) == (10_500_000, F)
+    n_tiny = 1000
+    prog, seen = _captured_chunk_program(
+        monkeypatch, conf["params"], _tiny_binary_dataset(n_tiny, columns),
+        is_eval=False)
+    compiled = prog.lower(*_like(one_chip, seen, rows_from=n_tiny,
+                                 rows_to=rows)).compile()
+    ma = _check(compiled, custom_call=True)
+    text = compiled.as_text()
+    assert _row_relayouts(text, rows) == []
+    producers = _operand_producers(text, rows)
+    assert len(producers) == 8 and all(
+        "/histogram/" in line for _name, line in producers), producers
+    assert 0.9e9 < ma.temp_size_in_bytes < 1.05e9, ma.temp_size_in_bytes
 
 
 def test_decomposed_cumulative_sums_of_the_chunk_program_read_split_find(

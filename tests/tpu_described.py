@@ -246,6 +246,36 @@ def _unlabelled(text, labels):
     return missing
 
 
+def _cell_config(name):
+    """A benchmark configuration's file (``benchmarks/configs``)."""
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            name + ".json")) as fh:
+        return json.load(fh)
+
+
+def _row_relayouts(text, rows):
+    """Lines of a compiled program in which the compiler moves a 1-D int8
+    row of ``rows`` into an ``s8[1,1,rows]``: the one-trip ``while`` that
+    carries it and the ``broadcast`` that fills its target.  Where
+    ``rows`` is no whole number of tiles, a row made apart and stacked
+    into the histogram kernel's ``[4, N]`` operand cost one such loop
+    (PERF.md section 6, PRs 38 and 39)."""
+    import re
+    row = "s8[1,1,%d]" % rows
+    return [line.strip()[:200] for line in text.splitlines()
+            if row in line and re.search(r" (while|broadcast)\(", line)]
+
+
+def _operand_producers(text, rows):
+    """The fusions that make the int8 histogram kernel's ``[4, rows]``
+    operand, as ``(name, line)``."""
+    import re
+    return [(found.group(1), found.group(0)) for found in re.finditer(
+        r"%%([\w.\-]+) = s8\[4,%d\]\S* fusion\([^\n]*" % rows, text)]
+
+
 class _TracedCounters(dict):
     """The telemetry counters a ``with`` block's traces added."""
 
