@@ -1,12 +1,18 @@
 """Lambdarank (NDCG) objective.
 
-Re-design of /root/reference/src/objective/rank_objective.hpp:19-230.  The
+Re-design of LightGBM's src/objective/rank_objective.hpp:19-230.  The
 reference loops queries with OpenMP and docs in O(q²) nested loops; the TPU
-formulation pads every query to the max query length and computes the whole
-pairwise lambda matrix per query with vmapped dense [Q, Q] ops, processed in
-query blocks (lax.map) to bound memory.  The 1M-entry sigmoid lookup table
-(rank_objective.hpp:179-192) is replaced by computing the sigmoid exactly —
-a table is a CPU trick, the VPU computes exp faster than it gathers.
+formulation computes each query's whole pairwise lambda matrix as one dense
+[L, L] block.  Queries are grouped by length into a few static classes
+(``query_layout``): a class of width L holds every query of at most L
+documents, each padded to L, and runs as a ``lax.map`` over query blocks of
+a bounded working set.  So a heavy tail of long queries costs each query
+its own class's L², not the longest query's.  The layout is built once,
+from the query lengths alone: the labels and the scores never shape the
+program, and the same layout is the same work.  The 1M-entry sigmoid
+lookup table (rank_objective.hpp:179-192) is replaced by computing the
+sigmoid exactly — a table is a CPU trick, the VPU computes exp faster than
+it gathers.
 
 Math parity (rank_objective.hpp:76-164):
 - pairs (high, low) sorted by score desc; only label(high) > label(low);
@@ -17,15 +23,112 @@ Math parity (rank_objective.hpp:76-164):
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..utils import log
-from ..metrics.dcg import DCGCalculator
 
-K_MIN_SCORE = -np.inf
+# pair slots one query block holds: bounds the [block, L, L] float32
+# working set of a class to ~64 MB an array
+PAIR_BUDGET = 1 << 24
+# length classes a layout may have: each is one more map in the program
+MAX_CLASSES = 12
+# class widths below one 128-lane tile are powers of two, above it whole
+# tiles
+TILE = 128
+
+
+class QueryLayout(NamedTuple):
+    """The static shape of the pairwise work: per class ``(width, blocks,
+    block)``, ``blocks * block`` query slots of ``width`` documents each.
+    ``pairs`` is Σ q² over the real queries, what any formulation has to
+    compute; ``pair_slots`` what the classes compute."""
+    classes: Tuple[Tuple[int, int, int], ...]
+    pairs: int
+
+    @property
+    def pair_slots(self) -> int:
+        return sum(b * k * w * w for w, b, k in self.classes)
+
+
+def _needed_width(sizes: np.ndarray) -> np.ndarray:
+    """The narrowest class width each query fits: a power of two up to 64,
+    else whole 128-lane tiles."""
+    sizes = np.maximum(np.asarray(sizes, np.int64), 1)
+    pow2 = np.left_shift(1, np.ceil(np.log2(sizes)).astype(np.int64))
+    return np.where(sizes <= TILE // 2, pow2, -(-sizes // TILE) * TILE)
+
+
+def _class_widths(needed: np.ndarray) -> np.ndarray:
+    """At most ``MAX_CLASSES`` widths that cover every query with the
+    fewest pair slots: each query goes to the narrowest chosen width it
+    fits, and a dynamic program over the distinct needed widths picks them
+    (the widest is always one)."""
+    widths, counts = np.unique(needed, return_counts=True)
+    m = widths.size
+    cum = np.concatenate(([0], np.cumsum(counts)))
+    sq = widths.astype(np.float64) ** 2
+    # cost[k][j]: least slots for the queries of widths[:j+1], k+1 classes,
+    # the widest at widths[j]
+    inf = float("inf")
+    cost = np.full((MAX_CLASSES, m), inf)
+    back = np.zeros((MAX_CLASSES, m), np.int64)
+    cost[0] = cum[1:] * sq
+    for k in range(1, MAX_CLASSES):
+        for j in range(k, m):
+            prev = cost[k - 1, :j] + (cum[j + 1] - cum[1:j + 1]) * sq[j]
+            i = int(np.argmin(prev))
+            cost[k, j], back[k, j] = prev[i], i
+    k = int(np.argmin(cost[:, m - 1]))
+    chosen, j = [m - 1], m - 1
+    while k > 0:
+        j = int(back[k, j])
+        chosen.append(j)
+        k -= 1
+    return widths[np.asarray(chosen[::-1])]
+
+
+def _blocks(n: int, width: int) -> Tuple[int, int]:
+    """(blocks, block): the fewest query blocks of at most PAIR_BUDGET
+    slots that hold ``n`` queries, as even as whole queries allow."""
+    most = max(1, PAIR_BUDGET // (width * width))
+    blocks = -(-n // most)
+    return blocks, -(-n // blocks)
+
+
+def query_layout(sizes) -> QueryLayout:
+    """The length classes of queries of ``sizes`` documents."""
+    sizes = np.asarray(sizes, np.int64)
+    needed = _needed_width(sizes)
+    widths = _class_widths(needed)
+    classes = []
+    for w, n in zip(widths, np.bincount(np.searchsorted(widths, needed),
+                                        minlength=widths.size)):
+        if n:
+            classes.append((int(w),) + _blocks(int(n), int(w)))
+    return QueryLayout(tuple(classes), int(np.sum(sizes * sizes)))
+
+
+def inverse_max_dcg(label, boundaries, label_gain, k: int) -> np.ndarray:
+    """1 / max DCG@k per query (rank_objective.hpp:53-63), 0 where the max
+    is 0: every query at once, the gains sorted within their query."""
+    label = np.asarray(label).astype(np.int64)
+    sizes = np.diff(np.asarray(boundaries, np.int64))
+    qid = np.repeat(np.arange(sizes.size), sizes)
+    gain = np.asarray(label_gain, np.float64)[label]
+    order = np.lexsort((-gain, qid))
+    rank = np.arange(label.size) - np.repeat(boundaries[:-1], sizes)
+    top = rank < k
+    discount = 1.0 / np.log2(2.0 + rank[top])
+    max_dcg = np.bincount(qid[top], weights=gain[order][top] * discount,
+                          minlength=sizes.size)
+    with np.errstate(divide="ignore"):
+        return np.where(max_dcg > 0, 1.0 / max_dcg, max_dcg).astype(
+            np.float32)
 
 
 class LambdarankNDCG:
@@ -51,51 +154,66 @@ class LambdarankNDCG:
         self.optimize_pos_at = int(config.max_position)
         self.weights = None
 
-    def init(self, metadata, num_data: int) -> None:
+    def init(self, metadata, num_data: int, rows=None) -> None:
+        """``rows``: where document c of the metadata lives in the score
+        vector (``num_data`` long), if not at c."""
         if metadata.query_boundaries is None:
             log.fatal("For lambdarank tasks, should have query information")
         label = np.asarray(metadata.label)
-        boundaries = np.asarray(metadata.query_boundaries)
-        nq = boundaries.size - 1
+        boundaries = np.asarray(metadata.query_boundaries, np.int64)
         sizes = np.diff(boundaries)
-        qmax = int(sizes.max())
+        if rows is None:
+            rows = np.arange(label.size, dtype=np.int64)
         self.num_data = num_data
-        dcg = DCGCalculator(self.label_gain)
+        self.layout = query_layout(sizes)
+        inv_max_dcg = inverse_max_dcg(label, boundaries, self.label_gain,
+                                      self.optimize_pos_at)
 
-        # cached inverse max DCG per query (rank_objective.hpp:53-63)
-        inv_max_dcg = np.zeros(nq, dtype=np.float32)
-        for q in range(nq):
-            lo, hi = boundaries[q], boundaries[q + 1]
-            max_dcg = dcg.cal_max_dcg_at_k(self.optimize_pos_at, label[lo:hi])
-            inv_max_dcg[q] = 1.0 / max_dcg if max_dcg > 0 else max_dcg
-
-        # padded [nq, qmax] doc-index layout
-        doc_index = np.full((nq, qmax), -1, dtype=np.int32)
-        for q in range(nq):
-            lo, hi = boundaries[q], boundaries[q + 1]
-            doc_index[q, :hi - lo] = np.arange(lo, hi)
-        valid = doc_index >= 0
-
-        self.doc_index = jnp.asarray(np.where(valid, doc_index, 0))
-        self.valid = jnp.asarray(valid)
-        self.counts = jnp.asarray(sizes.astype(np.int32))
-        self.inv_max_dcg = jnp.asarray(inv_max_dcg)
-        self.labels_padded = jnp.asarray(
-            np.where(valid, label[np.where(valid, doc_index, 0)], 0.0)
-            .astype(np.float32))
+        # each class: its queries in file order, padded to whole blocks
+        needed = _needed_width(sizes)
+        widths = np.asarray([w for w, _, _ in self.layout.classes])
+        of_class = np.searchsorted(widths, needed)
+        gain = self.label_gain[label.astype(np.int64)]
+        where = np.full(num_data, -1, np.int64)   # row -> flat class slot
+        base, tables = 0, []
+        for c, (w, blocks, block) in enumerate(self.layout.classes):
+            qs = np.flatnonzero(of_class == c)
+            slots = blocks * block
+            cnt = np.zeros(slots, np.int64)
+            cnt[:qs.size] = sizes[qs]
+            start = np.zeros(slots, np.int64)
+            start[:qs.size] = boundaries[qs]
+            valid = np.arange(w)[None, :] < cnt[:, None]
+            doc = np.where(valid, start[:, None] + np.arange(w)[None, :], 0)
+            where[rows[doc[valid]]] = base + np.flatnonzero(valid)
+            imd = np.zeros(slots, np.float32)
+            imd[:qs.size] = inv_max_dcg[qs]
+            shape = (blocks, block, w)
+            tables.append({
+                "rows": jnp.asarray(rows[doc].astype(np.int32).reshape(shape)),
+                "count": jnp.asarray(cnt.astype(np.int32).reshape(
+                    blocks, block)),
+                "label": jnp.asarray(np.where(valid, label[doc], 0).astype(
+                    np.int32).reshape(shape)),
+                "gain": jnp.asarray(np.where(valid, gain[doc], 0.0).astype(
+                    np.float32).reshape(shape)),
+                "inv_max_dcg": jnp.asarray(imd.reshape(blocks, block))})
+            base += slots * w
+        # rows of no query (a shard's padding) read the zero after the last
+        # slot
+        self.where = jnp.asarray(np.where(where < 0, base, where).astype(
+            np.int32))
+        self.tables = tuple(tables)
         self.discount = jnp.asarray(
-            dcg.discount[:qmax].astype(np.float32))
-        self.gains = jnp.asarray(self.label_gain)
-        self.qmax = qmax
-        self.nq = nq
+            (1.0 / np.log2(2.0 + np.arange(max(widths)))).astype(np.float32))
         if metadata.weights is not None:
-            self.weights = jnp.asarray(metadata.weights, jnp.float32)
-        # query block size bounds the [block, Q, Q] working set to ~64 MB
-        self.block = max(1, min(nq, (1 << 24) // max(qmax * qmax, 1)))
+            w = np.zeros(num_data, np.float32)
+            w[rows] = np.asarray(metadata.weights, np.float32)
+            self.weights = jnp.asarray(w)
 
     def get_gradients(self, score: jax.Array):
         _, params, fn = self.chunk_spec()
-        return fn(params, score)
+        return _iteration_program(fn)(params, score)
 
     def globalize_layout(self, global_md, shard_layout,
                          num_padded: int) -> None:
@@ -106,36 +224,27 @@ class LambdarankNDCG:
         labels/query layout concatenated in process order — valid because
         row sharding is query-atomic, dataset.cpp:189-206);
         ``shard_layout`` maps compacted global row c of process p to padded
-        position start_p + (c - c_p).  The rebuilt doc_index then indexes
-        the padded global score directly, and weights scatter into a
-        padded vector so the lambda products line up."""
-        self.init(global_md, int(np.sum([ln for _, ln in shard_layout])))
+        position start_p + (c - c_p).  The tables then index the padded
+        global score directly, and weights scatter into a padded vector so
+        the lambda products line up."""
         pad_pos = np.concatenate(
             [start + np.arange(ln) for start, ln in shard_layout]
-        ).astype(np.int32)
-        doc_index = np.asarray(self.doc_index)
-        valid = np.asarray(self.valid)
-        self.doc_index = jnp.asarray(
-            np.where(valid, pad_pos[doc_index], 0).astype(np.int32))
-        if self.weights is not None:
-            w = np.zeros(num_padded, np.float32)
-            w[pad_pos] = np.asarray(self.weights)
-            self.weights = jnp.asarray(w)
-        self.num_data = num_padded
+        ).astype(np.int64)
+        self.init(global_md, num_padded, rows=pad_pos)
 
     def chunk_spec(self):
-        # block is static (it shapes the padded query-block map); the
-        # scatter length follows the score length at trace time, so one
+        # the layout is static (it shapes every class's block map); the
+        # output length follows the score length at trace time, so one
         # callable serves both the true-row and shard-padded layouts
-        fn = functools.partial(_rank_gradients, block=self.block)
-        key = ("lambdarank", self.num_data, self.block, self.qmax, self.nq,
+        key = ("lambdarank", self.num_data, self.layout,
                self.weights is not None)
-        return key, self.chunk_params(), _RANK_FNS.setdefault(key, fn)
+        fn = _RANK_FNS.setdefault(key, functools.partial(
+            _rank_gradients, layout=self.layout))
+        return key, self.chunk_params(), fn
 
     def chunk_params(self):
-        return {"doc_index": self.doc_index, "valid": self.valid,
-                "labels": self.labels_padded, "inv_max_dcg": self.inv_max_dcg,
-                "discount": self.discount, "gains": self.gains,
+        return {"tables": self.tables, "where": self.where,
+                "discount": self.discount,
                 "sigmoid": jnp.float32(self._sigmoid),
                 "weights": self.weights}
 
@@ -154,91 +263,82 @@ class LambdarankNDCG:
 _RANK_FNS: dict = {}
 
 
-def _rank_gradients(params, score, *, block: int):
-    # named_scope: profile_dir= traces label the lambda ops with the
-    # objective (matches the telemetry "gradient" phase; ISSUE 2)
+@functools.lru_cache(maxsize=None)
+def _iteration_program(fn):
+    """The per-iteration path's gradient program: ``fn`` under the
+    ``gradient`` phase, as the chunk body puts it."""
+    def program(params, score):
+        with telemetry.phase_scope("gradient"):
+            return fn(params, score)
+    return jax.jit(program)
+
+
+def _rank_gradients(params, score, *, layout: QueryLayout):
+    # nested inside the "gradient" phase: a device trace reads the
+    # pairwise work by this name
     with jax.named_scope("gradient_lambdarank"):
-        lambdas, hessians = _lambdarank_grads(
-            score.astype(jnp.float32), params["doc_index"], params["valid"],
-            params["labels"], params["inv_max_dcg"], params["discount"],
-            params["gains"], params["sigmoid"], block)
+        # trace-time counters, once a program: the real pairs and the
+        # slots the classes compute for them
+        telemetry.count("rank/pairs", layout.pairs)
+        telemetry.count("rank/pair_slots", layout.pair_slots)
+        telemetry.count("rank/classes", len(layout.classes))
+        score = score.astype(jnp.float32)
+        lams, hess = [], []
+        for table in params["tables"]:
+            lam, hes = jax.lax.map(
+                functools.partial(_query_block, score=score,
+                                  discount=params["discount"],
+                                  sigmoid=params["sigmoid"]),
+                table)
+            lams.append(lam.reshape(-1))
+            hess.append(hes.reshape(-1))
+        zero = jnp.zeros((1,), jnp.float32)
+        where = params["where"]
+        lambdas = jnp.concatenate(lams + [zero])[where]
+        hessians = jnp.concatenate(hess + [zero])[where]
         if params["weights"] is not None:
-            w = params["weights"]
-            if w.shape[0] < lambdas.shape[0]:
-                # single-process DP pads rows at the tail; padded rows
-                # carry zero lambdas, so zero-padding the weights is exact
-                w = jnp.pad(w, (0, lambdas.shape[0] - w.shape[0]))
-            lambdas = lambdas * w
-            hessians = hessians * w
-        return lambdas, hessians
+            lambdas = lambdas * params["weights"]
+            hessians = hessians * params["weights"]
+        # single-process DP pads rows at the tail: they carry no document
+        pad = score.shape[0] - where.shape[0]
+        return jnp.pad(lambdas, (0, pad)), jnp.pad(hessians, (0, pad))
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def _lambdarank_grads(score, doc_index, valid, labels, inv_max_dcg, discount,
-                      gains, sigmoid, block: int):
-    # scatter length follows the (possibly shard-padded) score; doc_index
-    # never points at padding, so padded rows get exactly zero
-    num_data = score.shape[0]
-    nq, qmax = doc_index.shape
-    scores_padded = jnp.where(valid, score[doc_index], K_MIN_SCORE)
+def _query_block(table, *, score, discount, sigmoid):
+    """Pairwise lambdas of one block of ``[block, L]`` padded queries
+    (rank_objective.hpp:76-156), in each query's own document order."""
+    count, label, gain = table["count"], table["label"], table["gain"]
+    width = label.shape[-1]
+    pos = jnp.arange(width, dtype=jnp.int32)
+    valid = pos[None, :] < count[:, None]
+    s = jnp.where(valid, score[table["rows"]], -jnp.inf)
+    # score desc, stable; padding (-inf) sinks last
+    neg, ll, gg, src = jax.lax.sort(
+        (-s, label, gain, jnp.broadcast_to(pos, s.shape)), dimension=1,
+        is_stable=True, num_keys=1)
+    ss = jnp.where(valid, -neg, 0.0)      # valid sorts first: same mask
+    best = ss[:, 0]
+    worst = jnp.take_along_axis(
+        ss, jnp.maximum(count - 1, 0)[:, None], axis=1)[:, 0]
 
-    pad_q = (-nq) % block
-    def pad0(x):
-        return jnp.pad(x, [(0, pad_q)] + [(0, 0)] * (x.ndim - 1))
-    blocks = (nq + pad_q) // block
+    hi_s, lo_s = ss[:, :, None], ss[:, None, :]
+    hi_l, lo_l = ll[:, :, None], ll[:, None, :]
+    pair = (hi_l > lo_l) & valid[:, :, None] & valid[:, None, :]
+    delta = hi_s - lo_s
+    disc = discount[:width]
+    paired_disc = jnp.abs(disc[:, None] - disc[None, :])
+    delta_ndcg = ((gg[:, :, None] - gg[:, None, :]) * paired_disc
+                  * table["inv_max_dcg"][:, None, None])
+    delta_ndcg = jnp.where((hi_l != lo_l) & (best != worst)[:, None, None],
+                           delta_ndcg / (0.01 + jnp.abs(delta)), delta_ndcg)
+    sig = 2.0 / (1.0 + jnp.exp(2.0 * delta * sigmoid))
+    p_hess = sig * (2.0 - sig)
+    lam = jnp.where(pair, -sig * delta_ndcg, 0.0)
+    hes = jnp.where(pair, 2.0 * delta_ndcg * p_hess, 0.0)
 
-    def reshape(x):
-        return pad0(x).reshape((blocks, block) + x.shape[1:])
-
-    def one_query(s, l, imd):
-        """Pairwise lambdas for one padded query (rank_objective.hpp:76-156)."""
-        order = jnp.argsort(-s)          # score desc; padded (-inf) sink last
-        ss = s[order]
-        ll = l[order].astype(jnp.int32)
-        cnt = jnp.sum(ss != K_MIN_SCORE).astype(jnp.int32)
-        best = ss[0]
-        worst_idx = jnp.maximum(cnt - 1, 0)
-        worst_idx = jnp.where(
-            (worst_idx > 0) & (ss[worst_idx] == K_MIN_SCORE),
-            worst_idx - 1, worst_idx)
-        worst = ss[worst_idx]
-
-        hi_s, lo_s = ss[:, None], ss[None, :]
-        hi_l, lo_l = ll[:, None], ll[None, :]
-        pair = (hi_l > lo_l) & (hi_s != K_MIN_SCORE) & (lo_s != K_MIN_SCORE)
-        delta = hi_s - lo_s
-        dcg_gap = gains[hi_l] - gains[lo_l]
-        paired_disc = jnp.abs(discount[:, None] - discount[None, :])
-        delta_ndcg = dcg_gap * paired_disc * imd
-        delta_ndcg = jnp.where((hi_l != lo_l) & (best != worst),
-                               delta_ndcg / (0.01 + jnp.abs(delta)),
-                               delta_ndcg)
-        sig = 2.0 / (1.0 + jnp.exp(2.0 * delta * sigmoid))
-        p_hess = sig * (2.0 - sig)
-        lam = jnp.where(pair, -sig * delta_ndcg, 0.0)
-        hes = jnp.where(pair, 2.0 * delta_ndcg * p_hess, 0.0)
-
-        lam_sorted = jnp.sum(lam, axis=1) - jnp.sum(lam, axis=0)
-        hes_sorted = jnp.sum(hes, axis=1) + jnp.sum(hes, axis=0)
-        # unsort back to in-query doc order
-        lam_out = jnp.zeros_like(lam_sorted).at[order].set(lam_sorted)
-        hes_out = jnp.zeros_like(hes_sorted).at[order].set(hes_sorted)
-        return lam_out, hes_out
-
-    def block_fn(args):
-        s_b, l_b, imd_b = args
-        return jax.vmap(one_query)(s_b, l_b, imd_b)
-
-    lam_b, hes_b = jax.lax.map(
-        block_fn, (reshape(scores_padded), reshape(labels),
-                   pad0(inv_max_dcg).reshape(blocks, block)))
-    lam = lam_b.reshape(-1, qmax)[:nq]
-    hes = hes_b.reshape(-1, qmax)[:nq]
-
-    flat_idx = doc_index.reshape(-1)
-    flat_valid = valid.reshape(-1)
-    lambdas = jnp.zeros((num_data,), jnp.float32).at[flat_idx].add(
-        jnp.where(flat_valid, lam.reshape(-1), 0.0))
-    hessians = jnp.zeros((num_data,), jnp.float32).at[flat_idx].add(
-        jnp.where(flat_valid, hes.reshape(-1), 0.0))
-    return lambdas, hessians
+    lam_sorted = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
+    hes_sorted = jnp.sum(hes, axis=2) + jnp.sum(hes, axis=1)
+    # back to each query's document order
+    _, lam_out, hes_out = jax.lax.sort((src, lam_sorted, hes_sorted),
+                                       dimension=1, num_keys=1)
+    return lam_out, hes_out

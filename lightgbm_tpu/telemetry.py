@@ -333,6 +333,9 @@ COUNTER_FAMILIES = (
     "partition/route_pallas",     # level-wise row routing, once a level
     "partition/route_xla",
     "partition/xla",
+    "rank/classes",               # lambdarank's query-length classes, once a program
+    "rank/pair_slots",            # pair slots the classes compute, once a program
+    "rank/pairs",                 # the layout's real pairs (sum of q^2), once a program
     "serve/bucket_*",             # per-ladder-bucket dispatch counts
     "serve/coalesced_batches",
     "serve/coalesced_requests",

@@ -56,7 +56,7 @@ HEAVY_FIRST = (
     "test_tpu_compile_airline.py", "test_airline_cell.py",
     "test_distributed_telemetry.py", "test_goss_chunk.py",
     "test_route_pallas.py", "test_depthwise.py", "test_hist_int8.py",
-    "test_hist_int8_ranges.py",
+    "test_hist_int8_ranges.py", "test_rank_bucketed.py",
     "test_graftlint.py",
     "test_mixedbin_hybrid.py", "test_hist_float_pallas.py",
     "test_elastic.py", "test_health.py", "test_costmodel.py",

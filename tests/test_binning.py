@@ -179,3 +179,21 @@ def test_adversarial_count_ties_match_reference_oracle():
         permuted = _reference_find_bin_bounds(values, max_bin,
                                               tie_perm=rng)
         np.testing.assert_array_equal(base, permuted)
+
+
+@pytest.mark.parametrize("order", ["C", "F"])
+def test_binarize_in_row_blocks_on_threads_is_one_search_a_column(
+        order, monkeypatch):
+    """The table is quantized a block of rows of one column at a time, on
+    threads; the codes are the per-column search's, whatever the blocks."""
+    from lightgbm_tpu.io import dataset as dataset_mod
+    rng = np.random.default_rng(4)
+    x = np.asarray(rng.standard_normal((5003, 7)), order=order)
+    x[:, 3] = np.round(x[:, 3])              # a few distinct values
+    y = (x[:, 0] > 0).astype(np.float32)
+    monkeypatch.setattr(dataset_mod, "BINARIZE_BLOCK_ROWS", 1000)
+    ds = dataset_mod.Dataset.from_arrays(x, y, max_bin=63)
+    assert ds.bins.shape == (len(ds.bin_mappers), 5003)
+    for j_raw, j_inner in ds.used_feature_map.items():
+        want = ds.bin_mappers[j_inner].value_to_bin(x[:, j_raw])
+        np.testing.assert_array_equal(ds.bins[j_inner], want)
