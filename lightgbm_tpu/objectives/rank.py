@@ -9,7 +9,10 @@ documents, each padded to L, and runs as a ``lax.map`` over query blocks of
 a bounded working set.  So a heavy tail of long queries costs each query
 its own class's L², not the longest query's.  The layout is built once,
 from the query lengths alone: the labels and the scores never shape the
-program, and the same layout is the same work.  The 1M-entry sigmoid
+program, and the same layout is the same work.  A query's documents lie in
+consecutive rows, so it moves between the rows and its class slot as one
+window, read and added as the whole 128-lane chunks it touches, one index
+a chunk, with its lanes shifted into place.  The 1M-entry sigmoid
 lookup table (rank_objective.hpp:179-192) is replaced by computing the
 sigmoid exactly — a table is a CPU trick, the VPU computes exp faster than
 it gathers.
@@ -46,9 +49,10 @@ class QueryLayout(NamedTuple):
     """The static shape of the pairwise work: per class ``(width, blocks,
     block)``, ``blocks * block`` query slots of ``width`` documents each.
     ``pairs`` is Σ q² over the real queries, what any formulation has to
-    compute; ``pair_slots`` what the classes compute."""
+    compute; ``pair_slots`` what the classes compute; ``docs`` Σ q."""
     classes: Tuple[Tuple[int, int, int], ...]
     pairs: int
+    docs: int
 
     @property
     def pair_slots(self) -> int:
@@ -110,7 +114,8 @@ def query_layout(sizes) -> QueryLayout:
                                         minlength=widths.size)):
         if n:
             classes.append((int(w),) + _blocks(int(n), int(w)))
-    return QueryLayout(tuple(classes), int(np.sum(sizes * sizes)))
+    return QueryLayout(tuple(classes), int(np.sum(sizes * sizes)),
+                       int(np.sum(sizes)))
 
 
 def inverse_max_dcg(label, boundaries, label_gain, k: int) -> np.ndarray:
@@ -156,7 +161,9 @@ class LambdarankNDCG:
 
     def init(self, metadata, num_data: int, rows=None) -> None:
         """``rows``: where document c of the metadata lives in the score
-        vector (``num_data`` long), if not at c."""
+        vector (``num_data`` long), if not at c.  Each query's documents
+        have to lie in consecutive rows: a query moves between the rows and
+        its class slot as one window."""
         if metadata.query_boundaries is None:
             log.fatal("For lambdarank tasks, should have query information")
         label = np.asarray(metadata.label)
@@ -164,33 +171,42 @@ class LambdarankNDCG:
         sizes = np.diff(boundaries)
         if rows is None:
             rows = np.arange(label.size, dtype=np.int64)
+        # the row of each query's first document, and every other document
+        # right after it
+        first = rows[np.minimum(boundaries[:-1], max(rows.size - 1, 0))]
+        if not np.array_equal(rows, np.repeat(first, sizes) + (
+                np.arange(rows.size) - np.repeat(boundaries[:-1], sizes))):
+            log.fatal("lambdarank needs each query's documents in "
+                      "consecutive rows")
         self.num_data = num_data
         self.layout = query_layout(sizes)
         inv_max_dcg = inverse_max_dcg(label, boundaries, self.label_gain,
                                       self.optimize_pos_at)
 
-        # each class: its queries in file order, padded to whole blocks
+        # each class: its queries in file order, padded to whole blocks by
+        # empty queries whose window lies past the last row
         needed = _needed_width(sizes)
         widths = np.asarray([w for w, _, _ in self.layout.classes])
         of_class = np.searchsorted(widths, needed)
         gain = self.label_gain[label.astype(np.int64)]
-        where = np.full(num_data, -1, np.int64)   # row -> flat class slot
-        base, tables = 0, []
+        tables = []
         for c, (w, blocks, block) in enumerate(self.layout.classes):
             qs = np.flatnonzero(of_class == c)
             slots = blocks * block
             cnt = np.zeros(slots, np.int64)
             cnt[:qs.size] = sizes[qs]
+            row = np.full(slots, num_data, np.int64)
+            row[:qs.size] = first[qs]
             start = np.zeros(slots, np.int64)
             start[:qs.size] = boundaries[qs]
             valid = np.arange(w)[None, :] < cnt[:, None]
             doc = np.where(valid, start[:, None] + np.arange(w)[None, :], 0)
-            where[rows[doc[valid]]] = base + np.flatnonzero(valid)
             imd = np.zeros(slots, np.float32)
             imd[:qs.size] = inv_max_dcg[qs]
             shape = (blocks, block, w)
             tables.append({
-                "rows": jnp.asarray(rows[doc].astype(np.int32).reshape(shape)),
+                "row": jnp.asarray(row.astype(np.int32).reshape(
+                    blocks, block)),
                 "count": jnp.asarray(cnt.astype(np.int32).reshape(
                     blocks, block)),
                 "label": jnp.asarray(np.where(valid, label[doc], 0).astype(
@@ -198,11 +214,6 @@ class LambdarankNDCG:
                 "gain": jnp.asarray(np.where(valid, gain[doc], 0.0).astype(
                     np.float32).reshape(shape)),
                 "inv_max_dcg": jnp.asarray(imd.reshape(blocks, block))})
-            base += slots * w
-        # rows of no query (a shard's padding) read the zero after the last
-        # slot
-        self.where = jnp.asarray(np.where(where < 0, base, where).astype(
-            np.int32))
         self.tables = tuple(tables)
         self.discount = jnp.asarray(
             (1.0 / np.log2(2.0 + np.arange(max(widths)))).astype(np.float32))
@@ -243,8 +254,7 @@ class LambdarankNDCG:
         return key, self.chunk_params(), fn
 
     def chunk_params(self):
-        return {"tables": self.tables, "where": self.where,
-                "discount": self.discount,
+        return {"tables": self.tables, "discount": self.discount,
                 "sigmoid": jnp.float32(self._sigmoid),
                 "weights": self.weights}
 
@@ -282,44 +292,58 @@ def _rank_gradients(params, score, *, layout: QueryLayout):
         telemetry.count("rank/pairs", layout.pairs)
         telemetry.count("rank/pair_slots", layout.pair_slots)
         telemetry.count("rank/classes", len(layout.classes))
-        score = score.astype(jnp.float32)
-        lams, hess = [], []
+        # documents moved by whole-query windows: rows to class slots and
+        # back
+        telemetry.count("rank/segment_docs", 2 * layout.docs)
+        rows = score.shape[0]
+        # rows as [chunks, 128] lanes: the chunks of a window of the widest
+        # class from any query's first row, or from an empty query's at the
+        # last row, lie inside them
+        widest = max(w for w, _, _ in layout.classes)
+        chunks = rows // TILE + -(-widest // TILE) + 1
+        score = jnp.pad(score.astype(jnp.float32),
+                        (0, chunks * TILE - rows)).reshape(chunks, TILE)
+        moved = jnp.zeros((2, chunks, TILE), jnp.float32)
         for table in params["tables"]:
-            lam, hes = jax.lax.map(
+            pair = jax.lax.map(
                 functools.partial(_query_block, score=score,
                                   discount=params["discount"],
                                   sigmoid=params["sigmoid"]),
                 table)
-            lams.append(lam.reshape(-1))
-            hess.append(hes.reshape(-1))
-        zero = jnp.zeros((1,), jnp.float32)
-        where = params["where"]
-        lambdas = jnp.concatenate(lams + [zero])[where]
-        hessians = jnp.concatenate(hess + [zero])[where]
+            # each query's lambdas and hessians added over the chunks of
+            # its rows; they overlap the next queries' rows by zeros only
+            moved = _add_windows(moved, table["row"], pair)
+        lambdas = moved[0].reshape(-1)[:rows]
+        hessians = moved[1].reshape(-1)[:rows]
         if params["weights"] is not None:
-            lambdas = lambdas * params["weights"]
-            hessians = hessians * params["weights"]
-        # single-process DP pads rows at the tail: they carry no document
-        pad = score.shape[0] - where.shape[0]
-        return jnp.pad(lambdas, (0, pad)), jnp.pad(hessians, (0, pad))
+            # single-process DP pads rows at the tail: they carry no
+            # document
+            weights = params["weights"]
+            weights = jnp.pad(weights, (0, rows - weights.shape[0]))
+            lambdas = lambdas * weights
+            hessians = hessians * weights
+        return lambdas, hessians
 
 
 def _query_block(table, *, score, discount, sigmoid):
-    """Pairwise lambdas of one block of ``[block, L]`` padded queries
-    (rank_objective.hpp:76-156), in each query's own document order."""
+    """Pairwise lambdas and hessians of one block of ``[block, L]`` padded
+    queries (rank_objective.hpp:76-156), each query's scores read as one
+    window of its rows out of ``score`` laid out ``[chunks, 128]``, and its
+    results given back as the whole chunks of those rows,
+    ``[2, block, m + 1, 128]``, 0 past its documents."""
     count, label, gain = table["count"], table["label"], table["gain"]
     width = label.shape[-1]
     pos = jnp.arange(width, dtype=jnp.int32)
     valid = pos[None, :] < count[:, None]
-    s = jnp.where(valid, score[table["rows"]], -jnp.inf)
+    s = jnp.where(valid, _read_windows(score, table["row"], width), -jnp.inf)
     # score desc, stable; padding (-inf) sinks last
     neg, ll, gg, src = jax.lax.sort(
         (-s, label, gain, jnp.broadcast_to(pos, s.shape)), dimension=1,
         is_stable=True, num_keys=1)
     ss = jnp.where(valid, -neg, 0.0)      # valid sorts first: same mask
     best = ss[:, 0]
-    worst = jnp.take_along_axis(
-        ss, jnp.maximum(count - 1, 0)[:, None], axis=1)[:, 0]
+    # the last valid score, as the least: no gather
+    worst = jnp.min(jnp.where(valid, ss, jnp.inf), axis=1)
 
     hi_s, lo_s = ss[:, :, None], ss[:, None, :]
     hi_l, lo_l = ll[:, :, None], ll[:, None, :]
@@ -341,4 +365,57 @@ def _query_block(table, *, score, discount, sigmoid):
     # back to each query's document order
     _, lam_out, hes_out = jax.lax.sort((src, lam_sorted, hes_sorted),
                                        dimension=1, num_keys=1)
-    return lam_out, hes_out
+    return _chunk_windows(jnp.stack([lam_out, hes_out]), table["row"])
+
+
+def _rotate_lanes(x, by, left: bool):
+    """Each 128-lane row of ``x`` ``[..., 128]`` rotated by ``by`` lanes
+    (shaped as ``x`` less its last axis), in seven static rotations of a
+    power of two, each taken or not."""
+    for bit in range(7):
+        step = 1 << bit
+        x = jnp.where(((by >> bit) & 1)[..., None] == 1,
+                      jnp.roll(x, -step if left else step, axis=-1), x)
+    return x
+
+
+def _read_windows(score, row, width: int):
+    """``[..., width]``: the scores of rows ``row .. row + width - 1`` out
+    of ``score`` laid out ``[chunks, 128]``: the whole chunks the window
+    touches, one index a chunk, each rotated left by the row's lane in its
+    chunk and joined to the next at that lane."""
+    m = -(-width // TILE)
+    lane = row % TILE
+    x = score[row[..., None] // TILE + jnp.arange(m + 1)]   # [..., m+1, 128]
+    x = _rotate_lanes(x, lane[..., None], left=True)
+    x = jnp.where(jnp.arange(TILE) < TILE - lane[..., None, None],
+                  x[..., :-1, :], x[..., 1:, :])
+    return x.reshape(row.shape + (m * TILE,))[..., :width]
+
+
+def _chunk_windows(pair, row):
+    """``[2, block, m + 1, 128]``: each query's ``[2, width]`` window of
+    ``pair`` ``[2, block, width]`` as the whole chunks of the rows ``row ..``
+    it lies on: cut into 128-lane chunks, each rotated right by the row's
+    lane in its chunk and split at that lane between its own chunk and the
+    next; 0 elsewhere."""
+    width = pair.shape[-1]
+    m = -(-width // TILE)
+    lane = (row % TILE)[None, :, None, None]
+    x = jnp.pad(pair, ((0, 0), (0, 0), (0, m * TILE - width)))
+    x = _rotate_lanes(x.reshape(pair.shape[:2] + (m, TILE)), lane[..., 0],
+                      left=False)
+    x = jnp.pad(x, ((0, 0), (0, 0), (1, 1), (0, 0)))
+    return jnp.where(jnp.arange(TILE) >= lane, x[..., 1:, :], x[..., :-1, :])
+
+
+def _add_windows(moved, row, chunks):
+    """``moved`` ``[2, chunks, 128]`` plus each query's chunks of
+    ``chunks`` ``[blocks, 2, block, m + 1, 128]``, from the chunk of its
+    first row ``row`` ``[blocks, block]`` on, one index a chunk."""
+    index = (row // TILE)[..., None] + jnp.arange(chunks.shape[-2])
+    return jax.lax.scatter_add(
+        moved, index[..., None], chunks,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 4), inserted_window_dims=(1,),
+            scatter_dims_to_operand_dims=(1,)))

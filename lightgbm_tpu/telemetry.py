@@ -336,6 +336,7 @@ COUNTER_FAMILIES = (
     "rank/classes",               # lambdarank's query-length classes, once a program
     "rank/pair_slots",            # pair slots the classes compute, once a program
     "rank/pairs",                 # the layout's real pairs (sum of q^2), once a program
+    "rank/segment_docs",          # documents moved by whole-query windows, both ways, once a program
     "serve/bucket_*",             # per-ladder-bucket dispatch counts
     "serve/coalesced_batches",
     "serve/coalesced_requests",

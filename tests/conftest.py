@@ -53,7 +53,8 @@ HEAVY_FIRST = (
     "test_hist_int8_fold.py", "test_mixedbin.py", "test_wide_table.py",
     "test_tpu_compile_wide.py", "test_hybrid_voting.py", "test_gbdt.py",
     "test_streaming.py", "test_leafwise_wide.py", "test_leafcompact.py",
-    "test_tpu_compile_airline.py", "test_airline_cell.py",
+    "test_tpu_compile_airline.py", "test_tpu_compile_rank.py",
+    "test_airline_cell.py",
     "test_distributed_telemetry.py", "test_goss_chunk.py",
     "test_route_pallas.py", "test_depthwise.py", "test_hist_int8.py",
     "test_hist_int8_ranges.py", "test_rank_bucketed.py",

@@ -12,6 +12,7 @@ rounding is 6e-8 of the sum, so 1e-5 of the query set's largest |lambda|
 leaves room for a few hundred terms' roundings and no more.
 """
 import argparse
+import functools
 import os
 import sys
 
@@ -26,6 +27,7 @@ from lightgbm_tpu.config import ObjectiveConfig
 from lightgbm_tpu.metrics.dcg import DCGCalculator
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.objectives import rank
+from lightgbm_tpu.utils import log
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -136,6 +138,154 @@ def test_weights_scale_each_document():
     _close(hess, want_h * w)
 
 
+def _per_document_block(table, *, score, discount, sigmoid):
+    """One query block as the program computed it before queries moved as
+    windows (the former ``rank._query_block``): every slot's score
+    gathered by its own row index, sorted by score over the class width,
+    the pair terms, the sort back to each query's document order."""
+    count, label, gain = table["count"], table["label"], table["gain"]
+    width = label.shape[-1]
+    pos = jnp.arange(width, dtype=jnp.int32)
+    valid = pos[None, :] < count[:, None]
+    s = jnp.where(valid, score[table["rows"]], -jnp.inf)
+    neg, ll, gg, src = jax.lax.sort(
+        (-s, label, gain, jnp.broadcast_to(pos, s.shape)), dimension=1,
+        is_stable=True, num_keys=1)
+    ss = jnp.where(valid, -neg, 0.0)
+    best = ss[:, 0]
+    worst = jnp.take_along_axis(
+        ss, jnp.maximum(count - 1, 0)[:, None], axis=1)[:, 0]
+    hi_s, lo_s = ss[:, :, None], ss[:, None, :]
+    hi_l, lo_l = ll[:, :, None], ll[:, None, :]
+    pair = (hi_l > lo_l) & valid[:, :, None] & valid[:, None, :]
+    delta = hi_s - lo_s
+    disc = discount[:width]
+    paired_disc = jnp.abs(disc[:, None] - disc[None, :])
+    delta_ndcg = ((gg[:, :, None] - gg[:, None, :]) * paired_disc
+                  * table["inv_max_dcg"][:, None, None])
+    delta_ndcg = jnp.where((hi_l != lo_l) & (best != worst)[:, None, None],
+                           delta_ndcg / (0.01 + jnp.abs(delta)), delta_ndcg)
+    sig = 2.0 / (1.0 + jnp.exp(2.0 * delta * sigmoid))
+    p_hess = sig * (2.0 - sig)
+    lam = jnp.where(pair, -sig * delta_ndcg, 0.0)
+    hes = jnp.where(pair, 2.0 * delta_ndcg * p_hess, 0.0)
+    lam_sorted = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
+    hes_sorted = jnp.sum(hes, axis=2) + jnp.sum(hes, axis=1)
+    _, lam_out, hes_out = jax.lax.sort((src, lam_sorted, hes_sorted),
+                                       dimension=1, num_keys=1)
+    return lam_out, hes_out
+
+
+def _per_document(obj, score):
+    """The lambdas and hessians as the program made them before queries
+    moved as windows, kept as the oracle of the movement: the objective's
+    own tables with every slot's row beside them, every row's lambda and
+    hessian gathered by its own flat slot index (rows of no query read a
+    zero past the last slot)."""
+    _key, params, _fn = obj.chunk_spec()
+    rows = score.shape[0]
+    where = np.full(rows, -1, np.int64)
+    tables, base = [], 0
+    for table, (w, blocks, block) in zip(params["tables"],
+                                         obj.layout.classes):
+        row = np.asarray(table["row"])[..., None]
+        valid = np.arange(w) < np.asarray(table["count"])[..., None]
+        at = np.where(valid, row + np.arange(w), 0)
+        where[at[valid]] = base + np.flatnonzero(valid)
+        tables.append(dict(table, rows=jnp.asarray(at, jnp.int32)))
+        base += blocks * block * w
+    where = jnp.asarray(np.where(where < 0, base, where), jnp.int32)
+
+    @jax.jit
+    def program(params, tables, score, where):
+        lams, hess = [], []
+        for table in tables:
+            lam, hes = jax.lax.map(functools.partial(
+                _per_document_block, score=score,
+                discount=params["discount"], sigmoid=params["sigmoid"]),
+                table)
+            lams.append(lam.reshape(-1))
+            hess.append(hes.reshape(-1))
+        zero = jnp.zeros((1,), jnp.float32)
+        lam = jnp.concatenate(lams + [zero])[where]
+        hes = jnp.concatenate(hess + [zero])[where]
+        if params["weights"] is not None:
+            lam, hes = lam * params["weights"], hes * params["weights"]
+        return lam, hes
+    return program(params, tuple(tables), jnp.asarray(score), where)
+
+
+def _shard_padded(name):
+    """A ``_case`` laid out as two processes' query-atomic shards in the
+    padded-global rows ``globalize_layout`` builds: (objective, score over
+    the padded rows, padding rows)."""
+    label, bounds, score = _case(name)
+    half = int(bounds[bounds.size // 2])
+    shards = ((0, half), (half + 37, int(bounds[-1]) - half))
+    num_padded = half + 37 + shards[1][1] + 11
+    obj = create_objective("lambdarank", ObjectiveConfig())
+    obj.globalize_layout(_Meta(label, bounds), shards, num_padded)
+    pad_pos = np.concatenate([s + np.arange(n) for s, n in shards])
+    padded = np.zeros(num_padded, np.float32)
+    padded[pad_pos] = score
+    padding = np.setdiff1d(np.arange(num_padded), pad_pos)
+    padded[padding] = 7.0       # a shard's padding rows hold any score
+    return obj, padded, padding
+
+
+# name -> (lengths put in front of the seeded heavy tail, weighted)
+MOVES = dict({name: None for name in CASES}, **{
+    "one_document": ((1,), False),
+    "class_widths": ((64, 128, 256, 384), False),
+    "longest": ((4096,), False),
+    "weighted": ((1, 128, 300), True),
+})
+
+
+@pytest.mark.parametrize("name", sorted(MOVES) + ["shard_padded"])
+def test_the_movement_is_the_per_document_one(name):
+    """Whole-query windows in and out give the per-document gathers'
+    lambdas and hessians bit for bit: the same floats reach the pair
+    terms, and a row gets its own slot's value plus exact zeros."""
+    padding = np.zeros(0, np.int64)
+    if name == "shard_padded":
+        obj, score, padding = _shard_padded("class_boundaries")
+    elif MOVES[name] is None:
+        label, bounds, score = _case(name)
+        obj = _objective(label, bounds)
+    else:
+        extra, weighted = MOVES[name]
+        sizes, bounds = _heavy_tailed(13, queries=20, extra=extra)
+        rng = np.random.default_rng(17)
+        label = rng.integers(0, 5, bounds[-1])
+        score = rng.standard_normal(bounds[-1]).astype(np.float32)
+        weights = (rng.uniform(0.5, 2.0, bounds[-1]).astype(np.float32)
+                   if weighted else None)
+        obj = _objective(label, bounds, weights)
+        if name == "class_widths":
+            widths = {w for w, _, _ in obj.layout.classes}
+            assert set(extra) <= widths, widths
+    grad, hess = obj.get_gradients(jnp.asarray(score))
+    want_g, want_h = _per_document(obj, score)
+    np.testing.assert_array_equal(np.asarray(grad), np.asarray(want_g))
+    np.testing.assert_array_equal(np.asarray(hess), np.asarray(want_h))
+    assert np.abs(np.asarray(grad)).max() > 0
+    assert not np.asarray(grad)[padding].any()
+    assert not np.asarray(hess)[padding].any()
+
+
+def test_a_query_in_rows_apart_is_refused():
+    """A query moves as one window of consecutive rows: ``rows`` that put
+    two documents of one query apart fail at ``init``."""
+    label, bounds, _ = _case("length_one")
+    rows = np.arange(int(bounds[-1]))
+    lo = int(bounds[2])                       # the query of two documents
+    rows[[lo, lo + 1]] = rows[[lo + 1, lo]]
+    obj = create_objective("lambdarank", ObjectiveConfig())
+    with pytest.raises(log.LightGBMError, match="consecutive rows"):
+        obj.init(_Meta(label, bounds), int(bounds[-1]), rows=rows)
+
+
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_layout_rules(seed):
     sizes, _ = _heavy_tailed(seed, queries=400, extra=(1, 64, 128, 129))
@@ -206,6 +356,33 @@ def test_counters_are_the_layouts_once_a_program():
     assert counters["rank/pairs"] == obj.layout.pairs
     assert counters["rank/pair_slots"] == obj.layout.pair_slots
     assert counters["rank/classes"] == len(obj.layout.classes)
+
+
+@pytest.mark.parametrize("layout", ["rows", "shard_padded"])
+def test_segment_docs_are_counted_once_a_program(layout):
+    """``rank/segment_docs``: every document moved as part of its query's
+    window, in and out, counted once however often the program runs; a
+    shard's padding rows are no documents."""
+    if layout == "rows":
+        label, bounds, score = _case("class_boundaries", seed=31)
+        obj = _objective(label, bounds)
+    else:
+        obj, score, _padding = _shard_padded("class_boundaries")
+        bounds = _case("class_boundaries")[1]
+    telemetry.reset()
+    telemetry.enable(fence=False)
+    try:
+        jax.clear_caches()
+        rank._iteration_program.cache_clear()
+        rank._RANK_FNS.clear()
+        for _ in range(3):
+            jax.block_until_ready(obj.get_gradients(jnp.asarray(score)))
+        counters = telemetry.snapshot()["counters"]
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert counters["rank/segment_docs"] == 2 * int(bounds[-1])
+    assert obj.layout.docs == int(bounds[-1])
 
 
 def test_the_gradient_program_is_under_its_scope():
