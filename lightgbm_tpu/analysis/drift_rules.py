@@ -58,6 +58,7 @@ from .findings import Finding
 _TELEMETRY_CALLS = {
     "count": ("counter", 0),
     "count_route": ("counter", 1),     # arg 0 is the route group
+    "gauge": ("counter", 0),
     "span": ("span", 0),
     "collective_span": ("wire", 0),
     "record_collective": ("wire", 0),

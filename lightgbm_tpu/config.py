@@ -776,8 +776,16 @@ class BoostingConfig:
     elastic_shrink: bool = False
     straggler_k: int = 3
     tree_config: TreeConfig = dataclasses.field(default_factory=TreeConfig)
+    # the mesh a parallel tree_learner spans (NetworkConfig's and
+    # OverallConfig's keys, read here too): what GBDT.init builds the
+    # learner from when it is handed none
+    num_machines: int = 1
+    device_type: str = ""
 
     def set(self, params: Dict[str, str]) -> None:
+        self.num_machines = _get_int(params, "num_machines", self.num_machines)
+        log.check(self.num_machines >= 1, "num_machines should be >= 1")
+        self.device_type = _get_str(params, "device_type", self.device_type)
         self.num_iterations = _get_int(params, "num_iterations", self.num_iterations)
         log.check(self.num_iterations >= 0, "num_iterations should be >= 0")
         self.bagging_seed = _get_int(params, "bagging_seed", self.bagging_seed)

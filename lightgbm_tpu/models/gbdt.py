@@ -128,6 +128,8 @@ class GBDT:
         self.max_feature_idx = train_data.num_total_features - 1
         self.label_idx = train_data.label_idx
         self.sigmoid = objective.sigmoid if objective is not None else -1.0
+        if learner is None and boosting_config.tree_learner != "serial":
+            learner = _configured_learner(boosting_config)
         self._learner = learner or _serial_learner
 
         N = train_data.num_data
@@ -301,8 +303,11 @@ class GBDT:
                     "this streamed dataset's device bin matrix was "
                     "consumed by a previous mixed-bin GBDT.init — reload "
                     "the dataset to train another booster on it")
+                rows = _row_sharding(learner, N)
                 self.bins_device = self._place_bins(
-                    _arr0, self._bins_host(train_data))
+                    _arr0 if rows is None else functools.partial(
+                        jax.device_put, device=rows),
+                    self._bins_host(train_data))
             self.num_bins_device = _arr0(train_data.num_bins)
             self._row_valid = None
             init_score = train_data.metadata.init_score
@@ -2067,17 +2072,25 @@ class GBDT:
                     # num_data is already device-aligned (pad == 0)
                     valid_rows = self._row_valid
                 else:
-                    valid_rows = jnp.arange(N + pad) < N
-                    # commit the matrix row-sharded on the learner's mesh
-                    # ONCE: the resident loader's one-device array would
+                    # commit the row-aligned inputs row-sharded on the
+                    # learner's mesh ONCE: a one-device array would
                     # otherwise sit on device 0 and be re-distributed by
-                    # every chunk dispatch (a no-op for the streaming
-                    # loader, which already placed it so)
+                    # every chunk dispatch (a no-op for a table GBDT.init
+                    # or the streaming loader already placed so)
                     from jax.sharding import NamedSharding, PartitionSpec
                     from ..parallel.mesh import DATA_AXIS
-                    bins_p = jax.device_put(bins_p, NamedSharding(
-                        self._learner._mesh(),
-                        PartitionSpec(None, DATA_AXIS)))
+                    mesh = self._learner._mesh()
+
+                    def by_rows(a, leading=()):
+                        return jax.device_put(a, NamedSharding(
+                            mesh, PartitionSpec(*leading, DATA_AXIS)))
+                    valid_rows = by_rows(jnp.arange(N + pad) < N)
+                    bins_p = by_rows(bins_p, (None,))
+                    if not getattr(self.objective, "needs_global_score",
+                                   False):
+                        obj_p = jax.tree.map(
+                            lambda l: by_rows(l) if getattr(
+                                l, "ndim", 0) == 1 else l, obj_p)
                 cache = (num_shards, bins_p, obj_p, valid_rows)
                 self._dp_chunk_inputs = cache
             _, bins_p, obj_p, valid_rows = cache
@@ -2982,6 +2995,36 @@ def leafwise_compact_on(tree_config) -> bool:
     if c == "auto":
         return jax.default_backend() == "tpu"
     return c == "true"
+
+
+def _configured_learner(boosting_config):
+    """The parallel learner ``tree_learner`` names, built as task=train
+    builds it from the same ``key=value`` pairs (cli.Application.
+    init_train), for a caller that hands ``GBDT.init`` none."""
+    from ..config import OverallConfig
+    from ..parallel import create_parallel_learner
+    config = OverallConfig(boosting_config=boosting_config,
+                           device_type=boosting_config.device_type,
+                           is_parallel=True)
+    config.network_config.num_machines = boosting_config.num_machines
+    return create_parallel_learner(config)
+
+
+def _row_sharding(learner, rows: int):
+    """Where a single-process data-parallel learner's chunk program reads
+    the ``[F, rows]`` bin table: cut by rows over the learner's mesh, so
+    that each chip holds its own rows and no chip the whole table.  None
+    for any other learner, or rows that do not divide evenly (the chunk
+    program then pads a copy)."""
+    from ..parallel.learners import DataParallelLearner
+    if not isinstance(learner, DataParallelLearner):
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec
+    from ..parallel.mesh import DATA_AXIS
+    mesh = learner._mesh()
+    if rows % mesh.shape[DATA_AXIS]:
+        return None
+    return NamedSharding(mesh, PartitionSpec(None, DATA_AXIS))
 
 
 def _serial_learner(gbdt: GBDT, bins, grad, hess, row_mask, feature_mask):

@@ -835,8 +835,9 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # own: under vmap the finder's inner scope reads
         # "vmap(split_find)", which no phase pattern matches
         with phase_scope("split_find"), telemetry.span("split_find") as _sp:
-            res = _sp.fence(vsplit(hists, slot_g, slot_h, slot_c, num_bins,
-                                   feature_mask, mind, minh))
+            with telemetry.collective_batch(P):
+                res = _sp.fence(vsplit(hists, slot_g, slot_h, slot_c,
+                                       num_bins, feature_mask, mind, minh))
             can = alive & (res.gain > 0.0) & jnp.isfinite(res.gain)
 
             # ---- budget: split the top-gain slots first (within-level

@@ -177,6 +177,18 @@ def _owned_block(F: int, num_shards: int, axis_name: str):
     return Fb, Fpad, ids
 
 
+def _padded_seam(collective, pad):
+    """A histogram seam that pads its operand's feature axis to whole
+    owned blocks and hands it to ``collective`` (a
+    ``telemetry.collective_span``), so that the site files the bytes the
+    collective moves; it carries the span's label, so the grower does not
+    label it again (``grower_unified.wrap_schedule``)."""
+    def seam(x):
+        return collective(pad(x))
+    seam._tl_collective_site = collective._tl_collective_site
+    return seam
+
+
 def dp_ownership_seams(F: int, num_shards: int, site_prefix: str = "dp_rs",
                        loop: int = 1, phase: str = "grow",
                        root_loop: int = 1):
@@ -208,19 +220,19 @@ def dp_ownership_seams(F: int, num_shards: int, site_prefix: str = "dp_rs",
             widths[0] = (0, Fpad - F)
             return jnp.pad(x, widths)
 
-        def scatter0(h):
-            # per-split [F, B, ...] histogram (f32) or [F, B, lanes]
-            # INT accumulator — both carry features on axis 0
-            return jax.lax.psum_scatter(
-                pad_f(h), DATA_AXIS, scatter_dimension=0, tiled=True)
+        # per-split [F, B, ...] histogram (f32) or [F, B, lanes] INT
+        # accumulator — both carry features on axis 0
+        scat = _padded_seam(_c(
+            site_prefix + "/hist_scatter",
+            lambda h: jax.lax.psum_scatter(
+                h, DATA_AXIS, scatter_dimension=0, tiled=True),
+            kind="psum_scatter", loop=loop), pad_f)
 
         def own_slice(h):
             # replicated full root histogram -> this shard's block
             return jax.lax.dynamic_slice_in_dim(
                 pad_f(h), rank * Fb, Fb, axis=0)
 
-        scat = _c(site_prefix + "/hist_scatter", scatter0,
-                  kind="psum_scatter", loop=loop)
         schedule = SeamSchedule(
             hist_axis=DATA_AXIS,
             hist_reduce=scat, int_hist_reduce=scat,
@@ -576,8 +588,14 @@ class DataParallelLearner(_ParallelLearnerBase):
         reference — its N-machine data-parallel mode IS the ReduceScatter
         ownership schedule (data_parallel_tree_learner.cpp:135-235) — so
         true multi-process runs default to reduce_scatter, while
-        single-process meshes keep psum (simplest, measured equivalent at
-        small shard counts, PROFILE.md)."""
+        single-process meshes keep psum.  On four v5e chips at 28.75M
+        rows a chip (Airline's table, 255-leaf int8 level-wise trees) the
+        two ran 2.2156 (psum) and 2.2163 (reduce_scatter) iterations a
+        second with the same trees to the bit: the exchange is 0.3 of
+        some 451 ms an iteration under psum and 0.5 under reduce_scatter,
+        whose level reduce-scatters the compiler makes all-reduces of
+        the integer pair padded to whole blocks and whose SplitInfo
+        gathers add 0.25 ms (PERF.md, section 6)."""
         s = getattr(self.tree_config, "dp_schedule", "psum")
         if s == "auto":
             return ("reduce_scatter" if jax.process_count() > 1
@@ -642,17 +660,19 @@ class DataParallelLearner(_ParallelLearnerBase):
                 widths[axis] = (0, Fpad - F)
                 return jnp.pad(x, widths)
 
-            def int_reduce(acc):
-                # INT accumulators, feature axis 0 — int-domain scatter
-                # keeps the serial == distributed bit-exactness chain
-                return jax.lax.psum_scatter(
-                    pad_f(acc, 0), DATA_AXIS, scatter_dimension=0,
-                    tiled=True)
-
-            def hist_scatter(h):
-                # f32 [C, F, B, 3] level histogram, feature axis 1
-                return jax.lax.psum_scatter(
-                    pad_f(h, 1), DATA_AXIS, scatter_dimension=1, tiled=True)
+            # INT accumulators, feature axis 0 — int-domain scatter
+            # keeps the serial == distributed bit-exactness chain
+            int_reduce = _padded_seam(_c(
+                "dp_rs/depthwise/level_int_scatter",
+                lambda acc: jax.lax.psum_scatter(
+                    acc, DATA_AXIS, scatter_dimension=0, tiled=True),
+                kind="psum_scatter"), lambda acc: pad_f(acc, 0))
+            # f32 [C, F, B, 3] level histogram, feature axis 1
+            hist_scatter = _padded_seam(_c(
+                "dp_rs/depthwise/level_hist_scatter",
+                lambda h: jax.lax.psum_scatter(
+                    h, DATA_AXIS, scatter_dimension=1, tiled=True),
+                kind="psum_scatter"), lambda h: pad_f(h, 1))
 
             def own_slice(h):
                 # replicated full root histogram -> this shard's block
@@ -671,10 +691,8 @@ class DataParallelLearner(_ParallelLearnerBase):
                     own_s, DATA_AXIS,
                     site="dp_rs/depthwise/splitinfo_allreduce",
                     loop=loop_scale, phase=phase),
-                hist_reduce_level=_c("dp_rs/depthwise/level_hist_scatter",
-                                     hist_scatter, kind="psum_scatter"),
-                int_reduce_level=_c("dp_rs/depthwise/level_int_scatter",
-                                    int_reduce, kind="psum_scatter"),
+                hist_reduce_level=hist_scatter,
+                int_reduce_level=int_reduce,
                 own_slice=own_slice)
             return grow_tree_unified(
                 bins_s, grad_s, hess_s, mask_s, fmask_own, nbins_own,
@@ -743,6 +761,7 @@ class DataParallelLearner(_ParallelLearnerBase):
                goss, self._schedule(), use_pp,
                use_pp and partition_overlap_on(), jax.default_backend(),
                getattr(self.config, 'device_type', ''),
+               tuple(d.id for d in mesh.devices.flat),
                num_features, bool(health), self._key_extra(),
                tuple(id(f) for f in train_metric_fns),
                tuple(tuple(id(f) for f in fns) for fns in valid_metric_fns))
@@ -868,21 +887,32 @@ class DataParallelLearner(_ParallelLearnerBase):
             grow_fn = self._chunk_grow_fn(kwargs, num_features, num_shards,
                                           depthwise, use_compact,
                                           use_scatter, chunk_k[0])
-            body = make_chunk_body(
-                grad_fn=grad_fn, obj_params=obj_params, num_class=num_class,
-                lrf=lrf,
-                grow_fn=grow_fn,
-                has_bag=has_bag, has_ff=has_ff, bins=bins,
-                num_bins=num_bins, base_mask=valid_rows,
-                max_nodes=max_nodes, valid_bins=valid_bins,
-                valid_mparams=valid_mparams,
-                train_metric_fns=train_fns, train_mparams=train_mparams,
-                valid_metric_fns=valid_metric_fns, health_fn=health_fn,
-                goss_fn=goss_fn)
-            xs = ((row_masks, feat_masks) if goss_fn is None
-                  else (row_masks, feat_masks, goss_iters))
-            (score, vscores), (stacked, mvals, hvals) = jax.lax.scan(
-                body, (score, tuple(valid_scores)), xs)
+            with telemetry.collective_tally(chunk_k[0]) as tally:
+                body = make_chunk_body(
+                    grad_fn=grad_fn, obj_params=obj_params,
+                    num_class=num_class, lrf=lrf, grow_fn=grow_fn,
+                    has_bag=has_bag, has_ff=has_ff, bins=bins,
+                    num_bins=num_bins, base_mask=valid_rows,
+                    max_nodes=max_nodes, valid_bins=valid_bins,
+                    valid_mparams=valid_mparams,
+                    train_metric_fns=train_fns, train_mparams=train_mparams,
+                    valid_metric_fns=valid_metric_fns, health_fn=health_fn,
+                    goss_fn=goss_fn)
+                xs = ((row_masks, feat_masks) if goss_fn is None
+                      else (row_masks, feat_masks, goss_iters))
+                (score, vscores), (stacked, mvals, hvals) = jax.lax.scan(
+                    body, (score, tuple(valid_scores)), xs)
+            # what one tree moves across the chips, and under which
+            # schedule: the benchmark's collective_* metrics read these
+            trees = max(num_class, 1)
+            telemetry.gauge("collective/bytes_per_tree", tally.bytes // trees)
+            telemetry.gauge("collective/calls_per_tree", tally.calls // trees)
+            for kind, nbytes in tally.bytes_by_kind.items():
+                telemetry.gauge("collective/%s_bytes_per_tree" % kind,
+                                nbytes // trees)
+            telemetry.gauge("collective/trees_per_iter", trees)
+            telemetry.gauge("collective/shards", num_shards)
+            telemetry.gauge("collective/schedule_" + self._schedule(), 1)
             return score, vscores, stacked, mvals, hvals
 
         def param_spec(leaf):

@@ -238,6 +238,7 @@ which benchmark metric reads which):
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import os
 import threading
@@ -274,6 +275,7 @@ COUNTER_FAMILIES = (
     "ckpt/restored",
     "ckpt/snapshots",
     "ckpt/written",
+    "collective/*",               # a data-parallel chunk program's seams a tree, once a program
     "costmodel/aot_call_fallback",
     "costmodel/capture_failed",
     "elastic/shrinks",
@@ -835,6 +837,7 @@ def record_collective(site: str, kind: str, axis: Optional[str],
     site's wire seconds in the ``interconnect`` block."""
     if not _enabled:
         return
+    nbytes = int(nbytes) * _batch
     if phase is None and _span_stack:
         # default attribution: the OUTERMOST active span is the host-side
         # phase the compiled program executes under ("grow"/"train_chunk")
@@ -843,12 +846,15 @@ def record_collective(site: str, kind: str, axis: Optional[str],
     rec = _collectives.get(site)
     if rec is None:
         rec = _collectives[site] = {
-            "kind": kind, "axis": axis, "bytes_per_call": int(nbytes),
+            "kind": kind, "axis": axis, "bytes_per_call": nbytes,
             "traced_calls": 0, "loop": max(int(loop), 1), "phase": phase}
     rec["traced_calls"] += 1
+    if _tally is not None:
+        runs = max(1, int(loop) // _tally.per)
+        _tally.add(kind, nbytes * runs, runs)
     # shapes can differ between traces (re-trace at a new shape): keep the
     # largest payload as the representative per-call cost
-    rec["bytes_per_call"] = max(rec["bytes_per_call"], int(nbytes))
+    rec["bytes_per_call"] = max(rec["bytes_per_call"], nbytes)
     if _ring_armed:
         _ring_event("collective", site)
 
@@ -879,6 +885,58 @@ def collective_span(site: str, fn, *, kind: str, axis: Optional[str] = None,
 
 def collectives() -> Dict[str, dict]:
     return {k: dict(v) for k, v in _collectives.items()}
+
+
+class CollectiveTally:
+    """The collectives one trace of a program body files: logical payload
+    bytes and calls, by kind.  A site's ``loop`` counts its executions
+    per run of the program, and the body runs ``per`` times a run (the
+    fused chunk's trees), so each traced call stands for ``loop // per``
+    executions of the body; a site that does not know the chunk (loop 1)
+    is one, as it is where the trace is unrolled (the level-wise grower's
+    levels, a tree's root)."""
+
+    def __init__(self, per: int):
+        self.per = max(int(per), 1)
+        self.bytes = 0
+        self.calls = 0
+        self.bytes_by_kind: Dict[str, int] = {}
+
+    def add(self, kind: str, nbytes: int, calls: int) -> None:
+        self.bytes += nbytes
+        self.calls += calls
+        self.bytes_by_kind[kind] = self.bytes_by_kind.get(kind, 0) + nbytes
+
+
+_tally: Optional[CollectiveTally] = None
+_batch = 1
+
+
+@contextlib.contextmanager
+def collective_batch(n: int):
+    """Around a ``jax.vmap`` of ``n`` whose body holds a collective: the
+    trace sees one element's operand, the program moves ``n`` of them, so
+    each record inside counts ``n`` times its traced payload."""
+    global _batch
+    outer, _batch = _batch, _batch * max(int(n), 1)
+    try:
+        yield
+    finally:
+        _batch = outer
+
+
+@contextlib.contextmanager
+def collective_tally(per: int):
+    """``with collective_tally(per) as t:`` around the trace of a body
+    that runs ``per`` times a program run; ``t`` then holds what one run
+    of the body moves (``CollectiveTally``).  Nothing is counted while
+    the registry is off."""
+    global _tally
+    outer, _tally = _tally, CollectiveTally(per)
+    try:
+        yield _tally
+    finally:
+        _tally = outer
 
 
 def interconnect_snapshot() -> Optional[dict]:
@@ -1367,6 +1425,14 @@ def count(name: str, n: int = 1) -> None:
     recompiles).  No-op while disabled."""
     if _enabled:
         _counters[name] = _counters.get(name, 0) + n
+
+
+def gauge(name: str, value: int) -> None:
+    """Set a counter to a figure of a traced program (bytes a tree, the
+    schedule it took): set and not added, so that a second trace of the
+    same program does not double it.  No-op while disabled."""
+    if _enabled:
+        _counters[name] = int(value)
 
 
 def count_route(group: str, name: str) -> None:

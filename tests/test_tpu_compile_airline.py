@@ -6,6 +6,8 @@ rules these files keep).
 """
 import re
 
+import numpy as np
+
 import pytest
 
 import jax
@@ -91,3 +93,85 @@ def test_fused_chunk_program_compiles_at_the_airline_cell(
     assert ma.temp_size_in_bytes + ma.argument_size_in_bytes > 4 << 30
     assert (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.output_size_in_bytes) < 0.95 * 16_909_336_064
+
+
+def test_data_parallel_chunk_program_compiles_at_a_quarter_of_airline(
+        topo, as_tpu, monkeypatch):
+    """The program of the cell ``airline-dp4-levelwise-int8.train_sharded``
+    for the four chips of a described v5e:2x2: the configuration's own
+    ``key=value`` pairs, the learner ``GBDT.init`` builds from them, the
+    argument tree re-shaped to 115,000,000 rows, 28,750,000 a chip.  Each
+    chip's int8 passes sum in two accumulation ranges (every histogram
+    custom call hands on ``s32[2, 13, ...]``); the program asks for the
+    chips' integer pairs reduce-scattered by feature block, and the
+    compiler for the chip makes each an all-reduce of the pair over the
+    columns padded to 16, of which a chip then reads its block; and the
+    size argument of the cell: a chip's temporaries and arguments (1.92
+    and 0.83 GB) pass the 2.15 GB of the 12.5% line and leave 5% of the
+    chip free."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.objectives import create_objective
+    from lightgbm_tpu.parallel.learners import DataParallelLearner
+    from tpu_described import _Captured
+    conf = _cell_config("airline-dp4-levelwise-int8")
+    rows, columns = conf["rows"], conf["features"]
+    chips = int(conf["params"]["num_machines"])
+    assert (rows, columns, chips) == (115_000_000, 13, 4)
+    config = lgb.OverallConfig()
+    config.set(conf["params"], require_data=False)
+    n_tiny = 1000
+    booster = lgb.GBDT()
+    booster.init(config.boosting_config, _tiny_binary_dataset(n_tiny, columns),
+                 create_objective(config.objective_type,
+                                  config.objective_config))
+    learner = booster._learner
+    assert isinstance(learner, DataParallelLearner)
+    assert learner._schedule() == "reduce_scatter"
+    tpu_mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    seen = {}
+    real_chunk_program = learner.chunk_program
+
+    def capturing_chunk_program(*a, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(learner, "_mesh", lambda: tpu_mesh)
+            prog, num_shards = real_chunk_program(*a, **kw)
+
+        def call(*args):
+            seen["prog"], seen["args"] = prog, args
+            raise _Captured
+        return call, num_shards
+
+    monkeypatch.setattr(learner, "chunk_program", capturing_chunk_program)
+    with pytest.raises(_Captured):
+        booster.train_chunk(8, is_eval=False)
+
+    def conv(a):
+        a = np.asarray(a) if not hasattr(a, "shape") else a
+        shape = tuple(rows if d == n_tiny else d for d in a.shape)
+        spec = P(*("data" if d == n_tiny else None for d in a.shape))
+        return jax.ShapeDtypeStruct(shape, a.dtype,
+                                    sharding=NamedSharding(tpu_mesh, spec))
+
+    lowered = seen["prog"].lower(*jax.tree.map(conv, seen["args"]))
+    # the program asks for the reference's schedule: the seven level
+    # exchanges of the integer pair reduce-scattered by feature block
+    assert len(re.findall(r"stablehlo\.reduce_scatter", lowered.as_text())
+               ) == 7 * 2
+    compiled = lowered.compile()
+    ma = _check(compiled, custom_call=True)
+    text = compiled.as_text()
+    calls = re.findall(r"= (\S+) custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r"_hist_pallas_raw_fn", text)
+    assert len(calls) == 8 and all(c.startswith("s32[2,13,") for c in calls)
+    # ... which the compiler for the chip turns into an all-reduce of the
+    # pair over the 13 columns padded to 16, each chip then reading its
+    # block of four: one a level, the root's psum over 13 beside them
+    pairs = re.findall(r"= \((s32\[\d+),\d+,\d+\][^ ]*, s32\[[\d,]+\][^ ]*\) "
+                       r"all-reduce\(", text)
+    assert sorted(pairs) == ["s32[13"] + ["s32[16"] * 7, pairs
+    per_chip = ma.temp_size_in_bytes + ma.argument_size_in_bytes
+    assert per_chip > 2.15e9, (ma.temp_size_in_bytes,
+                               ma.argument_size_in_bytes)
+    assert per_chip + ma.output_size_in_bytes < 0.95 * 16_909_336_064
