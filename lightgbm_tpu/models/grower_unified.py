@@ -632,23 +632,22 @@ def _grow_leafwise(bins, grad, hess, row_mask, feature_mask, num_bins,
 # ====================================================== depthwise policy
 
 def _route_level_xla(partition_bins, slot_id, out_leaf, row_mask, feat_part,
-                     threshold, chosen, right_leaf, small_is_right, *,
+                     threshold, chosen, right_leaf, *,
                      num_bins_max: int, num_leaves: int):
     """One level's row routing in XLA: (new slot id, new leaf id, the
-    rows of every chosen slot's smaller child).  All per-slot attributes
-    a row needs (split feature, threshold, chosen flag, new right-leaf
-    id, smaller-child side) ride ONE [P, N] one-hot matmul instead of one
-    pass per attribute: the slot-select one-hot is the expensive object
-    (O(P·N) comparisons), so it is generated once and contracted against
-    a packed [P, K] table."""
+    rows of ``row_mask`` each slot sends right, [P] int32).  All per-slot
+    attributes a row needs (split feature, threshold, chosen flag, new
+    right-leaf id) ride ONE [P, N] one-hot matmul instead of one pass per
+    attribute: the slot-select one-hot is the expensive object (O(P·N)
+    comparisons), so it is generated once and contracted against a
+    packed [P, K] table, and counted against the rows that move."""
     f32 = jnp.float32
     i32 = jnp.int32
     P = chosen.shape[0]
     table = jnp.stack([feat_part.astype(f32),
                        threshold.astype(f32),
                        chosen.astype(f32),
-                       right_leaf.astype(f32),
-                       small_is_right.astype(f32)], axis=1)      # [P, 5]
+                       right_leaf.astype(f32)], axis=1)          # [P, 4]
     lsel = (slot_id[None, :] ==
             jnp.arange(P, dtype=i32)[:, None]).astype(f32)       # [P, N]
     # The table carries integer ids (feature, threshold, leaf).
@@ -666,12 +665,11 @@ def _route_level_xla(partition_bins, slot_id, out_leaf, row_mask, feat_part,
                  else jax.lax.Precision.HIGHEST)
     attrs = jnp.einsum("pn,pk->kn", lsel, table,
                        precision=attr_prec,
-                       preferred_element_type=jnp.float32)       # [5, N]
+                       preferred_element_type=jnp.float32)       # [4, N]
     feat_row = attrs[0].astype(i32)
     thr_row = attrs[1].astype(i32)
     in_chosen = attrs[2] > 0.5
     rl_row = attrs[3].astype(i32)
-    small_right_row = attrs[4] > 0.5
 
     # the row's bin on its slot's split feature: an O(F·N) feature
     # one-hot avoids materializing the old [P, N] row gather, but
@@ -690,11 +688,28 @@ def _route_level_xla(partition_bins, slot_id, out_leaf, row_mask, feat_part,
         row_bin = jnp.take_along_axis(
             partition_bins, feat_row[None, :], axis=0)[0].astype(i32)
     go_right = row_bin > thr_row
-    out_leaf = jnp.where(in_chosen & go_right, rl_row, out_leaf)
-    slot_id = (2 * slot_id
-               + jnp.where(in_chosen, go_right.astype(i32), 0))
-    sel = in_chosen & (go_right == small_right_row) & row_mask
-    return slot_id, out_leaf, sel
+    moved = in_chosen & go_right
+    out_leaf = jnp.where(moved, rl_row, out_leaf)
+    right = jnp.sum(lsel.astype(i32) * (moved & row_mask).astype(i32),
+                    axis=1)                                      # [P]
+    slot_id = 2 * slot_id + moved.astype(i32)
+    return slot_id, out_leaf, right
+
+
+def _row_flag(flags, ids):
+    """``flags[ids]`` for a short [K] bool table and [N] ids in [0, K):
+    the table as 32-bit words, a row's word picked by a chain of
+    selects, one a word, and its bit shifted out.  Elementwise, where
+    ``jnp.take`` of N rows is a gather."""
+    K = flags.shape[0]
+    W = -(-K // 32)
+    u32 = jnp.uint32
+    bits = jnp.pad(flags.astype(u32), (0, 32 * W - K)).reshape(W, 32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=u32), axis=1)   # [W]
+    word = jnp.full(ids.shape, words[0], u32)
+    for k in range(1, W):
+        word = jnp.where((ids >> 5) == k, words[k], word)
+    return ((word >> (ids & 31).astype(u32)) & 1) == 1
 
 
 def num_levels(num_leaves: int, max_depth: int = -1) -> int:
@@ -707,6 +722,29 @@ def num_levels(num_leaves: int, max_depth: int = -1) -> int:
     return d
 
 
+def hist_stats_of(compute_dtype, min_data_in_leaf: int,
+                  min_sum_hessian_in_leaf: float) -> int:
+    """Statistics a leaf column of the level-wise histograms: 2 (grad,
+    hess) where the per-bin count can refuse no split, else 3.  The
+    split search reads the count only in its test ``left_c >=
+    min_data_in_leaf`` / ``right_c >= ...``, beside ``left_h >=
+    min_sum_hessian_in_leaf`` with ``left_h = hessian sum + K_EPSILON``.
+    With ``min_data_in_leaf <= 1`` the count test refuses an empty side
+    alone.  In the int8 routes the search without counts refuses a side
+    none of whose bins holds a row of nonzero hessian code, read from the
+    integer sums (``ops/split.find_best_split``): that is every empty
+    side, and beside it only sides whose hessians sum to 0, which a floor
+    ``min_sum_hessian_in_leaf > K_EPSILON`` refuses too.  Each child's row
+    count comes exactly from the level's routing instead
+    (``_grow_depthwise``).  The float routes keep 3, and their kernels'
+    operand as it was."""
+    from ..ops.split import K_EPSILON
+    if (_is_int8(compute_dtype) and min_data_in_leaf <= 1
+            and min_sum_hessian_in_leaf > K_EPSILON):
+        return 2
+    return 3
+
+
 def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
                     s: SeamSchedule, partition_bins, *, num_leaves: int,
                     num_bins_max: int, min_data_in_leaf: int,
@@ -715,12 +753,13 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
                     partition_packing=None) -> TreeArrays:
     """Depth-wise (level-batched) growth — the TPU throughput path: the
     histograms of ALL leaves of a level build in ONE leaf-batched matmul
-    pass (3·P value columns fill the MXU; 8 batched passes for a 255-leaf
-    tree instead of 254 single-leaf passes), levels unrolled in Python
-    with static [P = 2^d] slot shapes.  The smaller-child + subtraction
-    trick is kept at level granularity.  Split-finding math is identical
-    to leaf-wise; split ORDER is by level (documented TPU-first trade),
-    the num_leaves budget honored best-first within each level."""
+    pass (2·P or 3·P value columns fill the MXU; 8 batched passes for a
+    255-leaf tree instead of 254 single-leaf passes), levels unrolled in
+    Python with static [P = 2^d] slot shapes.  The smaller-child +
+    subtraction trick is kept at level granularity, the children's sizes
+    counted exactly by the level's routing.  Split-finding math is
+    identical to leaf-wise; split ORDER is by level (documented TPU-first
+    trade), the num_leaves budget honored best-first within each level."""
     F, N = bins.shape
     L = num_leaves
     D = num_levels(L, max_depth)
@@ -729,15 +768,30 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
     i32 = jnp.int32
 
     from .. import telemetry
+    from ..ops.hist_pallas import NO_TARGET
 
-    maskf = row_mask.astype(f32)
     mind = float(min_data_in_leaf)
     minh = float(min_sum_hessian_in_leaf)
+    int8 = _is_int8(compute_dtype)
+    stats = hist_stats_of(compute_dtype, min_data_in_leaf,
+                          min_sum_hessian_in_leaf)
     leafbatch = _patchable("grower_depthwise", "histogram_leafbatch",
                            histogram_leafbatch)
     tree_scale = _tree_quant_max(compute_dtype, grad, hess, row_mask, s)
+    if stats == 2:
+        # a bin's hessian code sum reads 0 modulo 2^32 where it holds no
+        # row of nonzero code, or past 2^32 codes, where its float32 sum
+        # is more than 2^31 codes: an empty bin's rounding residue is
+        # some 2^-24 of its ancestors' bins, codes of 127 over every row
+        wrap_h = (jnp.maximum(tree_scale["quant_max"][1], 1e-30) / 127.0
+                  * float(1 << 31))
 
-    def batch_hist_rows(b, g, h, col_id, col_ok, C, level=False, salt=0):
+    def occupied(hist, hsum):
+        # [P, F, B] bool: the bins that hold a row of nonzero hessian code
+        return (hsum != 0) | (jnp.abs(hist[..., 1]) >= wrap_h)
+
+    def batch_hist_rows(b, g, h, col_id, col_ok, C, level=False, salt=0,
+                        targets=None):
         # level passes may use the scatter schedule; the root pass always
         # reduces in full
         int_red = s.int_reduce_level if level else None
@@ -746,6 +800,10 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # them
         extra = {"int_reduce": int_red} if int_red is not None else {}
         extra.update(tree_scale)
+        if stats != 3:
+            extra["stats"] = stats
+        if targets is not None:
+            extra["targets"] = targets
         if s.hist_feat_gather is not None:
             extra["feat_gather"] = s.hist_feat_gather
         if salt and compute_dtype == "int8_sr":
@@ -765,12 +823,21 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         red = (s.hist_reduce_level or s.hist_reduce) if level \
             else s.hist_reduce
         if red is not None:
-            out = red(out)
+            out = jax.tree.map(red, out)
         return out
 
-    def batch_hist(col_id, col_ok, C, level=False, salt=0):
+    def batch_hist(col_id, col_ok, C, level=False, salt=0, targets=None):
         return batch_hist_rows(bins, grad, hess, col_id, col_ok, C,
-                               level=level, salt=salt)
+                               level=level, salt=salt, targets=targets)
+
+    def global_count(count):
+        # rows counted over this shard's rows -> over every shard's
+        if s.hist_axis is None:
+            return count
+        telemetry.record_collective("depthwise/route_count_psum", "psum",
+                                    s.hist_axis,
+                                    telemetry._tree_nbytes(count))
+        return jax.lax.psum(count, s.hist_axis)
 
     vsplit = jax.vmap(s.split_finder or find_best_split,
                       in_axes=(0, 0, 0, 0, None, None, None, None))
@@ -786,17 +853,22 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
     # telemetry.DEVICE_PHASES, unconditionally, so a device trace
     # splits a level into histogram / split_find / row_route / tree_pack
     # with telemetry on or off.
+    # ``hists``: the level's [P, F, B, 3] float32 histograms, or with two
+    # statistics the pair ([P, F, B, 2] float32, [P, F, B] hessian code
+    # sums, int32 modulo 2^32), every operation below taken by both
     with jax.named_scope("level0"):
-        hists = batch_hist(jnp.zeros((N,), i32), row_mask, 1)  # [1,F,B,3]
+        hists = batch_hist(jnp.zeros((N,), i32), row_mask, 1)
         with phase_scope("histogram"):
-            root_stats = _root_stats_of(hists[0], s, compute_dtype, grad,
-                                        hess, row_mask)
+            root_stats = _root_stats_of(
+                (hists[0] if stats == 2 else hists)[0], s, compute_dtype,
+                grad, hess, row_mask)
+            root_count = global_count(jnp.sum(row_mask.astype(i32)))
             if s.own_slice is not None:
                 # ownership schedule: keep only this shard's contiguous
                 # feature block from here on (root stats above came from
                 # the full replicated histogram, so they stay
                 # bit-identical to the psum schedule)
-                hists = s.own_slice(hists)
+                hists = jax.tree.map(s.own_slice, hists)
 
     with phase_scope("split_find"):
         # per-slot level state (slot s at level d holds one candidate leaf)
@@ -805,7 +877,7 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         parent_node = jnp.full((1,), -1, i32)   # node owning the slot's leaf
         slot_g = root_stats[0][None]
         slot_h = root_stats[1][None]
-        slot_c = root_stats[2][None]
+        slot_c = root_count[None]               # exact rows a slot, i32
 
     with phase_scope("row_route"):
         slot_id = jnp.zeros((N,), i32)          # row → level-local slot
@@ -814,8 +886,7 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
     with phase_scope("tree_pack"):
         # output tree arrays (static size L)
         leaf_value = jnp.zeros((L,), f32)
-        leaf_count = jnp.zeros((L,), i32).at[0].set(
-            root_stats[2].astype(i32))
+        leaf_count = jnp.zeros((L,), i32).at[0].set(root_count)
         leaf_parent = jnp.full((L,), -1, i32)
         split_feature = jnp.zeros((max(L - 1, 1),), i32)
         threshold_bin = jnp.zeros((max(L - 1, 1),), i32)
@@ -835,8 +906,12 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # own: under vmap the finder's inner scope reads
         # "vmap(split_find)", which no phase pattern matches
         with phase_scope("split_find"), telemetry.span("split_find") as _sp:
+            # without a count column the search reads the bins' occupancy
+            # where it read the slot's rows
+            hist_f, rows_of = ((hists[0], occupied(*hists)) if stats == 2
+                               else (hists, slot_c.astype(f32)))
             with telemetry.collective_batch(P):
-                res = _sp.fence(vsplit(hists, slot_g, slot_h, slot_c,
+                res = _sp.fence(vsplit(hist_f, slot_g, slot_h, rows_of,
                                        num_bins, feature_mask, mind, minh))
             can = alive & (res.gain > 0.0) & jnp.isfinite(res.gain)
 
@@ -884,8 +959,6 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
             leaf_value = leaf_value.at[blx].set(res.left_output, mode="drop")
             leaf_value = leaf_value.at[rlx].set(res.right_output,
                                                 mode="drop")
-            leaf_count = leaf_count.at[blx].set(res.left_count, mode="drop")
-            leaf_count = leaf_count.at[rlx].set(res.right_count, mode="drop")
             leaf_parent = leaf_parent.at[blx].set(node_of, mode="drop")
             leaf_parent = leaf_parent.at[rlx].set(node_of, mode="drop")
 
@@ -899,7 +972,6 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
         # grower's stream-partition kernel.  The host span keeps its
         # canonical JSONL key.
         with phase_scope("row_route"), telemetry.span("partition") as _sp:
-            small_is_right = res.right_count < res.left_count   # ties → left
             # mixed-bin packing stores the matrix rows in packed order;
             # the per-slot partition feature must address that layout
             # (the recorded split_feature above stays canonical)
@@ -909,11 +981,19 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
             telemetry.count("partition/route_pallas" if route_kernel
                             else "partition/route_xla")
             level = (partition_bins, slot_id, out_leaf, row_mask, feat_part,
-                     res.threshold, chosen, right_leaf, small_is_right)
-            slot_id, out_leaf, sel = (
+                     res.threshold, chosen, right_leaf)
+            slot_id, out_leaf, right_c = (
                 route_level_pallas(*level) if route_kernel
                 else _route_level_xla(*level, num_bins_max=B, num_leaves=L))
+            # every slot's children, counted exactly: a slot's rows less
+            # those it sent right stay left
+            right_c = global_count(right_c)
+            left_c = slot_c - right_c
             _sp.fence((out_leaf, slot_id))
+
+        with phase_scope("tree_pack"):
+            leaf_count = leaf_count.at[blx].set(left_c, mode="drop")
+            leaf_count = leaf_count.at[rlx].set(right_c, mode="drop")
 
         if d + 1 >= D:
             break
@@ -928,37 +1008,49 @@ def _grow_depthwise(bins, grad, hess, row_mask, feature_mask, num_bins,
             parent_node = interleave(node_of, node_of)
             slot_g = interleave(res.left_sum_grad, res.right_sum_grad)
             slot_h = interleave(res.left_sum_hess, res.right_sum_hess)
-            slot_c = interleave(res.left_count.astype(f32),
-                                res.right_count.astype(f32))
+            slot_c = interleave(left_c, right_c)
 
         # ---- level histogram: build ONLY the smaller child of every chosen
-        # parent in one batched pass, derive the sibling by subtraction
-        # Smaller-child choice from the SplitResult counts (integer-valued
-        # f32 histogram sums; replicated under the data-parallel learner,
-        # whose counts come from psum'd histograms).  Above 2^24 rows per
-        # node the f32 rounding could mis-order near-equal children — that
-        # only means the pass histograms the slightly larger child (the
-        # sibling is still exact via subtraction), a perf non-event, so no
-        # recount is needed at any scale.
-        with phase_scope("row_route"):
-            par_of_row = slot_id // 2
+        # parent in one batched pass, derive the sibling by subtraction.
+        # The smaller child by the routing's exact counts, ties → left
+        # (replicated under the data-parallel learners: psum'd).  Column
+        # j sums the rows of new slot 2j + small_is_right[j], none where
+        # slot j did not split: the int8 kernels compare each row's slot
+        # with these targets themselves; the float routes take the rows'
+        # column and selection as vectors.
         # The masked full-N pass is the fastest smaller-child schedule
         # measured on v5e (1M and 11M rows): gathering the selected rows
         # into a compact N/2 buffer first (the masked-dense analog of the
         # reference's per-leaf index lists, data_partition.hpp) costs more
         # in cumsum/scatter/gather plumbing than the halved histogram pass
         # saves — see git history for the removed compaction path.
+        with phase_scope("row_route"):
+            small_is_right = right_c < left_c
+            if int8:
+                targets = jnp.where(
+                    chosen, 2 * jnp.arange(P, dtype=i32) + small_is_right,
+                    NO_TARGET)
+            else:
+                # the rows whose new slot is the built child of its parent
+                par_of_row = slot_id // 2
+                built = interleave(chosen & ~small_is_right,
+                                   chosen & small_is_right)
+                sel = row_mask & _row_flag(built, slot_id)
         with jax.named_scope("level%d" % (d + 1)):
-            hist_small = batch_hist(par_of_row, sel, P, level=True,
-                                    salt=d + 1)
+            hist_small = (
+                batch_hist(slot_id, row_mask, P, level=True, salt=d + 1,
+                           targets=targets) if int8
+                else batch_hist(par_of_row, sel, P, level=True, salt=d + 1))
             # the sibling subtraction and the slot interleave read and
-            # write whole [P, F, B, 3] histograms: the histogram phase's
+            # write whole [P, F, B, k] histograms: the histogram phase's
             with phase_scope("histogram"):
-                hist_large = hists - hist_small
-                flip = small_is_right[:, None, None, None]
-                hists = interleave(
-                    jnp.where(flip, hist_large, hist_small),
-                    jnp.where(flip, hist_small, hist_large))
+                def children(small, parent):
+                    large = parent - small
+                    flip = small_is_right.reshape(
+                        (P,) + (1,) * (small.ndim - 1))
+                    return interleave(jnp.where(flip, large, small),
+                                      jnp.where(flip, small, large))
+                hists = jax.tree.map(children, hist_small, hists)
 
     with phase_scope("tree_pack"):
         num_leaves_final = n_nodes + 1

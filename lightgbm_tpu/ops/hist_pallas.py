@@ -40,9 +40,11 @@ peak and cannot use the int8 MXU path.  This kernel owns the schedule:
   recast for a systolic array (the reference's double accumulators,
   bin.h:15-17, sit at the other end of this precision spectrum).
 
-Layout contract: bins_t [N, F] int8 (row-major TRANSPOSE of the dataset's
-[F, N] bin matrix), packed values [N, 4] int8 (gq, hq, ok, cid), output
-hist [C, F, B, 3] f32 after dequantization.
+Layout contract: bins [F, N] int8, packed values [4, N] int8 (gq, hq, ok,
+cid), output hist [C, F, B, 3] f32 after dequantization; or, for the
+level-wise grower where the per-bin count can refuse no split, [3, N]
+(gq, hq, the row's slot) beside each leaf column's target slot, output
+[C, F, B, 2].
 """
 from __future__ import annotations
 
@@ -127,14 +129,17 @@ def pair_to_f32(hi, lo):
                 + lo.astype(jnp.float32))
 
 
-def _reduce_int(acc, keep, paired, int_reduce, axis_name, pallas):
+def _reduce_int(acc, keep, paired, int_reduce, axis_name, pallas,
+                stats=3):
     """An int accumulator ([F, B, K], or [ranges, F, B, K] of a ranged
     pass) summed across its ranges and shards: float32
     [F, B, keep], the live value columns.  One range in all: the int32
     sum itself, reduced in the int domain as it always was, then cast.
     More: the integer pair, each half reduced by the same collective, so
     what a shard contributes is exact whatever the other shards hold and
-    serial == data-parallel stays bit for bit."""
+    serial == data-parallel stays bit for bit.  ``stats=2``: beside it
+    the hessian columns' integer sums modulo 2^32, int32 [F, B, keep / 2]
+    (``hess_sums``)."""
     from .. import telemetry
     if paired and acc.ndim == 3:
         acc = acc[None]            # one range a shard, several shards
@@ -151,9 +156,32 @@ def _reduce_int(acc, keep, paired, int_reduce, axis_name, pallas):
             "hist/int8_pallas_psum" if pallas else "hist/int8_xla_psum",
             "psum", axis_name, telemetry._tree_nbytes(halves))
         halves = tuple(jax.lax.psum(h, axis_name) for h in halves)
-    if paired:
-        return pair_to_f32(*halves)
-    return halves[0][..., :keep].astype(jnp.float32)
+    hist = (pair_to_f32(*halves) if paired
+            else halves[0][..., :keep].astype(jnp.float32))
+    if stats == 3:
+        return hist
+    return hist, hess_sums(halves, keep, stats)
+
+
+def hess_sums(halves, keep: int, stats: int):
+    """The hessian columns' integer sums modulo 2^32 (int32 arithmetic
+    wraps): ``hi * 65536 + lo`` of a pair, the int32 sum itself
+    otherwise.  Exact where the float32 sums are not: a sibling derived
+    by subtracting these is 0 exactly in a bin none of its rows holds,
+    where the float32 difference keeps its ancestors' rounding."""
+    h = [x[..., 1:keep:stats] for x in halves]
+    return (h[0] << 16) + h[1] if len(h) == 2 else h[0]
+
+
+def _dequantized(hist, B: int, C: int, stats: int, scale):
+    """``_reduce_int``'s [F, B, C * stats] sums -> [C, F, B, stats]
+    float32; with two statistics the pair (float32 [C, F, B, 2], the
+    hessian's integer sums [C, F, B])."""
+    if stats == 3:
+        return hist.reshape(-1, B, C, 3).transpose(2, 0, 1, 3) * scale
+    hist, hsum = hist
+    return (hist.reshape(-1, B, C, 2).transpose(2, 0, 1, 3) * scale,
+            hsum.reshape(-1, B, C).transpose(2, 0, 1))
 
 
 def _ranged_rows(N: int, chunk: int, axis_name=None):
@@ -171,14 +199,16 @@ def _ranged_rows(N: int, chunk: int, axis_name=None):
     return ranges, padded, accum_ranges(padded * shards, chunk) > 1
 
 
-def _hist_kernel(bins_ref, packed_ref, out_ref, *, stats=3,
+def _hist_kernel(bins_ref, packed_ref, *refs, stats=3,
                  skip_dead=False, row_axis=1, **static):
     # grid = (feature_blocks, row_chunks), rows minor: each feature
     # block's accumulator lives in VMEM across its whole row sweep and is
     # written back to HBM once.  A ranged pass puts the ranges between
     # the two, (feature_blocks, ranges, row_chunks of a range): the
     # accumulator lives across ONE range's sweep and every range writes
-    # its own
+    # its own.  ``refs`` is the output, behind the value rows' slot
+    # targets where the pass has them
+    *tgt_ref, out_ref = refs
     j = pl.program_id(row_axis)
 
     @pl.when(j == 0)
@@ -186,7 +216,8 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, stats=3,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     accumulate = functools.partial(_hist_accumulate, bins_ref, packed_ref,
-                                   out_ref, stats=stats, **static)
+                                   out_ref, tgt_ref=(tgt_ref or [None])[0],
+                                   stats=stats, **static)
     if skip_dead:
         # a chunk with no live row (leaf id -1 throughout) adds zeros to
         # every cell.  The compacted grower hands the kernel a leaf's
@@ -202,7 +233,7 @@ def _hist_kernel(bins_ref, packed_ref, out_ref, *, stats=3,
 
 def _hist_accumulate(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
                      compute_dtype, acc_dtype, stats=3, fold=1, gw=None,
-                     held=0):
+                     held=0, tgt_ref=None):
     """One chunk of rows added into the feature block's accumulator."""
     # pure arithmetic (no jnp.where): Mosaic cannot relayout replicated
     # boolean vectors.  VPU math runs wide (8-bit vector arithmetic is
@@ -211,7 +242,10 @@ def _hist_accumulate(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
     # TRANSPOSED [lanes, chunk] so the contraction is an NT-form matmul,
     # whichever of the two operands comes first.
     # ``stats`` values interleave per leaf column (3 = grad/hess/count;
-    # 5 = the f32 single-pass hi/lo packing g_hi,g_lo,h_hi,h_lo,count).
+    # 2 = grad/hess; 5 = the f32 single-pass hi/lo packing
+    # g_hi,g_lo,h_hi,h_lo,count).  A row joins the value rows whose leaf
+    # column its id names, or, with ``tgt_ref`` ([vrows, 1] int32), those
+    # whose target its id equals.
     wide = jnp.int32 if compute_dtype == jnp.int8 else jnp.float32
     # bin fold (``fold`` > 1): bin = hi * fold + lo.  ``hi`` keeps a
     # one-hot of B / fold rows; ``lo`` picks one of ``fold`` groups of
@@ -239,7 +273,9 @@ def _hist_accumulate(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
               * jnp.broadcast_to(packed[k:k + 1, :], (vrows, chunk)))
         terms = vk if terms is None else terms + vk
     cidb = jnp.broadcast_to(packed[stats:stats + 1, :], (vrows, chunk))
-    lmask = (cidb == leaf_j.astype(wide)).astype(wide)
+    want = (leaf_j if tgt_ref is None
+            else jnp.broadcast_to(tgt_ref[...], (vrows, chunk)))
+    lmask = (cidb == want.astype(wide)).astype(wide)
     vL = terms * lmask                              # [vrows, chunk]
     if fold == 1:
         vLt = vL.astype(compute_dtype)
@@ -267,19 +303,22 @@ def _hist_accumulate(bins_ref, packed_ref, out_ref, *, F, B, chunk, lanes,
             preferred_element_type=acc_dtype)   # [B/fold, vrows] | [vrows, B]
 
 
-def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
-                        dtype: str = "int8", lanes: int = LANES,
-                        stats: int = 3, fold: int = 1, gw: int = None,
-                        held: int = 0, skip_dead: bool = False,
-                        ranges: int = 1):
+def _hist_pallas_raw_fn(bins, packed, targets=None, *, B: int,
+                        chunk: int = 2048, dtype: str = "int8",
+                        lanes: int = LANES, stats: int = 3, fold: int = 1,
+                        gw: int = None, held: int = 0,
+                        skip_dead: bool = False, ranges: int = 1):
     """[F, B, lanes] accumulator from [F, N] bins and packed values
     ([F, B, gw] from a folded float pass; [ranges, F, B, lanes] from a
     ranged integer pass).
 
     Rows must be pre-padded to a multiple of ``chunk`` (pad cid with -1).
     packed is [stats + 1, N]: ``stats`` values per leaf column followed by
-    the cid row (stats=3: grad, hess, ok; stats=5: the f32 hi/lo packing
-    g_hi, g_lo, h_hi, h_lo, ok).  Three dtype modes:
+    the cid row (stats=3: grad, hess, ok; stats=2: grad, hess; stats=5:
+    the f32 hi/lo packing g_hi, g_lo, h_hi, h_lo, ok).  A row joins leaf
+    column ``cid``, or with ``targets`` ([C] int32, the integer modes) the
+    column whose target equals its ``cid`` (``row_targets``).  Three
+    dtype modes:
       "int8"  — packed int8 quantized levels, int8xint8->int32 MXU;
       "bf16"  — the SAME int8 levels riding bf16 operands (integers <= 127
                 are bf16-exact), bit-identical histograms to "int8";
@@ -340,6 +379,11 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
     fb, n_fblocks = feature_grid(F, B, lanes, chunk, held, ranges)
     if n_fblocks * fb > F:
         bins = jnp.pad(bins, ((0, n_fblocks * fb - F), (0, 0)))
+    operands = [bins, packed]
+    if targets is not None:
+        assert dtype == "int8", dtype
+        operands.append(row_targets(targets, stats, held or (
+            lanes if fold == 1 else fold * gw), gw if fold > 1 else None))
     if held:
         assert dtype != "bf16v" and fold == 1
         assert stats <= held <= lanes and held % 32 == 0
@@ -367,6 +411,7 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
             pl.BlockSpec((stats + 1, chunk), lambda i, j: (0, j)),
         ]
         out_specs = pl.BlockSpec(out_block, lambda i, j: (i, 0, 0))
+        const = lambda i, j: (0, 0)                 # noqa: E731
     else:
         per = N // chunk // ranges           # chunks of one range
         grid = (n_fblocks, ranges, per)
@@ -378,6 +423,9 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
         out_specs = pl.BlockSpec((None,) + out_block,
                                  lambda i, r, j: (r, i, 0, 0))
         out_shape = (ranges,) + out_shape
+        const = lambda i, r, j: (0, 0)              # noqa: E731
+    if targets is not None:
+        in_specs.append(pl.BlockSpec(operands[2].shape, const))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -386,7 +434,7 @@ def _hist_pallas_raw_fn(bins, packed, *, B: int, chunk: int = 2048,
         out_shape=jax.ShapeDtypeStruct(out_shape, acc_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid)),
-    )(bins, packed)
+    )(*operands)
     if ranges > 1:
         # the views below are the one-range kernel's, over the feature
         # axis and behind it: the ranges ride stacked on that axis
@@ -433,6 +481,25 @@ hist_pallas_raw = _costmodel.instrument(
             static_argnames=("B", "chunk", "dtype", "lanes", "stats", "fold",
                              "gw", "held", "skip_dead", "ranges")),
     phase="histogram")
+
+
+# the target of a value row that sums no rows: row ids are slots >= 0, or
+# -1 for a row outside the pass
+NO_TARGET = -2
+
+
+def row_targets(targets, stats: int, vrows: int, gw: int = None):
+    """[vrows, 1] int32: the row id each value row of the kernel's
+    operand sums, from ``targets`` [C], one a leaf column: value row r
+    holds statistic ``jj % stats`` of column ``jj // stats``, ``jj`` = r,
+    or r mod ``gw`` in a folded pass, and rows past the C columns take
+    ``NO_TARGET``."""
+    C = targets.shape[0]
+    col = [r % (gw or vrows) // stats for r in range(vrows)]
+    t = jnp.take(targets.astype(jnp.int32),
+                 jnp.asarray([min(c, C - 1) for c in col], jnp.int32))
+    live = jnp.asarray([c < C for c in col])
+    return jnp.where(live, t, NO_TARGET).reshape(vrows, 1)
 
 
 # what one kernel may hold of a v5e's VMEM (Mosaic's default scoped limit;
@@ -669,12 +736,14 @@ def quant_max_of(grad, hess, row_ok, axis_name=None):
 
 
 def quantize_values(grad, hess, col_ok, col_id, rng_bits=None,
-                    axis_name=None, stochastic=False, salt=0, quant_max=None):
+                    axis_name=None, stochastic=False, salt=0, quant_max=None,
+                    stats=3):
     """The int8 kernels' value operand: ``[4, N]`` int8 codes of grad and
     hess under one global scale each, the count row (``col_ok``) and the
     row's leaf column (``col_id``, -1 where not ``col_ok``), with the
     scale ``[3]`` f32 that dequantizes the three sums (the count row is
-    exact by construction).  The scale is the tree's (``quant_max``, from
+    exact by construction); with ``stats=2`` ``[3, N]`` and ``[2]``, no
+    count row.  The scale is the tree's (``quant_max``, from
     ``quant_max_of`` over the tree's rows) where the caller hands it, else
     the pass's own, over ``col_ok``'s rows (``axis_name`` as in
     ``quant_max_of``).
@@ -684,7 +753,7 @@ def quantize_values(grad, hess, col_ok, col_id, rng_bits=None,
     uniform bits come from a deterministic value-keyed hash
     (``stochastic_bits``), or from explicit ``rng_bits`` [2, N] uint32.
 
-    The four rows are ONE elementwise expression over ``[4, N]``, the
+    The rows are ONE elementwise expression over ``[stats + 1, N]``, the
     column id selected in the integer domain, narrowed by one convert: a
     1-D int8 row made apart and stacked after costs a compiled relayout
     loop a row wherever N is no whole number of tiles (PERF.md section 6,
@@ -694,7 +763,7 @@ def quantize_values(grad, hess, col_ok, col_id, rng_bits=None,
         quant_max = quant_max_of(grad, hess, col_ok, axis_name)
     gs = jnp.maximum(quant_max[0], 1e-30) / 127.0
     hs = jnp.maximum(quant_max[1], 1e-30) / 127.0
-    row = jax.lax.broadcasted_iota(jnp.int32, (4, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (stats + 1, 1), 0)
     first = row == 0
     y = jnp.where(first, grad, hess) / jnp.where(first, gs, hs)
     if rng_bits is None and not stochastic:
@@ -707,10 +776,12 @@ def quantize_values(grad, hess, col_ok, col_id, rng_bits=None,
         u = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
         q = jnp.floor(y + u)
     q = jnp.clip(q, -127, 127).astype(jnp.int32)
+    cid = col_id.astype(jnp.int32)
     codes = jnp.where(row < 2, q,
-                      jnp.where(row == 2, 1, col_id.astype(jnp.int32)))
-    codes = jnp.where(col_ok, codes, jnp.where(row == 3, -1, 0))
-    return codes.astype(jnp.int8), jnp.stack([gs, hs, jnp.float32(1.0)])
+                      jnp.where(row == 2, 1, cid) if stats == 3 else cid)
+    codes = jnp.where(col_ok, codes, jnp.where(row == stats, -1, 0))
+    return (codes.astype(jnp.int8),
+            jnp.stack([gs, hs, jnp.float32(1.0)][:stats]))
 
 
 def quant_saturation_count(grad, hess, axis_name=None):
@@ -750,22 +821,35 @@ def quant_saturation_count(grad, hess, axis_name=None):
 
 
 def _grouped(fn, bins, grad, hess, col_id, col_ok, num_cols, B, *,
-             group_width=42, **kw):
+             group_width=42, targets=None, **kw):
     """Split levels wider than ``group_width`` columns into balanced
     groups (the same rule as ops/histogram.histogram_leafbatch: ceil-split
     so the last group is never a nearly-empty full pass).  42 = one
     128-lane MXU tile (XLA paths); the Pallas kernels take 64 (one pass
-    of 192 value rows is cheaper than two passes over the data)."""
+    of 192 value rows is cheaper than two passes over the data).
+
+    ``targets`` ([num_cols] int32): column j sums the rows whose id is
+    its target, one of the level's new slots 2j and 2j + 1, or none
+    where the target is negative.  A group's ids and targets are taken
+    relative to its first slot, so that they stay inside the int8 id row
+    (slots up to 2 x 64 a group)."""
     if num_cols <= group_width:
+        if targets is not None:
+            kw["targets"] = targets
         return fn(bins, grad, hess, col_id, col_ok, num_cols, B, **kw)
     n_groups = -(-num_cols // group_width)
     width = -(-num_cols // n_groups)
     parts = []
     for base in range(0, num_cols, width):
         k = min(width, num_cols - base)
-        ok = col_ok & (col_id >= base) & (col_id < base + k)
-        parts.append(fn(bins, grad, hess, col_id - base, ok, k, B, **kw))
-    return jnp.concatenate(parts, axis=0)
+        lo, hi, ids = ((base, base + k, col_id - base) if targets is None
+                       else (2 * base, 2 * (base + k), col_id - 2 * base))
+        ok = col_ok & (col_id >= lo) & (col_id < hi)
+        if targets is not None:
+            t = targets[base:base + k]
+            kw["targets"] = jnp.where(t < 0, NO_TARGET, t - lo)
+        parts.append(fn(bins, grad, hess, ids, ok, k, B, **kw))
+    return jax.tree.map(lambda *p: jnp.concatenate(p, axis=0), *parts)
 
 
 def _class_acc_assemble(parts, packing, B: int, feat_axis: int = 0):
@@ -794,7 +878,8 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
                           dtype: str = "int8", rng_bits=None,
                           axis_name=None, int_reduce=None,
                           stochastic=False, salt=0, packing=None,
-                          feat_gather=None, quant_max=None):
+                          feat_gather=None, quant_max=None, stats=3,
+                          targets=None):
     """Drop-in histogram_leafbatch equivalent on the Pallas kernel.
 
     ``bins`` is the usual [F, N] matrix (int8 or uint8).  The int32
@@ -806,6 +891,10 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
     unfolded ones of 17-32 and 43-64 columns hold the one-hot in the MXU
     and stream their live value rows (``held_onehot``); the accumulator
     handed on is [F, B, lanes] in every case.
+
+    ``stats=2`` builds grad and hess alone, [C, F, B, 2]: two thirds of
+    the value rows, so 64 columns fit one 128-lane tile and every rule
+    above reads 2 x C live rows.  ``targets``: see ``_grouped``.
 
     ``packing`` (mixed-bin layout): one kernel launch per bin-width class
     — the narrow class's [Fc, 64, lanes] accumulator costs a quarter of
@@ -825,22 +914,24 @@ def hist_pallas_leafbatch(bins, grad, hess, col_id, col_ok, num_cols: int,
             dtype=dtype, rng_bits=rng_bits, axis_name=axis_name,
             int_reduce=int_reduce, stochastic=stochastic, salt=salt,
             packing=packing, feat_gather=feat_gather,
-            quant_max=quant_max))
+            quant_max=quant_max, stats=stats, targets=targets))
 
 
 def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
                      chunk, dtype, rng_bits, axis_name=None,
                      int_reduce=None, stochastic=False, salt=0,
-                     packing=None, feat_gather=None, quant_max=None):
+                     packing=None, feat_gather=None, quant_max=None,
+                     stats=3, targets=None):
     F, N = bins.shape
-    lanes = LANES if num_cols <= 42 else 192
+    lanes = LANES if stats * num_cols <= LANES else 192
     # ONE quantization for every class pass: the scale comes from the same
     # grad/hess/col_ok whatever the feature layout, so packed and uniform
     # passes quantize identically (bit-identity precondition)
     packed, scale = quantize_values(grad, hess, col_ok, col_id, rng_bits,
                                     axis_name=axis_name,
                                     stochastic=stochastic, salt=salt,
-                                    quant_max=quant_max)  # [4, N] int8
+                                    quant_max=quant_max,
+                                    stats=stats)  # [stats + 1, N] int8
 
     ranges, padded, paired = _ranged_rows(N, chunk, axis_name)
     pad = padded - N
@@ -848,10 +939,11 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         packed = jnp.pad(packed, ((0, 0), (0, pad)), constant_values=-1)
     from .. import telemetry
+    telemetry.count("hist/stats_" + str(stats))
 
     def launch(rows, width):
-        fold, gw = hist_fold(3, num_cols, width, lanes)
-        held = held_onehot(3, num_cols, width, lanes, dtype)
+        fold, gw = hist_fold(stats, num_cols, width, lanes)
+        held = held_onehot(stats, num_cols, width, lanes, dtype)
         # counted per pass, here: two passes of one shape share one trace
         # of the jitted kernel, so its own counters see them once
         telemetry.count("hist/pallas_fold_" + str(fold))
@@ -860,9 +952,10 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
                         feature_grid(rows.shape[0], width, lanes, chunk,
                                      held, ranges)[1])
         # [F, width, lanes], behind its ranges where the pass is ranged
-        return hist_pallas_raw(rows.astype(jnp.int8), packed, B=width,
-                               chunk=chunk, dtype=dtype, lanes=lanes,
-                               fold=fold, gw=gw, held=held, ranges=ranges)
+        return hist_pallas_raw(rows.astype(jnp.int8), packed, targets,
+                               B=width, chunk=chunk, dtype=dtype,
+                               lanes=lanes, stats=stats, fold=fold, gw=gw,
+                               held=held, ranges=ranges)
 
     fa = int(ranges > 1)                 # the accumulator's feature axis
     if _packing_on(packing):
@@ -882,10 +975,9 @@ def _hist_pallas_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
         assert int_reduce is None, \
             "feat_gather does not compose with the ownership int scatter"
         acc = jnp.take(acc, feat_gather, axis=fa)
-    hist = _reduce_int(acc, num_cols * 3, paired, int_reduce, axis_name,
-                       pallas=True)
-    hist = hist.reshape(-1, B, num_cols, 3).transpose(2, 0, 1, 3)
-    return hist * scale
+    hist = _reduce_int(acc, num_cols * stats, paired, int_reduce,
+                       axis_name, pallas=True, stats=stats)
+    return _dequantized(hist, B, num_cols, stats, scale)
 
 
 def hist_pallas_float_leafbatch(bins, grad, hess, col_id, col_ok,
@@ -1021,13 +1113,14 @@ def hist_quant_xla(bins, grad, hess, col_id, col_ok, num_cols: int,
                    num_bins_max: int, *, chunk: int = 65536, rng_bits=None,
                    axis_name=None, int_reduce=None,
                    stochastic=False, salt=0, packing=None,
-                   feat_gather=None, quant_max=None):
+                   feat_gather=None, quant_max=None, stats=3, targets=None):
     """XLA reference of the SAME quantized-gradient math as the Pallas int8
     kernel (bit-identical output) — the CPU-testable oracle and the
     fallback on non-TPU backends.  ``packing``: per-class int accumulators
     assembled canonically before the cross-shard reduction, exactly like
     the Pallas route (int32 sums are order-free, so packed == uniform is
-    bit-exact here by construction)."""
+    bit-exact here by construction).  ``stats``, ``targets``: see
+    ``hist_pallas_leafbatch``."""
     from .. import telemetry
     telemetry.count("hist/xla_int_kernel")
     with jax.named_scope("histogram"), telemetry.span("histogram") as sp:
@@ -1037,36 +1130,38 @@ def hist_quant_xla(bins, grad, hess, col_id, col_ok, num_cols: int,
             axis_name=axis_name, int_reduce=int_reduce,
             feat_gather=feat_gather,
             stochastic=stochastic, salt=salt, packing=packing,
-            quant_max=quant_max))
+            quant_max=quant_max, stats=stats, targets=targets))
 
 
 def _quant_xla_acc(bins, packed, B: int, C: int, chunk: int,
-                   ranges: int = 1):
-    """One class's raw [F, B, C*3] int32 accumulator over the kernel's
-    operand (``quantize_values``' [4, N] codes; rows pre-padded, with a
-    column id of -1), or its [ranges, F, B, C*3]: the chunks cut into
-    that many equal ranges, each summed from zero."""
+                   ranges: int = 1, targets=None):
+    """One class's raw [F, B, C*stats] int32 accumulator over the kernel's
+    operand (``quantize_values``' [stats + 1, N] codes; rows pre-padded,
+    with a column id of -1), or its [ranges, F, B, C*stats]: the chunks
+    cut into that many equal ranges, each summed from zero."""
     F = bins.shape[0]
     N = bins.shape[1]
+    stats = packed.shape[0] - 1
     n_chunks = N // chunk
     bins_c = bins.astype(jnp.int32).reshape(F, n_chunks,
                                             chunk).transpose(1, 0, 2)
     packed = packed.astype(jnp.int32)
-    vals_c = packed[:3].T.reshape(n_chunks, chunk, 3)
-    cid_c = packed[3].reshape(n_chunks, chunk)
+    vals_c = packed[:stats].T.reshape(n_chunks, chunk, stats)
+    cid_c = packed[stats].reshape(n_chunks, chunk)
     ib = jnp.arange(B, dtype=jnp.int32)
-    ic = jnp.arange(C, dtype=jnp.int32)
+    ic = (jnp.arange(C, dtype=jnp.int32) if targets is None
+          else targets.astype(jnp.int32))
 
     def body(carry, xs):
         bc, vc, cc = xs
         oh = (bc[:, :, None] == ib).astype(jnp.int32)
         lsel = (cc[:, None] == ic).astype(jnp.int32)
-        vL = (lsel[:, :, None] * vc[:, None, :]).reshape(chunk, C * 3)
+        vL = (lsel[:, :, None] * vc[:, None, :]).reshape(chunk, C * stats)
         out = jnp.einsum("fcb,ck->fbk", oh, vL,
                          preferred_element_type=jnp.int32)
         return carry + out, None
 
-    init = jnp.zeros((F, B, C * 3), jnp.int32)
+    init = jnp.zeros((F, B, C * stats), jnp.int32)
     if ranges == 1:
         hist, _ = jax.lax.scan(body, init, (bins_c, vals_c, cid_c))
         return hist
@@ -1080,7 +1175,9 @@ def _quant_xla_acc(bins, packed, B: int, C: int, chunk: int,
 def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
                         chunk, rng_bits, axis_name=None, int_reduce=None,
                         stochastic=False, salt=0, packing=None,
-                        feat_gather=None, quant_max=None):
+                        feat_gather=None, quant_max=None, stats=3,
+                        targets=None):
+    from .. import telemetry
     F, N = bins.shape
     C = num_cols
     # don't pad a small input up to a full default chunk
@@ -1088,7 +1185,8 @@ def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
     packed, scale = quantize_values(grad, hess, col_ok, col_id, rng_bits,
                                     axis_name=axis_name,
                                     stochastic=stochastic, salt=salt,
-                                    quant_max=quant_max)
+                                    quant_max=quant_max, stats=stats)
+    telemetry.count("hist/stats_" + str(stats))
     ranges, padded, paired = _ranged_rows(N, chunk, axis_name)
     pad = padded - N
     if pad:
@@ -1097,15 +1195,14 @@ def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
 
     fa = int(ranges > 1)                 # the accumulator's feature axis
     if _packing_on(packing):
-        from .. import telemetry
         telemetry.count("hist/mixedbin_xla_int")
         parts = [_quant_xla_acc(
             jax.lax.slice_in_dim(bins, start, start + cnt, axis=0),
-            packed, width, C, chunk, ranges)
+            packed, width, C, chunk, ranges, targets)
             for start, cnt, width in packing.ranges]
-        hist = _class_acc_assemble(parts, packing, B, fa)  # [F, B, C*3] i32
+        hist = _class_acc_assemble(parts, packing, B, fa)  # [F, B, C*k] i32
     else:
-        hist = _quant_xla_acc(bins, packed, B, C, chunk, ranges)
+        hist = _quant_xla_acc(bins, packed, B, C, chunk, ranges, targets)
     if feat_gather is not None:
         # storage->canonical reorder IN the int domain, before the
         # cross-shard psum (commutes elementwise) — see _hist_pallas_one
@@ -1113,7 +1210,6 @@ def _hist_quant_xla_one(bins, grad, hess, col_id, col_ok, num_cols, B, *,
             "feat_gather does not compose with the ownership int scatter"
         hist = jnp.take(hist, feat_gather, axis=fa)
     # int-domain feature scatter or cross-shard sum, then float32
-    hist = _reduce_int(hist, C * 3, paired, int_reduce, axis_name,
-                       pallas=False)
-    hist = hist.reshape(-1, B, C, 3).transpose(2, 0, 1, 3)
-    return hist * scale
+    hist = _reduce_int(hist, C * stats, paired, int_reduce, axis_name,
+                       pallas=False, stats=stats)
+    return _dequantized(hist, B, C, stats, scale)

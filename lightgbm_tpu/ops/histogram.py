@@ -108,7 +108,7 @@ def _einsum_chunk(chunk: int, F: int, B: int, itemsize: int, N: int) -> int:
 
 
 def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
-                    int_levels: bool = False):
+                    int_levels: bool = False, stats: int = 3):
     """Analytic cost of ONE leaf-batched histogram pass — the dense
     one-hot-matmul MAC count PROFILE.md's roofline derives by hand
     (N x F x B x lanes per group; the MXU tile floor makes <=42 leaf
@@ -123,21 +123,17 @@ def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
     levels are modeled on the PALLAS grouping rule — balanced groups of
     <=64 columns (hist_pallas._grouped(group_width=64); the XLA einsum
     fallback groups by 42, but the analytic note exists for the Pallas
-    routes cost analysis cannot see into).  Filed per traced pass via
-    costmodel.note_traced_pass."""
-    if num_cols <= 42:
-        groups, lanes = 1, 128.0
-    elif num_cols <= 64:
-        groups, lanes = 1, 192.0
-    else:
-        groups = -(-num_cols // 64)
-        width = -(-num_cols // groups)
-        lanes = 128.0 if width <= 42 else 192.0
+    routes cost analysis cannot see into).  ``stats`` value rows a
+    column (2 for the int8 passes without the count).  Filed per traced
+    pass via costmodel.note_traced_pass."""
+    groups = 1 if num_cols <= 64 else -(-num_cols // 64)
+    width = -(-num_cols // groups)
+    lanes = 128.0 if stats * width <= 128 else 192.0
     cells = float(B) * lanes                 # accumulator cells a feature
     if int_levels and groups == 1:
         from .hist_pallas import held_onehot, hist_fold
-        fold, gw = hist_fold(3, num_cols, B, int(lanes))
-        held = held_onehot(3, num_cols, B, int(lanes), "int8")
+        fold, gw = hist_fold(stats, num_cols, B, int(lanes))
+        held = held_onehot(stats, num_cols, B, int(lanes), "int8")
         if fold > 1:
             cells = float(-(-B // fold)) * fold * gw
         elif held:
@@ -149,7 +145,7 @@ def dense_pass_cost(N: int, F: int, B: int, num_cols: int,
 
 
 def _note_hist_pass(bins, num_cols: int, num_bins_max: int,
-                    compute_dtype, packing=None) -> None:
+                    compute_dtype, packing=None, stats: int = 3) -> None:
     """Analytic roofline note(s) for one leaf-batched pass.  Under mixed-bin
     packing the pass is really one pass PER bin-width class, so one note is
     filed per class (keyed ``binclass<width>``) — PROFILE.md's roofline rows
@@ -163,7 +159,7 @@ def _note_hist_pass(bins, num_cols: int, num_bins_max: int,
     if _packing_active(packing):
         for _, cnt, width in packing.ranges:
             macs, bytes_moved = dense_pass_cost(N, cnt, width, num_cols,
-                                                int_levels)
+                                                int_levels, stats)
             costmodel.note_traced_pass(
                 "histogram",
                 ("pass", N, cnt, width, num_cols, dt,
@@ -171,7 +167,7 @@ def _note_hist_pass(bins, num_cols: int, num_bins_max: int,
                 macs=macs, bytes_moved=bytes_moved)
         return
     macs, bytes_moved = dense_pass_cost(N, F, num_bins_max, num_cols,
-                                        int_levels)
+                                        int_levels, stats)
     costmodel.note_traced_pass(
         "histogram", ("pass", N, F, num_bins_max, num_cols, dt),
         macs=macs, bytes_moved=bytes_moved)
@@ -308,7 +304,8 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         salt=0, packing=None,
                         feat_gather=None,
                         skip_dead: bool = False,
-                        quant_max=None) -> jax.Array:
+                        quant_max=None, stats: int = 3,
+                        targets=None) -> jax.Array:
     """Build histograms for MANY leaves in ONE matmul pass.
 
     The single-leaf one-hot matmul starves the MXU: the value operand has
@@ -340,12 +337,20 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     sums; the other routes take no notice.
     ``quant_max``: the int8 routes' scale, the tree's own
     (``hist_pallas.quant_max_of``) in place of this pass's.
+    ``stats``, ``targets`` (the int8 routes): ``stats=2`` builds
+    [C, F, B, 2], grad and hess without the count, and hands it back
+    beside the hessian's integer sums, [C, F, B] int32 modulo 2^32
+    (``hist_pallas.hess_sums``); with ``targets`` ([C] int32) column j
+    sums the rows whose ``col_id`` equals ``targets[j]``
+    (``hist_pallas._grouped``).
     """
+    int8 = str(compute_dtype).startswith("int8")
+    assert int8 or (stats == 3 and targets is None), compute_dtype
     if _packing_active(packing):
         telemetry.count("hist/mixedbin_leafbatch")
     _note_hist_pass(bins, num_cols, num_bins_max, compute_dtype,
-                    packing=packing)
-    if str(compute_dtype).startswith("int8"):
+                    packing=packing, stats=stats)
+    if int8:
         # quantized-gradient path: Pallas int8-MXU kernel on TPU, the
         # bit-identical XLA formulation elsewhere (ops/hist_pallas.py).
         # The Pallas kernel carries bins as int8 bit-patterns, so bin ids
@@ -362,14 +367,15 @@ def histogram_leafbatch(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     num_bins_max, axis_name=axis_name,
                     int_reduce=int_reduce, stochastic=stochastic,
                     salt=salt, packing=packing, feat_gather=feat_gather,
-                    quant_max=quant_max))
+                    quant_max=quant_max, stats=stats, targets=targets))
         telemetry.count("hist/xla_int8")
         with telemetry.span("histogram") as sp:
             return sp.fence(hist_quant_xla(
                 bins, grad, hess, col_id, col_ok, num_cols, num_bins_max,
                 chunk=chunk, axis_name=axis_name, int_reduce=int_reduce,
                 stochastic=stochastic, salt=salt, packing=packing,
-                feat_gather=feat_gather, quant_max=quant_max))
+                feat_gather=feat_gather, quant_max=quant_max, stats=stats,
+                targets=targets))
     # float dtypes on TPU: hand-scheduled Pallas kernel with bf16 operands
     # (f32 rides a hi/lo operand split — one 5-stat pass for narrow
     # levels, two 3-stat passes wider).  This routes AROUND the XLA
@@ -510,11 +516,12 @@ def hist_quant_segsum(bins, grad, hess, col_id, col_ok, num_cols: int,
                       num_bins_max: int, chunk: int = 0, rng_bits=None,
                       compute_dtype=None, axis_name=None, int_reduce=None,
                       salt=0, packing=None, feat_gather=None,
-                      quant_max=None):
+                      quant_max=None, stats=3, targets=None):
     """Scatter-add variant of the quantized-gradient histogram — exact
     int32 accumulation, so it is bit-identical to hist_pallas/hist_quant_xla
     (ops/hist_pallas.py) at any summation order; the CPU-fast oracle for
-    int8-path quality tests."""
+    int8-path quality tests.  ``stats``, ``targets``: see
+    ``histogram_leafbatch``."""
     from .hist_pallas import check_int8_row_capacity, quantize_values
     if _packing_active(packing):
         bins = _unpack_bins(bins, packing)
@@ -528,13 +535,19 @@ def hist_quant_segsum(bins, grad, hess, col_id, col_ok, num_cols: int,
     codes, scale = quantize_values(grad, hess, col_ok, col_id, rng_bits,
                                    axis_name=axis_name,
                                    stochastic=(compute_dtype == "int8_sr"),
-                                   salt=salt, quant_max=quant_max)
-    vals = codes[:3]          # [3, N] i8; its own column ids pass 127
+                                   salt=salt, quant_max=quant_max,
+                                   stats=stats)
+    vals = codes[:stats]      # [stats, N] i8; its own column ids pass 127
+    if targets is not None:
+        # the column whose target the row's id is, else none
+        hit = col_id[None, :] == targets[:, None]                # [C, N]
+        col_id = jnp.where(jnp.any(hit, axis=0),
+                           jnp.argmax(hit, axis=0), C)
     cid = jnp.where(col_ok, col_id, C).astype(jnp.int32)
     ids = (cid[None, :] * F + jnp.arange(F, dtype=jnp.int32)[:, None]) * B \
         + bins.astype(jnp.int32)
     v = jnp.broadcast_to(vals.T.astype(jnp.int32)[None],
-                         (F, N, 3)).reshape(-1, 3)
+                         (F, N, stats)).reshape(-1, stats)
     hist = jax.ops.segment_sum(v, ids.reshape(-1),
                                num_segments=(C + 1) * F * B)
     if axis_name is not None:
@@ -542,8 +555,10 @@ def hist_quant_segsum(bins, grad, hess, col_id, col_ok, num_cols: int,
         telemetry.record_collective("hist/int8_segsum_psum", "psum",
                                     axis_name, telemetry._tree_nbytes(hist))
         hist = jax.lax.psum(hist, axis_name)   # int-domain cross-shard sum
-    hist = _feat_take(hist.reshape(C + 1, F, B, 3)[:C], feat_gather, 1)
-    return hist.astype(jnp.float32) * scale
+    hist = _feat_take(hist.reshape(C + 1, F, B, stats)[:C], feat_gather, 1)
+    if stats == 3:
+        return hist.astype(jnp.float32) * scale
+    return hist.astype(jnp.float32) * scale, hist[..., 1]
 
 
 def histogram_segsum(bins: jax.Array, grad: jax.Array, hess: jax.Array,

@@ -41,7 +41,7 @@ class SplitResult(NamedTuple):
     threshold: jax.Array     # i32 bin threshold
     left_output: jax.Array   # f32
     right_output: jax.Array
-    left_count: jax.Array    # i32
+    left_count: jax.Array    # i32; None from a histogram without counts
     right_count: jax.Array
     left_sum_grad: jax.Array
     left_sum_hess: jax.Array  # raw (no epsilon)
@@ -57,7 +57,16 @@ def find_best_split(hist: jax.Array, sum_grad: jax.Array, sum_hess: jax.Array,
 
     Parameters
     ----------
-    hist : [F, B, 3] float32 (sum_grad, sum_hess, count)
+    hist : [F, B, 3] float32 (sum_grad, sum_hess, count), or [F, B, 2]
+        (sum_grad, sum_hess): the search without counts, for
+        ``min_data_in_leaf <= 1`` beside ``min_sum_hessian_in_leaf >
+        K_EPSILON`` (the int8 level-wise routes).  ``num_data`` is then
+        ``occupied``, [F, B] bool: the bins that hold a row of nonzero
+        hessian code, known exactly from the integer sums.  A side with
+        no such bin is refused, as the count test refuses an empty side
+        and the hessian test one whose rows sum to 0: its float32 sums,
+        the leaf's total less the other side's, are a rounding residue
+        that could pass a small floor.  The result's counts are None
     sum_grad, sum_hess, num_data : leaf totals (raw, no epsilon)
     num_bins : [F] int32 — real bin count per feature (B is padded)
     feature_mask : [F] bool — feature_fraction sampling / ownership masks
@@ -95,17 +104,20 @@ def _threshold_scan(hist, sum_grad, sum_hess, num_data, num_bins,
     """Shared [F, B] threshold scan: cumulative left sums, the validity
     mask and the per-candidate gain score — the common core of the full
     best-split search and the voting learner's per-feature local gains.
-    Returns (cg, ch, cc, score, gain_shift)."""
-    F, B, _ = hist.shape
+    Returns (cg, ch, cc, score, gain_shift); cc is None for a histogram
+    without counts, whose ``num_data`` is its bins' occupancy
+    (``find_best_split``)."""
+    F, B, stats = hist.shape
+    counted = stats == 3
     eps = jnp.float32(K_EPSILON)
 
     cg = jnp.cumsum(hist[:, :, 0], axis=1)   # [F, B] left sums at threshold t
     ch = jnp.cumsum(hist[:, :, 1], axis=1)
-    cc = jnp.cumsum(hist[:, :, 2], axis=1)
+    cc = jnp.cumsum(hist[:, :, 2], axis=1) if counted else None
 
     total_g = sum_grad.astype(jnp.float32)
     total_h = sum_hess.astype(jnp.float32)
-    total_c = num_data.astype(jnp.float32)
+    total_c = num_data.astype(jnp.float32) if counted else None
 
     # per threshold t (bin <= t left):
     left_g = cg
@@ -113,13 +125,24 @@ def _threshold_scan(hist, sum_grad, sum_hess, num_data, num_bins,
     left_c = cc
     right_g = total_g - cg
     right_h = (total_h - ch) + eps           # raw_right + ε
-    right_c = total_c - cc
+    right_c = total_c - cc if counted else None
 
     thresholds = jnp.arange(B, dtype=jnp.int32)
+    if counted:
+        valid = ((right_c >= min_data_in_leaf)
+                 & (left_c >= min_data_in_leaf)
+                 & (right_h >= min_sum_hessian_in_leaf))
+    else:
+        # the first and last bins a row of nonzero hessian code sits in:
+        # threshold t leaves one on each side where first <= t < last
+        occupied = num_data
+        first = jnp.min(jnp.where(occupied, thresholds, B), axis=1)
+        last = jnp.max(jnp.where(occupied, thresholds, -1), axis=1)
+        valid = ((thresholds[None, :] >= first[:, None])
+                 & (thresholds[None, :] < last[:, None])
+                 & (right_h >= min_sum_hessian_in_leaf))
     valid = (
-        (right_c >= min_data_in_leaf)
-        & (left_c >= min_data_in_leaf)
-        & (right_h >= min_sum_hessian_in_leaf)
+        valid
         & (left_h >= min_sum_hessian_in_leaf)
         & (thresholds[None, :] <= (num_bins[:, None] - 2))
         & feature_mask[:, None]
@@ -150,11 +173,12 @@ def per_feature_best_scores(hist, sum_grad, sum_hess, num_data, num_bins,
 def _find_best_split_scoped(hist, sum_grad, sum_hess, num_data, num_bins,
                             feature_mask, min_data_in_leaf,
                             min_sum_hessian_in_leaf) -> SplitResult:
-    F, B, _ = hist.shape
+    F, B, stats = hist.shape
+    counted = stats == 3
     eps = jnp.float32(K_EPSILON)
     total_g = sum_grad.astype(jnp.float32)
     total_h = sum_hess.astype(jnp.float32)
-    total_c = num_data.astype(jnp.float32)
+    total_c = num_data.astype(jnp.float32) if counted else None
 
     cg, ch, cc, score, gain_shift = _threshold_scan(
         hist, sum_grad, sum_hess, num_data, num_bins, feature_mask,
@@ -175,10 +199,10 @@ def _find_best_split_scoped(hist, sum_grad, sum_hess, num_data, num_bins,
 
     lg = cg[best_f, t]
     lh_raw = ch[best_f, t]
-    lc = cc[best_f, t]
+    lc = cc[best_f, t] if counted else None
     rg = total_g - lg
     rh_raw = total_h - lh_raw
-    rc = total_c - lc
+    rc = total_c - lc if counted else None
 
     return SplitResult(
         gain=jnp.where(jnp.isfinite(gain_raw), gain_raw - gain_shift, NEG_INF),
@@ -186,8 +210,8 @@ def _find_best_split_scoped(hist, sum_grad, sum_hess, num_data, num_bins,
         threshold=t,
         left_output=_leaf_output(lg, lh_raw + eps),
         right_output=_leaf_output(rg, rh_raw + eps),
-        left_count=lc.astype(jnp.int32),
-        right_count=rc.astype(jnp.int32),
+        left_count=lc.astype(jnp.int32) if counted else None,
+        right_count=rc.astype(jnp.int32) if counted else None,
         left_sum_grad=lg,
         left_sum_hess=lh_raw,
         right_sum_grad=rg,

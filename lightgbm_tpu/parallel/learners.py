@@ -435,12 +435,17 @@ def voting_seams(F: int, feature_shards: int, top_k: int, int8: bool,
             # full-F (the compact pane keeps all features for the
             # partition; int8: already int-psum'd global) — static
             # shapes, so the slice resolves at trace time
+            # without a count column (the int8 level-wise passes of two
+            # statistics) ``cnt`` is the bins' occupancy, [F, B] as the
+            # histogram is, and is sliced as it is
+            counted = hist.shape[-1] == 3
             if hist.shape[0] == Fb:
-                hist_own, nb_own, fm_own = hist, nb, fm
+                hist_own, nb_own, fm_own, occ_own = hist, nb, fm, cnt
             else:
                 hist_own = jnp.take(hist, own_s, axis=0)
                 nb_own = jnp.take(nb, own_s)
                 fm_own = fm[own_s] & ownok
+                occ_own = None if counted else jnp.take(cnt, own_s, axis=0)
             # 1. local per-feature best gains over the owned block.  The
             # leaf totals for the vote scoring come from the HISTOGRAM
             # ITSELF (any one feature's bins sum to the leaf's rows), not
@@ -453,10 +458,10 @@ def voting_seams(F: int, feature_shards: int, top_k: int, int8: bool,
             # local evidence: local left/right sums against local totals.
             # (int8: hist is already global, so the bin sums are the
             # global totals and the vote ranking matches a global scorer.)
-            tot = jnp.sum(hist_own[0], axis=0)           # [3] g, h, count
+            tot = jnp.sum(hist_own[0], axis=0)
             scores = per_feature_best_scores(hist_own, tot[0], tot[1],
-                                             tot[2], nb_own, fm_own,
-                                             mind, minh)
+                                             tot[2] if counted else occ_own,
+                                             nb_own, fm_own, mind, minh)
             # 2. top-k vote (argsort is stable: gain ties resolve to the
             # smaller feature id, matching SplitInfo::MaxReducer)
             order = jnp.argsort(-scores)
@@ -482,7 +487,8 @@ def voting_seams(F: int, feature_shards: int, top_k: int, int8: bool,
                 vh = jax.lax.psum(vh, DATA_AXIS)
             # 4. owned-block search over the voted set only, then the
             # packed SplitInfo allreduce across feature blocks
-            local = find_best_split(vh, sg, sh, cnt,
+            local = find_best_split(vh, sg, sh, cnt if counted
+                                    else jnp.take(occ_own, voted, axis=0),
                                     jnp.take(nb_own, voted),
                                     fm_own[voted], mind, minh)
             gid = jnp.take(own_s, voted)[local.feature]

@@ -300,6 +300,7 @@ COUNTER_FAMILIES = (
     "hist/pallas_ineligible",
     "hist/pallas_int8",
     "hist/pallas_kernel_*",       # per-width kernel-class hits
+    "hist/stats_*",               # int passes of k statistics a column
     "hist/xla_einsum",
     "hist/xla_int8",
     "hist/xla_int_kernel",
@@ -431,6 +432,7 @@ def phase_scope(name: str):
     return jax.named_scope(name)
 
 WIRE_SITE_FAMILIES = (
+    "depthwise/route_count_psum",  # a level's right counts, rows sharded
     "dp/grad_score_allgather",
     "elastic/survivor_pmin",
     "elastic/times_allgather",

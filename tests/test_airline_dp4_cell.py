@@ -90,26 +90,30 @@ def test_the_collective_counters_are_the_payload_reckoned_by_hand(dp_run):
     """One tree of 255 leaves in 8 levels over 4 shards, 13 columns of up
     to 254 bins: the tree's int8 scale (a pmax of two float32), the root's
     integer pair psum'd over all 13 columns, the pair of every level after
-    it reduce-scattered over the columns padded to 16 (blocks of 4), three
-    value columns a smaller child, and the packed split of every slot of
-    every level gathered from the four shards."""
+    it reduce-scattered over the columns padded to 16 (blocks of 4), two
+    value columns a smaller child (``min_data_in_leaf=1``: no count
+    column), the packed split of every slot of every level gathered from
+    the four shards (no counts in it), and the rows: the root's, and the
+    rows every slot of every level sends right, psum'd as int32."""
     from lightgbm_tpu.ops.split import SplitResult
     _line, counters, _trees = dp_run
-    B, stats, pair = 254, 3, 2
+    B, stats, pair = 254, 2, 2
     root = pair * 13 * B * stats * 4
     smaller_children = sum(1 << (level - 1) for level in range(1, 8))
     levels = pair * 16 * B * stats * smaller_children * 4
-    split_info = len(SplitResult._fields) * 4 * sum(
+    rows = 4 + 4 * sum(1 << d for d in range(8))
+    split_info = (len(SplitResult._fields) - 2) * 4 * sum(
         1 << d for d in range(8))
     assert counters["collective/pmax_bytes_per_tree"] == 8
-    assert counters["collective/psum_bytes_per_tree"] == root
+    assert counters["collective/psum_bytes_per_tree"] == root + rows
     assert counters["collective/psum_scatter_bytes_per_tree"] == levels
     assert counters["collective/all_gather_bytes_per_tree"] == split_info
     assert counters["collective/bytes_per_tree"] == (
-        8 + root + levels + split_info)
+        8 + root + rows + levels + split_info)
     # the pmax, the root, 2 x 8 level passes (the last level in two
-    # halves of 64 children), 8 gathers of the split
-    assert counters["collective/calls_per_tree"] == 1 + 1 + 16 + 8
+    # halves of 64 children), 8 gathers of the split, the root's rows and
+    # 8 levels' right counts
+    assert counters["collective/calls_per_tree"] == 1 + 1 + 16 + 8 + 1 + 8
     assert counters["collective/trees_per_iter"] == 1
     assert counters["collective/shards"] == 4
     assert counters["collective/schedule_reduce_scatter"] == 1

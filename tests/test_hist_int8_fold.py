@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from lightgbm_tpu.ops.hist_pallas import (hist_pallas_leafbatch,
+from lightgbm_tpu.ops.hist_pallas import (INT8_HIST_MAX_ROWS,
+                                          hist_pallas_leafbatch,
                                           hist_quant_xla, quantize_values)
 
 
@@ -73,7 +74,9 @@ def test_bin_fold_bit_identical(num_cols, B):
 
 def test_bin_fold_rule_and_counters(monkeypatch):
     """The rule's table at the cell's shapes, where it must not fold, and
-    the hist/pallas_fold_<k> counters of one traced level-wise tree."""
+    the hist/pallas_fold_<k> counters of one traced level-wise tree, with
+    the two statistics a column of ``min_data_in_leaf <= 1`` and with the
+    three of ``min_data_in_leaf=2``."""
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
     from lightgbm_tpu.ops.hist_pallas import fold_options, hist_fold
@@ -104,29 +107,130 @@ def test_bin_fold_rule_and_counters(monkeypatch):
     # one-hot holds it to fold 2, and only while that saves an eighth
     assert [hist_fold(3, c, 64, 128) for c in (1, 4, 5)] == [
         (2, 4), (2, 12), (1, None)]
+    # two statistics a column (grad, hess): fold 8 holds four columns,
+    # fold 4 sixteen, and 32 and 64 columns stay unfolded
+    table2 = {1: (8, 2), 2: (8, 4), 4: (8, 8), 5: (4, 10), 8: (4, 16),
+              16: (4, 32), 17: (2, 36), 24: (2, 48), 25: (1, None),
+              32: (1, None), 64: (1, None)}
+    for num_cols, want in table2.items():
+        for B in (255, 256):
+            assert hist_fold(2, num_cols, B, 128) == want, (num_cols, B)
+    # the benchmark's tables have 254 bins: there 16 columns of two fold
+    # by 2 (127 + 64 operand rows) and not by 4 (64 + 128)
+    assert [hist_fold(2, c, 254, 128) for c in (1, 2, 4, 8, 16, 32)] == [
+        (8, 2), (8, 4), (8, 8), (4, 16), (2, 32), (1, None)]
 
     # one 255-leaf level-wise tree traced on the TPU route (shapes no other
     # test traces: a cached trace would count nothing): eight passes of
     # 1, 1, 2, 4, 8, 16, 32 and 64 leaf columns
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        n, f = 4104, 5
-        S = jax.ShapeDtypeStruct
-        jax.make_jaxpr(lambda *a: grow_tree_depthwise_jit(
-            *a, compute_dtype="int8", num_leaves=255, num_bins_max=255,
-            min_data_in_leaf=1, min_sum_hessian_in_leaf=1.0, max_depth=-1,
-            packing=None))(
-            S((f, n), jnp.uint8), S((n,), jnp.float32),
-            S((n,), jnp.float32), S((n,), jnp.bool_), S((f,), jnp.bool_),
-            S((f,), jnp.int32))
-        counters = telemetry.snapshot()["counters"]
-    finally:
-        telemetry.disable()
+
+    def traced(n, min_data_in_leaf):
         telemetry.reset()
-    folds = {k: v for k, v in counters.items()
-             if k.startswith("hist/pallas_fold_")}
+        telemetry.enable()
+        try:
+            f = 5
+            S = jax.ShapeDtypeStruct
+            jax.make_jaxpr(lambda *a: grow_tree_depthwise_jit(
+                *a, compute_dtype="int8", num_leaves=255, num_bins_max=255,
+                min_data_in_leaf=min_data_in_leaf,
+                min_sum_hessian_in_leaf=1.0, max_depth=-1, packing=None))(
+                S((f, n), jnp.uint8), S((n,), jnp.float32),
+                S((n,), jnp.float32), S((n,), jnp.bool_),
+                S((f,), jnp.bool_), S((f,), jnp.int32))
+            counters = telemetry.snapshot()["counters"]
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+        folds = {k: v for k, v in counters.items()
+                 if k.startswith("hist/pallas_fold_")}
+        assert sum(folds.values()) == counters["hist/pallas_int8"] == 8
+        return folds, counters
+
+    folds, counters = traced(4104, 1)
+    assert folds == {"hist/pallas_fold_8": 4, "hist/pallas_fold_4": 2,
+                     "hist/pallas_fold_1": 2}
+    assert counters["hist/stats_2"] == 8 and "hist/stats_3" not in counters
+    folds, counters = traced(4105, 2)
     assert folds == {"hist/pallas_fold_8": 3, "hist/pallas_fold_4": 2,
                      "hist/pallas_fold_2": 1, "hist/pallas_fold_1": 2}
-    assert sum(folds.values()) == counters["hist/pallas_int8"] == 8
+    assert counters["hist/stats_3"] == 8 and "hist/stats_2" not in counters
+
+
+# the level-wise tree's passes with two statistics a column: (fold,
+# held) of each of 1, 2, 4, 8, 16, 32 and 64 leaf columns at 255 bins
+TWO_STAT_PASSES = {1: (8, 0), 2: (8, 0), 4: (8, 0), 8: (4, 0), 16: (4, 0),
+                   32: (1, 64), 64: (1, 0)}
+
+
+@pytest.mark.parametrize("num_cols,F,cap", [
+    (c, 3, None) for c in TWO_STAT_PASSES] + [
+    # the feature-block grid (48 features a block at 128 lanes, 48 with
+    # the one-hot held), and a pass cut into three accumulation ranges
+    (32, 100, None), (64, 100, None), (4, 3, 1100), (64, 3, 1100)])
+def test_two_statistics_are_the_first_two_of_three(monkeypatch, num_cols,
+                                                   F, cap):
+    """A level pass with two statistics a column (grad, hess) and slot
+    targets: each column sums the rows whose new slot is its target, the
+    smaller child of a parent that split, and its ``[F, B, 2]`` cells
+    equal the first two statistics of the three-statistic pass over the
+    same rows as leaf column and selection, bit for bit, at every fold
+    and held shape of a 255-leaf tree's passes; the hessian's integer
+    sums handed back beside them are the scatter-add oracle's; the XLA
+    route reads the same; a parent that did not split sums nothing."""
+    from lightgbm_tpu.ops.histogram import hist_quant_segsum
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu.ops import hist_pallas
+    from lightgbm_tpu.ops.hist_pallas import (NO_TARGET, _ranged_rows,
+                                              held_onehot, hist_fold,
+                                              quant_max_of)
+    if cap is not None:
+        monkeypatch.setattr(hist_pallas, "INT8_HIST_MAX_ROWS", cap)
+        jax.clear_caches()
+    B, N, chunk = 255, 3000, 512
+    lanes = 128
+    assert (hist_fold(2, num_cols, B, lanes)[0],
+            held_onehot(2, num_cols, B, lanes, "int8")) == \
+        TWO_STAT_PASSES[num_cols]
+    assert _ranged_rows(N, chunk)[0] == (1 if cap is None else 3)
+    rng = np.random.RandomState(num_cols + F)
+    bins = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
+    grad = jnp.asarray(rng.randn(N).astype(np.float32))
+    hess = jnp.asarray(rng.rand(N).astype(np.float32))
+    ok = jnp.asarray(rng.rand(N) < 0.8)
+    slot = jnp.asarray(rng.randint(0, 2 * num_cols, N).astype(np.int32))
+    chosen = rng.rand(num_cols) < 0.75
+    chosen[0] = num_cols == 1            # a parent that did not split
+    small_right = rng.rand(num_cols) < 0.5
+    targets = jnp.asarray(np.where(
+        chosen, 2 * np.arange(num_cols) + small_right, NO_TARGET
+    ).astype(np.int32))
+    sel = ok & (slot == targets[slot // 2])
+    scale = quant_max_of(grad, hess, ok)
+    with pltpu.force_tpu_interpret_mode():
+        three = hist_pallas_leafbatch(bins, grad, hess, slot // 2, sel,
+                                      num_cols, B, chunk=chunk,
+                                      quant_max=scale)
+        two, hsum = hist_pallas_leafbatch(
+            bins, grad, hess, slot, ok, num_cols, B, chunk=chunk,
+            quant_max=scale, stats=2, targets=targets)
+    assert two.shape == (num_cols, F, B, 2) and three.shape[-1] == 3
+    assert hsum.shape == (num_cols, F, B) and hsum.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(two), np.asarray(three[..., :2]))
+    xla = hist_quant_xla(bins, grad, hess, slot, ok, num_cols, B,
+                         chunk=chunk, quant_max=scale, stats=2,
+                         targets=targets)
+    # the oracle sums in one accumulator, under the cap it was made for
+    monkeypatch.setattr(hist_pallas, "INT8_HIST_MAX_ROWS",
+                        INT8_HIST_MAX_ROWS)
+    oracle = hist_quant_segsum(bins, grad, hess, slot, ok, num_cols, B,
+                               quant_max=scale, stats=2, targets=targets)
+    for got in (xla, oracle):
+        np.testing.assert_array_equal(np.asarray(two), np.asarray(got[0]))
+        np.testing.assert_array_equal(np.asarray(hsum), np.asarray(got[1]))
+    assert np.asarray(two).any() and np.asarray(hsum).any()
+    np.testing.assert_array_equal(np.asarray(hsum) != 0,
+                                  np.asarray(two[..., 1]) != 0)
+    assert num_cols == 1 or not np.asarray(two[0]).any()
+    if cap is not None:
+        jax.clear_caches()

@@ -92,38 +92,58 @@ def test_held_onehot_rule():
 
 @pytest.mark.parametrize("num_leaves,want", [(255, 2), (127, 1), (63, 0)])
 def test_held_onehot_counter(monkeypatch, num_leaves, want):
-    """hist/pallas_held_onehot, counted once a pass at trace time: a
-    255-leaf level-wise tree has two unfolded passes that turn round
-    (level 6, 32 leaf columns, and level 7, 64), a 127-leaf tree the
-    first of them, a 63-leaf tree, every pass folded, none."""
+    """hist/pallas_held_onehot, counted once a pass at trace time.  With
+    three statistics a column (``min_data_in_leaf=2``) a 255-leaf
+    level-wise tree has two unfolded passes that turn round (level 6, 32
+    leaf columns, and level 7, 64), a 127-leaf tree the first of them, a
+    63-leaf tree, every pass folded, none.  With two
+    (``min_data_in_leaf=1``) level 7's 128 value rows are one tile and
+    keep the one-hot streamed: one fewer where a tree has level 7."""
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.models.grower_unified import grow_tree_depthwise_jit
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        n, f = 4168 + num_leaves, 5       # shapes no other test traces
-        S = jax.ShapeDtypeStruct
-        jax.make_jaxpr(lambda *a: grow_tree_depthwise_jit(
-            *a, compute_dtype="int8", num_leaves=num_leaves,
-            num_bins_max=255, min_data_in_leaf=1,
-            min_sum_hessian_in_leaf=1.0, max_depth=-1, packing=None))(
-            S((f, n), jnp.uint8), S((n,), jnp.float32),
-            S((n,), jnp.float32), S((n,), jnp.bool_), S((f,), jnp.bool_),
-            S((f,), jnp.int32))
-        counters = telemetry.snapshot()["counters"]
-    finally:
-        telemetry.disable()
+
+    def counted(n, min_data_in_leaf):
         telemetry.reset()
+        telemetry.enable()
+        try:
+            f = 5
+            S = jax.ShapeDtypeStruct
+            jax.make_jaxpr(lambda *a: grow_tree_depthwise_jit(
+                *a, compute_dtype="int8", num_leaves=num_leaves,
+                num_bins_max=255, min_data_in_leaf=min_data_in_leaf,
+                min_sum_hessian_in_leaf=1.0, max_depth=-1, packing=None))(
+                S((f, n), jnp.uint8), S((n,), jnp.float32),
+                S((n,), jnp.float32), S((n,), jnp.bool_),
+                S((f,), jnp.bool_), S((f,), jnp.int32))
+            return telemetry.snapshot()["counters"]
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+
+    passes = {255: 8, 127: 7, 63: 6}[num_leaves]
+    # shapes no other test traces
+    counters = counted(4168 + num_leaves, 2)
     assert counters["hist/pallas_held_onehot"] == want
-    assert counters["hist/pallas_int8"] == {255: 8, 127: 7, 63: 6}[num_leaves]
+    assert counters["hist/pallas_int8"] == passes == counters["hist/stats_3"]
+    counters = counted(4169 + num_leaves, 1)
+    assert counters["hist/pallas_held_onehot"] == want - (num_leaves == 255)
+    assert counters["hist/pallas_int8"] == passes == counters["hist/stats_2"]
 
 
-def test_held_onehot_same_trees(monkeypatch):
+@pytest.mark.parametrize("min_data_in_leaf,rows,held", [
+    # two statistics a column: level 6 is traced turned round (64 value
+    # rows; level 7's 128 are one tile and stream against it)
+    ("1", 3001, {0, 64}),
+    # three: levels 6 and 7 are (96 and 192 value rows); rows enough
+    # for trees past 128 leaves of two rows or more
+    ("2", 5003, {0, 96, 192})])
+def test_held_onehot_same_trees(monkeypatch, min_data_in_leaf, rows, held):
     """The grower over the turned-round pass and over the streamed one:
     the model text of three 255-leaf level-wise iterations is byte-equal
     (the kernel's ints being equal does not say so: the float histograms
-    behind it must come out in the same layout)."""
+    behind it must come out in the same layout), with two statistics a
+    leaf column and with three."""
     import lightgbm_tpu as lgb
     from jax.experimental.pallas import tpu as pltpu
     from lightgbm_tpu import costmodel, telemetry
@@ -131,11 +151,12 @@ def test_held_onehot_same_trees(monkeypatch):
     from lightgbm_tpu.models import gbdt as gbdt_mod
     from lightgbm_tpu.ops import hist_pallas
     rng = np.random.RandomState(31)
-    x = rng.randn(3001, 5)                # a shape no other test trains
-    y = ((x[:, 0] * x[:, 1] + 0.5 * x[:, 2] + 0.3 * rng.randn(3001)) > 0
+    x = rng.randn(rows, 5)                # a shape no other test trains
+    y = ((x[:, 0] * x[:, 1] + 0.5 * x[:, 2] + 0.3 * rng.randn(rows)) > 0
          ).astype(np.float32)
     params = {"objective": "binary", "num_leaves": "255", "max_bin": "255",
-              "min_data_in_leaf": "1", "min_sum_hessian_in_leaf": "0.01",
+              "min_data_in_leaf": min_data_in_leaf,
+              "min_sum_hessian_in_leaf": "0.01",
               "num_iterations": "3", "learning_rate": "0.2",
               "grow_policy": "depthwise", "hist_dtype": "int8"}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -179,9 +200,9 @@ def test_held_onehot_same_trees(monkeypatch):
         return "\n".join(t.to_string() for t in booster.models), ruled
 
     text, ruled = train(True)
-    # of the eight passes levels 6 and 7 were traced turned round, and
-    # level 7 decided splits: every tree grew past 128 leaves
-    assert set(ruled) == {0, 96, 192}
+    # the eight passes' held shapes, and level 7 decided splits: every
+    # tree grew past 128 leaves
+    assert set(ruled) == held
     assert all(int(t.split()[0]) > 128
                for t in text.split("num_leaves=")[1:])
     text_streamed, ruled = train(False)

@@ -165,6 +165,23 @@ def test_int8_same_trees_per_iteration(data, tl, extra, policy, compact):
 
 @pytest.mark.parametrize("tl,extra", [
     ("hybrid", {"feature_shards": "2"}),
+    ("voting", {"top_k": "10"})])
+def test_int8_two_statistics_same_trees(data, tl, extra):
+    """The level-wise int8 passes without a count column
+    (``min_data_in_leaf=1`` beside a hessian floor): hybrid's owned
+    blocks and voting's voted features slice each bin's occupancy as
+    they slice the histogram, and grow the serial run's trees."""
+    x, y = data
+    base = {"grow_policy": "depthwise", "leafwise_compact": "false",
+            "hist_dtype": "int8", "min_data_in_leaf": "1"}
+    serial = _serial(x, y, base)
+    par = _train(tl, 4, x, y, dict(base, **extra))
+    _assert_same_trees_across_schedules(serial, par,
+                                        f"{tl} two statistics")
+
+
+@pytest.mark.parametrize("tl,extra", [
+    ("hybrid", {"feature_shards": "2"}),
     ("voting", {"top_k": "10"}),
 ])
 @pytest.mark.parametrize("policy,compact,hd", [

@@ -1,11 +1,12 @@
 """The level-wise grower's row-routing kernel (ops/route_pallas.py).
 
-On the CPU the kernel runs under the Pallas interpreter.  Two things are
-held: its three outputs equal the XLA routing's
+On the CPU the kernel runs under the Pallas interpreter.  Three things
+are held: its three outputs equal the XLA routing's
 (``grower_unified._route_level_xla``) element for element over the
-shapes the grid has to follow, and a tree grown with the kernel under the
-level loop is the tree grown with the XLA routing.  The chip's own
-compile of it is ``tests/test_tpu_compile_programs.py``'s and
+shapes the grid has to follow, the rows each slot sends right are the
+count of the new slot ids over ``row_mask``, and a tree grown with the
+kernel under the level loop is the tree grown with the XLA routing.  The
+chip's own compile of it is ``tests/test_tpu_compile_programs.py``'s and
 ``tests/test_tpu_compile_wide.py``'s.
 """
 import numpy as np
@@ -38,12 +39,11 @@ def _level(F, N, P, seed, packing=None):
     chosen = rng.rand(P) < 0.67
     chosen[-1] = True
     right_leaf = rng.randint(1, L, P).astype(np.int32)
-    small_is_right = rng.rand(P) < 0.5
     feat_part = grower_unified.partition_feature(packing,
                                                  jnp.asarray(feature))
     return [jnp.asarray(a) for a in (bins, slot_id, out_leaf, row_mask)] + [
-        feat_part] + [jnp.asarray(a) for a in (threshold, chosen, right_leaf,
-                                               small_is_right)]
+        feat_part] + [jnp.asarray(a) for a in (threshold, chosen,
+                                               right_leaf)]
 
 
 def _both(args):
@@ -59,10 +59,24 @@ def _both(args):
     return got, want
 
 
-def _assert_equal(got, want):
-    for name, g, w in zip(("slot_id", "out_leaf", "sel"), got, want):
+def _assert_equal(got, want, args=None):
+    for name, g, w in zip(("slot_id", "out_leaf", "right"), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    if args is not None:
+        _assert_counts(got, args)
+
+
+def _assert_counts(got, args):
+    """The rows each slot sent right are the rows of ``row_mask`` that
+    took its odd new slot: a count of the new slot ids."""
+    slot_new, right = np.asarray(got[0]), np.asarray(got[2])
+    mask = np.asarray(args[3])
+    P = args[6].shape[0]
+    counts = np.bincount(slot_new[mask], minlength=2 * P)
+    assert counts.shape == (2 * P,)
+    np.testing.assert_array_equal(right, counts[1::2])
+    assert right.dtype == np.int32
 
 
 @pytest.fixture
@@ -84,11 +98,12 @@ def small_blocks(monkeypatch):
 def test_kernel_equals_xla_routing(small_blocks, F, grid, P):
     N = 2432
     assert route_pallas.route_grid(F, N) == grid
-    got, want = _both(_level(F, N, P, seed=F + P))
-    _assert_equal(got, want)
+    args = _level(F, N, P, seed=F + P)
+    got, want = _both(args)
+    _assert_equal(got, want, args)
     # the case says something: rows went both ways and some stayed
-    slot_id, out_leaf, sel = (np.asarray(a) for a in got)
-    assert sel.any() and not sel.all()
+    slot_id, out_leaf, right = (np.asarray(a) for a in got)
+    assert right.any() and right.sum() < np.asarray(args[3]).sum()
     assert (slot_id % 2 == 1).any() and (slot_id % 2 == 0).any()
 
 
@@ -101,7 +116,7 @@ def test_kernel_equals_xla_routing_at_2000_columns(P):
     assert route_pallas.route_grid(F, N) == (512, 4, 2048, 1)
     args = _level(F, N, P, seed=P)
     got, want = _both(args)
-    _assert_equal(got, want)
+    _assert_equal(got, want, args)
     last = np.asarray(args[1]) == P - 1
     assert last.any() and int(args[4][P - 1]) == 1999
     assert np.asarray(got[0])[last].max() == 2 * (P - 1) + 1
@@ -109,14 +124,36 @@ def test_kernel_equals_xla_routing_at_2000_columns(P):
 
 @pytest.mark.parametrize("N", [1000, 128, 3000])
 def test_rows_that_are_no_whole_number_of_128(small_blocks, N):
-    got, want = _both(_level(28, N, 16, seed=N))
-    _assert_equal(got, want)
+    args = _level(28, N, 16, seed=N)
+    got, want = _both(args)
+    _assert_equal(got, want, args)
+
+
+@pytest.mark.parametrize("P", [1, 4, 16, 128])
+@pytest.mark.parametrize("N", [2433, 5000])
+def test_right_counts_are_the_new_slots_count(small_blocks, N, P):
+    """The kernel's counts at a ragged N, whose tail chunk runs past the
+    table (the rows it reads there are not counted), against
+    ``np.bincount`` of the new slot ids over ``row_mask``: every slot's
+    right child, and its left child as its rows less those."""
+    args = _level(28, N, P, seed=N + P)
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.block_until_ready(
+            jax.jit(route_pallas.route_level_pallas)(*args))
+    _assert_counts(got, args)
+    slot, mask = np.asarray(args[1]), np.asarray(args[3])
+    counts = np.bincount(np.asarray(got[0])[mask], minlength=2 * P)
+    np.testing.assert_array_equal(
+        np.bincount(slot[mask], minlength=P) - np.asarray(got[2]),
+        counts[0::2])
+    assert counts[1::2].any() and counts[0::2].any()
 
 
 def test_more_than_128_slots(small_blocks):
     """A level of 512 slots (1,023 leaves): the table in slabs of 128."""
-    got, want = _both(_level(28, 2432, 512, seed=9))
-    _assert_equal(got, want)
+    args = _level(28, 2432, 512, seed=9)
+    got, want = _both(args)
+    _assert_equal(got, want, args)
     assert np.asarray(got[0]).max() > 2 * 384
 
 
@@ -130,14 +167,14 @@ def test_partition_packing_remap(small_blocks):
     plain = _level(F, 2432, 16, seed=77)
     assert not np.array_equal(np.asarray(args[4]), np.asarray(plain[4]))
     got, want = _both(args)
-    _assert_equal(got, want)
+    _assert_equal(got, want, args)
 
 
 def test_nothing_chosen_moves_no_row(small_blocks):
     args = _level(28, 2432, 16, seed=5)
     args[6] = jnp.zeros_like(args[6])
     got, want = _both(args)
-    _assert_equal(got, want)
+    _assert_equal(got, want, args)
     np.testing.assert_array_equal(np.asarray(got[0]),
                                   2 * np.asarray(args[1]))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(args[2]))

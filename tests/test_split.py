@@ -1,6 +1,7 @@
 """Split-search tests against a literal NumPy port of the reference scan
 (feature_histogram.hpp:106-165)."""
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.split import find_best_split, K_EPSILON
@@ -123,3 +124,62 @@ def test_left_right_outputs_consistent():
         np.testing.assert_allclose(float(res.left_sum_grad), lg, rtol=1e-4)
         np.testing.assert_allclose(float(res.left_output),
                                    -lg / (lh + K_EPSILON), rtol=1e-3)
+
+
+def _counted_case(seed, F=4, B=16, n=400):
+    """A [F, B, 3] histogram of exact counts over one row population,
+    with bins that hold no row, and its leaf totals."""
+    rng = np.random.RandomState(seed)
+    hist = np.zeros((F, B, 3), dtype=np.float64)
+    g = rng.randn(n)
+    h = rng.rand(n) + 0.05
+    for f in range(F):
+        # a feature's rows in a few of its bins: empty sides at both ends
+        lo, hi = sorted(rng.choice(B, 2, replace=False))
+        bins = rng.randint(lo, hi + 1, size=n)
+        for b_, g_, h_ in zip(bins, g, h):
+            hist[f, b_] += [g_, h_, 1.0]
+    return (jnp.asarray(hist, jnp.float32), jnp.float32(hist[0, :, 0].sum()),
+            jnp.float32(hist[0, :, 1].sum()), jnp.float32(n))
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("min_data,min_hess", [(0.0, 1e-3), (1.0, 0.5)])
+def test_search_without_counts_is_the_count_tests(seed, min_data, min_hess):
+    """Where ``min_data_in_leaf <= 1`` and the floor is above K_EPSILON,
+    the search over grad and hess alone, reading each bin's occupancy,
+    finds the split the search with counts finds: every field of the
+    result but the counts, bit for bit."""
+    hist, sg, sh, n = _counted_case(seed)
+    F, B = hist.shape[:2]
+    args = (jnp.full((F,), B, jnp.int32), jnp.ones((F,), bool),
+            min_data, min_hess)
+    three = find_best_split(hist, sg, sh, n, *args)
+    two = find_best_split(hist[..., :2], sg, sh, hist[..., 2] > 0, *args)
+    assert two.left_count is None and two.right_count is None
+    assert np.isfinite(float(three.gain))
+    for name in three._fields:
+        if not name.endswith("_count"):
+            np.testing.assert_array_equal(np.asarray(getattr(two, name)),
+                                          np.asarray(getattr(three, name)),
+                                          name)
+
+
+def test_search_without_counts_refuses_an_empty_side_of_residue():
+    """A leaf whose rows sit in bins 0 - 4, every bin of the same grad to
+    hess ratio, so that no real split gains; its totals carry a rounding
+    residue of 0.01 hess and -0.003 grad, as a sibling derived by float32
+    subtraction does.  A threshold of 4 or more leaves the right side no
+    row and the residue, which passes a floor of 1e-3 and outgains every
+    real split: the search reading occupancy refuses it, one that took
+    every bin as occupied would not."""
+    B = 8
+    hist = np.zeros((1, B, 2), np.float32)
+    hist[0, :5] = [-1.0, 1.0]
+    occupied = jnp.asarray(hist[..., 1] > 0)
+    args = (jnp.asarray(hist), jnp.float32(-5.003), jnp.float32(5.01))
+    rest = (jnp.full((1,), B, jnp.int32), jnp.ones((1,), bool), 1.0, 1e-3)
+    res = find_best_split(*args, occupied, *rest)
+    assert (not np.isfinite(float(res.gain))) or int(res.threshold) < 4
+    blind = find_best_split(*args, jnp.ones((1, B), bool), *rest)
+    assert int(blind.threshold) >= 4 and float(blind.gain) > 1e-3

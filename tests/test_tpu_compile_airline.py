@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, _captured_chunk_program, _cell_config, _check, _like,
     no_persistent_cache, one_chip, _operand_producers, _pass_rules,
-    _row_relayouts, _shape, _tiny_binary_dataset, topo)
+    _root_hist_layouts, _row_relayouts, _shape, _tiny_binary_dataset,
+    topo)
 
 AIRLINE_F, AIRLINE_N = 13, 7 * 8022 * 2048   # 115,000,000 rows as padded
 
@@ -60,12 +61,18 @@ def test_fused_chunk_program_compiles_at_the_airline_cell(
     configuration's own ``key=value`` pairs, 13 columns, its argument tree
     re-shaped to the 115,000,000 rows.  Seven accumulation ranges a pass
     (every histogram custom call hands on ``s32[7, 13, ...]``), each
-    pass's ``[4, N]`` int8 operand made by one fusion with no row
-    relayout around it, and the size argument of the cell: the compiler
-    counts 7.38 GB of temporaries and 3.22 GB of arguments, 64 and 28
-    bytes a row (PR 39; 9.22 GB, 80 bytes, with the 28 relayouts a tree
-    of PR 36 - 38), and a per-row float32 temporary too many would be
-    another 0.46 GB."""
+    pass's ``[3, N]`` int8 operand (grad and hess codes and the row's
+    slot: ``min_data_in_leaf=1`` beside a hessian floor builds no count
+    column) made by one fusion with no row relayout around it, the root's
+    float histogram laid out as the three-statistic program lays out its
+    own (``{2,1,3,0}``: another layout rounded the root's gains otherwise,
+    PERF.md section 6, PR 44), and the size argument of the cell: the
+    compiler counts 7.84 GB of temporaries and 3.22 GB of arguments, 68
+    and 28 bytes a row, and a peak of 10.70 GB, 93 bytes a row (7.38 GB,
+    64 bytes, and a peak of 11.16 GB with the count column and the
+    level's column ids and row selection; 9.22 GB, 80 bytes, with the 28
+    relayouts a tree before the operand was one fusion), and a per-row
+    float32 temporary too many would be another 0.46 GB."""
     conf = _cell_config("airline-levelwise-int8")
     rows, columns = conf["rows"], conf["features"]
     assert (rows, columns) == (115_000_000, 13)
@@ -83,12 +90,14 @@ def test_fused_chunk_program_compiles_at_the_airline_cell(
     assert len(calls) == 8 and all(c.startswith("s32[7,13,") for c in calls)
     assert "/histogram/range_sum/" in text
     assert _row_relayouts(text, rows) == []
-    producers = _operand_producers(text, rows)
+    producers = _operand_producers(text, rows, stats=2)
     assert len(producers) == 8 and all(
         "/histogram/" in line for _name, line in producers), producers
+    assert _root_hist_layouts(text, columns) == {"{2,1,3,0:T(8,128)}"}
     per_row = (ma.temp_size_in_bytes + ma.argument_size_in_bytes) / rows
-    assert 88 < per_row < 97, (ma.temp_size_in_bytes,
-                               ma.argument_size_in_bytes)
+    assert 92 < per_row < 101, (ma.temp_size_in_bytes,
+                                ma.argument_size_in_bytes)
+    assert ma.peak_memory_in_bytes / rows < 95, ma.peak_memory_in_bytes
     # over the 4.00 GiB line on its bytes alone, and 5% of the chip free
     assert ma.temp_size_in_bytes + ma.argument_size_in_bytes > 4 << 30
     assert (ma.temp_size_in_bytes + ma.argument_size_in_bytes

@@ -14,8 +14,8 @@ from tpu_described import (  # noqa: F401 (fixtures)
     as_tpu, B, _Captured, _captured_chunk_program, _cell_config, _check, F,
     _grow_args, _GROW_KW, HBM_BYTES, LEAVES, _like, _lower_route_kernel, N,
     no_persistent_cache, one_chip, _operand_producers, _range_passes,
-    _row_relayouts, _shape, _tiny_binary_dataset, topo, _TracedCounters,
-    _unlabelled)
+    _root_hist_layouts, _row_relayouts, _shape, _tiny_binary_dataset, topo,
+    _TracedCounters, _unlabelled)
 
 NARROW_N = 10_502_144    # benchmarks/configs/higgs-levelwise-int8, padded
 
@@ -156,11 +156,14 @@ def test_chunk_program_at_the_narrow_cell_relayouts_no_int8_row(
         one_chip, as_tpu, monkeypatch):
     """The program of ``higgs-levelwise-int8.train`` at its own
     10,500,000 rows, no whole number of the 1-D int8 layout's tiles.
-    Each pass's ``[4, N]`` operand is one fusion under ``histogram``, and
+    Each pass's ``[3, N]`` operand (no count row: ``min_data_in_leaf=0``
+    beside a hessian floor) is one fusion under ``histogram``, and
     the compiler moves no int8 row: until PR 39 the rows were made apart
     and stacked, and 28 one-trip loops a tree carried each into an
     ``s8[1,1,N]`` (12.7 ms an iteration on the chip, PERF.md section 5);
-    the temporaries were 1.136 GB, now 0.976."""
+    the temporaries were 1.136 GB, 0.976 with the count row, now 0.974.
+    The root's float histogram is laid out as the three-statistic
+    program lays out its own, row-major (PERF.md section 6, PR 44)."""
     conf = _cell_config("higgs-levelwise-int8")
     rows, columns = conf["rows"], conf["features"]
     assert (rows, columns) == (10_500_000, F)
@@ -173,9 +176,10 @@ def test_chunk_program_at_the_narrow_cell_relayouts_no_int8_row(
     ma = _check(compiled, custom_call=True)
     text = compiled.as_text()
     assert _row_relayouts(text, rows) == []
-    producers = _operand_producers(text, rows)
+    producers = _operand_producers(text, rows, stats=2)
     assert len(producers) == 8 and all(
         "/histogram/" in line for _name, line in producers), producers
+    assert _root_hist_layouts(text, columns) == {"{3,2,1,0:T(8,128)}"}
     assert 0.9e9 < ma.temp_size_in_bytes < 1.05e9, ma.temp_size_in_bytes
 
 

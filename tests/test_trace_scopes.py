@@ -302,12 +302,12 @@ def test_kernel_locations_do_not_depend_on_telemetry(monkeypatch):
     seen = []
     real = route_pallas.route_pallas_raw
 
-    def spy(*args):
+    def spy(*args, **kwargs):
         frames = list(source_info_util.user_frames(
             source_info_util.current().traceback))
         seen.append([(f.file_name, f.function_name, f.start_line)
                      for f in frames[1:kept + 1]])      # [0] is this spy
-        return real(*args)
+        return real(*args, **kwargs)
 
     monkeypatch.setattr(route_pallas, "route_pallas_raw", spy)
     monkeypatch.setattr(grower_unified, "route_pallas_ok", lambda *a: True)

@@ -115,24 +115,48 @@ def test_feature_blocks_fit_the_scoped_vmem():
 
 # ------------------------------------------- the program and the reference
 
+_WIDE_RUNS = {}
+
+
+def _wide_run(min_data_in_leaf="1"):
+    """One run of the benchmark's own harness on the wide cell cut to
+    2,048 rows and 104 columns (``bench_cut.run_cut_cell``), with the
+    cell's ``min_data_in_leaf`` of 1 or another laid over it; once a
+    module and value."""
+    from bench_cut import run_cut_cell
+    if min_data_in_leaf not in _WIDE_RUNS:
+        _WIDE_RUNS[min_data_in_leaf] = run_cut_cell(
+            CELL, 3000000019, ROWS, COLUMNS,
+            params=({"min_data_in_leaf": min_data_in_leaf}
+                    if min_data_in_leaf != "1" else None))
+    return _WIDE_RUNS[min_data_in_leaf]
+
+
 @pytest.fixture(scope="module")
 def wide_run():
-    """One run of the benchmark's own harness on the wide cell cut to
-    2,048 rows and 104 columns (``bench_cut.run_cut_cell``)."""
-    from bench_cut import run_cut_cell
-    return run_cut_cell(CELL, 3000000019, ROWS, COLUMNS)
+    yield _wide_run()
+    _WIDE_RUNS.clear()
 
 
-def test_wide_program_took_the_feature_block_grid(wide_run):
-    _line, counters = wide_run
-    # one traced tree: seven passes of 128 lanes in 3 blocks of 40, the
-    # 64-leaf pass of 192 lanes in 4 of 32; the two unfolded passes (32
-    # and 64 leaves) hold the one-hot; no pass left the Pallas route
+# (statistics a leaf column, feature blocks a tree, passes that hold the
+# one-hot): the cell's ``min_data_in_leaf=1`` builds two, grad and hess,
+# and all eight passes are 128 lanes in 3 blocks of 40, the 64-leaf one
+# too (128 value rows), which streams the one-hot; 2 keeps the count
+# column, and the 64-leaf pass of 192 value rows is 192 lanes in 4
+# blocks of 32 and holds it, as the 32-leaf pass does in both
+@pytest.mark.parametrize("min_data_in_leaf,stats,blocks,held", [
+    ("1", 2, 8 * 3, 1), ("2", 3, 7 * 3 + 4, 2)])
+def test_wide_program_took_the_feature_block_grid(
+        wide_run, min_data_in_leaf, stats, blocks, held):
+    _line, counters = _wide_run(min_data_in_leaf)
+    # one traced tree; no pass left the Pallas route
     passes = sum(v for k, v in counters.items()
                  if k.startswith("hist/pallas_fold_"))
     assert passes and passes % 8 == 0
-    assert counters["hist/pallas_fblocks"] == passes // 8 * (7 * 3 + 4)
-    assert counters["hist/pallas_held_onehot"] == passes // 8 * 2
+    assert counters["hist/stats_%d" % stats] == passes
+    assert "hist/stats_%d" % (5 - stats) not in counters
+    assert counters["hist/pallas_fblocks"] == passes // 8 * blocks
+    assert counters["hist/pallas_held_onehot"] == passes // 8 * held
     assert "hist/xla_int_kernel" not in counters
     # and every level's row routing took the kernel
     assert counters["partition/route_pallas"] == passes

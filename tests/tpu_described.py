@@ -117,20 +117,24 @@ def _pass_rules(dtype, lanes, stats, num_cols):
             held_onehot(stats, num_cols, 256, lanes, dtype))
 
 
-def _lower_kernel(one_chip, features, dtype, lanes, stats, num_cols):
+def _lower_kernel(one_chip, features, dtype, lanes, stats, num_cols,
+                  targets=False):
     """The raw kernel, traced anew (a wrapper of its own, so that no
-    cached trace answers) and lowered for the described chip."""
+    cached trace answers) and lowered for the described chip; with
+    ``targets``, a level pass's: each leaf column's slot a [num_cols]
+    operand."""
     from lightgbm_tpu.ops.hist_pallas import _hist_pallas_raw_fn
     fold, gw, held = _pass_rules(dtype, lanes, stats, num_cols)
 
-    def fresh(bins, packed):
-        return _hist_pallas_raw_fn(bins, packed, B=256, chunk=2048,
+    def fresh(bins, packed, *tgt):
+        return _hist_pallas_raw_fn(bins, packed, *tgt, B=256, chunk=2048,
                                    dtype=dtype, lanes=lanes, stats=stats,
                                    fold=fold, gw=gw, held=held)
     return jax.jit(fresh).lower(
         _shape(one_chip, (features, 2048 * 4), jnp.int8),
         _shape(one_chip, (stats + 1, 2048 * 4),
-               jnp.bfloat16 if dtype == "bf16v" else jnp.int8))
+               jnp.bfloat16 if dtype == "bf16v" else jnp.int8),
+        *([_shape(one_chip, (num_cols,), jnp.int32)] if targets else []))
 
 
 def _lower_route_kernel(one_chip, features, rows, slots):
@@ -138,8 +142,7 @@ def _lower_route_kernel(one_chip, features, rows, slots):
     traced anew and lowered for the described chip."""
     from lightgbm_tpu.ops.route_pallas import route_level_pallas
     per_slot = [_shape(one_chip, (slots,), dt)
-                for dt in (jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
-                           jnp.bool_)]
+                for dt in (jnp.int32, jnp.int32, jnp.bool_, jnp.int32)]
     return jax.jit(lambda *a: route_level_pallas(*a)).lower(
         _shape(one_chip, (features, rows), jnp.uint8),   # partition_bins
         _shape(one_chip, (rows,), jnp.int32),            # slot_id
@@ -268,12 +271,27 @@ def _row_relayouts(text, rows):
             if row in line and re.search(r" (while|broadcast)\(", line)]
 
 
-def _operand_producers(text, rows):
-    """The fusions that make the int8 histogram kernel's ``[4, rows]``
-    operand, as ``(name, line)``."""
+def _operand_producers(text, rows, stats=3):
+    """The fusions that make the int8 histogram kernel's
+    ``[stats + 1, rows]`` operand, as ``(name, line)``: ``[4, rows]`` with
+    the count row, ``[3, rows]`` for the level-wise passes without it."""
     import re
     return [(found.group(1), found.group(0)) for found in re.finditer(
-        r"%%([\w.\-]+) = s8\[4,%d\]\S* fusion\([^\n]*" % rows, text)]
+        r"%%([\w.\-]+) = s8\[%d,%d\]\S* fusion\([^\n]*"
+        % (stats + 1, rows), text)]
+
+
+def _root_hist_layouts(text, features, bins=254, stats=2):
+    """The layouts a compiled level-wise program gives its root's float32
+    histogram, ``f32[1, features, bins, stats]``, memory space aside.
+    The split search's sums over a leaf's bins round by its layout: the
+    root's, laid out otherwise than the three-statistic program lays out
+    its own, moved the last place of Airline's gains (PERF.md section 6,
+    PR 44)."""
+    import re
+    return {re.sub(r"S\(\d+\)", "", found)
+            for found in re.findall(r"f32\[1,%d,%d,%d\](\{[^}]*\})"
+                                    % (features, bins, stats), text)}
 
 
 class _TracedCounters(dict):
