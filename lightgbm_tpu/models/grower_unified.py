@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.histogram import build_histogram, histogram_leafbatch
+from ..ops.leafid_pallas import replay_leaf_ids
 from ..ops.route_pallas import route_level_pallas, route_pallas_ok
 from ..ops.split import SplitResult, find_best_split
 from ..telemetry import phase_scope
@@ -1091,7 +1092,19 @@ class _CompactState(NamedTuple):
     cand_right_g: jax.Array
     cand_right_h: jax.Array
     leaf_depth: jax.Array       # [L] i32
+    split_leaf: jax.Array       # [L-1] i32 — the leaf node n split
     done: jax.Array             # bool
+
+
+def _split_leaf_ids(leaf_ids, bins, row, leaf, threshold, new_leaf):
+    """The original-order leaf ids after one split: leaf ``leaf``'s rows
+    whose code on table row ``row`` exceeds ``threshold`` go to
+    ``new_leaf``.  One pass over every row; the compacted grower's route
+    where the replay kernel is not eligible, and the kernel's oracle."""
+    code = jax.lax.dynamic_index_in_dim(
+        bins, row, axis=0, keepdims=False).astype(jnp.int32)
+    return jnp.where((leaf_ids == leaf) & (code > threshold), new_leaf,
+                     leaf_ids)
 
 
 def _run_if(pred, fn, operand):
@@ -1146,8 +1159,12 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
     B = num_bins_max
     f32 = jnp.float32
     ppack = partition_packing if partition_packing is not None else packing
-    c2p_arr = (jnp.asarray(ppack.c2p, jnp.int32)
-               if ppack is not None and len(ppack.widths) > 1 else None)
+    # the original-order leaf ids: on a TPU one replay of the tree's splits
+    # after the loop (ops/leafid_pallas.py), elsewhere a pass over every
+    # row at each split; counted once a program, where the route is chosen
+    replay = route_pallas_ok(bins.dtype, B)
+    _tl.count("partition/leafids_pallas" if replay
+              else "partition/leafids_xla")
     table = bucket_table(N, min_width=max(BLOCK, (-(-N // BLOCK) * BLOCK)
                                           >> 9))
     P = table[0]
@@ -1246,7 +1263,9 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
             leaf_parent=jnp.full((L,), -1, jnp.int32),
             leaf_value=zeros_f,
             leaf_count=zeros_i.at[0].set(root_c.astype(jnp.int32)),
-            leaf_ids=jnp.zeros((N,), jnp.int32),
+            # under the replay the loop carries no row-long array: the
+            # ids are made after it
+            leaf_ids=jnp.zeros((0 if replay else N,), jnp.int32),
         )
         state = _CompactState(
             tree=tree,
@@ -1270,6 +1289,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
             cand_right_g=zeros_f.at[0].set(root_best.right_sum_grad),
             cand_right_h=zeros_f.at[0].set(root_best.right_sum_hess),
             leaf_depth=zeros_i.at[0].set(1),
+            split_leaf=jnp.zeros((L - 1,), jnp.int32),
             done=jnp.asarray(False),
         )
 
@@ -1282,7 +1302,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
             # lanes the kernel will read; the range itself stays where
             # it lies
             cs, lanes = range_origin(pane, start, W)
-            pfeat = feat if c2p_arr is None else c2p_arr[feat]
+            pfeat = partition_feature(ppack, feat)
             fbin = jax.lax.dynamic_slice(
                 pane, (side, pfeat, cs), (1, 1, lanes)).reshape(lanes)
             fbin = fbin.astype(jnp.int32) & 255   # int8 pane -> uint8 bin
@@ -1365,12 +1385,12 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
 
             # --- original-order leaf ids (score updates need them; the
             # pane's permutation never leaves this function)
-            with phase_scope("row_route"):
-                ofeat = feat if c2p_arr is None else c2p_arr[feat]
-                obin = jax.lax.dynamic_index_in_dim(
-                    bins, ofeat, axis=0, keepdims=False).astype(jnp.int32)
-                leaf_ids = jnp.where((tree.leaf_ids == bl) & (obin > thr),
-                                     new_leaf, tree.leaf_ids)
+            leaf_ids = tree.leaf_ids
+            if not replay:
+                with phase_scope("row_route"):
+                    leaf_ids = _split_leaf_ids(
+                        leaf_ids, bins, partition_feature(ppack, feat), bl,
+                        thr, new_leaf)
 
             # --- partition the parent's lane range at ITS tier (local,
             # collective-free: shards may take different branches).  One
@@ -1497,6 +1517,7 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
                                      lbest.right_sum_hess,
                                      rbest.right_sum_hess),
                     leaf_depth=put(state.leaf_depth, depth, depth),
+                    split_leaf=state.split_leaf.at[node].set(bl),
                 )
 
         # profiler alignment (ISSUE 2): label the compacted split body so
@@ -1506,7 +1527,16 @@ def _grow_leafcompact(bins, grad, hess, row_mask, feature_mask, num_bins,
         with phase_scope("split_find"):
             return state._replace(done=state.done | ~should_split)
 
-    return jax.lax.fori_loop(0, L - 1, body, state).tree
+    state = jax.lax.fori_loop(0, L - 1, body, state)
+    if not replay:
+        return state.tree
+    tree = state.tree
+    with phase_scope("row_route"):
+        leaf_ids = replay_leaf_ids(
+            bins, tree.num_leaves, state.split_leaf,
+            partition_feature(ppack, tree.split_feature), tree.threshold_bin,
+            interpret=interpret)
+    return tree._replace(leaf_ids=leaf_ids)
 
 
 # ======================================================= jitted wrappers

@@ -329,6 +329,8 @@ COUNTER_FAMILIES = (
     "partition/dma_serial",
     "partition/env_no_pallas",
     "partition/in_pane",          # kernels that read and write the pane
+    "partition/leafids_pallas",   # compacted trees' leaf ids replayed by the kernel, once a program
+    "partition/leafids_xla",      # ... or updated by XLA at every split, once a program
     "partition/pallas",
     "partition/pallas_eligible",
     "partition/pallas_ineligible",

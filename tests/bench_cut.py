@@ -68,10 +68,13 @@ def run_cut_cell(cell, seed, rows, columns, fence=False, fault=None,
     if tpu_routes:
         mp.setattr(jax, "default_backend", lambda: "tpu")
     # the TPU interpreter keeps the partition kernels' aliased pane in
-    # the call's own buffer only as an ANY argument (compact.PANE_SPACE)
+    # the call's own buffer only as an ANY argument (compact.PANE_SPACE),
+    # and has no view of a ref for the leaf-id replay's words
+    # (leafid_pallas.WORDS_BY_VIEW)
     from jax.experimental import pallas as pl
-    from lightgbm_tpu.ops import compact
+    from lightgbm_tpu.ops import compact, leafid_pallas
     mp.setattr(compact, "PANE_SPACE", pl.ANY)
+    mp.setattr(leafid_pallas, "WORDS_BY_VIEW", False)
     telemetry.reset()
     telemetry.enable(fence=fence)
     try:

@@ -8,6 +8,7 @@ mode on CPU) vs the stable-argsort XLA oracle.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.compact import (BLOCK, bucket_table, pack_planes,
@@ -398,6 +399,79 @@ def test_compact_grower_reads_each_child_from_its_side():
         np.testing.assert_array_equal(np.asarray(getattr(t1, field)),
                                       np.asarray(getattr(t2, field)),
                                       err_msg=field)
+
+
+def _walk(tree, bins):
+    """Every row's leaf by walking the tree from its root."""
+    left, right = np.asarray(tree.left_child), np.asarray(tree.right_child)
+    feature = np.asarray(tree.split_feature)
+    thr = np.asarray(tree.threshold_bin)
+    node = np.zeros(bins.shape[1], np.int64)
+    inner = np.ones(bins.shape[1], bool)
+    while inner.any():
+        at = node[inner]
+        go = bins[feature[at], np.flatnonzero(inner)] > thr[at]
+        node[inner] = np.where(go, right[at], left[at])
+        inner = node >= 0
+    return (~node).astype(np.int32)
+
+
+@pytest.mark.parametrize("bagging", [False, True])
+def test_compact_grower_leaf_ids_from_the_replay_kernel(monkeypatch,
+                                                        bagging):
+    """A tree grown with its leaf ids replayed after the split loop by
+    ``ops/leafid_pallas.py`` (the interpreter, in chunks of 1,024 rows: a
+    ragged tail at 5,000) is the tree grown with the per-split XLA update,
+    every array bit for bit, and its ids are the tree's own walk for
+    every row, the rows outside ``row_mask`` too; each program counts the
+    route it took, once."""
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.models import grower_unified
+    from lightgbm_tpu.ops import leafid_pallas
+    monkeypatch.setattr(leafid_pallas, "MAX_ROWS", 1024)
+    rng = np.random.RandomState(31)
+    N, F, L = 5000, 6, 63
+    x = rng.randn(N, F)
+    bins = np.stack([np.clip((np.argsort(np.argsort(x[:, k])) * 255) // N,
+                             0, 254) for k in range(F)]).astype(np.uint8)
+    grad = (x[:, 0] * x[:, 1] + 0.5 * x[:, 2]
+            + rng.randn(N)).astype(np.float32)
+    hess = rng.uniform(0.5, 1.0, N).astype(np.float32)
+    row_mask = ~(rng.rand(N) < 0.4) if bagging else np.ones(N, bool)
+    args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.asarray(row_mask), jnp.ones((F,), bool),
+            jnp.full((F,), 255, jnp.int32))
+    kw = dict(num_leaves=L, num_bins_max=255, min_data_in_leaf=20,
+              min_sum_hessian_in_leaf=1e-3, max_depth=-1,
+              compute_dtype=jnp.float32, packing=None, interpret=True)
+
+    def grow(kernel):
+        monkeypatch.setattr(grower_unified, "route_pallas_ok",
+                            lambda *a: kernel)
+        telemetry.reset()
+        telemetry.enable()
+        try:
+            # a wrapper of its own: no trace made before may answer
+            tree = jax.jit(lambda *a: grower_unified
+                           ._grow_tree_leafcompact_fn(*a, **kw))(*args)
+            return (jax.tree.map(np.asarray, tree),
+                    dict(telemetry.snapshot()["counters"]))
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+
+    tree, counters = grow(True)
+    assert counters["partition/leafids_pallas"] == 1
+    assert "partition/leafids_xla" not in counters
+    tree_xla, counters = grow(False)
+    assert counters["partition/leafids_xla"] == 1
+    assert "partition/leafids_pallas" not in counters
+    assert int(tree.num_leaves) == L
+    for name, got, want in zip(tree._fields, tree, tree_xla):
+        np.testing.assert_array_equal(got, want, name)
+    np.testing.assert_array_equal(tree.leaf_ids, _walk(tree, bins))
+    if bagging:
+        assert len(np.unique(tree.leaf_ids[~row_mask])) > L // 2
 
 
 def _manual_replay(bins, grad, hess, row_mask, num_bins, feature_mask, *,

@@ -142,10 +142,12 @@ def test_compact_grower_grows_the_plain_growers_tree(
             interpret=True)
     if num_bin == 255:
         from jax.experimental import pallas as pl
-        from lightgbm_tpu.ops import compact
+        from lightgbm_tpu.ops import compact, leafid_pallas
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        # as bench_cut.run_cut_cell: the TPU interpreter's ANY
+        # as bench_cut.run_cut_cell: the TPU interpreter's ANY, and no
+        # view of a ref
         monkeypatch.setattr(compact, "PANE_SPACE", pl.ANY)
+        monkeypatch.setattr(leafid_pallas, "WORDS_BY_VIEW", False)
         telemetry.reset()
         telemetry.enable(fence=True)
         try:
@@ -160,6 +162,8 @@ def test_compact_grower_grows_the_plain_growers_tree(
             "hist/pallas_fold_8"] >= 2
         assert "hist/pallas_fold_1" not in counters
         assert "hist/xla_einsum" not in counters
+        # the leaf ids replayed after the split loop
+        assert counters["partition/leafids_pallas"] == 1
     else:
         tree = grow()
     splits, values, counts = plain_best_first(
@@ -214,6 +218,9 @@ def test_leafwise_program_took_the_kernels(leafwise_run):
     assert counters["partition/in_pane"] == counters["partition/pallas"]
     assert "partition/xla" not in counters
     assert "partition/wide_f_fallback" not in counters
+    # the leaf ids replayed once a tree program after its split loop
+    assert counters["partition/leafids_pallas"] >= 1
+    assert "partition/leafids_xla" not in counters
     assert counters["hist/pallas_f32"] >= 2
     assert "hist/xla_einsum" not in counters
     # every float pass (one leaf column of five statistics, 255 bins)

@@ -103,6 +103,8 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
             partition_overlap=True, **kw).compile()
     assert traced["partition/in_pane"] == traced["partition/pallas"] == 9
     assert traced["partition/pallas_rblocks"] == 27
+    assert traced["partition/leafids_pallas"] == 1
+    assert "partition/leafids_xla" not in traced
     _cell_size(_check(compiled, custom_call=True))
     text = _LEAFCOMPACT["text"] = compiled.as_text()
     assert _range_passes(text, WIDE_F) == []
@@ -138,6 +140,17 @@ def test_grow_leafcompact_f32_compiles_on_the_wide_table(one_chip, as_tpu):
 _LEAFCOMPACT = {}   # the tree program's compiled text, once a module
 
 
+def _leafcompact_text(one_chip):
+    if not _LEAFCOMPACT:
+        from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
+        kw = dict(_GROW_KW, min_data_in_leaf=1,
+                  min_sum_hessian_in_leaf=100.0)
+        _LEAFCOMPACT["text"] = grow_tree_leafcompact.lower(
+            *_grow_args(one_chip, WIDE_N, WIDE_F), use_pallas_partition=True,
+            partition_overlap=True, **kw).compile().as_text()
+    return _LEAFCOMPACT["text"]
+
+
 def test_the_compiler_put_nothing_of_a_panes_size_into_grow_leafcompact(
         one_chip, as_tpu):
     """The number form of "XLA put no copy in" (PR 37): of what the
@@ -150,14 +163,7 @@ def test_the_compiler_put_nothing_of_a_panes_size_into_grow_leafcompact(
     from lightgbm_tpu import costmodel
     from lightgbm_tpu.ops import compact
     from lightgbm_tpu.telemetry import DEVICE_PHASES
-    if not _LEAFCOMPACT:
-        from lightgbm_tpu.models.grower_unified import grow_tree_leafcompact
-        kw = dict(_GROW_KW, min_data_in_leaf=1,
-                  min_sum_hessian_in_leaf=100.0)
-        _LEAFCOMPACT["text"] = grow_tree_leafcompact.lower(
-            *_grow_args(one_chip, WIDE_N, WIDE_F), use_pallas_partition=True,
-            partition_overlap=True, **kw).compile().as_text()
-    text = _LEAFCOMPACT["text"]
+    text = _leafcompact_text(one_chip)
     labels = costmodel.label_unscoped_ops(text)
     assert _unlabelled(text, labels) == []
     assert {found[0] for found in labels.values()} <= set(
@@ -173,6 +179,32 @@ def test_the_compiler_put_nothing_of_a_panes_size_into_grow_leafcompact(
     # the table's int8 view for the pane's packing, once a tree
     assert any(found[0] == "partition" and found[1] == "fusion"
                for found in labels.values())
+
+
+def test_leaf_ids_are_replayed_once_after_the_split_loop(one_chip, as_tpu):
+    """The original-order leaf ids of one tree on the wide table: no
+    operation over the table's rows under ``row_route`` inside the split
+    loop (the parent compared every row at each of 254 splits, and copied
+    the ids into VMEM and back around it), and one call of the replay
+    kernel after the loop, which reads the 8-bit table as it is laid out:
+    no copy or conversion of it in front of the call."""
+    text = _leafcompact_text(one_chip)
+    rows = re.compile(r"\[(\d+,)*(%d|%d)\]" % (WIDE_N, WIDE_N_PADDED))
+    in_loop = [line.strip()[:160] for line in text.splitlines()
+               if "/while/body/" in line and "/row_route/" in line
+               and rows.search(line.split(" = ", 1)[-1].split("(", 1)[0])]
+    assert in_loop == [], in_loop
+    calls = re.findall(r"= s32\[(\d+),128\]\S* custom-call\(([^)]*)\)"
+                       r'[^\n]*"tpu_custom_call"[^\n]*op_name="([^"]*)"',
+                       text)
+    replay = [c for c in calls if "/row_route/jit(_replay_fn)" in c[2]]
+    assert len(replay) == 1, calls
+    tiles, operands, op_name = replay[0]
+    assert int(tiles) * 128 == WIDE_N      # the ids as [N / 128, 128]
+    assert "/while/" not in op_name
+    table = operands.split(", ")[-1]
+    assert re.search(r"%s = u8\[%d,%d\]\S* parameter\(" % (
+        re.escape(table), WIDE_F, WIDE_N), text), table
 
 
 _F32_BUFFER = re.compile(r"f32\[([\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\)")
